@@ -672,10 +672,10 @@ class HTTPApi:
                 "healthy": app.ring.healthy_count(),
                 "replication_factor": app.ring.rf,
             },
-            # accelerator health at a glance: backend, device count,
-            # age of the last successful dispatch — the wedge-vs-idle
-            # signal bench r04/r05 lacked (never initializes a backend
-            # on processes that haven't touched the device)
+            # accelerator health at a glance: backend, device kind and
+            # count, per-device memory, age of the last successful
+            # dispatch — the hung-vs-idle signal (never initializes a
+            # backend on processes that haven't touched the device)
             "device": device_status(),
             # search freshness at a glance (the write-path twin of the
             # device block): per-tenant staleness, oldest unflushed

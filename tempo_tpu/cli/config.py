@@ -171,11 +171,6 @@ def load_config(path: str | None = None, text: str | None = None) -> tuple[AppCo
             "search_structural_bucket_max_nodes", 16),
         search_structural_remainder_pages=storage.get(
             "search_structural_remainder_pages", False),
-        # persistent XLA compile cache for the search kernels
-        # (docs/search-packed-residency.md#persistent-compile-cache);
-        # empty = off, hits surface as jit_cache_events{result=persisted}
-        search_compile_cache_dir=storage.get(
-            "search_compile_cache_dir", ""),
         # owner-routed HBM (docs/search-hbm-ownership.md): consistent-
         # hash block-group ownership across the fleet; false (default)
         # is a true noop, members/self auto-derive from the multihost
@@ -217,8 +212,9 @@ def load_config(path: str | None = None, text: str | None = None) -> tuple[AppCo
         search_breaker_cooldown_s=storage.get(
             "search_breaker_cooldown_s", 5.0),
         robustness_faults=storage.get("robustness_faults", ""),
-        # restartable host state (header snapshot + persistent XLA
-        # compile cache); absent = auto (<wal_dir>/host-state), "" = off
+        # restartable host state (header snapshot under this directory
+        # + persistent XLA compile cache where utils.jaxenv places it);
+        # absent = auto (<wal_dir>/host-state), "" = off
         host_state_dir=storage.get("host_state_dir"),
     )
     cfg = AppConfig(

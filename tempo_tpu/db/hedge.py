@@ -28,7 +28,7 @@ def hedged_call(fn, *args, hedge_after_s: float = 0.5, max_hedges: int = 2):
     def attempt():
         try:
             results.put((True, fn(*args)))
-        except Exception as e:  # noqa: BLE001 — relayed to the caller
+        except Exception as e:  # noqa: BLE001 — re-raised in the caller
             results.put((False, e))
 
     total = 1 + max_hedges

@@ -82,7 +82,8 @@ class TempoDBConfig:
     # many distinct values stage their packed bytes to HBM and run the
     # substring prefilter ON DEVICE (search/dict_probe.py) instead of
     # the host memmem walk — at 10M distinct values the host walk is
-    # ~312 ms per fresh tag-set vs single-digit-ms on chip (BENCH_r05).
+    # ~312 ms per fresh tag-set (a CPU-container host timing; the chip
+    # side is not measured on today's code).
     # Mirrors pipeline.NATIVE_SCAN_THRESHOLD (the same scale at which
     # the HOST scan moves to the native memmem path); <= 0 keeps every
     # probe on the exact host path. None = the dict_probe default (50k).
@@ -239,14 +240,6 @@ class TempoDBConfig:
     # blobs under this many rows stay on the per-span walk (batch
     # setup costs more than it saves on tiny pushes)
     search_analytics_min_rows: int = 64
-    # persistent XLA compilation cache directory for the SEARCH kernels
-    # (jax_compilation_cache_dir): a cold process replays first-seen-
-    # shape compiles from disk instead of re-paying XLA. Empty
-    # (default) = off. Hits surface as jit_cache_events{result=
-    # persisted}. (host_state_dir's auto mode already wires this for
-    # full TempoDB deployments; this knob reaches the same machinery
-    # without the rest of host state.)
-    search_compile_cache_dir: str = ""
     # stage + compile-warm hot batches in the background after each poll
     # so the first query pays neither (off by default: polls in tests and
     # write-only processes must not spin up device work)
@@ -319,11 +312,12 @@ class TempoDBConfig:
     robustness_faults: str = ""
     # shard batches over the device mesh when >1 device is visible
     auto_mesh: bool = True
-    # restartable host state (VERDICT r4 #3): None = auto (persistent
-    # XLA compile cache + header snapshot under <wal_dir>/host-state);
-    # "" disables; a path overrides the location. A cold restart then
-    # replays compiles from disk and loads header rollups without one
-    # backend read per block.
+    # restartable host state: persistent XLA compile cache (placed by
+    # utils.jaxenv.enable_compile_cache, never under this directory) +
+    # header snapshot. None = auto (snapshot under <wal_dir>/host-state);
+    # "" disables both; a path overrides the snapshot location. A cold
+    # restart then replays compiles from disk and loads header rollups
+    # without one backend read per block.
     host_state_dir: str | None = None
 
 
@@ -505,26 +499,21 @@ class TempoDB:
         # (search_blocks)
         self._breq_jobs_cache = BoundedCache(32)
         self._search_lock = threading.Lock()
-        # explicit search-kernel compile cache (search_compile_cache_dir):
-        # applied BEFORE the host-state auto wiring below so an
-        # operator's explicit location wins (enable_compile_cache keeps
-        # the first configured dir)
-        if self.cfg.search_compile_cache_dir:
-            from tempo_tpu.utils.jaxenv import enable_compile_cache
-
-            enable_compile_cache(self.cfg.search_compile_cache_dir)
         # restartable host state: header snapshot + persistent XLA
-        # compile cache. Auto default lives under the WAL dir — per-node
-        # durable storage that already must survive restarts. The
-        # snapshot sits in a SUBDIR because WAL replay deletes unknown
-        # files in its root.
+        # compile cache. The snapshot's auto default lives under the WAL
+        # dir — per-node durable storage that already must survive
+        # restarts — in a SUBDIR because WAL replay deletes unknown
+        # files in its root. The compile cache is placed by
+        # utils.jaxenv alone (JAX_COMPILATION_CACHE_DIR, else a fixed
+        # path in the checkout): a cache under a per-run WAL dir moves
+        # every run and never hits.
         sd = self.cfg.host_state_dir
         self._state_dir = (os.path.join(wal_dir, "host-state")
                           if sd is None else (sd or None))
         if self._state_dir:
             from tempo_tpu.utils.jaxenv import enable_compile_cache
 
-            enable_compile_cache(os.path.join(self._state_dir, "xla-cache"))
+            enable_compile_cache()
             self._load_host_state()
 
     def _ensure_mesh(self) -> None:

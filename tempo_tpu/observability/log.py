@@ -13,16 +13,20 @@ import time
 
 
 def get_logger(name: str = "tempo_tpu") -> logging.Logger:
-    logger = logging.getLogger(name)
-    if not logger.handlers:
+    """The named logger, writing through ONE stderr handler that sits on
+    the top-level package logger: "tempo_tpu.x" children reach it by
+    propagation and inherit its level (a handler on each child as well
+    printed every child line twice)."""
+    top = logging.getLogger(name.split(".")[0])
+    if not top.handlers:
         h = logging.StreamHandler(sys.stderr)
         h.setFormatter(logging.Formatter(
             'ts=%(asctime)s level=%(levelname)s logger=%(name)s msg="%(message)s"',
             datefmt="%Y-%m-%dT%H:%M:%S",
         ))
-        logger.addHandler(h)
-        logger.setLevel(logging.INFO)
-    return logger
+        top.addHandler(h)
+        top.setLevel(logging.INFO)
+    return logging.getLogger(name)
 
 
 class TenantTokenBucket:

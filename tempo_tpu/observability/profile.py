@@ -60,6 +60,9 @@ from collections import deque
 from . import metrics as obs
 from . import selftrace
 from . import tracing
+from .log import get_logger
+
+log = get_logger("tempo_tpu.profile")
 
 STAGES = ("build", "h2d", "compile", "execute", "d2h", "lock_wait")
 
@@ -167,7 +170,7 @@ class Dispatch:
     record is published (ring + metrics + span event) on close()."""
 
     __slots__ = ("mode", "stages", "h2d_bytes", "d2h_bytes", "jit",
-                 "attrs", "t0", "_prof", "_closed")
+                 "jit_key", "attrs", "t0", "_prof", "_closed")
     enabled = True
 
     def __init__(self, prof, mode: str):
@@ -176,6 +179,7 @@ class Dispatch:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.jit = None       # None (no kernel), "hit" or "miss"
+        self.jit_key = None   # the shape signature compile_check saw
         self.attrs: dict = {}
         self.t0 = time.perf_counter()
         self._prof = prof
@@ -199,6 +203,7 @@ class Dispatch:
         (tracing + XLA compile dominate it) and "execute" on a hit."""
         miss = self._prof._compile_miss(key)
         self.jit = "miss" if miss else "hit"
+        self.jit_key = key
         return miss
 
     def fence(self, arrays) -> "Dispatch":
@@ -374,6 +379,13 @@ class DispatchProfiler:
             obs.h2d_bytes.inc(rec.h2d_bytes)
         if rec.d2h_bytes:
             obs.d2h_bytes.inc(rec.d2h_bytes)
+        if rec.jit == "miss":
+            # one line per first-seen shape signature: which jit key
+            # compiled and how long that dispatch call took — cold start
+            # is the sum of these
+            log.info("jit compile: mode=%s compile_ms=%.1f key=%r",
+                     rec.mode, rec.stages.get("compile", 0.0) * 1e3,
+                     rec.jit_key)
         rd = rec.as_dict()
         stack = getattr(_collect_local, "stack", None)
         if stack:
@@ -475,32 +487,23 @@ def configure(enabled: bool | None = None, fence: bool | None = None,
 _persist_watch_registered = False
 
 
-def watch_persistent_compile_cache() -> bool:
+def watch_persistent_compile_cache() -> None:
     """Register a jax.monitoring listener that books every persistent-
     compilation-cache HIT as jit_cache_events{result=persisted} — the
     operator-visible proof that a cold process is replaying first-seen-
     shape compiles from disk (utils.jaxenv.enable_compile_cache wires
-    the cache itself; TempoDBConfig.search_compile_cache_dir /
-    host_state_dir turn it on). Idempotent; returns False when the
-    running jax build lacks the monitoring hooks."""
+    the cache itself). Idempotent."""
     global _persist_watch_registered
     if _persist_watch_registered:
-        return True
-    try:
-        from jax import monitoring as _monitoring
+        return
+    from jax import monitoring as _monitoring
 
-        def _on_event(event: str, **kw) -> None:
-            # jax 0.4.x records '/jax/compilation_cache/cache_hits'
-            # per retrieval; match loosely so minor renames keep the
-            # signal rather than silently zeroing it
-            if "compilation_cache" in event and "hit" in event:
-                obs.jit_cache_events.inc(result="persisted")
+    def _on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            obs.jit_cache_events.inc(result="persisted")
 
-        _monitoring.register_event_listener(_on_event)
-    except Exception:  # noqa: BLE001 — observability extra, never fatal
-        return False
+    _monitoring.register_event_listener(_on_event)
     _persist_watch_registered = True
-    return True
 
 
 def dispatch(mode: str):
@@ -532,15 +535,13 @@ def build_info() -> dict:
     except Exception:  # noqa: BLE001 — identity, never fatal
         info["jax"] = "absent"
     try:
-        from jax._src import xla_bridge as _xb
-
-        if getattr(_xb, "_backends", None):
+        if _backend_initialized():
             import jax
 
             info["backend"] = jax.default_backend()
         else:
             info["backend"] = "uninitialized"
-    except Exception:  # noqa: BLE001 — internal API moves across versions
+    except Exception:  # noqa: BLE001 — identity, never fatal (no jax)
         info["backend"] = "unknown"
     try:
         from tempo_tpu.ops import native as _native
@@ -560,12 +561,11 @@ def build_info() -> dict:
 
 
 def device_status() -> dict:
-    """The /status "device" block: accelerator backend + device count
-    (WITHOUT initializing a backend — write-only processes must never
-    claim a chip for a status probe) and the age of the last successful
-    dispatch, the operator's first wedge-vs-idle signal (bench r04/r05
-    recorded zeroed CPU-fallback headlines that were indistinguishable
-    from a regression because nothing surfaced this)."""
+    """The /status "device" block: accelerator backend, device kind and
+    count, per-device memory as the runtime reports it (WITHOUT
+    initializing a backend — write-only processes must never claim a
+    chip for a status probe) and the age of the last successful
+    dispatch, the operator's first hung-vs-idle signal."""
     out: dict = {
         "dispatches": PROFILER._dispatches,
         "profiling_enabled": PROFILER.enabled,
@@ -574,36 +574,50 @@ def device_status() -> dict:
     out["last_dispatch_age_s"] = (round(time.time() - t, 3)
                                   if t is not None else None)
     try:
-        # the circuit breaker's verdict IS the wedge signal now: /status
-        # and bench's device_wedged headline read this instead of
-        # ad-hoc probing (tempo_tpu/robustness/breaker.py)
+        # the circuit breaker's verdict IS the hung-device signal:
+        # /status reads this instead of ad-hoc probing
+        # (tempo_tpu/robustness/breaker.py)
         from tempo_tpu.robustness import BREAKER
 
         out["breaker"] = BREAKER.snapshot()
         out["wedged"] = BREAKER.blocking()
     except Exception:  # noqa: BLE001 — status must never 500
         pass
-    try:
-        from jax._src import xla_bridge as _xb
-
-        initialized = bool(getattr(_xb, "_backends", None))
-    except Exception:  # noqa: BLE001 — internal API moves across versions
-        # can't tell whether a backend exists: report unknown rather
-        # than probe — jax.default_backend() would INITIALIZE one, and
-        # on TPU that claims the chip out from under the serving process
-        out["backend"] = "unknown"
-        return out
-    if not initialized:
+    if not _backend_initialized():
         out["backend"] = "uninitialized"
         return out
     try:
         import jax
 
+        devs = jax.devices()
         out["backend"] = jax.default_backend()
-        out["device_count"] = jax.device_count()
-    except Exception as e:  # noqa: BLE001 — a wedged tunnel must not 500 /status
+        out["device_count"] = len(devs)
+        out["device_kind"] = devs[0].device_kind
+        out["devices"] = [_device_memory(d) for d in jax.local_devices()]
+    except Exception as e:  # noqa: BLE001 — a dead device must not 500 /status
         out["backend"] = "error"
         out["error"] = f"{type(e).__name__}: {e}"
+    return out
+
+
+def _backend_initialized() -> bool:
+    """Whether this process already holds a JAX backend. It looks and
+    never probes: jax.default_backend() would INITIALIZE one, and on TPU
+    that claims the chip out from under the serving process."""
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge._backends)
+
+
+def _device_memory(d) -> dict:
+    """One device's allocator view. `memory_stats()` is None on backends
+    that keep no allocator stats (CPU); the keys are then absent rather
+    than zero, so a reader cannot mistake "not reported" for "empty"."""
+    out = {"id": d.id}
+    stats = d.memory_stats() or {}
+    for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
+        if k in stats:
+            out[k] = int(stats[k])
     return out
 
 

@@ -57,10 +57,11 @@ def locked_collective(rec=None):
         ok = dispatch_lock.acquire()
     if not ok:
         obs.dispatch_lock_timeouts.inc()
-        BREAKER.record_fault("lock_timeout", mode="mesh")
-        raise DispatchLockTimeout(
-            f"collective dispatch lock not acquired within {timeout:.1f}s"
-            " — another dispatch is wedged while holding it")
+        msg = (f"collective dispatch lock not acquired within "
+               f"{timeout:.1f}s — another dispatch is wedged while "
+               "holding it")
+        BREAKER.record_fault("lock_timeout", mode="mesh", detail=msg)
+        raise DispatchLockTimeout(msg)
     try:
         if rec is not None:
             rec.add_stage("lock_wait", time.perf_counter() - t0)
@@ -87,14 +88,7 @@ def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map across jax versions: newer jax exposes it top-level
-    with `check_vma`; older releases only have the experimental module
-    with the same knob spelled `check_rep`. Every distributed kernel
-    routes through here so a version bump is a one-line change."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
+    """`jax.shard_map` with this repo's defaults (replication checking
+    off unless asked); every distributed kernel routes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -1,10 +1,10 @@
 """Device circuit breaker: closed → open → half-open → closed.
 
 One hung dispatch is a fault; N faults inside a window mean the device
-tunnel itself is gone, and every further dispatch would burn a watchdog
-timeout learning the same thing. The breaker aggregates the faults the
-dispatch guard books and flips the whole serving path to the host route
-in one place:
+itself has stopped answering, and every further dispatch would burn a
+watchdog timeout learning the same thing. The breaker aggregates the
+faults the dispatch guard books and flips the whole serving path to the
+host route in one place:
 
   closed     normal: every dispatch allowed; faults accumulate in the
              sliding window; threshold trips to open.
@@ -21,7 +21,11 @@ in one place:
 Transitions emit ``tempo_search_device_breaker_transitions_total``,
 update the state gauge, annotate the active self-trace span, and log —
 ``/status``'s device block and bench's ``device_wedged`` headline read
-:meth:`snapshot` instead of ad-hoc probing.
+:meth:`snapshot` instead of ad-hoc probing. Every booked fault is also
+logged at error level with the absorbed exception's text (rate-limited
+per kind and mode): the host route answers byte-identically, so that
+line and ``last_fault`` are where a compiler refusal or an HBM
+RESOURCE_EXHAUSTED can be read.
 
 Hot-path contract: with the breaker disabled (or closed),
 ``allow_device`` / ``record_success`` are attribute reads — no lock, no
@@ -38,9 +42,10 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 from tempo_tpu.observability.flightrecorder import (RECORDER,
                                                     TRIGGER_BREAKER)
-from tempo_tpu.observability.log import get_logger
+from tempo_tpu.observability.log import TenantTokenBucket, get_logger
 
 log = get_logger("tempo_tpu.breaker")
+_DETAIL_MAX = 4000  # chars of an absorbed exception's text kept
 
 CLOSED = "closed"
 OPEN = "open"
@@ -66,6 +71,10 @@ class CircuitBreaker:
         self._transitions: dict[str, int] = {}
         self._last_fault: dict[str, object] | None = None
         self._last_fault_t: float | None = None
+        # fault log lines are rate-limited per kind/mode: a hung device
+        # repeats the same fault per dispatch, and the log must not
+        # become the incident
+        self._fault_log = TenantTokenBucket(rate=1.0, burst=5)
         self._lock = threading.Lock()
 
     # ---- hot-path reads ----
@@ -119,12 +128,18 @@ class CircuitBreaker:
 
     # ---- event booking (dispatch guard + lock timeout call these) ----
 
-    def record_fault(self, kind: str, mode: str = "") -> None:
+    def record_fault(self, kind: str, mode: str = "",
+                     detail: str = "") -> None:
         """Book one device fault (kind=timeout|error|lock_timeout,
-        mode = the profiler's dispatch mode for stage context). Counted
-        even when the breaker is disabled — the operator still sees the
-        faults; only the state machine is gated."""
+        mode = the profiler's dispatch mode for stage context, detail =
+        the absorbed exception's text). Counted and logged even when
+        the breaker is disabled — the operator still sees the faults;
+        only the state machine is gated."""
         obs.device_faults.inc(kind=kind, mode=mode or "unknown")
+        detail = detail[:_DETAIL_MAX]
+        if self._fault_log.allow(f"{kind}/{mode}"):
+            log.error("device fault absorbed by the host route: "
+                      "kind=%s mode=%s %s", kind, mode or "unknown", detail)
         span = tracing.current_span()
         if span.recording:
             span.add_event("device.fault", kind=kind, mode=mode)
@@ -133,7 +148,8 @@ class CircuitBreaker:
         now = time.monotonic()
         tripped = False
         with self._lock:
-            self._last_fault = {"kind": kind, "mode": mode}
+            self._last_fault = {"kind": kind, "mode": mode,
+                                "detail": detail}
             self._last_fault_t = now
             if self._state == HALF_OPEN:
                 # the recovery probe failed: straight back to open,
@@ -183,6 +199,7 @@ class CircuitBreaker:
             self._probe_granted_t = 0.0
             self._last_fault = None
             self._last_fault_t = None
+            self._fault_log = TenantTokenBucket(rate=1.0, burst=5)
 
     # ---- internals ----
 

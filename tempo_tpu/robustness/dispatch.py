@@ -1,15 +1,17 @@
 """Deadline-bounded device dispatch: the watchdog around every kernel.
 
-A wedged device tunnel hangs the CALLING thread at the dispatch (or its
-H2D/D2H transfer) with no way to interrupt it from Python. The guard
-therefore runs the dispatch body on a watchdog worker thread and bounds
-the WAIT: past ``search_device_dispatch_timeout_s`` (clamped to the
+A device that stops answering hangs the CALLING thread at the dispatch
+(or its H2D/D2H transfer) with no way to interrupt it from Python. The
+guard therefore runs the dispatch body on a watchdog worker thread and
+bounds the WAIT: past ``search_device_dispatch_timeout_s`` (clamped to the
 request deadline's remaining budget) the caller abandons the worker,
 books a breaker fault with the dispatch's profiler mode as stage
 context, and raises :class:`DeviceDispatchTimeout` — which the batcher
 catches and answers through the byte-identical host path. A backend
 error from the dispatch (XLA runtime / injected) books the same way as
-kind=error.
+kind=error, with the exception's text handed to the breaker, which
+logs it: the absorbed answer is correct, so the log line is the only
+place the cause can be read.
 
 The abandoned worker thread finishes (or never does) on its own; the
 pool bounds how many can leak — and after ``threshold`` faults the
@@ -146,7 +148,9 @@ class DispatchGuard:
                 raise
             except Exception as e:
                 if _is_device_error(e):
-                    BREAKER.record_fault("error", mode=mode)
+                    BREAKER.record_fault(
+                        "error", mode=mode,
+                        detail=f"{type(e).__name__}: {e}")
                     raise DeviceDispatchError(
                         f"{mode}: {type(e).__name__}: {e}") from e
                 raise
@@ -175,7 +179,10 @@ class DispatchGuard:
             out = fut.result(timeout=timeout)
         except concurrent.futures.TimeoutError:
             fut.cancel()  # no-op if running; the worker is abandoned
-            BREAKER.record_fault("timeout", mode=mode)
+            BREAKER.record_fault(
+                "timeout", mode=mode,
+                detail=f"no result within the {timeout:.3f}s watchdog "
+                       "deadline")
             # flight recorder: a watchdog fire means a dispatch is
             # wedged RIGHT NOW — snapshot before the abandonment
             # propagates (no lock held here)
@@ -192,7 +199,8 @@ class DispatchGuard:
             raise
         except Exception as e:
             if _is_device_error(e):
-                BREAKER.record_fault("error", mode=mode)
+                BREAKER.record_fault(
+                    "error", mode=mode, detail=f"{type(e).__name__}: {e}")
                 raise DeviceDispatchError(
                     f"{mode}: {type(e).__name__}: {e}") from e
             raise

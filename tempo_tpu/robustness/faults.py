@@ -52,12 +52,12 @@ CATALOG: dict[str, tuple[str, str]] = {
         "error path: breaker fault kind=error, host fallback)",
         "robustness/dispatch.py DispatchGuard.run worker"),
     "device_dispatch_hang": (
-        "sleep inside the watchdogged device dispatch (wedged-tunnel "
+        "sleep inside the watchdogged device dispatch (hung-device "
         "path: watchdog timeout, breaker fault kind=timeout, host "
         "fallback); arm with delay= past the watchdog deadline",
         "robustness/dispatch.py DispatchGuard.run worker"),
     "h2d_delay": (
-        "sleep inside the host->device staging put (slow/wedged relay; "
+        "sleep inside the host->device staging put (slow or hung H2D; "
         "with delay past the watchdog deadline the staging dispatch "
         "times out and the group host-routes)",
         "search/multiblock.py place_batch"),

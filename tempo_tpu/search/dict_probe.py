@@ -3,8 +3,8 @@
 The host-side dictionary probe (pipeline.substring_value_ids — numpy
 char.find, or the native memmem walk) sits serially in front of every
 fresh (block, tag-set) dispatch; at BASELINE high cardinality it is the
-dominant cost (312 ms at 10M distinct values, BENCH_r05) while the device
-scan itself is single-digit ms. This module moves the probe to where the
+dominant cost (~312 ms at 10M distinct values, a CPU-container host
+timing) in front of the device scan. This module moves the probe to where the
 columns already live — the near-data-processing move of "Near Data
 Processing in Taurus Database" / the predicate-offload pattern of
 "GPU-Augmented OLAP Execution Engine" (PAPERS.md): evaluate the filter

@@ -26,7 +26,7 @@ Cost model (all inputs observable, nothing guessed twice):
 where B = real dictionary bytes, S = staged (padded buf+pos+off) bytes,
 T = term count. Rates are EWMAs over recent observations, bucketed by
 log-size so the model tracks the measured non-linearity (the CPU probe
-is ~linear at 1M values and super-linear at 10M — BENCH_r05); fixed
+is ~linear at 1M values and super-linear at 10M, CPU timings); fixed
 costs are plain EWMAs. Observations arrive two ways:
 
   - the planner registers as a dispatch-profiler listener
@@ -524,7 +524,7 @@ class OffloadPlanner:
         listener): dictionary AND page-batch staging H2D — the batch
         observations carry PHYSICAL (packed) byte counts, so the
         staging-cost side of every decision scales with what actually
-        crosses the relay, not the unpacked layout. The host prefilter
+        crosses host→device, not the unpacked layout. The host prefilter
         is NOT harvested here — pipeline._probe_tags feeds it directly
         with the dictionary fingerprint attached (and also reports it
         to the profiler, where only the aggregate lands)."""
@@ -640,7 +640,7 @@ def stage_veto(block, fp, n_shards: int = 1) -> bool:
     batched paths. Always False when the planner is disabled (the
     static-threshold behavior) — EXCEPT while the device circuit
     breaker blocks the device: then every staging is vetoed regardless
-    of planner state, so a wedged tunnel is never handed a dictionary
+    of planner state, so a hung device is never handed a dictionary
     upload (robustness.breaker; one attribute read when closed)."""
     from tempo_tpu.robustness import BREAKER
 

@@ -366,9 +366,13 @@ class QueryStats:
 
 def apportion(totals: dict, weights: list) -> list[dict]:
     """Split per-stage totals across members proportionally to
-    `weights`, conserving the sum exactly: members 0..n-2 get
-    total*w/W and the LAST member takes the remainder, so per stage
-    sum(shares) == total to the last float bit."""
+    `weights`, conserving the sum: members 0..n-2 get total*w/W and the
+    LAST member takes the remainder of the same running accumulation,
+    so per stage adding the shares up in member order with `+` — how
+    every consumer folds them (add_device_stages) — gives back `total`.
+    The guarantee is about that accumulation, not about the builtin
+    sum(): from Python 3.12 sum() compensates float rounding and may
+    land one ulp away."""
     n = len(weights)
     if n == 1:
         return [dict(totals)]

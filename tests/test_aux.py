@@ -433,20 +433,3 @@ def test_request_queue_sub_request_memory_bound():
         q.enqueue("t", i)
     with pytest.raises(TooManyRequests):
         q.enqueue("t", 3)
-
-
-def test_honor_jax_platforms_applies_config(monkeypatch):
-    """The env→config bridge every entry point uses: with JAX_PLATFORMS
-    set, jax.config must reflect it (the env var alone does not gate a
-    registered TPU plugin's backend init)."""
-    import jax
-
-    from tempo_tpu.utils.jaxenv import honor_jax_platforms
-
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    honor_jax_platforms(required=True)
-    assert jax.config.jax_platforms == "cpu"
-    # unset env: helper must be a no-op, not clear the config
-    monkeypatch.delenv("JAX_PLATFORMS")
-    honor_jax_platforms()
-    assert jax.config.jax_platforms == "cpu"

@@ -936,28 +936,3 @@ def test_host_state_opt_out(tmp_path):
     _ingest(db, "t1", 2)
     db.poll()
     assert not (tmp_path / "wal" / "host-state").exists()
-
-
-def test_compile_cache_dir_configured(tmp_path):
-    # subprocess: jax's compilation-cache config is process-global and
-    # FIRST-wins (explicit env beats per-TempoDB defaults), so an
-    # in-process assert would see whichever test ran first
-    import subprocess
-    import sys
-
-    code = (
-        "import jax; jax.config.update('jax_platforms','cpu')\n"
-        "from tempo_tpu.db import TempoDB, TempoDBConfig\n"
-        "from tempo_tpu.backend import LocalBackend\n"
-        f"TempoDB(LocalBackend({str(tmp_path / 'blocks')!r}),"
-        f" {str(tmp_path / 'wal')!r}, TempoDBConfig())\n"
-        "print(jax.config.jax_compilation_cache_dir)\n"
-    )
-    env = dict(__import__('os').environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=120)
-    want = str(tmp_path / "wal" / "host-state" / "xla-cache")
-    assert out.stdout.strip().endswith(want), (out.stdout, out.stderr[-500:])
-    import os as _os
-    assert _os.path.isdir(want)

@@ -606,6 +606,59 @@ def test_status_device_block_reads_breaker(tmp_path):
     assert d["wedged"] is True
 
 
+def test_status_device_block_names_the_device():
+    """/status names what the process runs on: backend, device kind and
+    count, and per-device memory as the runtime reports it. On the CPU
+    backend the allocator keeps no stats, so the memory keys are absent
+    (not zero); a booked fault's text is readable in last_fault."""
+    import jax
+
+    from tempo_tpu.observability.profile import device_status
+
+    devs = jax.devices()  # the test harness's backend is initialized
+    d = device_status()
+    assert d["backend"] == "cpu"
+    assert d["device_kind"] == devs[0].device_kind
+    assert d["device_count"] == len(devs)
+    assert [x["id"] for x in d["devices"]] == [x.id for x in devs]
+    for x in d["devices"]:
+        assert "bytes_in_use" not in x and "peak_bytes_in_use" not in x
+    robustness.BREAKER.reset()
+    robustness.BREAKER.enabled = True
+    try:
+        robustness.BREAKER.record_fault(
+            "error", mode="batched",
+            detail="XlaRuntimeError: RESOURCE_EXHAUSTED: out of HBM")
+        last = device_status()["breaker"]["last_fault"]
+        assert "RESOURCE_EXHAUSTED" in last["detail"]
+    finally:
+        robustness.BREAKER.reset()
+        robustness.BREAKER.enabled = False
+
+
+def test_absorbed_device_error_is_logged_with_its_text(caplog):
+    """The host route answers byte-identically, so the error-level log
+    line is where a compiler refusal can be read."""
+    import logging
+
+    robustness.BREAKER.reset()
+    robustness.BREAKER.enabled = True
+    try:
+        with caplog.at_level(logging.ERROR, logger="tempo_tpu.breaker"):
+            with pytest.raises(robustness.DeviceDispatchError):
+                robustness.GUARD.run("batched", _raise_xla_like)
+        text = "\n".join(r.getMessage() for r in caplog.records)
+        assert "kind=error mode=batched" in text
+        assert "Mosaic failed to compile" in text
+    finally:
+        robustness.BREAKER.reset()
+        robustness.BREAKER.enabled = False
+
+
+def _raise_xla_like():
+    raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+
 def test_debug_faults_route_json(tmp_path):
     """/debug/faults is covered by test_debug_routes' generic contract;
     here: the payload carries catalog + armed + breaker and is

@@ -461,11 +461,11 @@ def test_seed_does_not_double_feed_and_cold_stage_predicts_compile():
 
 
 def test_cold_process_does_not_stage_huge_dict_blindly():
-    """With a relay-slow observed H2D, a non-resident 720 MB dictionary
+    """With a slow observed H2D, a non-resident 720 MB dictionary
     must NOT be staged: the staging bytes dominate any probe win."""
     p = planner.configure(enabled=True, seed=False, reset=True)
     p.seed_on_first_use = False
-    p.observe("h2d", 1.0, nbytes=50 << 20)       # ~50 MB/s relay
+    p.observe("h2d", 1.0, nbytes=50 << 20)       # a slow ~50 MB/s H2D
     p.observe("host_probe", 0.35, nbytes=160 << 20)  # PR4's measured 312ms/10M
     p.observe("device_probe", 0.01, nbytes=800 << 20)  # chip-fast probe
     d = p.decide_probe(n_vals=10_000_000, dict_bytes=160 << 20,
@@ -584,7 +584,7 @@ def test_offline_replay_from_profile_snapshot(tmp_path, capsys):
     p = planner.OffloadPlanner(enabled=True, seed=False)
     n = p.ingest_profile_snapshot(snap)
     assert n >= 3
-    # chip-fast probe + slow relay: big non-resident dict stays host,
+    # chip-fast probe + slow H2D: big non-resident dict stays host,
     # resident flips device
     d_cold = p.decide_probe(n_vals=10_000_000, dict_bytes=160 << 20,
                             resident=False, site="offline")
@@ -625,8 +625,8 @@ def test_planner_metrics_documented_and_incremented():
 
 def test_device_scalar_params_shared_across_queries():
     """Two distinct compiled queries with the same (default) bounds must
-    reuse the SAME device scalar arrays — the per-query scalar H2D puts
-    were measured relay tax (engine.py docstring)."""
+    reuse the SAME device scalar arrays — each scalar put is its own
+    host→device transfer (engine.py docstring)."""
     from tempo_tpu.search.engine import device_scalar
 
     pages = ColumnarPages.build(_corpus(50, seed=7), PageGeometry(32, 8))

@@ -64,7 +64,13 @@ def test_apportion_conserves_totals_exactly():
         shares = query_stats.apportion(totals, weights)
         assert len(shares) == len(weights)
         for stage, total in totals.items():
-            assert sum(s[stage] for s in shares) == total  # EXACT
+            # folded in member order with `+`, as add_device_stages does
+            # (builtin sum() is compensated from Python 3.12 on)
+            acc = 0.0
+            for s in shares:
+                acc += s[stage]
+            assert acc == total  # EXACT
+
 
 def test_apportion_weights_proportional():
     shares = query_stats.apportion({"execute": 1.0}, [3, 1])
