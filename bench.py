@@ -1315,19 +1315,10 @@ def phase_selftrace_overhead():
             assert identical, "selftrace gate on/off responses diverged"
 
             # deterministic protocol cost: exactly what the gate adds
-            # to one request — lower a representative 5-stage dispatch
-            # record + annotate the request span with the QueryStats
-            # headline dict — measured enabled vs disabled (the span
-            # itself exists either way under plain self-tracing)
-            class _Rec:
-                mode = "batched"
-                jit = "hit"
-                h2d_bytes = 4096
-                d2h_bytes = 256
-                stages = {"build": 1e-4, "h2d": 2e-4, "compile": 0.0,
-                          "execute": 4e-4, "d2h": 1e-4}
-
-            rec = _Rec()
+            # to one request — annotate the request span with the
+            # QueryStats headline dict — measured enabled vs disabled
+            # (the span and its observed dispatch.<stage> children
+            # exist either way under plain self-tracing)
             qd = {"wall_ms": 2.0, "device_seconds": 4e-4,
                   "blocks_inspected": 4,
                   "bytes_inspected": {"host": 1 << 16, "device": 1 << 18},
@@ -1337,8 +1328,7 @@ def phase_selftrace_overhead():
             def protocol_loop(n):
                 t0 = time.perf_counter()
                 for _ in range(n):
-                    with tracer.start_span("bench.request") as span:
-                        SELFTRACE.lower_dispatch(rec, parent=span)
+                    with tracer.start_span("bench.request"):
                         SELFTRACE.annotate_query(qd)
                 return time.perf_counter() - t0
 

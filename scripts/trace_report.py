@@ -1,0 +1,190 @@
+"""One traced chipbench run, and what its spans say beyond the result line.
+
+    python3 scripts/trace_report.py --workload share16.triage --seed 7 \
+        --seconds 40 [--trace 0] [--scale tiny]
+
+Runs the cell through `chipbench.run.run` (the benchmark's own code, no
+copy of it) and, from the run's spans, prints one `TRACE_REPORT {json}`
+line and writes it to
+`chiprun_out/trace_report.<cell>.<seed>.t<trace>.json`:
+
+  end_to_end   the cell's end-to-end readers on THIS run, so a traced
+               and an untraced run of one seed give the cost of tracing
+  coverage     per search: how much of `http.request` (accept -> last
+               byte written) its child spans cover, the wait before the
+               handler (its `accept_wait_ms`) counted with them
+  slowest      for the slowest 5 % of searches (by `http.request`): self
+               time (a span's duration less what its children cover)
+               summed by span name, per search, without the overlays
+               (`device.scan`, `coalescer.wait`); a search's
+               sub-requests run side by side and an inline
+               `coalescer.launch` lies inside its sibling
+               `batcher.dispatch`, so the sum can pass the wall time; and
+               the share of it in spans that have children (time no
+               span explains)
+
+Only what the spans alone give: nothing here is matched against the
+profiler's trace (that needs the harness to keep its `zero_wall_ns`,
+PERF.md section 7a). A traced run's spans also go to
+`chiprun_out/spans.<cell>.<seed>.json.gz`. `--trace 0` runs untraced and
+reports `end_to_end` alone. The last line of stdout is the benchmark's
+own result line, as `chipbench.run` prints it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gzip
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench import run as bench_run  # noqa: E402
+from chipbench.layers import spans as sp  # noqa: E402
+from chipbench.lib import median, percentile  # noqa: E402
+from chipbench.xplane import merge  # noqa: E402
+
+# spans that overlay the host's own: the device's timeline, and a
+# member's wait for its launch, which lies over the `batcher.dispatch`
+# or `batcher.drain` the member's thread was in meanwhile
+OVERLAYS = {sp.DEVICE, "coalescer.wait"}
+
+
+def total(iv: list) -> int:
+    return sum(b - a for a, b in iv)
+
+
+def inside(a: list, b: list) -> list:
+    """The part of the union `a` that lies inside the union `b` (both
+    sorted and disjoint, as `merge` leaves them)."""
+    out, j = [], 0
+    for lo, hi in a:
+        while j < len(b) and b[j][1] <= lo:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < hi:
+            out.append([max(lo, b[k][0]), min(hi, b[k][1])])
+            k += 1
+    return out
+
+
+def self_ns(spans: list) -> dict:
+    """Self time of one trace's spans, summed by name: name -> ns."""
+    kids: dict = {}
+    for s in spans:
+        if s["parent_id"] and s["name"] not in OVERLAYS:
+            kids.setdefault(s["parent_id"], []).append(
+                [s["start_ns"], s["end_ns"]])
+    out: dict = {}
+    for s in spans:
+        if s["name"] in OVERLAYS:
+            continue
+        own = [[s["start_ns"], s["end_ns"]]]
+        covered = total(inside(
+            merge(kids.get(s["span_id"], [])), own))
+        out[s["name"]] = out.get(s["name"], 0) + total(own) - covered
+    return out
+
+
+def report(view: dict, e2e_names: list) -> dict:
+    out: dict = {"workload": view["workload"], "end_to_end": {}}
+    for name in e2e_names:
+        v = bench_run.load_reader("metrics", name).compute(view)
+        if v is not None:
+            out["end_to_end"][name] = float(v)
+    spans = view["spans"]
+    traces = sp.searches(spans)
+    if not traces:
+        return out
+    parents = {s["parent_id"] for s in spans if s["parent_id"]}
+    rows = []
+    for tid, ss in traces.items():
+        roots = sp.named(ss, sp.REQUEST)
+        if len(roots) != 1:
+            continue
+        root = roots[0]
+        kids = merge([[c["start_ns"], c["end_ns"]] for c in ss
+                         if c["parent_id"] == root["span_id"]])
+        dur = root["end_ns"] - root["start_ns"]
+        waited = root["attributes"].get("accept_wait_ms", 0.0) * 1e6
+        rows.append({
+            "ns": dur, "spans": len(ss),
+            "covered": (waited + total(inside(
+                kids, [[root["start_ns"] + waited, root["end_ns"]]]))) / dur,
+            "by_span": self_ns(ss),
+            # names of this trace's spans that have children: time that
+            # falls to them is time no span explains
+            "has_children": {s["name"] for s in ss
+                             if s["span_id"] in parents}})
+    out["searches"] = len(rows)
+    out["spans_per_search_median"] = median([r["spans"] for r in rows])
+    cov = [r["covered"] for r in rows]
+    out["coverage"] = {"median": median(cov), "min": min(cov),
+                       "p05": percentile([-c for c in cov], 95) * -1}
+    rows.sort(key=lambda r: -r["ns"])
+    for label, part in (("slowest", rows[:max(1, len(rows) // 20)]),
+                        ("all", rows)):
+        by_name: dict = {}
+        unexplained = 0
+        for r in part:
+            for name, ns in r["by_span"].items():
+                by_name[name] = by_name.get(name, 0) + ns
+                if name in r["has_children"]:
+                    unexplained += ns
+        self_sum = sum(by_name.values())
+        out[label] = {
+            "searches": len(part),
+            "mean_ms": sum(r["ns"] for r in part) / len(part) / 1e6,
+            "self_over_wall": self_sum / sum(r["ns"] for r in part),
+            "min_ms": min(r["ns"] for r in part) / 1e6,
+            "ms_per_search_by_span": {
+                k: v / len(part) / 1e6 for k, v in sorted(
+                    by_name.items(), key=lambda kv: -kv[1])},
+            "share_in_spans_with_children": unexplained / self_sum,
+        }
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=1)
+    ap.add_argument("--scale", choices=("full", "tiny"), default="full")
+    args = ap.parse_args(argv)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    e2e = [m["name"] for m in bench["end_to_end"]
+           if args.workload in m.get("workloads", [args.workload])]
+    got: dict = {}
+
+    def hook(stage, state):
+        if stage == "done":
+            got["report"] = report(state["run_view"], e2e)
+            got["spans"] = state["run_view"]["spans"]
+
+    result, code = bench_run.run(args, hook=hook)
+    if "report" in got:
+        rep = dict(got["report"], seed=args.seed, trace=args.trace,
+                   seconds=args.seconds)
+        line = json.dumps(rep)
+        print("TRACE_REPORT " + line, flush=True)
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        stem = os.path.join(ROOT, "chiprun_out",
+                            f"%s.{args.workload}.{args.seed}")
+        with open(stem % "trace_report" + f".t{args.trace}.json", "w") as f:
+            f.write(line + "\n")
+        if got["spans"]:
+            with gzip.open(stem % "spans" + ".json.gz", "wt") as f:
+                json.dump(got["spans"], f)
+    if result is not None:
+        print(json.dumps(result), flush=True)
+    return code
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -191,13 +191,10 @@ GATED_FUNCTIONS = (
     GatedFunction("tempo_tpu.search.analytics",
                   "AnalyticsEngine.consume_blob", ("enabled",),
                   "search_analytics_enabled"),
-    # dogfood self-ingest: span lowering and query-stat annotation only
-    # run when self-traces actually flow into the `_selftrace` tenant —
-    # the default-off deployment pays one attribute read before any
-    # tracer lookup, clock read, or span synthesis
-    GatedFunction("tempo_tpu.observability.selftrace",
-                  "SelfTraceGate.lower_dispatch", ("ingest_enabled",),
-                  "selftrace_ingest_enabled"),
+    # dogfood self-ingest: query-stat annotation only runs when
+    # self-traces actually flow into the `_selftrace` tenant — the
+    # default-off deployment pays one attribute read before any tracer
+    # lookup or clock read
     GatedFunction("tempo_tpu.observability.selftrace",
                   "SelfTraceGate.annotate_query", ("ingest_enabled",),
                   "selftrace_ingest_enabled"),
@@ -264,12 +261,21 @@ GUARDED_CALLS = (
     # opted in — mentioning it in a test guards like the gate itself)
     GuardedCall("ANALYTICS", ("consume_blob", "stage_for_batch"), (),
                 "enabled", "want_agg", "search_analytics_enabled"),
-    # dogfood hooks on hot paths (dispatch finish, query-stat publish):
-    # call sites gate on the one-attribute read so the default-off
-    # deployment never enters the lowering/annotation protocol
-    GuardedCall("SELFTRACE", ("lower_dispatch", "annotate_query"), (),
+    # dogfood hook on a hot path (query-stat publish): call sites gate
+    # on the one-attribute read so the default-off deployment never
+    # enters the annotation protocol
+    GuardedCall("SELFTRACE", ("annotate_query",), (),
                 "ingest_enabled", "SELFTRACE",
                 "selftrace_ingest_enabled"),
+    # served-search spans written after the fact and the device
+    # timeline's watcher: with no tracer installed (`self_tracing`
+    # off) a call site must not build the attributes, take the launch
+    # id or start the watcher thread — every site is dominated by a
+    # `span.recording` or `tracing.get_tracer() is not None` test
+    GuardedCall("tracing", ("record_span",), (), "recording",
+                "get_tracer", "self_tracing"),
+    GuardedCall("DEVICE_TIMELINE", ("watch",), (), "recording",
+                "get_tracer", "self_tracing"),
     # flight-recorder triggers (breaker trip, watchdog, slow query)
     # live on failure paths of otherwise-hot code: each site reads
     # RECORDER.enabled before snapshotting state into a bundle

@@ -138,7 +138,10 @@ class HTTPApi:
                body: bytes = b"") -> tuple[int, dict | str]:
         from tempo_tpu.observability import tracing
 
-        parent = tracing.extract_traceparent(headers)
+        # under serve_http the `http.request` root is open and already
+        # took the caller's traceparent: this span is its child
+        parent = (None if tracing.current_span().recording
+                  else tracing.extract_traceparent(headers))
         with tracing.start_span(f"HTTP {method} {_route_template(path)}",
                                 kind=tracing.KIND_SERVER,
                                 parent=parent) as span:
@@ -786,14 +789,49 @@ def serve_http(api: HTTPApi, host: str = "0.0.0.0", port: int = 3200):
     """Blocking stdlib server; returns the server object when used via
     threading (tests call .shutdown())."""
 
+    from tempo_tpu.observability import tracing
+
+    class Accepted(tuple):
+        """A client address that carries the accept stamp from the
+        accept thread to the handler thread."""
+
+    class Server(ThreadingHTTPServer):
+        def get_request(self):
+            request, addr = super().get_request()
+            if tracing.get_tracer() is not None:
+                addr = Accepted(addr)
+                addr.accept_ns = tracing.now_ns()
+            return request, addr
+
     class Handler(BaseHTTPRequestHandler):
+        def _request_span(self):
+            """`http.request`, the root of a served request's trace:
+            from the accept (before the handler thread started and the
+            headers were parsed) to the last byte of the reply. One
+            request per connection (HTTP/1.0), so the accept stamp
+            belongs to this request."""
+            if tracing.get_tracer() is None:
+                return tracing.NOOP_SPAN
+            entered = tracing.now_ns()
+            accept_ns = getattr(self.client_address, "accept_ns", entered)
+            return tracing.start_span(
+                "http.request", kind=tracing.KIND_SERVER,
+                parent=tracing.extract_traceparent(self.headers),
+                start_ns=accept_ns,
+                accept_wait_ms=(entered - accept_ns) / 1e6)
+
         def do_GET(self):  # noqa: N802 — stdlib API
-            u = urlparse(self.path)
-            query = {k: v[0] for k, v in parse_qs(u.query).items()}
-            code, body = api.handle("GET", u.path, query, self.headers)
-            self._reply(code, body)
+            with self._request_span():
+                u = urlparse(self.path)
+                query = {k: v[0] for k, v in parse_qs(u.query).items()}
+                code, body = api.handle("GET", u.path, query, self.headers)
+                self._reply(code, body)
 
         def do_POST(self):  # noqa: N802
+            with self._request_span():
+                self._post()
+
+        def _post(self):
             u = urlparse(self.path)
             query = {k: v[0] for k, v in parse_qs(u.query).items()}
             MAX_BODY = 64 << 20  # cap hostile/streaming bodies
@@ -827,6 +865,18 @@ def serve_http(api: HTTPApi, host: str = "0.0.0.0", port: int = 3200):
             self._reply(code, out)
 
         def _reply(self, code, body):
+            """Render, compress and write; `http.reply` spans it and
+            the open `http.request` takes the status and the size."""
+            with tracing.start_span("http.reply") as span:
+                sent = self._write(code, body)
+                if span.recording:
+                    span.set_attribute("bytes", sent)
+            root = tracing.current_span()
+            if root.recording:
+                root.set_attributes(**{"http.status_code": code,
+                                       "bytes": sent})
+
+        def _write(self, code, body) -> int:
             if isinstance(body, SSEBody):
                 # streaming: no Content-Length, no gzip, flush per
                 # event — buffering would defeat the route's purpose
@@ -835,15 +885,16 @@ def serve_http(api: HTTPApi, host: str = "0.0.0.0", port: int = 3200):
                 self.send_header("Cache-Control", "no-cache")
                 self.send_header("Connection", "close")
                 self.end_headers()
+                sent = 0
                 try:
                     for frame in body.events:
-                        self.wfile.write(frame.encode())
+                        sent += self.wfile.write(frame.encode())
                         self.wfile.flush()
                 except (BrokenPipeError, ConnectionResetError):
                     pass  # client hung up; close() below cleans up
                 finally:
                     body.close()
-                return
+                return sent
             if isinstance(body, (bytes, bytearray)):
                 # negotiated protobuf (Accept: application/protobuf on
                 # the query routes) — reference frontend.go:121-127
@@ -872,9 +923,10 @@ def serve_http(api: HTTPApi, host: str = "0.0.0.0", port: int = 3200):
             self.send_header("Content-Length", str(len(data)))
             self.end_headers()
             self.wfile.write(data)
+            return len(data)
 
         def log_message(self, *a):  # quiet
             pass
 
-    server = ThreadingHTTPServer((host, port), Handler)
+    server = Server((host, port), Handler)
     return server

@@ -16,6 +16,9 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from tempo_tpu.observability import metrics as obs
+from tempo_tpu.observability import tracing
+
 
 class TooManyRequests(Exception):
     """Queue full for tenant (reference: HTTP 429)."""
@@ -174,7 +177,15 @@ class QueueWorkerPool:
             item = self.queue.get()
             if item is None:
                 return  # stopped
-            _tenant, (fut, fn, ctx, stop_event) = item
+            tenant, (fut, fn, ctx, stop_event, enqueued, parent, depth) = item
+            # the queue wait, enqueue -> a worker has the job: the
+            # histogram sample and the span are the same two stamps
+            started = tracing.now_ns()
+            obs.frontend_queue_duration.observe((started - enqueued) / 1e9)
+            if parent is not None and tracing.get_tracer() is not None:
+                tracing.record_span(
+                    "frontend.queue_wait", enqueued, started, parent=parent,
+                    tenant=tenant, depth=depth)
             if not fut.set_running_or_notify_cancel():
                 continue
             if stop_event is not None and stop_event.is_set():
@@ -193,7 +204,14 @@ class QueueWorkerPool:
         self._ensure_started()
         fut: concurrent.futures.Future = concurrent.futures.Future()
         ctx = ctx if ctx is not None else contextvars.copy_context()
-        self.queue.enqueue(tenant, (fut, fn, ctx, stop_event))
+        # with a tracer: the submitter's span, which the wait hangs
+        # under, and the tenant's queue length as this job found it
+        parent, depth = None, 0
+        if tracing.get_tracer() is not None:
+            parent = tracing.current_span().context
+            depth = self.queue.lengths().get(tenant, 0)
+        self.queue.enqueue(tenant, (fut, fn, ctx, stop_event,
+                                    tracing.now_ns(), parent, depth))
         return fut
 
     def run_jobs(self, tenant: str, jobs, fn, stop_event=None):
@@ -232,7 +250,6 @@ class QueueWorkerPool:
                     # DeadlineExceeded is booked ONCE by the frontend
                     # under reason=deadline — counting it here too
                     # would double-bill the same event.
-                    from tempo_tpu.observability import metrics as obs
                     from tempo_tpu.robustness import DeadlineExceeded
 
                     if not isinstance(e, DeadlineExceeded):

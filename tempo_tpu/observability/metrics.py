@@ -365,6 +365,22 @@ coalesce_wait_seconds = Histogram(
     "time a query spent waiting in the coalescing window before its "
     "fused dispatch launched",
     buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1))
+prepare_memo = Counter(
+    "tempo_search_prepare_memo_total",
+    "per-group lookups of the batcher's (batch, predicate) prepare "
+    "memo by result (hit|miss); a miss pays the per-block predicate "
+    "compile on the host")
+frontend_queue_duration = Histogram(
+    "tempo_query_frontend_queue_duration_seconds",
+    "time a frontend sub-request waited in the per-tenant fair queue "
+    "before a worker started it (reference: "
+    "cortex_query_frontend_queue_duration_seconds)",
+    buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5))
+device_timeline_dropped = Counter(
+    "tempo_search_device_timeline_dropped_total",
+    "kernel launches left off the traced device timeline (no "
+    "`device.scan` span): the watcher's queue was full, or the span's "
+    "export raised")
 fallback_scans = Counter("tempo_search_fallback_scans_total",
                          "trace-block proto scans for blocks lacking "
                          "search data")

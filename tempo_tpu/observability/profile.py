@@ -25,8 +25,13 @@ recent ones) and aggregate into metrics:
   tempo_search_jit_cache_events_total{result}       (counter)
   tempo_search_h2d_bytes_total / tempo_search_d2h_bytes_total
 
-Stage events also annotate the active self-trace span, so a slow
-query's own trace shows which stage ate the time.
+Each timed stage also becomes a `dispatch.<stage>` child of the active
+self-trace span, with the interval the stage timer observed, so a slow
+query's own trace shows which stage ate the time and when. While a
+tracer is installed, DEVICE_TIMELINE adds the device's side: one
+`device.scan` span per kernel launch, from a watcher thread that waits
+on the launches' outputs in launch order (no fence on the dispatch
+path).
 
 Design constraints (mirrors tracing.py's noop stance):
 - A TRUE noop path: with profiling disabled every call site pays one
@@ -53,12 +58,13 @@ Design constraints (mirrors tracing.py's noop stance):
 from __future__ import annotations
 
 import contextlib
+import itertools
+import queue
 import threading
 import time
 from collections import deque
 
 from . import metrics as obs
-from . import selftrace
 from . import tracing
 from .log import get_logger
 
@@ -122,6 +128,9 @@ class _NoopDispatch:
     def add_stage(self, name, seconds):
         return self
 
+    def add_interval(self, name, start_ns, end_ns):
+        return self
+
     def add_bytes(self, h2d=0, d2h=0):
         return self
 
@@ -156,12 +165,11 @@ class _StageTimer:
         self._name = name
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._t0 = tracing.now_ns()
         return self
 
     def __exit__(self, *a):
-        self._rec.add_stage(self._name,
-                            time.perf_counter() - self._t0)
+        self._rec.add_interval(self._name, self._t0, tracing.now_ns())
         return False
 
 
@@ -169,13 +177,17 @@ class Dispatch:
     """One in-flight dispatch's profile record. Context-manager; the
     record is published (ring + metrics + span event) on close()."""
 
-    __slots__ = ("mode", "stages", "h2d_bytes", "d2h_bytes", "jit",
-                 "jit_key", "attrs", "t0", "_prof", "_closed")
+    __slots__ = ("mode", "stages", "intervals", "h2d_bytes", "d2h_bytes",
+                 "jit", "jit_key", "attrs", "t0", "_prof", "_closed")
     enabled = True
 
     def __init__(self, prof, mode: str):
         self.mode = mode
         self.stages: dict[str, float] = {}
+        # (stage, start_ns, end_ns) as the stage timers read them, kept
+        # only while a tracer is installed: _finish writes them as
+        # `dispatch.<stage>` spans
+        self.intervals = [] if tracing.get_tracer() is not None else None
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.jit = None       # None (no kernel), "hit" or "miss"
@@ -190,6 +202,15 @@ class Dispatch:
 
     def add_stage(self, name: str, seconds: float) -> "Dispatch":
         self.stages[name] = self.stages.get(name, 0.0) + seconds
+        return self
+
+    def add_interval(self, name: str, start_ns: int,
+                     end_ns: int) -> "Dispatch":
+        """A stage from two `tracing.now_ns()` stamps: its seconds and
+        its span are the same two clock reads."""
+        self.add_stage(name, (end_ns - start_ns) / 1e9)
+        if self.intervals is not None:
+            self.intervals.append((name, start_ns, end_ns))
         return self
 
     def add_bytes(self, h2d: int = 0, d2h: int = 0) -> "Dispatch":
@@ -312,12 +333,14 @@ class DispatchProfiler:
                 self._stage_listeners.append(fn)
 
     def observe_stage(self, stage: str, mode: str, seconds: float,
-                      nbytes: int = 0) -> None:
+                      nbytes: int = 0, spanned: bool = False) -> None:
         """Record one stage observation outside a dispatch record (e.g.
         staging H2D that serves many later dispatches, or the drain-side
         D2H fetch). Noop when disabled. `nbytes` feeds the transfer
         counters only for the transfer stages; other stages (the host
-        prefilter's scanned bytes) keep it in the aggregates alone."""
+        prefilter's scanned bytes) keep it in the aggregates alone.
+        `spanned`: the caller wrote a span from the same two stamps, so
+        the trace gets no `profile.stage` event beside it."""
         # liveness stamp (see dispatch()) — but NOT for host-only work:
         # mode=host_probe runs with the device wedged just fine, and a
         # fresh last_dispatch_age_s fed by host scans would mask exactly
@@ -345,6 +368,8 @@ class DispatchProfiler:
                 fn(stage, mode, seconds, nbytes)
             except Exception:  # noqa: BLE001 — listeners never fail a scan
                 pass
+        if spanned:
+            return
         span = tracing.current_span()
         if span.recording:
             span.add_event("profile.stage", stage=stage, mode=mode,
@@ -414,19 +439,29 @@ class DispatchProfiler:
                 fn(rd)
             except Exception:  # noqa: BLE001 — listeners never fail a scan
                 pass
-        span = tracing.current_span()
-        if span.recording:
-            span.add_event(
-                "dispatch.profile", mode=rec.mode,
-                jit_cache=rec.jit or "",
-                **{f"{k}_ms": round(v * 1e3, 3)
-                   for k, v in rec.stages.items()})
-            # dogfood pipeline: the record additionally lowers into
-            # per-stage child spans of the active span, so structural
-            # queries over span.stage see real dispatch telemetry
-            # (observability/selftrace; gate off = one attribute read)
-            if selftrace.SELFTRACE.ingest_enabled:
-                selftrace.SELFTRACE.lower_dispatch(rec, parent=span)
+        if rec.intervals:
+            span = tracing.current_span()
+            if span.recording:
+                self._write_stage_spans(rec, span.context)
+
+    @staticmethod
+    def _write_stage_spans(rec: Dispatch, parent) -> None:
+        """`dispatch.<stage>` children of the span that was active when
+        the dispatch closed, each with the interval its stage timer
+        observed. A stage that was only given a duration (add_stage)
+        has no span: a start and end nobody read from the clock would
+        be worse than none on a timeline shared with the device's."""
+        for stage, start_ns, end_ns in rec.intervals:
+            span = tracing.start_span(f"dispatch.{stage}", parent=parent,
+                                      start_ns=start_ns, stage=stage,
+                                      mode=rec.mode)
+            if stage == "h2d" and rec.h2d_bytes:
+                span.set_attribute("bytes", rec.h2d_bytes)
+            elif stage == "d2h" and rec.d2h_bytes:
+                span.set_attribute("bytes", rec.d2h_bytes)
+            if stage in ("compile", "execute") and rec.jit is not None:
+                span.set_attribute("jit_cache", rec.jit)
+            span.end(end_ns)
 
     # ---- operator surface ----
 
@@ -467,6 +502,100 @@ class DispatchProfiler:
 
 
 PROFILER = DispatchProfiler()
+
+
+class DeviceTimeline:
+    """The device's timeline as the program can see it, on the span
+    clock: one `device.scan` span per kernel launch.
+
+    Launch sites hand their outputs over right after the enqueue
+    (`watch`, only while a tracer is installed); one watcher thread
+    takes them in that order and waits on each (`block_until_ready`
+    releases the GIL). The device runs launches in enqueue order, so a
+    launch ran from the later of (its enqueue, the previous launch's
+    outputs ready) to its own outputs ready. Nothing is fenced on the
+    dispatch path: the batcher's dispatch/drain pipelining stays.
+
+    Limits: the watcher needs the GIL to stamp, so while Python is busy
+    elsewhere a completion is stamped up to a switch interval late and
+    busy time is over-read there; two threads that enqueue at the same
+    moment may hand over in the other order, which moves time between
+    their two spans and leaves the union as it was."""
+
+    IDLE_EXIT_S = 1.0
+    # launches queued for the watcher at most: each pins its output
+    # arrays on the device until it is taken. The device runs some
+    # tens deep at most, so a queue this long means the watcher is
+    # stuck behind a launch that never completes; further launches
+    # are then left off the timeline and counted instead
+    MAX_QUEUED = 1024
+
+    def __init__(self) -> None:
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: threading.Thread | None = None
+        self._ids = itertools.count(1)
+
+    def watch(self, out, parent, queries: int, blocks: int,
+              kernel: str) -> int:
+        """Queue one launch's outputs; returns its launch id (the join
+        key `coalescer.wait` / `coalescer.launch` carry). `parent` is
+        the SpanContext the `device.scan` span hangs under."""
+        launch = next(self._ids)
+        with self._lock:
+            if self._q.qsize() >= self.MAX_QUEUED:
+                obs.device_timeline_dropped.inc()
+                return launch
+            self._q.put((launch, tracing.now_ns(), out, parent, queries,
+                         blocks, kernel))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, daemon=True, name="device-timeline")
+                self._thread.start()
+        return launch
+
+    def running(self) -> bool:
+        with self._lock:
+            return self._thread is not None
+
+    def _run(self) -> None:
+        prev_ready = 0
+        try:
+            while True:
+                try:
+                    item = self._q.get(timeout=self.IDLE_EXIT_S)
+                except queue.Empty:
+                    with self._lock:
+                        # the thread lives only while a tracer is installed
+                        if (tracing.get_tracer() is None
+                                and self._q.empty()):
+                            self._thread = None
+                            return
+                    continue
+                launch, enqueued, out, parent, queries, blocks, kernel = item
+                fence_arrays(out)
+                ready = tracing.now_ns()
+                try:
+                    if tracing.get_tracer() is not None:
+                        tracing.record_span(
+                            "device.scan", max(enqueued, prev_ready), ready,
+                            parent=parent, launch=launch, queries=queries,
+                            blocks=blocks, kernel=kernel)
+                except Exception:  # noqa: BLE001 — an exporter that
+                    # raises loses this launch's span, not the watcher
+                    obs.device_timeline_dropped.inc()
+                    log.exception("device.scan span of launch %d lost",
+                                  launch)
+                prev_ready = ready
+        finally:
+            # whatever ended this thread, the next `watch` starts
+            # another (unless one already has)
+            with self._lock:
+                if self._thread is threading.current_thread():
+                    self._thread = None
+
+
+DEVICE_TIMELINE = DeviceTimeline()
 
 
 def configure(enabled: bool | None = None, fence: bool | None = None,
@@ -512,8 +641,9 @@ def dispatch(mode: str):
 
 
 def observe_stage(stage: str, mode: str, seconds: float,
-                  nbytes: int = 0) -> None:
-    PROFILER.observe_stage(stage, mode, seconds, nbytes=nbytes)
+                  nbytes: int = 0, spanned: bool = False) -> None:
+    PROFILER.observe_stage(stage, mode, seconds, nbytes=nbytes,
+                           spanned=spanned)
 
 
 def build_info() -> dict:

@@ -4,27 +4,25 @@
 the "tempo traces tempo" loop: tracing.InProcessExporter pushes every
 finished self-trace span through the normal distributor/TenantInstance
 ingest path into the reserved ``_selftrace`` tenant, and THIS module
-enriches those traces at two points the plain exporter cannot see:
+enriches those traces where the plain exporter cannot see:
 
-  - ``lower_dispatch``: a finished profiler dispatch record
-    (observability/profile.Dispatch) is lowered into per-stage CHILD
-    spans — build/h2d/compile/execute/d2h/lock_wait — under the span
-    that was active when the dispatch closed, with transfer bytes and
-    the jit-cache verdict as attributes. Stage times are reconstructed
-    (laid back-to-back ending at the lowering instant), not observed
-    live, so structural queries like
-    ``{ span.stage = "h2d" && duration > 50ms }`` work over real
-    dispatch telemetry.
   - ``annotate_query``: a finished request-scope QueryStats breakdown
     attaches as ``query.*`` attributes on the request span, so the
     trace of a slow search carries its own cost accounting.
+
+The per-stage ``dispatch.<stage>`` children (build/h2d/compile/execute/
+d2h/lock_wait, with transfer bytes and the jit-cache verdict) come from
+the dispatch profiler itself, with the intervals its stage timers
+observed (observability/profile.py), whenever a tracer records — so
+structural queries like ``{ span.stage = "h2d" && duration > 50ms }``
+over this tenant mean what they say.
 
 Noop contract (the PR 9 stance, statically checked by the
 NoopContractChecker): with the gate off every call site pays ONE
 attribute read — no allocation, no clock, no lock — and outputs are
 byte-identical. Feedback safety: the ingest-of-self-spans path runs
 under tracing._suppressed, so the spans describing the self-ingest are
-never themselves traced; additionally both hooks bail when the current
+never themselves traced; additionally the hook bails when the current
 span is not recording, which covers suppressed and sampled-out paths.
 
 The anomaly flight recorder (observability/flightrecorder.RECORDER)
@@ -35,14 +33,7 @@ snapshot bounded diagnostic bundles whose trace ids resolve in
 
 from __future__ import annotations
 
-import time
-
 from . import tracing
-
-# lowering order — stages are laid back-to-back in the order the
-# dispatch path actually runs them (profile.STAGES minus the reorder:
-# lock_wait precedes the guarded body on mesh paths)
-_STAGE_ORDER = ("lock_wait", "build", "h2d", "compile", "execute", "d2h")
 
 
 class SelfTraceGate:
@@ -53,43 +44,6 @@ class SelfTraceGate:
 
     def __init__(self) -> None:
         self.ingest_enabled = False
-
-    def lower_dispatch(self, rec, parent=None) -> None:
-        """Lower a finished profiler ``Dispatch`` record into per-stage
-        child spans of `parent` (default: the current span). The record
-        holds durations, not timestamps, so the children are synthesized
-        back-to-back ending now — inside the real dispatch window to
-        clock resolution, and honest about per-stage duration, which is
-        what structural duration predicates query."""
-        if not self.ingest_enabled:
-            return
-        tracer = tracing.get_tracer()
-        if tracer is None:
-            return
-        if parent is None:
-            parent = tracing.current_span()
-        if not parent.recording or not rec.stages:
-            return
-        end_ns = time.time_ns()
-        cursor = end_ns - int(sum(rec.stages.values()) * 1e9)
-        for stage in _STAGE_ORDER:
-            sec = rec.stages.get(stage)
-            if sec is None:
-                continue
-            dur_ns = int(sec * 1e9)
-            span = tracer.start_span(f"dispatch.{stage}",
-                                     parent=parent.context,
-                                     stage=stage, mode=rec.mode)
-            if span.recording:
-                if stage == "h2d" and rec.h2d_bytes:
-                    span.set_attribute("bytes", rec.h2d_bytes)
-                elif stage == "d2h" and rec.d2h_bytes:
-                    span.set_attribute("bytes", rec.d2h_bytes)
-                if stage in ("compile", "execute") and rec.jit is not None:
-                    span.set_attribute("jit_cache", rec.jit)
-                span.start_ns = cursor
-                span.end(end_ns=cursor + dur_ns)
-            cursor += dur_ns
 
     def annotate_query(self, d: dict) -> None:
         """Attach a finished request-scope QueryStats dict (to_dict

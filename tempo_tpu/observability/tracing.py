@@ -47,6 +47,30 @@ STATUS_UNSET = 0
 STATUS_OK = 1
 STATUS_ERROR = 2
 
+# the span clock: unix nanoseconds from ONE wall-clock anchor plus the
+# monotonic counter, so stamps taken on different threads order and
+# subtract exactly inside a run (time.time_ns() can step) and still
+# compare with a wall_ns sampled by whoever correlates an external
+# trace. Every stamp a span, a profiler stage or a batcher stage sum
+# takes is a read of this one clock. The price: between two anchors
+# the clock does not follow the wall clock's corrections, so exported
+# times drift against other hosts' by that much (docs/observability.md).
+_CLOCK_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def now_ns() -> int:
+    return _CLOCK_OFFSET_NS + time.perf_counter_ns()
+
+
+def _reanchor_clock() -> None:
+    """Take the anchor anew once the wall clock has moved a millisecond
+    off it: called when a tracer is installed, the one moment no span
+    of it is open. Below that the clock stays continuous."""
+    global _CLOCK_OFFSET_NS
+    offset = time.time_ns() - time.perf_counter_ns()
+    if abs(offset - _CLOCK_OFFSET_NS) > 1_000_000:
+        _CLOCK_OFFSET_NS = offset
+
 
 class SpanContext:
     __slots__ = ("trace_id", "span_id", "sampled")
@@ -65,12 +89,13 @@ class Span:
                  "status_message", "_tracer", "_token")
 
     def __init__(self, tracer, name: str, context: SpanContext,
-                 parent_span_id: bytes | None, kind: int):
+                 parent_span_id: bytes | None, kind: int,
+                 start_ns: int | None = None):
         self.name = name
         self.context = context
         self.parent_span_id = parent_span_id
         self.kind = kind
-        self.start_ns = time.time_ns()
+        self.start_ns = start_ns or now_ns()
         self.end_ns = 0
         self.attributes: dict = {}
         self.events: list = []
@@ -92,7 +117,7 @@ class Span:
         return self
 
     def add_event(self, name: str, **attributes) -> "Span":
-        self.events.append((time.time_ns(), name, attributes))
+        self.events.append((now_ns(), name, attributes))
         return self
 
     def set_status(self, code: int, message: str = "") -> "Span":
@@ -107,12 +132,12 @@ class Span:
         return self.set_status(STATUS_ERROR, str(exc))
 
     def end(self, end_ns: int | None = None) -> None:
-        """end_ns: explicit end timestamp for synthesized spans (the
-        dogfood pipeline lowers profiler stage records into child spans
-        whose times are reconstructed, not observed live)."""
+        """end_ns: a `now_ns()` stamp the caller already took at the
+        edge this span ends on (a wait that ended on another thread, a
+        stage whose histogram sample reads the same stamp)."""
         if self.end_ns:
             return
-        self.end_ns = end_ns or time.time_ns()
+        self.end_ns = end_ns or now_ns()
         if self.context.sampled:
             self._tracer._on_end(self)
 
@@ -152,7 +177,7 @@ class _NoopSpan:
     def record_exception(self, exc):
         return self
 
-    def end(self):
+    def end(self, end_ns=None):
         pass
 
     def __enter__(self):
@@ -192,7 +217,7 @@ class NonRecordingSpan:
     def record_exception(self, exc):
         return self
 
-    def end(self):
+    def end(self, end_ns=None):
         pass
 
     def __enter__(self):
@@ -219,7 +244,12 @@ class Tracer:
         self._rng = random.Random()
 
     def start_span(self, name: str, kind: int = KIND_INTERNAL,
-                   parent: SpanContext | None = None, **attributes):
+                   parent: SpanContext | None = None,
+                   start_ns: int | None = None, **attributes):
+        """`start_ns`: a `now_ns()` stamp taken where the spanned work
+        began — with `parent=` a context captured there and
+        `end(end_ns=)`, a wait that crossed threads is written after
+        the fact from its two stamps (`record_span`)."""
         if _suppressed.get():
             return NOOP_SPAN
         cur = _current_span.get()
@@ -238,7 +268,7 @@ class Tracer:
                                                 or b"\x00" * 8, False))
         ctx = SpanContext(trace_id,
                           self._rng.getrandbits(64).to_bytes(8, "big"), True)
-        span = Span(self, name, ctx, parent_id, kind)
+        span = Span(self, name, ctx, parent_id, kind, start_ns)
         if attributes:
             span.attributes.update(attributes)
         return span
@@ -513,6 +543,8 @@ _tracer: Tracer | None = None
 
 def set_tracer(tracer: Tracer | None) -> None:
     global _tracer
+    if tracer is not None:
+        _reanchor_clock()
     _tracer = tracer
 
 
@@ -521,12 +553,23 @@ def get_tracer() -> Tracer | None:
 
 
 def start_span(name: str, kind: int = KIND_INTERNAL,
-               parent: SpanContext | None = None, **attributes):
+               parent: SpanContext | None = None,
+               start_ns: int | None = None, **attributes):
     """Module-level convenience: noop when no tracer is installed."""
     t = _tracer
     if t is None:
         return NOOP_SPAN
-    return t.start_span(name, kind=kind, parent=parent, **attributes)
+    return t.start_span(name, kind=kind, parent=parent, start_ns=start_ns,
+                        **attributes)
+
+
+def record_span(name: str, start_ns: int, end_ns: int,
+                parent: SpanContext | None = None, **attributes) -> None:
+    """Write a finished span from two `now_ns()` stamps. Call sites on
+    hot paths guard on `get_tracer() is not None` (or a recording
+    span) BEFORE building the attributes."""
+    start_span(name, parent=parent, start_ns=start_ns,
+               **attributes).end(end_ns)
 
 
 def current_span():

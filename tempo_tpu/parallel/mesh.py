@@ -43,14 +43,13 @@ def locked_collective(rec=None):
     fault into the circuit breaker and raises DispatchLockTimeout, so
     the submitter falls back to the host path instead of stacking.
     <= 0 restores the unbounded wait."""
-    import time
-
     from tempo_tpu.robustness import BREAKER, GUARD, FAULTS
     from tempo_tpu.robustness.dispatch import DispatchLockTimeout
     from tempo_tpu.observability import metrics as obs
+    from tempo_tpu.observability import tracing
 
     timeout = GUARD.lock_timeout_s
-    t0 = time.perf_counter()
+    t0 = tracing.now_ns()
     if timeout and timeout > 0:
         ok = dispatch_lock.acquire(timeout=timeout)
     else:
@@ -64,7 +63,7 @@ def locked_collective(rec=None):
         raise DispatchLockTimeout(msg)
     try:
         if rec is not None:
-            rec.add_stage("lock_wait", time.perf_counter() - t0)
+            rec.add_interval("lock_wait", t0, tracing.now_ns())
         if FAULTS.active:
             # simulates a dispatch wedged INSIDE the collective section
             # (holding the lock): later submitters hit the bounded wait

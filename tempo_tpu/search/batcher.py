@@ -212,7 +212,9 @@ class _PendingCoalesce:
     def __init__(self, batch, gen):
         self.batch = batch
         self.gen = gen
-        self.items = []     # [(mq, top_k, Future, t_submit, QueryStats|None)]
+        # [(mq, top_k, Future, submit stamp (tracing.now_ns),
+        #   QueryStats|None, the submitter's SpanContext|None)]
+        self.items = []
 
 
 class _FusedOut:
@@ -368,12 +370,18 @@ class QueryCoalescer:
         The submitter's active QueryStats is captured WITH the item
         (the contextvar does not survive into the window-timer flush
         thread): at flush time the dispatch's profiled stage times are
-        apportioned across the member queries' stats."""
+        apportioned across the member queries' stats. So is, while a
+        tracer is installed, the submitter's span context (its
+        `batcher.Search`): the member's `coalescer.wait` hangs under it
+        whichever thread flushes."""
         import concurrent.futures
         import heapq
         import time as _time
 
         fut = concurrent.futures.Future()
+        parent = None
+        if tracing.get_tracer() is not None:
+            parent = tracing.current_span().context
         st = getattr(mq, "structural", None)
         key = (id(batch), None)
         if st is not None:
@@ -387,8 +395,8 @@ class QueryCoalescer:
                 # recorded here, so _run won't double-book solo_shape.
                 obs.structural_stack_events.inc(result="solo_disabled")
                 grp = _PendingCoalesce(batch, -1)
-                grp.items.append((mq, top_k, fut, _time.perf_counter(),
-                                  query_stats.current()))
+                grp.items.append((mq, top_k, fut, tracing.now_ns(),
+                                  query_stats.current(), parent))
                 self._run(grp)
                 return fut
             key = skey
@@ -404,8 +412,8 @@ class QueryCoalescer:
             if grp is None:
                 self._gen += 1
                 grp = self._pending[key] = _PendingCoalesce(batch, self._gen)
-            grp.items.append((mq, top_k, fut, _time.perf_counter(),
-                              query_stats.current()))
+            grp.items.append((mq, top_k, fut, tracing.now_ns(),
+                              query_stats.current(), parent))
             if len(grp.items) >= self.max_queries:
                 del self._pending[key]
                 flush_now = grp
@@ -514,16 +522,52 @@ class QueryCoalescer:
                 qs.add_device_stages(share, h2d_bytes=bs["b"],
                                      fused_q=len(items))
 
+    @staticmethod
+    def _trace_launch(lspan, items, batch, out, recs,
+                      launched: int) -> None:
+        """Close one launch's spans at `launched`, the stamp taken when
+        the kernel call returned: `coalescer.launch` (open since the
+        flush began) ends there, the device timeline takes the outputs
+        over, and every traced member gets its `coalescer.wait`, from
+        its own submit to this launch, under its own `batcher.Search`.
+        All carry the launch id, so a reader joins a member's wait to
+        the one launch and the one `device.scan` that served it."""
+        if not lspan.recording:
+            return
+        # the profiler's names: a fused launch is kernel and mode
+        # `coalesced`, a solo one kernel `multi` in mode `batched`
+        fused = len(items) > 1
+        kernel = "coalesced" if fused else "multi"
+        mode = "coalesced" if fused else "batched"
+        launch = profile.DEVICE_TIMELINE.watch(
+            out, lspan.context, len(items), len(batch.blocks), kernel)
+        lspan.set_attributes(
+            launch=launch, queries=len(items), blocks=len(batch.blocks),
+            kernel=kernel,
+            jit_cache=(recs[0].get("jit_cache", "") if recs else ""))
+        lspan.end(launched)
+        for _mq, _k, _fut, t_submit, _qs, parent in items:
+            if parent is not None:
+                tracing.record_span(
+                    "coalescer.wait", t_submit, launched, parent=parent,
+                    launch=launch, queries=len(items), mode=mode)
+
     def _run(self, grp: _PendingCoalesce) -> None:
-        import time as _time
-
-        from tempo_tpu.observability import profile
-
         items = grp.items
         try:
-            now = _time.perf_counter()
-            for _mq, _k, _fut, t0, _qs in items:
-                obs.coalesce_wait_seconds.observe(now - t0)
+            now = tracing.now_ns()
+            for _mq, _k, _fut, t0, _qs, _p in items:
+                obs.coalesce_wait_seconds.observe((now - t0) / 1e9)
+            # the launch's own span hangs under its first traced member
+            # and is CURRENT for the kernel call, so the profiler's
+            # `dispatch.<stage>` spans land under it on whichever thread
+            # flushes (the window pool's threads carry no span)
+            first = next((p for *_r, p in items
+                          if p is not None and p.sampled), None)
+            lspan = tracing.NOOP_SPAN
+            if first is not None:
+                lspan = tracing.start_span("coalescer.launch",
+                                           parent=first, start_ns=now)
             structural = bool(
                 items and getattr(items[0][0], "structural", None)
                 is not None)
@@ -559,16 +603,20 @@ class QueryCoalescer:
                 else:
                     obs.structural_stack_events.inc(result="solo_shape")
             if len(items) == 1:
-                mq, _k, fut, _t0, _qs = items[0]
-                t0d = _time.perf_counter()
-                with profile.collect_records() as recs:
-                    out = self.engine.scan_async(grp.batch, mq)
-                self._attribute(items, recs, _time.perf_counter() - t0d)
+                mq, _k, fut, _t0, _qs, _p = items[0]
+                with lspan:
+                    t0d = tracing.now_ns()
+                    with profile.collect_records() as recs:
+                        out = self.engine.scan_async(grp.batch, mq)
+                    launched = tracing.now_ns()
+                    self._trace_launch(lspan, items, grp.batch, out, recs,
+                                       launched)
+                self._attribute(items, recs, (launched - t0d) / 1e9)
                 start_fetch(out)
                 obs.scan_dispatches.inc(mode="batched")
                 fut.set_result(out)
                 return
-            mqs = [mq for mq, _k, _f, _t, _qs in items]
+            mqs = [it[0] for it in items]
             cq = stack_queries(mqs)
             st = getattr(cq, "structural", None)
             if st is not None and getattr(st, "slot_nodes", 0):
@@ -584,11 +632,15 @@ class QueryCoalescer:
                     row["dispatches"] += 1
                     row["active_nodes"] += st.active_nodes
                     row["slot_nodes"] += st.slot_nodes
-            k = max(k for _mq, k, _f, _t, _qs in items)
-            t0d = _time.perf_counter()
-            with profile.collect_records() as recs:
-                out = self.engine.coalesced_scan_async(grp.batch, cq, k)
-            self._attribute(items, recs, _time.perf_counter() - t0d)
+            k = max(it[1] for it in items)
+            with lspan:
+                t0d = tracing.now_ns()
+                with profile.collect_records() as recs:
+                    out = self.engine.coalesced_scan_async(grp.batch, cq, k)
+                launched = tracing.now_ns()
+                self._trace_launch(lspan, items, grp.batch, out, recs,
+                                   launched)
+            self._attribute(items, recs, (launched - t0d) / 1e9)
             obs.scan_dispatches.inc(mode="coalesced")
             obs.coalesced_queries.inc(len(items))
             # D2H starts async NOW; the one blocking sync point happens
@@ -597,12 +649,12 @@ class QueryCoalescer:
             # which still has its own dispatch loop to overlap
             start_fetch(out)
             shared = _FusedOut(out)
-            for qi, (_mq, _k, fut, _t0, _qs) in enumerate(items):
-                fut.set_result(_FusedSlice(shared, qi))
+            for qi, it in enumerate(items):
+                it[2].set_result(_FusedSlice(shared, qi))
         except BaseException as e:  # noqa: BLE001 — delivered via futures
-            for _mq, _k, fut, _t0, _qs in items:
-                if not fut.done():
-                    fut.set_exception(e)
+            for it in items:
+                if not it[2].done():
+                    it[2].set_exception(e)
 
     def stats(self) -> dict:
         with self._lock:
@@ -1225,14 +1277,43 @@ class BlockBatcher:
         # operator's first question about a slow query is which stage ate
         # it — host prune, staging IO+H2D, predicate compile, kernel, or
         # the D2H fetch/merge
-        import time as _time
         stages = {"header_prune": 0.0, "staging": 0.0, "prepare": 0.0,
                   "dispatch": 0.0, "drain": 0.0, "host_fallback": 0.0}
-        t_search0 = _time.perf_counter()
+        t_search0 = tracing.now_ns()
+
+        def book(stage, t0, gi, group, key=None, val=None):
+            """One stage interval ends now. Its seconds go to the stage
+            sums; in a traced search the same two stamps make the
+            `batcher.<stage>` child of `batcher.Search` (`span`, bound
+            below, before any stage runs)."""
+            t1 = tracing.now_ns()
+            stages[stage] += (t1 - t0) / 1e9
+            if span.recording:
+                sp = tracing.start_span(
+                    "batcher.stage" if stage == "staging"
+                    else "batcher." + stage,
+                    parent=span.context, start_ns=t0, group=gi,
+                    blocks=len(group))
+                if key is not None:
+                    sp.set_attribute(key, val)
+                sp.end(t1)
 
         def drain_one():
-            t0 = _time.perf_counter()
-            gkey, cached, mq, pre, fut = inflight.popleft()
+            t0 = tracing.now_ns()
+            item = inflight.popleft()
+            dspan = tracing.NOOP_SPAN
+            if span.recording:
+                dspan = tracing.start_span(
+                    "batcher.drain", parent=span.context, start_ns=t0,
+                    group=item[0], blocks=len(item[2].jobs))
+            try:
+                drain(dspan, *item)
+            finally:
+                t1 = tracing.now_ns()
+                stages["drain"] += (t1 - t0) / 1e9
+                dspan.end(t1)
+
+        def drain(dspan, gi, gkey, cached, mq, pre, fut):
             try:
                 if hasattr(fut, "result"):  # coalescer Future vs tuple
                     # NOT timed as d2h: a coalescer Future's wait
@@ -1245,7 +1326,7 @@ class BlockBatcher:
                 # transfer, not queue. Watchdog-bounded: a wedged
                 # device can hang the SYNC even when the enqueue
                 # returned, and that hang must become a fault too.
-                t0d = _time.perf_counter()
+                t0d = tracing.now_ns()
 
                 def _sync(fut=fut):
                     count, inspected, scores, idx, *ext = fut
@@ -1264,7 +1345,6 @@ class BlockBatcher:
                 # not waited for
                 results.metrics.partial = True
                 obs.partial_results.inc(reason="deadline")
-                stages["drain"] += _time.perf_counter() - t0
                 return
             except robustness.DeviceFault:
                 # the dispatch (or its sync) died on the device — the
@@ -1274,15 +1354,19 @@ class BlockBatcher:
                 # each member's drain resubmits its own query here.
                 # book_skips=False: the main loop already counted this
                 # group's skipped blocks/reasons at prepare time.
-                host_route(cached.jobs, gkey,
-                           hdr_reasons_for(cached.jobs),
+                host_route(gi, cached.jobs, gkey,
+                           hdr_reasons_for(gi, cached.jobs),
                            book_skips=False)
-                stages["drain"] += _time.perf_counter() - t0
                 return
-            d2h_s = _time.perf_counter() - t0d
+            t1d = tracing.now_ns()
+            d2h_s = (t1d - t0d) / 1e9
+            if span.recording:
+                # what the `d2h` stage times: the one blocking sync
+                tracing.record_span("batcher.sync", t0d, t1d,
+                                    parent=dspan.context, group=gi)
             profile.observe_stage(
                 "d2h", "batched", d2h_s,
-                nbytes=scores.nbytes + idx.nbytes + 8)
+                nbytes=scores.nbytes + idx.nbytes + 8, spanned=True)
             if qs is not None:
                 # the wait THIS query paid for its results (for a fused
                 # group the first drainer pays the real sync); count=False
@@ -1333,7 +1417,6 @@ class BlockBatcher:
                 results.add(m)
             if agg_counts:
                 results.add_agg(mq.agg_stage.decode(agg_counts[0]))
-            stages["drain"] += _time.perf_counter() - t0
 
         def _skip_reason_counts(skip, reasons) -> dict:
             """reason -> count for the skipped blocks: the header prune
@@ -1421,7 +1504,7 @@ class BlockBatcher:
         # state
         want_agg = ANALYTICS.enabled and agg_requested(req)
 
-        def host_route(group, gkey, hdr_reasons, book_skips=True):
+        def host_route(gi, group, gkey, hdr_reasons, book_skips=True):
             """Scan one group ENTIRELY on the host path: this member is
             not the group's owner (owner-routed HBM), the breaker is
             open/half-open without a probe token, or this group's device
@@ -1435,7 +1518,7 @@ class BlockBatcher:
             already counted this group's skipped blocks/reasons —
             re-booking would inflate skipped_blocks and break the
             wedged-vs-healthy identity whenever a block dict-prunes."""
-            t0 = _time.perf_counter()
+            t0 = tracing.now_ns()
             try:
                 host = self._host_batch(group)
                 skip = [r is not None for r in hdr_reasons]
@@ -1516,34 +1599,32 @@ class BlockBatcher:
                 if agg_counts:
                     results.add_agg(mq.agg_stage.decode(agg_counts[0]))
             finally:
-                stages["host_fallback"] += _time.perf_counter() - t0
+                book("host_fallback", t0, gi, group)
 
-        def hdr_reasons_for(group):
+        def hdr_reasons_for(gi, group):
             """Header-only prune BEFORE staging: a decidably-dead group
             (time window, tag rollup) costs no IO and no HBM. Returns
             the per-job skip REASON list (None = scan it) — truthiness
             keeps `all(...)`/`any(...)` semantics of the old bool list
             while the why survives into the query stats. Memoized so
-            repeats are O(1)."""
-            t0 = _time.perf_counter()
-            try:
-                return _hdr_reasons_for(group)
-            finally:
-                stages["header_prune"] += _time.perf_counter() - t0
-
-        def _hdr_reasons_for(group):
+            repeats are O(1): only a miss, where the headers are read,
+            writes a `batcher.header_prune` span."""
+            t0 = tracing.now_ns()
             gkey = tuple(j.key for j in group)
             with self._lock:
                 reasons = self._prune_cache.get((gkey, sig))
                 if reasons is not None:
                     self._prune_cache.move_to_end((gkey, sig))
-                    return reasons
+            if reasons is not None:
+                stages["header_prune"] += (tracing.now_ns() - t0) / 1e9
+                return reasons
             reasons = [block_header_skip_reason(j.header, req)
                        for j in group]
             with self._lock:
                 self._prune_cache[(gkey, sig)] = reasons
                 while len(self._prune_cache) > _PRUNE_CACHE_MAX:
                     self._prune_cache.popitem(last=False)
+            book("header_prune", t0, gi, group)
             return reasons
 
         prefetched: dict = {}
@@ -1560,7 +1641,7 @@ class BlockBatcher:
                 return  # no lookahead H2D at a blocked device
             for gi in range(from_idx, len(groups)):
                 g = groups[gi]
-                if all(hdr_reasons_for(g)):
+                if all(hdr_reasons_for(gi, g)):
                     continue
                 k = tuple(j.key for j in g)
                 if OWNERSHIP.enabled:
@@ -1604,7 +1685,7 @@ class BlockBatcher:
                     obs.partial_results.inc(reason="deadline")
                     break
                 gkey = tuple(j.key for j in group)
-                hdr_reasons = hdr_reasons_for(group)
+                hdr_reasons = hdr_reasons_for(gi, group)
                 if all(hdr_reasons):
                     results.metrics.skipped_blocks += len(group)
                     if qs is not None:
@@ -1627,13 +1708,13 @@ class BlockBatcher:
                         obs.hbm_owner_routed.inc(route="non_owner_host")
                         if qs is not None:
                             qs.add_cache("non_owner_route")
-                        host_route(group, gkey, hdr_reasons)
+                        host_route(gi, group, gkey, hdr_reasons)
                         continue
                 if not robustness.BREAKER.allow_device():
                     # breaker open (or half-open with its probe tokens
                     # spent): this group runs the byte-identical host
                     # route — no staging put, no device dispatch
-                    host_route(group, gkey, hdr_reasons)
+                    host_route(gi, group, gkey, hdr_reasons)
                     continue
                 if OWNERSHIP.enabled:
                     # counted AFTER the breaker gate: route=owner means
@@ -1643,10 +1724,11 @@ class BlockBatcher:
                     obs.hbm_owner_routed.inc(route="owner")
                 # memo lookup needs the staged batch's identity; the memo
                 # itself lives on the cached batch so it dies with it
-                t0 = _time.perf_counter()
+                t0 = tracing.now_ns()
                 pf = prefetched.pop(gkey, None)
                 fut_staged, pf_event = pf if pf is not None else (None, None)
-                if qs is not None:
+                _event = None
+                if qs is not None or span.recording:
                     # cache behavior as THIS query saw it (the global
                     # batch_cache_events counters can't say whose re-stage
                     # it was). A prefetched group carries the event judged
@@ -1669,10 +1751,10 @@ class BlockBatcher:
                     # the staging H2D hit the wedged device (fault
                     # booked): host tier already holds the stacked
                     # arrays, answer from there
-                    stages["staging"] += _time.perf_counter() - t0
-                    host_route(group, gkey, hdr_reasons)
+                    book("staging", t0, gi, group)
+                    host_route(gi, group, gkey, hdr_reasons)
                     continue
-                stages["staging"] += _time.perf_counter() - t0
+                book("staging", t0, gi, group, "cache", _event)
                 if qs is not None:
                     qs.add_cache(_event)
                     if _event != "hbm_hit" and cached.batch.staged_dicts:
@@ -1686,8 +1768,10 @@ class BlockBatcher:
                     pre = cached.query_cache.get(sig)
                     if pre is not None:
                         cached.query_cache.move_to_end(sig)
+                obs.prepare_memo.inc(
+                    result="miss" if pre is None else "hit")
                 if pre is None:
-                    t0 = _time.perf_counter()
+                    t0 = tracing.now_ns()
                     # attributed: query compilation can fire the device
                     # dictionary probe (mode=dict_probe) — that dispatch
                     # belongs to this query's bill (no wall fallback:
@@ -1697,7 +1781,8 @@ class BlockBatcher:
                         pre = prepare(group, cached.batch,
                                       [r is not None for r in hdr_reasons],
                                       hdr_reasons)
-                    stages["prepare"] += _time.perf_counter() - t0
+                    book("prepare", t0, gi, group, "terms",
+                         pre.get("n_terms", 0))
                     with self._lock:
                         cached.query_cache[sig] = pre
                         while len(cached.query_cache) > _QUERY_CACHE_MAX:
@@ -1745,7 +1830,7 @@ class BlockBatcher:
                     # 10K blocks on every dispatch
                     mq._device_params = dp
                 results.metrics.skipped_blocks += pre["skipped"]
-                t0 = _time.perf_counter()
+                t0 = tracing.now_ns()
                 if self.coalescer is not None:
                     # concurrent peers hitting this batch within the
                     # window share ONE fused kernel launch; a dispatch
@@ -1767,6 +1852,9 @@ class BlockBatcher:
                     try:
                         with query_stats.attributed_dispatch(qs):
                             fut = self.engine.scan_async(cached.batch, mq)
+                        if span.recording:
+                            profile.DEVICE_TIMELINE.watch(
+                                fut, span.context, 1, len(group), "multi")
                         start_fetch(fut)  # D2H begins now, overlapping
                     except robustness.DeviceFault:
                         # direct-path dispatch died at submit (fault
@@ -1774,13 +1862,13 @@ class BlockBatcher:
                         # skips were already counted above, so the
                         # resubmit must not re-book them. Interest for
                         # this gkey is released by the outer finally.
-                        stages["dispatch"] += _time.perf_counter() - t0
-                        host_route(group, gkey, hdr_reasons,
+                        book("dispatch", t0, gi, group)
+                        host_route(gi, group, gkey, hdr_reasons,
                                    book_skips=False)
                         continue
-                stages["dispatch"] += _time.perf_counter() - t0
+                book("dispatch", t0, gi, group)
                 dispatches += 1
-                inflight.append((gkey, cached, mq, pre, fut))
+                inflight.append((gi, gkey, cached, mq, pre, fut))
                 # this search never returns to this batch: release its
                 # interest NOW so later peers don't arm windows for a
                 # fusion that can no longer happen (a parked query still
@@ -1823,7 +1911,7 @@ class BlockBatcher:
                 qs.add_stage(k, v)
         self.last_dispatches = dispatches
         self.last_scan = {
-            "total_ms": round((_time.perf_counter() - t_search0) * 1000, 3),
+            "total_ms": round((tracing.now_ns() - t_search0) / 1e6, 3),
             "stages_ms": {k: round(v * 1000, 3) for k, v in stages.items()},
             "scan_dispatches": dispatches,
             "groups": len(groups),

@@ -340,14 +340,26 @@ def test_profiler_record_aggregation_and_ring(profiler_reset):
 
 
 def test_profiler_stage_events_annotate_span(sync_tracer, profiler_reset):
-    tracer, _ = sync_tracer
+    tracer, exporter = sync_tracer
     with tracer.start_span("query") as span:
         with profile.dispatch("single") as rec:
-            rec.add_stage("execute", 0.003)
+            with rec.stage("execute"):
+                time.sleep(0.001)
+            rec.add_stage("build", 0.003)   # a duration nobody stamped
         profile.observe_stage("d2h", "single", 0.001)
-    names = [name for _ts, name, _attrs in span.events]
-    assert "dispatch.profile" in names
-    assert "profile.stage" in names
+        # its caller wrote a span from the same stamps: no event beside it
+        profile.observe_stage("d2h", "batched", 0.001, spanned=True)
+    # out-of-record observations stay events; a timed stage is a child
+    # span with the interval its timer observed, and a stage that was
+    # only given a duration has no span (its edges were never read)
+    assert [name for _ts, name, _attrs in span.events] == ["profile.stage"]
+    kids = [s for s in exporter.spans if s.name.startswith("dispatch.")]
+    assert [s.name for s in kids] == ["dispatch.execute"]
+    ex = kids[0]
+    assert ex.parent_span_id == span.context.span_id
+    assert span.start_ns <= ex.start_ns < ex.end_ns <= span.end_ns
+    assert (ex.end_ns - ex.start_ns) / 1e9 == rec.stages["execute"]
+    assert ex.attributes == {"stage": "execute", "mode": "single"}
 
 
 def test_profiler_ring_resize_and_bound(profiler_reset):

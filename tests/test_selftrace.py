@@ -15,6 +15,7 @@ id resolves.
 
 import json
 import os
+import time
 
 import pytest
 
@@ -22,7 +23,7 @@ from tempo_tpu import robustness, tempopb
 from tempo_tpu.api.http import HTTPApi
 from tempo_tpu.db.tempodb import TempoDBConfig
 from tempo_tpu.modules import App, AppConfig
-from tempo_tpu.observability import selftrace, tracing
+from tempo_tpu.observability import profile, selftrace, tracing
 from tempo_tpu.observability.flightrecorder import (RECORDER,
                                                     TRIGGER_BREAKER,
                                                     TRIGGER_SLOW_QUERY,
@@ -247,21 +248,7 @@ def test_sse_stream_metrics_and_self_trace(tmp_path):
         app.shutdown()
 
 
-# ------------------------------------------------ stage-span lowering
-
-
-class _Rec:
-    """Minimal stand-in for a finished profile.Dispatch record."""
-
-    mode = "batched"
-    jit = "miss"
-    h2d_bytes = 4096
-    d2h_bytes = 128
-
-    def __init__(self, stages=None):
-        self.stages = stages if stages is not None else {
-            "build": 0.001, "h2d": 0.002, "compile": 0.003,
-            "execute": 0.004, "d2h": 0.0005}
+# --------------------------------------------------- stage child spans
 
 
 def _sync_tracer():
@@ -271,50 +258,66 @@ def _sync_tracer():
     return exp, tracer
 
 
-def test_lower_dispatch_synthesizes_ordered_stage_children():
+def _timed_dispatch(stages=("build", "h2d", "compile", "d2h")):
+    """One profiler record whose stages are timed as the dispatch sites
+    time them (`rec.stage(...)`), with transfer bytes and a jit miss."""
+    with profile.dispatch("batched") as rec:
+        rec.compile_check(("test_selftrace", time.perf_counter_ns()))
+        rec.add_bytes(h2d=4096, d2h=128)
+        for stage in stages:
+            with rec.stage(stage):
+                time.sleep(0.0005)
+    return rec
+
+
+def test_dispatch_stage_children_carry_observed_intervals():
+    """The dogfood tenant's `dispatch.<stage>` spans come from the
+    profiler's stage timers: whether or not the ingest gate is on, each
+    child's start and end are the two clock reads of its timer."""
     exp, tracer = _sync_tracer()
-    selftrace.configure(ingest_enabled=True)
+    selftrace.configure(ingest_enabled=False)
     with tracer.start_span("req") as parent:
-        SELFTRACE.lower_dispatch(_Rec(), parent=parent)
+        rec = _timed_dispatch()
     children = [s for s in exp.spans if s.name.startswith("dispatch.")]
     assert [s.name for s in children] == [
         "dispatch.build", "dispatch.h2d", "dispatch.compile",
-        "dispatch.execute", "dispatch.d2h"]
+        "dispatch.d2h"]
     for s in children:
         assert s.parent_span_id == parent.context.span_id
         assert s.context.trace_id == parent.context.trace_id
         assert s.attributes["mode"] == "batched"
-        assert s.end_ns > s.start_ns
-    by_name = {s.name: s for s in children}
-    # durations survive the lowering (what structural dur predicates see)
-    assert by_name["dispatch.execute"].end_ns - \
-        by_name["dispatch.execute"].start_ns == 4_000_000
-    # back-to-back, in stage order
+        assert s.attributes["stage"] == s.name.split(".")[1]
+        assert parent.start_ns <= s.start_ns < s.end_ns <= parent.end_ns
+        # the span and the stage's seconds are the same two stamps
+        assert (s.end_ns - s.start_ns) / 1e9 == rec.stages[
+            s.attributes["stage"]]
+    # in the order they ran, none starting before the one before ended
+    # and none laid end to end with it
     for a, b in zip(children, children[1:]):
-        assert a.end_ns == b.start_ns
+        assert a.end_ns < b.start_ns
+    by_name = {s.name: s for s in children}
     # transfer bytes + jit verdict ride along
     assert by_name["dispatch.h2d"].attributes["bytes"] == 4096
     assert by_name["dispatch.d2h"].attributes["bytes"] == 128
-    assert by_name["dispatch.execute"].attributes["jit_cache"] == "miss"
     assert by_name["dispatch.compile"].attributes["jit_cache"] == "miss"
     assert "jit_cache" not in by_name["dispatch.h2d"].attributes
 
 
-def test_lower_dispatch_noop_paths():
+def test_dispatch_stage_children_noop_paths():
     exp, tracer = _sync_tracer()
-    selftrace.configure(ingest_enabled=True)
-    # no recording parent (NOOP span) → nothing synthesized
-    SELFTRACE.lower_dispatch(_Rec())
+    # no recording parent (no span open) → no children
+    _timed_dispatch()
     assert exp.spans == []
-    # empty stage map → nothing
-    with tracer.start_span("req") as parent:
-        SELFTRACE.lower_dispatch(_Rec(stages={}), parent=parent)
+    # no timed stage → nothing
+    with tracer.start_span("req"):
+        _timed_dispatch(stages=())
     assert [s.name for s in exp.spans] == ["req"]
-    # gate off → nothing, even with a live parent
-    selftrace.configure(ingest_enabled=False)
-    with tracer.start_span("req2") as parent:
-        SELFTRACE.lower_dispatch(_Rec(), parent=parent)
-    assert [s.name for s in exp.spans] == ["req", "req2"]
+    # no tracer installed → the record keeps no intervals at all
+    tracing.set_tracer(None)
+    rec = _timed_dispatch()
+    assert rec.intervals is None and set(rec.stages) == {
+        "build", "h2d", "compile", "d2h"}
+    assert [s.name for s in exp.spans] == ["req"]
 
 
 def test_annotate_query_attaches_headline_costs():

@@ -326,25 +326,26 @@ def test_contract_new_structural_gates_registered():
 
 
 def test_contract_selftrace_gates_registered():
-    """The dogfood gate is pinned by BOTH registries: the lowering /
-    annotation / recorder entry points test their gate attribute first
+    """The dogfood gate is pinned by BOTH registries: the annotation /
+    recorder entry points test their gate attribute first
     (GatedFunction) and the hot-path call sites are dominated by the
     one-attribute gate read (GuardedCall) — the checker run over the
     real package enforces them; this pins that the entries exist so a
-    refactor cannot silently drop the noop contract."""
+    refactor cannot silently drop the noop contract. So are the spans
+    written after the fact and the device timeline's watcher: with no
+    tracer installed no call site reaches them."""
     from tempo_tpu.analysis.contracts import (GATED_FUNCTIONS,
                                               GUARDED_CALLS)
 
     gated = {(g.qualname, g.knob) for g in GATED_FUNCTIONS}
-    assert ("SelfTraceGate.lower_dispatch",
-            "selftrace_ingest_enabled") in gated
     assert ("SelfTraceGate.annotate_query",
             "selftrace_ingest_enabled") in gated
     assert ("FlightRecorder.record",
             "selftrace_ingest_enabled") in gated
     guarded = {(m, g.knob) for g in GUARDED_CALLS for m in g.methods}
-    assert ("lower_dispatch", "selftrace_ingest_enabled") in guarded
     assert ("annotate_query", "selftrace_ingest_enabled") in guarded
+    assert ("record_span", "self_tracing") in guarded
+    assert ("watch", "self_tracing") in guarded
     assert ("record", "selftrace_ingest_enabled") in guarded
 
 
