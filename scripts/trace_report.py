@@ -10,6 +10,10 @@ line and writes it to
 
   end_to_end   the cell's end-to-end readers on THIS run, so a traced
                and an untraced run of one seed give the cost of tracing
+  topk         inside the window: launches by top-k path
+               (`tempo_search_topk_dispatches_total{path}`) beside the
+               device dispatches, and the `dispatch.execute` spans by
+               their `topk` attribute
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -34,6 +38,7 @@ own result line, as `chipbench.run` prints it.
 from __future__ import annotations
 
 import argparse
+import collections
 import gzip
 import json
 import os
@@ -44,7 +49,7 @@ sys.path.insert(0, ROOT)
 
 from chipbench import run as bench_run  # noqa: E402
 from chipbench.layers import spans as sp  # noqa: E402
-from chipbench.lib import median, percentile  # noqa: E402
+from chipbench.lib import delta, median, percentile  # noqa: E402
 from chipbench.xplane import merge  # noqa: E402
 
 # spans that overlay the host's own: the device's timeline, and a
@@ -96,6 +101,17 @@ def report(view: dict, e2e_names: list) -> dict:
         if v is not None:
             out["end_to_end"][name] = float(v)
     spans = view["spans"]
+    out["topk"] = {
+        "rows": delta(view, "tempo_search_topk_dispatches_total",
+                      path="rows"),
+        "direct": delta(view, "tempo_search_topk_dispatches_total",
+                        path="direct"),
+        "device_dispatches": sum(
+            delta(view, "tempo_search_scan_dispatches_total", mode=m)
+            for m in bench_run.DEVICE_MODES),
+        "execute_spans": dict(collections.Counter(
+            s["attributes"].get("topk", "absent") for s in spans
+            if s["name"] == "dispatch.execute"))}
     traces = sp.searches(spans)
     if not traces:
         return out

@@ -354,6 +354,11 @@ retention_deleted = Counter("tempodb_retention_deleted_total",
                             "blocks hard-deleted by retention")
 scan_dispatches = Counter("tempo_search_scan_dispatches_total",
                           "device scan kernel dispatches")
+topk_dispatches = Counter(
+    "tempo_search_topk_dispatches_total",
+    "scan kernel launches by the top-k path their shape takes "
+    "(engine.topk_row_width): path=rows, a tournament on row maxima, "
+    "or path=direct, one sort of a small input")
 batch_cache_events = Counter("tempo_search_batch_cache_events_total",
                              "staged-batch HBM cache hits/misses/evictions")
 coalesced_queries = Counter(

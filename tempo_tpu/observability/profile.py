@@ -461,6 +461,8 @@ class DispatchProfiler:
                 span.set_attribute("bytes", rec.d2h_bytes)
             if stage in ("compile", "execute") and rec.jit is not None:
                 span.set_attribute("jit_cache", rec.jit)
+                if "topk" in rec.attrs:
+                    span.set_attribute("topk", rec.attrs["topk"])
             span.end(end_ns)
 
     # ---- operator surface ----

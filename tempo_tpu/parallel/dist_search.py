@@ -27,7 +27,9 @@ from tempo_tpu.search.columnar import ColumnarPages
 from tempo_tpu.search.engine import (
     DEVICE_ARRAYS,
     DEFAULT_TOP_K,
+    book_topk,
     entry_match_mask,
+    latest_k,
     masked_topk,
     pad_page_axis,
 )
@@ -223,9 +225,9 @@ class DistributedScanEngine:
             inspected = jax.lax.psum(local_inspected, SCAN_AXIS)
             all_scores = jax.lax.all_gather(scores, SCAN_AXIS).reshape(-1)
             all_idx = jax.lax.all_gather(gidx, SCAN_AXIS).reshape(-1)
-            k = min(top_k, all_scores.shape[0])
-            top_scores, pos = jax.lax.top_k(all_scores, k)
-            return count, inspected, top_scores, all_idx[pos]
+            top_scores, top_idx = latest_k(
+                all_scores, all_idx, min(top_k, all_scores.shape[0]))
+            return count, inspected, top_scores, top_idx
 
         from tempo_tpu.parallel.mesh import shard_map_compat
 
@@ -300,6 +302,7 @@ class DistributedScanEngine:
             # so no two threads can interleave per-device shard_map
             # queues; time queued behind others lands in lock_wait
             stage = "compile" if miss else "execute"
+            book_topk(rec, d["entry_valid"].size // self.n_shards, k)
             with locked_collective(rec):
                 with rec.stage(stage):
                     out = self._dist_kernel(

@@ -38,8 +38,9 @@ def host_scan_single(pages: ColumnarPages, cq, top_k: int):
     """The single-block host fallback (breaker open, or the device
     dispatch faulted): the SAME scan_kernel pinned to the CPU backend
     over the host container — byte-identical to the device dispatch
-    (same padded shapes, host range tables; masked_topk's equal-start
-    tie caveat applies). The batched twin is search/batcher.host_scan."""
+    (same padded shapes, host range tables; equal start seconds resolve
+    to the lowest flat index on both). The batched twin is
+    search/batcher.host_scan."""
     import time
 
     import jax.numpy as jnp

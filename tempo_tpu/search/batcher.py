@@ -65,10 +65,8 @@ def host_scan(host, mq, top_k: int):
     the same kernel over the same padded shapes and the same compiled
     predicate semantics (host range tables; the device hit-mask path
     yields identical matches), the results are byte-identical to the
-    device dispatch, with the one documented caveat shared by
-    masked_topk's two-stage path: equal-start ties at the top-k
-    boundary may resolve to a different (equally valid) entry than the
-    MESH kernel's gather ordering would pick.
+    device dispatch, ties included: equal start seconds resolve to the
+    lowest flat index on every path (masked_topk).
 
     The CPU-staged arrays memoize on the HostBatch (`_cpu_staged`), so
     a wedged-device soak re-stages each batch once, not per query; the

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import profile
 
 from .columnar import ColumnarPages
@@ -288,41 +289,77 @@ def fetch_coalesced_out(out):
     return fetched
 
 
-_TOPK_CHUNK = 8192
+def topk_row_width(n: int, k: int) -> int:
+    """Row width W of masked_topk's rows path for `n` scores of which
+    `k` are kept, or 0 where it sorts the input directly: a pure
+    function of the static shape, so the kernel and the host-side
+    booking (book_topk) read the same choice. W is the power of two
+    nearest sqrt(n / k) from below, at least the 128 lanes: n / W row
+    maxima and k * W candidates are then about equal, 2 * sqrt(n * k)
+    elements sorted instead of n. Inputs of up to 32,768 scores, and a
+    k whose candidates would pass a quarter of the input, are sorted
+    whole."""
+    if n <= 32768 or k < 1:
+        return 0
+    w = 128
+    while 4 * w * w * k <= n:
+        w *= 2
+    return w if 4 * k * w <= n else 0
+
+
+def book_topk(rec, n: int, k: int) -> None:
+    """Count one kernel launch under the top-k path its shape takes
+    (`n` scores per masked_topk call: a shard's share under a mesh) and
+    name the path on the launch's `dispatch.execute` span."""
+    w = topk_row_width(n, min(k, n))
+    obs.topk_dispatches.inc(path="rows" if w else "direct")
+    rec.set(topk=f"rows:{w}" if w else "direct")
+
+
+def latest_k(score, idx, k: int):
+    """The first k of `score`, with their `idx`, in the order (score
+    descending, idx ascending), along the last axis. One stable sort by
+    score: equal scores keep the order they came in, so callers hand
+    them over with `idx` ascending among equals. Stability is asked of
+    the sort and not hoped of `lax.top_k`, which keeps the lower
+    position only where it lowers to a stable sort: the TPU compiler
+    splits a batched one (the fused kernels' under vmap) into
+    value-only sorts that do not."""
+    neg, idx = jax.lax.sort((-score, idx), num_keys=1, is_stable=True)
+    return -neg[..., :k], idx[..., :k]
 
 
 def masked_topk(mask, entry_start, top_k: int):
     """Top-k most recent matches (by start second); score -1 marks
-    non-matches. Returns (scores i32 [k], flat idx i32 [k]).
+    non-matches. Returns (scores i32 [k], flat idx i32 [k]), exactly
+    the first k of a full sort by (score descending, flat index
+    ascending): equal start seconds resolve to the lowest flat index,
+    on every path, device or host route.
 
-    Two-stage for large inputs: lax.top_k over 1M elements costs ~2ms on
-    v5e (it partial-sorts the full array); chunked per-group top-k then a
-    global pass over G*k candidates is ~4x cheaper. The SCORES returned
-    are identical to single-stage top_k (every global winner wins its
-    chunk), but tie-breaking among equal start seconds differs: lax.top_k
-    breaks ties by lowest flat index, while the two-stage pass orders
-    candidates by (chunk, rank) — so at the k boundary a tie may resolve
-    to a different entry than the single-stage path would pick. Callers
-    treat equal-start results as unordered (the reference sorts results
-    by start time only, search/util.go), so this is semantically
-    invisible; do not rely on index-level equality between the paths."""
+    A sort of the whole input is what a large one is spared. The flat
+    scores are viewed as rows of W (topk_row_width) and a tournament on
+    the row maxima picks k rows, by (maximum descending, row
+    ascending). Every element of another row is at or below that row's
+    maximum, which each of the k chosen rows matches or beats with an
+    element that, on a tie, has the lower flat index: so the answer
+    lies within the chosen k * W elements, and is the first k of them
+    in the same order (taken row by row in ascending order, which is
+    flat-index order)."""
     score = jnp.where(
         mask, jnp.minimum(entry_start, jnp.uint32(2**31 - 1)).astype(jnp.int32),
         jnp.int32(-1),
     ).reshape(-1)
     n = score.shape[0]
     k = min(top_k, n)
-    if n > 4 * _TOPK_CHUNK and k <= _TOPK_CHUNK:
-        groups = -(-n // _TOPK_CHUNK)
-        padded = jnp.pad(score, (0, groups * _TOPK_CHUNK - n),
-                         constant_values=-1).reshape(groups, _TOPK_CHUNK)
-        s1, i1 = jax.lax.top_k(padded, k)                  # [G, k]
-        base = (jnp.arange(groups, dtype=jnp.int32) * _TOPK_CHUNK)[:, None]
-        cand_idx = (i1.astype(jnp.int32) + base).reshape(-1)
-        s2, i2 = jax.lax.top_k(s1.reshape(-1), k)
-        return s2, cand_idx[i2]
-    top_scores, top_idx = jax.lax.top_k(score, k)
-    return top_scores, top_idx.astype(jnp.int32)
+    w = topk_row_width(n, k)
+    if not w:
+        return latest_k(score, jnp.arange(n, dtype=jnp.int32), k)
+    rows2d = jnp.pad(score, (0, -n % w), constant_values=-1).reshape(-1, w)
+    _, rows = latest_k(jnp.max(rows2d, axis=1),
+                       jnp.arange(rows2d.shape[0], dtype=jnp.int32), k)
+    rows = jnp.sort(rows)
+    flat = rows[:, None] * w + jnp.arange(w, dtype=jnp.int32)
+    return latest_k(rows2d[rows].reshape(-1), flat.reshape(-1), k)
 
 
 @functools.partial(jax.jit, static_argnames=("n_terms", "top_k", "widths",
@@ -452,6 +489,7 @@ class ScanEngine:
              None if span_cols is None else
              tuple(sorted((n, tuple(a.shape))
                           for n, a in span_cols.items()))))
+        book_topk(_rec, d["entry_valid"].size, k)
         with _rec.stage("compile" if miss else "execute"):
             out = scan_kernel(
                 d["kv_key"], d["kv_val"],

@@ -30,7 +30,7 @@ from tempo_tpu.observability import profile
 
 from .columnar import ColumnarPages
 from .dict_probe import _pow2
-from .engine import DEFAULT_TOP_K, masked_topk
+from .engine import DEFAULT_TOP_K, book_topk, latest_k, masked_topk
 from . import packing
 from .packing import duration_ok, mask_select_grouped, unpack_ids
 from .pipeline import (
@@ -990,16 +990,18 @@ def dist_multi_scan_kernel(mesh, kv_key, kv_val, entry_start, entry_end,
         inspected = jax.lax.psum(local_inspected, SCAN_AXIS)
         all_scores = jax.lax.all_gather(scores, SCAN_AXIS).reshape(-1)
         all_idx = jax.lax.all_gather(gidx, SCAN_AXIS).reshape(-1)
-        k = min(top_k, all_scores.shape[0])
-        top_scores, pos = jax.lax.top_k(all_scores, k)
+        # shard after shard, each in masked_topk's order: equal start
+        # seconds come in ascending global index, and the merge keeps it
+        top_scores, top_idx = latest_k(
+            all_scores, all_idx, min(top_k, all_scores.shape[0]))
         if agg is not None:
             # per-shard dense counts over the local page slice psum to
             # the global histogram — integer adds, so the distributed
             # answer is bit-equal to the single-device one
             agg_counts = jax.lax.psum(
                 agg_entry_counts(mask, entry_agg, agg), SCAN_AXIS)
-            return count, inspected, top_scores, all_idx[pos], agg_counts
-        return count, inspected, top_scores, all_idx[pos]
+            return count, inspected, top_scores, top_idx, agg_counts
+        return count, inspected, top_scores, top_idx
 
     from tempo_tpu.parallel.mesh import shard_map_compat
 
@@ -1106,7 +1108,8 @@ def dist_coalesced_scan_kernel(mesh, kv_key, kv_val, entry_start, entry_end,
     """Coalesced scan sharded over the mesh's scan axis: the page axis
     splits across devices, the [Q,...] query tables replicate, and the
     per-shard per-query top-k candidates all_gather into a per-query
-    global top-k (lax.top_k batches over the leading query axis).
+    global top-k (engine.latest_k sorts along the last axis, so it
+    batches over the leading query axis).
 
     Plan-shape stacking composes with both span layouts (the static
     `span_sharded` flag, see dist_multi_scan_kernel): with replicated
@@ -1194,9 +1197,8 @@ def dist_coalesced_scan_kernel(mesh, kv_key, kv_val, entry_start, entry_end,
         Qn = all_scores.shape[1]
         flat_scores = jnp.swapaxes(all_scores, 0, 1).reshape(Qn, -1)
         flat_idx = jnp.swapaxes(all_idx, 0, 1).reshape(Qn, -1)
-        k = min(top_k, flat_scores.shape[-1])
-        top_scores, pos = jax.lax.top_k(flat_scores, k)      # batched [Q,k]
-        top_idx = jnp.take_along_axis(flat_idx, pos, axis=-1)
+        top_scores, top_idx = latest_k(                      # batched [Q,k]
+            flat_scores, flat_idx, min(top_k, flat_scores.shape[-1]))
         if agg is not None:
             return counts, inspected, top_scores, top_idx, agg_counts
         return counts, inspected, top_scores, top_idx
@@ -1365,6 +1367,7 @@ class MultiBlockEngine:
             stage = "compile" if miss else "execute"
             rec.set(kernel="multi", blocks=len(batch.blocks),
                     scan_bytes=batch.device_nbytes)
+            book_topk(rec, d["entry_valid"].size // self.n_shards, k)
             if self.mesh is not None:
                 from tempo_tpu.parallel import mesh as mesh_mod
 
@@ -1469,6 +1472,7 @@ class MultiBlockEngine:
             stage = "compile" if miss else "execute"
             rec.set(kernel="coalesced", queries=cq.n_queries,
                     scan_bytes=batch.device_nbytes)
+            book_topk(rec, d["entry_valid"].size // self.n_shards, top_k)
             if self.mesh is not None:
                 from tempo_tpu.parallel import mesh as mesh_mod
 

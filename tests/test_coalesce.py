@@ -34,10 +34,9 @@ from tempo_tpu.search.multiblock import (
 
 
 def _corpus(n=200, seed=0):
-    """Entries with UNIQUE start seconds: the two top-k implementations
-    only differ in tie-breaks among equal starts (documented as
-    semantically invisible), and byte-identity tests must not depend on
-    that."""
+    """Entries with unique start seconds, so the order of a result list
+    says which entry is which. (Equal start seconds resolve to the
+    lowest flat index on every path: tests/test_topk.py.)"""
     rng = random.Random(seed)
     entries = []
     for i in range(n):

@@ -70,10 +70,10 @@ def _clean_robustness():
 
 def _corpus(n_entries: int, seed: int,
             extra_vals: tuple = ()) -> ColumnarPages:
-    """Small corpus with UNIQUE start seconds: top-k tie ordering at the
-    k boundary is the one documented divergence between kernel variants
-    (masked_topk docstring), and the identity assertions here are about
-    the control plane, not tie arbitration."""
+    """Small corpus with unique start seconds: the identity assertions
+    here are about the control plane, not tie arbitration (equal start
+    seconds resolve to the lowest flat index on every path:
+    tests/test_topk.py)."""
     rng = np.random.default_rng(seed)
     E, C = 256, 4
     P = -(-n_entries // E)
