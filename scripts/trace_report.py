@@ -14,6 +14,15 @@ line and writes it to
                (`tempo_search_topk_dispatches_total{path}`) beside the
                device dispatches, and the `dispatch.execute` spans by
                their `topk` attribute
+  mesh         what a mesh adds, and what a skewed split would show:
+               launches in the window by mode and `shards`
+               (`tempo_search_scan_dispatches_total`), groups staged and
+               evictions in the window, the share of the window's
+               searches that inspected the whole tenant, per launch the
+               profiler's stage seconds (`lock_wait` among them), bytes
+               in use and peak per device, and from the profiler's
+               trace each device's busy seconds and the collective ops
+               by name (`collective_share.mesh`'s own rule)
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -94,6 +103,54 @@ def self_ns(spans: list) -> dict:
     return out
 
 
+def mesh_facts(view: dict) -> dict:
+    """The `mesh` block: counters, answers and trace of one run."""
+    import jax
+
+    from chipbench.lib import metric_sum
+    from chipbench.ops.search import work
+
+    is_collective = bench_run.load_reader(
+        "layers", "collective_share.mesh").is_collective
+    name = "tempo_search_scan_dispatches_total"
+    launches = {}
+    for lab, v in view["counters"]["after"].get(name, {}).items():
+        d = v - view["counters"]["before"].get(name, {}).get(lab, 0.0)
+        if d:
+            launches[lab] = d
+    cache = "tempo_search_batch_cache_events_total"
+    stage = "tempo_search_dispatch_stage_seconds"
+    stages = {}
+    for st in ("build", "execute", "compile", "lock_wait", "d2h"):
+        n = delta(view, stage + "_count", stage=st)
+        if n:
+            stages[st] = {"launches": n, "ms_per_launch": delta(
+                view, stage + "_sum", stage=st) / n * 1e3}
+    done = [r for r in view["records"] if r["status"] == 200]
+    whole = sum(work(None, r).get("inspected_entries")
+                == view["manifest"]["entries"] for r in done)
+    out = {
+        "launches_in_window": launches,
+        "groups_staged": metric_sum(view["counters"]["after"], cache,
+                                    result="miss"),
+        "evictions_in_window": delta(view, cache, result="evict"),
+        "whole_tenant_share": whole / len(done) if done else None,
+        "stages": stages,
+        "devices": [dict(id=d.id, **{
+            k: int((d.memory_stats() or {}).get(k, 0))
+            for k in ("bytes_in_use", "peak_bytes_in_use")})
+            for d in jax.devices()]}
+    trace = view.get("trace")
+    if trace:
+        out["busy_s"] = [d["busy_ns"] / 1e9 for d in trace["devices"]]
+        out["collective_ops_s"] = {k: v / 1e9 for k, v in trace["ops_ns"]
+                                   if is_collective(k)}
+        out["ops_s"] = [[k, v / 1e9] for k, v in trace["ops_ns"][:25]]
+        out["programs"] = {k: [trace["program_calls"][k], v / 1e9]
+                           for k, v in trace["programs_ns"].items()}
+    return out
+
+
 def report(view: dict, e2e_names: list) -> dict:
     out: dict = {"workload": view["workload"], "end_to_end": {}}
     for name in e2e_names:
@@ -112,6 +169,7 @@ def report(view: dict, e2e_names: list) -> dict:
         "execute_spans": dict(collections.Counter(
             s["attributes"].get("topk", "absent") for s in spans
             if s["name"] == "dispatch.execute"))}
+    out["mesh"] = mesh_facts(view)
     traces = sp.searches(spans)
     if not traces:
         return out

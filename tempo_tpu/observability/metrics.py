@@ -103,10 +103,17 @@ class Counter(_Metric):
         return _BoundCounter(self, self._key(labels))
 
     def value(self, **labels: LabelValue) -> float:
+        """Sum of the series that carry every given label with the given
+        value: the one series when all its labels are given, and still
+        the right count for a reader that names fewer labels than the
+        writers set (`scan_dispatches.value(mode="batched")` over every
+        `shards`)."""
+        want = set(labels.items())
         # locked like every writer: a bare dict read races resize-in-
         # progress under free-threading and misses published updates
         with self._lock:
-            return self._series.get(self._key(labels), 0)
+            return sum(v for k, v in self._series.items()
+                       if want.issubset(k))
 
 
 class _BoundCounter:
@@ -352,8 +359,11 @@ search_inspected = Counter("tempo_search_inspected_traces_total",
 compactions = Counter("tempodb_compaction_runs_total", "compaction runs")
 retention_deleted = Counter("tempodb_retention_deleted_total",
                             "blocks hard-deleted by retention")
-scan_dispatches = Counter("tempo_search_scan_dispatches_total",
-                          "device scan kernel dispatches")
+scan_dispatches = Counter(
+    "tempo_search_scan_dispatches_total",
+    "scan kernel dispatches by mode (single, batched, coalesced, "
+    "host_fallback) and by shards: the size of the mesh the launch ran "
+    "over, 1 for a one-device launch or a host scan")
 topk_dispatches = Counter(
     "tempo_search_topk_dispatches_total",
     "scan kernel launches by the top-k path their shape takes "

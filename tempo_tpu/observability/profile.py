@@ -461,8 +461,9 @@ class DispatchProfiler:
                 span.set_attribute("bytes", rec.d2h_bytes)
             if stage in ("compile", "execute") and rec.jit is not None:
                 span.set_attribute("jit_cache", rec.jit)
-                if "topk" in rec.attrs:
-                    span.set_attribute("topk", rec.attrs["topk"])
+                for key in ("topk", "shards", "pages_per_shard"):
+                    if key in rec.attrs:
+                        span.set_attribute(key, rec.attrs[key])
             span.end(end_ns)
 
     # ---- operator surface ----

@@ -264,7 +264,7 @@ class BackendSearchBlock:
                 else:
                     with query_stats.attributed_dispatch(qs):
                         out = engine.scan_staged(sp, cq)
-                    obs.scan_dispatches.inc(mode="single")
+                    obs.scan_dispatches.inc(mode="single", shards=1)
                     render_pages = sp.pages
                     placement = "device"
             except DeviceFault:
@@ -286,7 +286,7 @@ class BackendSearchBlock:
             else:
                 out = host_scan_single(pages, cq,
                                        engine._resolve_top_k(cq))
-                obs.scan_dispatches.inc(mode="host_fallback")
+                obs.scan_dispatches.inc(mode="host_fallback", shards=1)
                 render_pages = pages
                 placement = "host"
         if pruned:

@@ -520,8 +520,7 @@ class QueryCoalescer:
                 qs.add_device_stages(share, h2d_bytes=bs["b"],
                                      fused_q=len(items))
 
-    @staticmethod
-    def _trace_launch(lspan, items, batch, out, recs,
+    def _trace_launch(self, lspan, items, batch, out, recs,
                       launched: int) -> None:
         """Close one launch's spans at `launched`, the stamp taken when
         the kernel call returned: `coalescer.launch` (open since the
@@ -541,7 +540,8 @@ class QueryCoalescer:
             out, lspan.context, len(items), len(batch.blocks), kernel)
         lspan.set_attributes(
             launch=launch, queries=len(items), blocks=len(batch.blocks),
-            kernel=kernel,
+            kernel=kernel, shards=self.engine.n_shards,
+            pages_per_shard=self.engine.pages_per_shard(batch),
             jit_cache=(recs[0].get("jit_cache", "") if recs else ""))
         lspan.end(launched)
         for _mq, _k, _fut, t_submit, _qs, parent in items:
@@ -611,7 +611,8 @@ class QueryCoalescer:
                                        launched)
                 self._attribute(items, recs, (launched - t0d) / 1e9)
                 start_fetch(out)
-                obs.scan_dispatches.inc(mode="batched")
+                obs.scan_dispatches.inc(mode="batched",
+                                        shards=self.engine.n_shards)
                 fut.set_result(out)
                 return
             mqs = [it[0] for it in items]
@@ -639,7 +640,8 @@ class QueryCoalescer:
                 self._trace_launch(lspan, items, grp.batch, out, recs,
                                    launched)
             self._attribute(items, recs, (launched - t0d) / 1e9)
-            obs.scan_dispatches.inc(mode="coalesced")
+            obs.scan_dispatches.inc(mode="coalesced",
+                                    shards=self.engine.n_shards)
             obs.coalesced_queries.inc(len(items))
             # D2H starts async NOW; the one blocking sync point happens
             # on the first waiter's drain (lazy demux), not here — a
@@ -1579,7 +1581,7 @@ class BlockBatcher:
                                 self._host_total += cpu_b - prev
                                 self._evict_host_locked()
                                 self._publish_gauges_locked()
-                obs.scan_dispatches.inc(mode="host_fallback")
+                obs.scan_dispatches.inc(mode="host_fallback", shards=1)
                 inspected -= pre["entries_skipped"]
                 results.metrics.inspected_blocks += pre["inspected_blocks"]
                 results.metrics.inspected_bytes += pre["inspected_bytes"]
@@ -1903,7 +1905,8 @@ class BlockBatcher:
             # with the coalescer active the LAUNCH counters are kept at
             # flush time (mode="batched" solo, mode="coalesced" fused) —
             # counting submits here would double-book shared launches
-            obs.scan_dispatches.inc(dispatches, mode="batched")
+            obs.scan_dispatches.inc(dispatches, mode="batched",
+                                    shards=self.engine.n_shards)
         if qs is not None:
             for k, v in stages.items():
                 qs.add_stage(k, v)

@@ -1290,6 +1290,11 @@ class MultiBlockEngine:
             return 0
         return int(d["kv_key"].shape[0]) - int(batch.n_pages)
 
+    def pages_per_shard(self, batch: BlockBatch) -> int:
+        """Staged pages of the group, padding included, that each device
+        of the mesh reads (all of them off a mesh)."""
+        return int(batch.device["kv_key"].shape[0]) // self.n_shards
+
     def place(self, host: HostBatch) -> BlockBatch:
         """H2D of a host-stacked batch (sharded over the mesh if any)."""
         if self.mesh is None:
@@ -1366,7 +1371,8 @@ class MultiBlockEngine:
                               for n, a in span_cols.items()))))
             stage = "compile" if miss else "execute"
             rec.set(kernel="multi", blocks=len(batch.blocks),
-                    scan_bytes=batch.device_nbytes)
+                    scan_bytes=batch.device_nbytes, shards=self.n_shards,
+                    pages_per_shard=self.pages_per_shard(batch))
             book_topk(rec, d["entry_valid"].size // self.n_shards, k)
             if self.mesh is not None:
                 from tempo_tpu.parallel import mesh as mesh_mod
@@ -1471,7 +1477,8 @@ class MultiBlockEngine:
                               for n, a in span_cols.items()))))
             stage = "compile" if miss else "execute"
             rec.set(kernel="coalesced", queries=cq.n_queries,
-                    scan_bytes=batch.device_nbytes)
+                    scan_bytes=batch.device_nbytes, shards=self.n_shards,
+                    pages_per_shard=self.pages_per_shard(batch))
             book_topk(rec, d["entry_valid"].size // self.n_shards, top_k)
             if self.mesh is not None:
                 from tempo_tpu.parallel import mesh as mesh_mod

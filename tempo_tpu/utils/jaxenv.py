@@ -31,6 +31,36 @@ def compile_cache_dir() -> str:
             or DEFAULT_COMPILE_CACHE_DIR)
 
 
+def _adopt_entries_without_atime(path: str) -> None:
+    """Give every cache entry that lacks one its access-time file.
+
+    With `jax_compilation_cache_max_size` set, jax keeps `<key>-atime`
+    beside each `<key>-cache` and reads them all before every write (its
+    LRU eviction). A directory filled while the limit was off has none,
+    and then EVERY later write fails on the first such entry
+    (FileNotFoundError on its `-atime`, a warning per compile): nothing
+    new is ever persisted and each restart compiles again. Seen in PR
+    26's chip runs (PERF.md): a checkout's `.jax_cache`, filled without
+    a limit, on a machine whose environment sets one; every run compiled
+    53 s of mesh kernels again. An adopted entry gets the oldest possible
+    time, so it is the first to go when the directory outgrows the
+    limit."""
+    try:
+        names = set(os.listdir(path))
+    except OSError:
+        return
+    for name in names:
+        if not name.endswith("-cache"):
+            continue
+        atime = name[:-len("-cache")] + "-atime"
+        if atime not in names:
+            try:
+                with open(os.path.join(path, atime), "wb") as f:
+                    f.write((0).to_bytes(8, "little"))
+            except OSError:
+                return  # read-only directory: jax will say so itself
+
+
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at `compile_cache_dir()`
     so a process restart replays XLA compiles from disk instead of
@@ -56,6 +86,8 @@ def enable_compile_cache() -> str:
     # only value ever written otherwise is the resolver's
     if jax.config.jax_compilation_cache_dir != path:
         jax.config.update("jax_compilation_cache_dir", path)
+    if jax.config.jax_compilation_cache_max_size != -1:
+        _adopt_entries_without_atime(path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       _MIN_COMPILE_TIME_S)
     watch_persistent_compile_cache()

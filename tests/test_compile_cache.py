@@ -60,6 +60,52 @@ def test_default_is_one_fixed_gitignored_path_in_the_checkout(tmp_path):
     assert not got.startswith(str(tmp_path))
 
 
+_LIMITED_CHILD = (
+    "import jax, jax.numpy as jnp, warnings\n"
+    "from tempo_tpu.utils.jaxenv import enable_compile_cache\n"
+    "warnings.simplefilter('error')\n"
+    "enable_compile_cache()\n"
+    "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+    "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+    "print(jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).sum())\n"
+)
+
+
+def test_a_size_limit_over_a_directory_filled_without_one(tmp_path):
+    """Entries written while `jax_compilation_cache_max_size` was off
+    have no `-atime` file; with the limit on, jax reads every entry's
+    before each write and fails on the first that is missing, so nothing
+    new is persisted. The resolver adopts them (oldest first to go),
+    only when a limit is set."""
+    cache = tmp_path / "cache"
+    cache.mkdir()
+    (cache / "jit_old-0123-cache").write_bytes(b"x" * 64)
+    (cache / "jit_kept-4567-cache").write_bytes(b"y" * 64)
+    (cache / "jit_kept-4567-atime").write_bytes((9).to_bytes(8, "little"))
+
+    def child(limit):
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(cache))
+        env.pop("JAX_COMPILATION_CACHE_MAX_SIZE", None)
+        if limit:
+            env["JAX_COMPILATION_CACHE_MAX_SIZE"] = str(limit)
+        return subprocess.run([sys.executable, "-c", _LIMITED_CHILD], env=env,
+                              cwd=REPO, capture_output=True, text=True,
+                              timeout=120)
+
+    out = child(None)
+    assert out.returncode == 0, out.stderr[-800:]
+    assert not (cache / "jit_old-0123-atime").exists()
+    before = set(os.listdir(cache))
+    out = child(1 << 30)
+    # a write that failed would have warned, and warnings are errors there
+    assert out.returncode == 0, out.stderr[-800:]
+    assert (cache / "jit_old-0123-atime").read_bytes() == bytes(8)
+    assert (cache / "jit_kept-4567-atime").read_bytes()[0] == 9
+    new = set(os.listdir(cache)) - before - {"jit_old-0123-atime"}
+    assert any(n.endswith("-atime") for n in new), sorted(new)
+
+
 def _shipped_python_files():
     skip = {".git", "tests", "chiprun_out", ".smoke_checkout", ".jax_cache",
             "__pycache__"}
