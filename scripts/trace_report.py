@@ -16,13 +16,25 @@ line and writes it to
                their `topk` attribute
   mesh         what a mesh adds, and what a skewed split would show:
                launches in the window by mode and `shards`
-               (`tempo_search_scan_dispatches_total`), groups staged and
+               (`tempo_search_scan_dispatches_total`) and per second,
+               whether their parameters were resident on the mesh
+               (`tempo_search_mesh_param_placements_total`), groups staged and
                evictions in the window, the share of the window's
                searches that inspected the whole tenant, per launch the
                profiler's stage seconds (`lock_wait` among them), bytes
                in use and peak per device, and from the profiler's
                trace each device's busy seconds and the collective ops
                by name (`collective_share.mesh`'s own rule)
+  enqueue_split  what the kernel call of a solo mesh launch, which is what
+               the collective lock is held for, costs the host, by where
+               the query's parameters are (jit places arguments in C++,
+               where no Python profile looks, so the arms are run): after
+               the window, the process quiet, one thread, 200 enqueues in
+               a row over one resident group, with the parameters on
+               device 0 as PR 26 placed them, with them resident on the
+               mesh, and the `jax.device_put` that places them alone.
+               In the window the same call is the `execute` stage of
+               `mesh.stages`, one thread among many, GIL waits included
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -52,6 +64,7 @@ import gzip
 import json
 import os
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -65,6 +78,84 @@ from chipbench.xplane import merge  # noqa: E402
 # member's wait for its launch, which lies over the `batcher.dispatch`
 # or `batcher.drain` the member's thread was in meanwhile
 OVERLAYS = {sp.DEVICE, "coalescer.wait"}
+
+
+def enqueue_split(server, calls: int = 200):
+    """What a solo mesh launch's kernel call costs the host, by where
+    its six query parameters are: after the window, the process quiet,
+    one thread, `calls` enqueues in a row of the kernel the window ran,
+    over one resident group and one of its memoised predicates, the
+    collective lock held throughout. Three arms, ms a call:
+
+      as_parent  the parameters uncommitted on device 0
+                 (`jnp.asarray`, `jnp.uint32`: PR 26's placement), so
+                 jit moves them to every device inside the call
+      put        `jax.device_put` of the six host values to the mesh,
+                 replicated, and nothing else: what placing them costs
+                 when it is done ahead of the call
+      resident   the parameters put on the mesh once, before the loop:
+                 the call is the enqueue alone
+
+    Each arm's loop is timed to the last enqueue's return (`enqueue`)
+    and to its outputs ready (`drained`). None off a mesh, or when the
+    group's memo holds no plain predicate."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from tempo_tpu.parallel import mesh as mesh_mod
+    from tempo_tpu.search import multiblock
+    from tempo_tpu.search.engine import resolve_top_k
+
+    batcher = server.app.reader_db.batcher
+    eng = batcher.engine
+    if eng.mesh is None:
+        return None
+    with batcher._lock:
+        found = next(((c.batch, pre) for c in batcher._cache.values()
+                      for pre in c.query_cache.values()
+                      if not pre["all_skip"] and pre.get("val_hits") is None
+                      and pre.get("structural") is None), None)
+    if found is None:
+        return None
+    batch, pre = found
+    d = batch.device
+    kernel = multiblock.dist_multi_scan_kernel
+    host = (np.asarray(pre["term_keys"]), np.asarray(pre["val_ranges"]),
+            *(np.uint32(min(int(pre[k]), 0xFFFFFFFF))
+              for k in ("dur_lo", "dur_hi", "win_start", "win_end")))
+    spec = NamedSharding(eng.mesh, PartitionSpec())
+
+    def launch(params):
+        return kernel(
+            eng.mesh, d["kv_key"], d["kv_val"], d["entry_start"],
+            d["entry_end"], d["entry_dur"], d["entry_valid"],
+            d["page_block"], *params, None, None, d.get("entry_dur_res"),
+            None, None, None, n_terms=pre["n_terms"],
+            top_k=resolve_top_k(eng.top_k, 20), widths=batch.widths,
+            plan=None, span_sharded=False,
+            shard_tail=eng._shard_tail(batch, d), agg=None)
+
+    on_zero = (jnp.asarray(host[0]), jnp.asarray(host[1]),
+               *(jnp.uint32(int(v)) for v in host[2:]))
+    resident = jax.device_put(host, spec)
+    arms = {"as_parent": lambda: launch(on_zero),
+            "put": lambda: jax.device_put(host, spec),
+            "resident": lambda: launch(resident)}
+    out = {"calls": calls, "pages_per_shard": eng.pages_per_shard(batch)}
+    with mesh_mod.dispatch_lock:
+        for name, arm in arms.items():
+            jax.block_until_ready(arm())      # compile, if new here
+            t = time.perf_counter()
+            for _ in range(calls):
+                last = arm()
+            enqueue = time.perf_counter() - t
+            jax.block_until_ready(last)
+            out[name] = {
+                "enqueue_ms": enqueue / calls * 1e3,
+                "drained_ms": (time.perf_counter() - t) / calls * 1e3}
+    return out
 
 
 def total(iv: list) -> int:
@@ -131,6 +222,11 @@ def mesh_facts(view: dict) -> dict:
                 == view["manifest"]["entries"] for r in done)
     out = {
         "launches_in_window": launches,
+        "launches_per_s": sum(launches.values()) / view["window_wall_s"],
+        # absent on a tree that has no such counter: both read 0
+        "param_placements": {r: delta(
+            view, "tempo_search_mesh_param_placements_total", result=r)
+            for r in ("placed", "reused")},
         "groups_staged": metric_sum(view["counters"]["after"], cache,
                                     result="miss"),
         "evictions_in_window": delta(view, cache, result="evict"),
@@ -238,7 +334,9 @@ def main(argv=None) -> int:
 
     def hook(stage, state):
         if stage == "done":
-            got["report"] = report(state["run_view"], e2e)
+            got["report"] = dict(
+                report(state["run_view"], e2e),
+                enqueue_split=enqueue_split(state["server"]))
             got["spans"] = state["run_view"]["spans"]
 
     result, code = bench_run.run(args, hook=hook)

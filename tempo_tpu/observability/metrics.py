@@ -369,6 +369,13 @@ topk_dispatches = Counter(
     "scan kernel launches by the top-k path their shape takes "
     "(engine.topk_row_width): path=rows, a tournament on row maxima, "
     "or path=direct, one sort of a small input")
+mesh_param_placements = Counter(
+    "tempo_search_mesh_param_placements_total",
+    "mesh scan launches by where their query parameters were: "
+    "result=reused, resident on the mesh from an earlier launch of the "
+    "predicate, or result=placed, put there by this launch (a first "
+    "launch, a predicate the batcher no longer memoises, every fused "
+    "launch's stacked tables); never moves off a mesh")
 batch_cache_events = Counter("tempo_search_batch_cache_events_total",
                              "staged-batch HBM cache hits/misses/evictions")
 coalesced_queries = Counter(

@@ -269,13 +269,21 @@ class DistributedScanEngine:
                 k *= 2
             from tempo_tpu.search.engine import ScanEngine
 
-            with rec.stage("build"):
-                tk, vr, dlo, dhi, ws, we = ScanEngine.query_device_params(cq)
-            vh = getattr(cq, "val_hits", None)
-            widths = getattr(sp, "widths", None)
             st = getattr(cq, "structural", None)
+            with rec.stage("build"):
+                # every replicated operand resident on the mesh before
+                # the collective lock (parallel.mesh.put_replicated)
+                tk, vr, dlo, dhi, ws, we = ScanEngine.query_device_params(
+                    cq, self.mesh)
+                vh = getattr(cq, "val_hits", None)
+                if vh is not None:
+                    from tempo_tpu.parallel.mesh import put_replicated
+
+                    vh = put_replicated(self.mesh, vh)
+                s_tables = None if st is None else st.device_tables(
+                    self.mesh)
+            widths = getattr(sp, "widths", None)
             plan = None if st is None else st.plan
-            s_tables = None if st is None else st.device_tables()
             span_cols = (getattr(sp, "span_device", None)
                          if st is not None else None)
             span_sharded = bool(st is not None

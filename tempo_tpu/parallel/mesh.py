@@ -10,10 +10,11 @@ and DCN across slices, replacing the goroutine fan-out + Results channel.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 SCAN_AXIS = "shards"
 
@@ -84,6 +85,51 @@ def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
     import numpy as np
 
     return Mesh(np.array(devs), (SCAN_AXIS,))
+
+
+@functools.lru_cache(maxsize=None)
+def replicated(mesh: Mesh) -> NamedSharding:
+    """The placement of a collective launch's replicated operands (the
+    `P()` entries of its shard_map's in_specs): whole on every device
+    of the mesh."""
+    return NamedSharding(mesh, PartitionSpec())
+
+
+def placed_for(arr, mesh) -> bool:
+    """Whether a device array lives where a launch over `mesh` (None:
+    one device, no mesh) reads it without moving it."""
+    on = getattr(arr.sharding, "mesh", None)
+    return on is None if mesh is None else on == mesh
+
+
+def put(tree, sharding):
+    """Host or device arrays (any pytree; None leaves stay None) placed
+    on the mesh with `sharding`, committed, in one `jax.device_put`. An
+    array that is already there comes back as it is."""
+    if jax.process_count() > 1:
+        # multi-host: every process holds the same host value and
+        # transfers only its own devices' slices (a device_put of a
+        # global array would need every device to be addressable)
+        import numpy as np
+
+        def place(v):
+            if isinstance(v, jax.Array) and v.sharding == sharding:
+                return v
+            v = np.asarray(v)
+            return jax.make_array_from_callback(
+                v.shape, sharding, lambda idx: v[idx])
+
+        return jax.tree_util.tree_map(place, tree)
+    return jax.device_put(tree, sharding)
+
+
+def put_replicated(mesh: Mesh, tree):
+    """The ONE way a collective launch's replicated operands get to the
+    mesh, done before `locked_collective` is entered so that the locked
+    section is the enqueue alone. An operand left uncommitted on one
+    device is instead re-placed on every device inside the jit call, on
+    its slow path, under the lock, at every launch."""
+    return put(tree, replicated(mesh))
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):

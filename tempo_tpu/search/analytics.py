@@ -179,14 +179,22 @@ class AggStage:
         self._device = None
         self._lock = threading.Lock()
 
-    def device(self):
-        """Memoized device placement (uncommitted — mesh dispatches
-        reshard it through the kernel's in_spec)."""
+    def device(self, sharding=None):
+        """Memoized device placement, where the batch's engine reads
+        its page-sharded operands: `sharding` is the engine's page
+        sharding on a mesh (the keys shard with their pages, as the
+        dist kernels' in_specs say), None off one (the default
+        device). A batch is staged by one engine, so one placement."""
         with self._lock:
             if self._device is None:
-                _, jnp = _jax()
+                if sharding is None:
+                    _, jnp = _jax()
 
-                self._device = jnp.asarray(self.host)
+                    self._device = jnp.asarray(self.host)
+                else:
+                    from tempo_tpu.parallel.mesh import put
+
+                    self._device = put(self.host, sharding)
             return self._device
 
     def cpu(self):

@@ -1394,8 +1394,12 @@ class BlockBatcher:
             if new_dp is not None:
                 # the uploaded query tables live in HBM: account them
                 # against the batch so the cache_bytes budget sees
-                # per-predicate device memory, not just page arrays
-                dpb = int(sum(getattr(a, "nbytes", 0) for a in new_dp))
+                # per-predicate device memory, not just page arrays.
+                # On a mesh they are replicated: every device holds the
+                # whole of each (`nbytes` is its logical size), and the
+                # budget is one sum over the mesh's devices
+                dpb = int(sum(getattr(a, "nbytes", 0) for a in new_dp)
+                          ) * self.engine.n_shards
                 with self._lock:
                     if pre.get("device_params") is None:
                         pre["device_params"] = new_dp

@@ -199,17 +199,9 @@ def place_device_dict(packed: PackedDeviceDict, mesh=None,
         dev = {k: jax.device_put(v, sharding) for k, v in host.items()}
     elif mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from tempo_tpu.parallel.mesh import SCAN_AXIS
+        from tempo_tpu.parallel.mesh import SCAN_AXIS, put
 
-        spec = NamedSharding(mesh, P(SCAN_AXIS))
-        if jax.process_count() > 1:
-            dev = {
-                k: jax.make_array_from_callback(
-                    v.shape, spec, lambda idx, v=v: v[idx])
-                for k, v in host.items()
-            }
-        else:
-            dev = {k: jax.device_put(v, spec) for k, v in host.items()}
+        dev = put(host, NamedSharding(mesh, P(SCAN_AXIS)))
     else:
         dev = {k: jnp.asarray(v) for k, v in host.items()}
     profile.observe_stage("h2d", "dict_probe", time.perf_counter() - t0,
@@ -365,8 +357,16 @@ def probe_value_hits(ddev: DeviceDict, needles: list[bytes]):
                 fp=(ddev.packed.fingerprint.hex()[:16]
                     if ddev.packed.fingerprint else None))
         if ddev.mesh is not None:
-            from tempo_tpu.parallel.mesh import locked_collective
+            from tempo_tpu.parallel.mesh import (
+                locked_collective, put_replicated,
+            )
 
+            # the needles on every device of the mesh BEFORE the lock,
+            # like every collective launch's replicated operands: the
+            # lock holds the enqueue, not an upload
+            with rec.stage("build"):
+                needle_args = put_replicated(ddev.mesh,
+                                             (arr, lens, empties))
             # collective dispatch: serialize with every other shard_map
             # enqueue in the process (the probe fires during query
             # compile, concurrent with scan dispatches on the same
@@ -376,8 +376,7 @@ def probe_value_hits(ddev: DeviceDict, needles: list[bytes]):
                 with rec.stage(stage):
                     out = dist_probe_kernel(
                         ddev.mesh, d["buf"], d["pos"], d["off"],
-                        d["n_real"], jnp.asarray(arr), jnp.asarray(lens),
-                        jnp.asarray(empties), n_needle_max=Lp)
+                        d["n_real"], *needle_args, n_needle_max=Lp)
             # fence after releasing the collective lock (lock-order
             # suite: no blocking wait under dispatch_lock); the stage
             # timer accumulates so kernel time books to the same stage

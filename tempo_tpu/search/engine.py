@@ -401,7 +401,7 @@ _scalar_lock = threading.Lock()
 _SCALAR_CACHE_MAX = 512
 
 
-def device_scalar(v: int):
+def device_scalar(v: int, mesh=None):
     """uint32 scalar as a device array, memoized by VALUE across
     dispatches and queries. Every compiled query uploads four of these
     (duration/window bounds) and the common values — 0 and UINT32_MAX
@@ -409,16 +409,27 @@ def device_scalar(v: int):
     put is its own host→device transfer with a fixed per-call cost, so
     re-putting the same four scalars per query is avoidable overhead.
     Bounded LRU; jit treats equal-valued scalars identically, so sharing
-    is invisible to the cache keys."""
+    is invisible to the cache keys.
+
+    `mesh`: where the launch that reads it runs. A scalar for a mesh is
+    put on every device of it (parallel.mesh.put_replicated) and
+    memoized under (value, mesh): a device-0 scalar handed to a mesh
+    launch would be re-placed inside the locked call."""
     v = int(v)
+    key = v if mesh is None else (v, mesh)
     with _scalar_lock:
-        hit = _SCALAR_CACHE.get(v)
+        hit = _SCALAR_CACHE.get(key)
         if hit is not None:
-            _SCALAR_CACHE.move_to_end(v)
+            _SCALAR_CACHE.move_to_end(key)
             return hit
-    arr = jnp.uint32(v)
+    if mesh is None:
+        arr = jnp.uint32(v)
+    else:
+        from tempo_tpu.parallel.mesh import put_replicated
+
+        arr = put_replicated(mesh, np.uint32(v))
     with _scalar_lock:
-        _SCALAR_CACHE[v] = arr
+        _SCALAR_CACHE[key] = arr
         while len(_SCALAR_CACHE) > _SCALAR_CACHE_MAX:
             _SCALAR_CACHE.popitem(last=False)
     return arr
@@ -436,7 +447,7 @@ class ScanEngine:
         return resolve_top_k(self.top_k, cq.limit)
 
     @staticmethod
-    def query_device_params(cq: CompiledQuery):
+    def query_device_params(cq: CompiledQuery, mesh=None):
         """Query params as device arrays, uploaded ONCE per query and
         cached on the CompiledQuery — one search fans out over many
         blocks/pages with the same query, and every small H2D transfer
@@ -444,17 +455,32 @@ class ScanEngine:
         memoize BY
         VALUE across queries (device_scalar), so a fresh query with the
         default unbounded window re-uploads nothing but its term
-        tables."""
+        tables.
+
+        `mesh`: the mesh the query's launches run over, None off a mesh.
+        Off a mesh the arrays are what they always were: uncommitted, on
+        the default device. On a mesh they are put on every device of it
+        once (parallel.mesh.put_replicated): the dist kernels' in_specs
+        want them replicated, and an array that is not is re-placed on
+        every device by every launch, inside the collective lock. The
+        cache holds the arrays of the last placement asked for, and
+        their own sharding says which that was: a query that moves
+        between an engine with a mesh and one without gets the right
+        arrays from each."""
+        from tempo_tpu.parallel.mesh import placed_for, put_replicated
+
         cached = getattr(cq, "_device_params", None)
-        if cached is None:
-            cached = (
-                jnp.asarray(cq.term_keys), jnp.asarray(cq.val_ranges),
-                device_scalar(cq.dur_lo),
-                device_scalar(min(cq.dur_hi, 0xFFFFFFFF)),
-                device_scalar(cq.win_start),
-                device_scalar(min(cq.win_end, 0xFFFFFFFF)),
-            )
-            object.__setattr__(cq, "_device_params", cached)
+        if cached is not None and placed_for(cached[0], mesh):
+            return cached
+        bounds = (cq.dur_lo, min(cq.dur_hi, 0xFFFFFFFF),
+                  cq.win_start, min(cq.win_end, 0xFFFFFFFF))
+        if mesh is None:
+            tables = (jnp.asarray(cq.term_keys), jnp.asarray(cq.val_ranges))
+        else:
+            tables = put_replicated(
+                mesh, (np.asarray(cq.term_keys), np.asarray(cq.val_ranges)))
+        cached = tables + tuple(device_scalar(v, mesh) for v in bounds)
+        object.__setattr__(cq, "_device_params", cached)
         return cached
 
     def scan_staged_async(self, sp: StagedPages, cq: CompiledQuery,

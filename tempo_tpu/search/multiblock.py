@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tempo_tpu import tempopb
+from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import profile
 
 from .columnar import ColumnarPages
@@ -359,18 +360,11 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
     t0 = time.perf_counter()
     cat = host.cat
     if sharding is not None:
-        if jax.process_count() > 1:
-            # multi-host: each process transfers ONLY its devices' page
-            # slices (the callback runs per addressable shard) — the
-            # per-host staging of the local shard; device_put of a global
-            # array would require every device to be addressable
-            dev = {
-                k: jax.make_array_from_callback(
-                    v.shape, sharding, lambda idx, v=v: v[idx])
-                for k, v in cat.items()
-            }
-        else:
-            dev = {k: jax.device_put(v, sharding) for k, v in cat.items()}
+        from tempo_tpu.parallel import mesh as mesh_mod
+
+        # multi-host: each process transfers ONLY its devices' page
+        # slices — the per-host staging of the local shard (mesh.put)
+        dev = mesh_mod.put(cat, sharding)
     else:
         dev = {k: jnp.asarray(v) for k, v in cat.items()}
     # page-array H2D only; the dictionary placement below times itself
@@ -403,29 +397,14 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
             # every sharded span array (span axis AND the [P, E] entry
             # range columns) splits on its leading axis, aligned with
             # the page sharding — per-shard span HBM ~1/P of replicated
-            sh_spec = NamedSharding(sharding.mesh, P(SCAN_AXIS))
-            if jax.process_count() > 1:
-                span_dev = {
-                    k: jax.make_array_from_callback(
-                        v.shape, sh_spec, lambda idx, v=v: v[idx])
-                    for k, v in span_host.items()
-                }
-            else:
-                span_dev = {k: jax.device_put(v, sh_spec)
-                            for k, v in span_host.items()}
+            span_dev = mesh_mod.put(
+                span_host, NamedSharding(sharding.mesh, P(SCAN_AXIS)))
         elif sharding is not None and jax.process_count() > 1:
             # span columns REPLICATE (the legacy layout): parent
             # pointers and segment ranges index the GLOBAL span axis,
             # and the dist kernels evaluate the structural mask outside
             # shard_map then hand the [P,E] verdicts to the sharded scan
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            rep = NamedSharding(sharding.mesh, P())
-            span_dev = {
-                k: jax.make_array_from_callback(
-                    v.shape, rep, lambda idx, v=v: v[idx])
-                for k, v in span_host.items()
-            }
+            span_dev = mesh_mod.put_replicated(sharding.mesh, span_host)
         else:
             span_dev = {k: jnp.asarray(v)
                         for k, v in span_host.items()}
@@ -1295,15 +1274,47 @@ class MultiBlockEngine:
         of the mesh reads (all of them off a mesh)."""
         return int(batch.device["kv_key"].shape[0]) // self.n_shards
 
+    @property
+    def _page_sharding(self):
+        """Where a launch reads its page-sharded operands (None off a
+        mesh: the default device)."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from tempo_tpu.parallel.mesh import SCAN_AXIS
+
+        return NamedSharding(self.mesh, P(SCAN_AXIS))
+
     def place(self, host: HostBatch) -> BlockBatch:
         """H2D of a host-stacked batch (sharded over the mesh if any)."""
         if self.mesh is None:
             return place_batch(host)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from tempo_tpu.parallel.mesh import SCAN_AXIS
+        return place_batch(host, sharding=self._page_sharding,
+                           mesh=self.mesh)
 
-        spec = NamedSharding(self.mesh, P(SCAN_AXIS))
-        return place_batch(host, sharding=spec, mesh=self.mesh)
+    def _place_params(self, tables: tuple) -> tuple:
+        """A launch's per-query tables (None entries stay None) as
+        device arrays where its kernel reads them: the default device,
+        or every device of the mesh. On a mesh this is the rule of
+        parallel.mesh.put_replicated: placed in the `build` stage,
+        before the collective lock is taken."""
+        if self.mesh is None:
+            return tuple(None if t is None else jnp.asarray(t)
+                         for t in tables)
+        from tempo_tpu.parallel.mesh import put_replicated
+
+        return put_replicated(self.mesh, tables)
+
+    def _book_params(self, rec, reused: bool) -> None:
+        """Say on the launch's record and counter whether its query
+        parameters were already resident on the mesh (`reused`) or this
+        launch put them there (`placed`). Off a mesh there is nothing
+        to say: the question is a mesh's."""
+        if self.mesh is None:
+            return
+        result = "reused" if reused else "placed"
+        obs.mesh_param_placements.inc(result=result)
+        rec.set(params=result)
 
     def stage(self, blocks: list[ColumnarPages]) -> BlockBatch:
         """Stack + place a batch on device(s)."""
@@ -1331,17 +1342,22 @@ class MultiBlockEngine:
             with rec.stage("build"):
                 # params uploaded once per MultiQuery (duck-typed:
                 # MultiQuery has the same param attributes CompiledQuery
-                # has)
+                # has), to where this engine's launches read them
                 from .engine import ScanEngine
 
-                tk, vr, dlo, dhi, ws, we = ScanEngine.query_device_params(mq)
+                resident = getattr(mq, "_device_params", None)
+                params = ScanEngine.query_device_params(mq, self.mesh)
+                tk, vr, dlo, dhi, ws, we = params
                 vh = getattr(mq, "val_hits", None)
-                bg = None if vh is None else jnp.asarray(mq.block_group)
+                bg = None
+                if vh is not None:
+                    vh, bg = self._place_params((vh, mq.block_group))
                 # structural plan (search/structural.py): static plan in
                 # the jit key, dynamic tables uploaded once per query
                 st = getattr(mq, "structural", None)
                 plan = None if st is None else st.plan
-                s_tables = None if st is None else st.device_tables()
+                s_tables = None if st is None else st.device_tables(
+                    self.mesh)
                 span_cols = (batch.span_device if st is not None
                              else None)
                 # ?agg= reduction (search/analytics.py): the staged
@@ -1350,7 +1366,8 @@ class MultiBlockEngine:
                 agg_stage = getattr(mq, "agg_stage", None)
                 agg = None if agg_stage is None else agg_stage.n_keys
                 entry_agg = (None if agg_stage is None
-                             else agg_stage.device())
+                             else agg_stage.device(self._page_sharding))
+            self._book_params(rec, params is resident)
             widths = batch.widths
             args = (d["kv_key"], d["kv_val"], d["entry_start"],
                     d["entry_end"], d["entry_dur"], d["entry_valid"],
@@ -1429,18 +1446,20 @@ class MultiBlockEngine:
             d = batch.device
             with rec.stage("build"):
                 vh = getattr(cq, "val_hits", None)
-                bg = None if vh is None else jnp.asarray(cq.block_group)
-                tables = (
-                    jnp.asarray(cq.term_keys), jnp.asarray(cq.val_ranges),
-                    jnp.asarray(cq.term_active),
-                    jnp.asarray(cq.dur_lo), jnp.asarray(cq.dur_hi),
-                    jnp.asarray(cq.win_start), jnp.asarray(cq.win_end))
+                bg = None if vh is None else cq.block_group
+                # the stacked tables of THIS fused launch, uploaded in
+                # one put to where the launch reads them
+                *tables, vh, bg = self._place_params((
+                    cq.term_keys, cq.val_ranges, cq.term_active,
+                    cq.dur_lo, cq.dur_hi, cq.win_start, cq.win_end,
+                    vh, bg))
                 # plan-shape stacking (structural.StackedStructural):
                 # one shared static plan, [Q,...]-stacked parameter
                 # tables uploaded once per fused dispatch
                 st = getattr(cq, "structural", None)
                 plan = None if st is None else st.plan
-                s_tables = None if st is None else st.device_tables()
+                s_tables = None if st is None else st.device_tables(
+                    self.mesh)
                 span_cols = batch.span_device if st is not None else None
                 # ?agg= stage: batch-global staged keys shared across
                 # the fused query axis (any member requesting agg turns
@@ -1449,7 +1468,8 @@ class MultiBlockEngine:
                 agg_stage = getattr(cq, "agg_stage", None)
                 agg = None if agg_stage is None else agg_stage.n_keys
                 entry_agg = (None if agg_stage is None
-                             else agg_stage.device())
+                             else agg_stage.device(self._page_sharding))
+            self._book_params(rec, False)
             st_bytes = 0 if st is None else sum(
                 int(getattr(t, "nbytes", 0)) for t in st.tables
                 if t is not None)

@@ -409,11 +409,12 @@ class CompiledStructural:
                 self.block_group, self.dur_params, self.kind_params,
                 self.agg_params)
 
-    def device_tables(self):
+    def device_tables(self, mesh=None):
         """Tables as device arrays, uploaded once per compiled query
         (the query_device_params idiom — every re-put is a separate
-        host→device transfer)."""
-        return _device_tables_cached(self, self.tables())
+        host→device transfer), to where launches over `mesh` read
+        them (None: the default device)."""
+        return _device_tables_cached(self, self.tables(), mesh)
 
     def shape_sig(self) -> tuple:
         """Jit-cache contribution: the plan IS shape (static), plus the
@@ -470,8 +471,8 @@ class StackedStructural:
     tables: tuple            # 7 leaves, each [Q, ...] or None
     n_queries: int
 
-    def device_tables(self):
-        return _device_tables_cached(self, self.tables)
+    def device_tables(self, mesh=None):
+        return _device_tables_cached(self, self.tables, mesh)
 
     def shape_sig(self) -> tuple:
         def sig(t):
@@ -682,8 +683,8 @@ class BucketedStructural:
     active_nodes: int = 0    # sum of members' real (unpadded) slots
     slot_nodes: int = 0      # n_queries * (NS + NT) bucket slots
 
-    def device_tables(self):
-        return _device_tables_cached(self, self.tables)
+    def device_tables(self, mesh=None):
+        return _device_tables_cached(self, self.tables, mesh)
 
     def shape_sig(self) -> tuple:
         def sig(t):
@@ -807,18 +808,26 @@ def stack_bucketed(sts: list, pad_q: int,
         slot_nodes=Qn * (NS + NT))
 
 
-def _device_tables_cached(owner, tables: tuple) -> tuple:
+def _device_tables_cached(owner, tables: tuple, mesh=None) -> tuple:
     """One upload per compiled/stacked predicate, memoized on the owner
     (shared by CompiledStructural and StackedStructural so the upload
-    path has exactly one implementation)."""
-    import jax.numpy as jnp
-
+    path has exactly one implementation) under the placement it was
+    made for. On a mesh the tables go to every device of it, as the
+    dist kernels' in_specs want them (parallel.mesh.put_replicated)."""
     cached = getattr(owner, "_device_tables", None)
-    if cached is None:
-        cached = owner._device_tables = tuple(
-            (jnp.asarray(t) if isinstance(t, np.ndarray) else t)
-            for t in tables)
-    return cached
+    if cached is not None and cached[0] == mesh:
+        return cached[1]
+    if mesh is None:
+        import jax.numpy as jnp
+
+        placed = tuple((jnp.asarray(t) if isinstance(t, np.ndarray) else t)
+                       for t in tables)
+    else:
+        from tempo_tpu.parallel.mesh import put_replicated
+
+        placed = put_replicated(mesh, tuple(tables))
+    owner._device_tables = (mesh, placed)
+    return placed
 
 
 class StructuralCompileError(ValueError):
