@@ -8,6 +8,6 @@
       driving the real HTTP API (in-process single binary by default, or
       --url for a running cluster), with latency thresholds.
 
-The north-star TPU-vs-CPU scan benchmark stays at the repo root
-(bench.py) — the driver runs that one on real hardware.
+The benchmark the driver runs on the chip is `chipbench/`
+(`BENCHMARK.json`); these two are CPU-side harnesses (ROADMAP De4).
 """

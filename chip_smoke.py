@@ -18,8 +18,8 @@ What it drives, in order:
 
   build     `make -C native clean libtempotpu.so` (the .so is git-ignored)
   corpus    a bulk tenant of `--blocks` x `--entries-per-block` search
-            entries (bench.py's build_corpus shape: 1024-entry pages,
-            4 tags per entry), written to a local backend before the
+            entries (build_corpus: 1024-entry pages, 4 tags per
+            entry), written to a local backend before the
             server starts and found by its poll. While it is written a
             plain numpy scan of the same columns answers every query
             below: that is the reference.
@@ -72,15 +72,13 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from bench import build_corpus  # noqa: E402  (numpy only at import)
-
 BULK_TENANT = "smoke-bulk"
 WRITE_TENANT = "smoke-write"
 PAGE_ENTRIES = 1024          # build_corpus / scale_large_blocks geometry
 EXHAUSTIVE_TAG = "x-dbg-exhaustive"   # search/pipeline.EXHAUSTIVE_SEARCH_TAG
 # tempo_search_scan_dispatches_total modes that are batcher launches on
-# the device ("single" is the ingester's one-block leg, "host_fallback"
-# the CPU route; a mesh launch counts as batched/coalesced here and shows
+# the device (the ingester's one-block leg among them: a one-block
+# batch; "host_fallback" is the CPU route; a mesh launch counts as batched/coalesced here and shows
 # as mode="mesh" in the dispatch profiler's stage histogram)
 DEVICE_MODES = ("batched", "coalesced")
 WRITE_GROUP = 100            # pushed traces per tag-search group (< top_k)
@@ -102,8 +100,8 @@ ingester:
   n_ingesters: 3
   replication_factor: 2
 compactor:
-  # the bulk tenant's blocks carry search containers only (bench.py's
-  # generator); compacting them is ROADMAP R3's cell, not this smoke
+  # the bulk tenant's blocks carry search containers only (build_corpus);
+  # compacting them is ROADMAP R3's cell, not this smoke
   tick_s: 86400
 """
 
@@ -128,6 +126,65 @@ class Checks:
 
 class Fatal(Exception):
     """A step without which nothing after it means anything."""
+
+
+def build_corpus(n_entries: int, E: int = 1024, C: int = 4, seed: int = 7):
+    """Synthesize ColumnarPages-shaped arrays directly (fast, numpy) —
+    semantically identical to ColumnarPages.build output."""
+    from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
+
+    rng = np.random.default_rng(seed)
+    services = [f"svc-{i:03d}" for i in range(64)]
+    statuses = ["200", "404", "500"]
+    regions = ["us-east-1", "us-west-2", "eu-west-1", "ap-south-1"]
+    names = [f"op-{i}" for i in range(32)]
+    key_dict = sorted(["service.name", "http.status_code", "region", "name"])
+    val_dict = sorted(set(services + statuses + regions + names))
+    vidx = {v: i for i, v in enumerate(val_dict)}
+    kidx = {k: i for i, k in enumerate(key_dict)}
+
+    P = -(-n_entries // E)
+    assert C >= 4
+
+    svc = rng.integers(0, len(services), size=(P, E))
+    st = rng.integers(0, len(statuses), size=(P, E))
+    rg = rng.integers(0, len(regions), size=(P, E))
+    nm = rng.integers(0, len(names), size=(P, E))
+    svc_ids = np.array([vidx[s] for s in services], dtype=np.int32)[svc]
+    st_ids = np.array([vidx[s] for s in statuses], dtype=np.int32)[st]
+    rg_ids = np.array([vidx[s] for s in regions], dtype=np.int32)[rg]
+    nm_ids = np.array([vidx[s] for s in names], dtype=np.int32)[nm]
+
+    kv_key = np.full((P, E, C), -1, dtype=np.int32)
+    kv_val = np.full((P, E, C), -1, dtype=np.int32)
+    for j, (kname, vals) in enumerate((
+        ("service.name", svc_ids), ("http.status_code", st_ids),
+        ("region", rg_ids), ("name", nm_ids),
+    )):
+        kv_key[:, :, j] = kidx[kname]
+        kv_val[:, :, j] = vals
+
+    e_idx = np.arange(E, dtype=np.int32)
+    entry_start = (1_600_000_000 + rng.integers(0, 86_400, size=(P, E))).astype(np.uint32)
+    entry_end = entry_start + rng.integers(0, 60, size=(P, E)).astype(np.uint32)
+    entry_dur = rng.integers(1, 60_000, size=(P, E)).astype(np.uint32)
+    entry_valid = np.zeros((P, E), dtype=bool)
+    flat_n = np.minimum(n_entries - np.arange(P) * E, E)
+    entry_valid[:] = e_idx[None, :] < flat_n[:, None]
+
+    pages = ColumnarPages(
+        geometry=PageGeometry(E, C), key_dict=key_dict, val_dict=val_dict,
+        kv_key=kv_key, kv_val=kv_val,
+        entry_start=entry_start, entry_end=entry_end, entry_dur=entry_dur,
+        entry_valid=entry_valid,
+        entry_root_svc=svc_ids.astype(np.int32),
+        entry_root_name=nm_ids.astype(np.int32),
+        trace_ids=np.zeros((P, E, 16), dtype=np.uint8),
+        n_entries=n_entries,
+        header={"n_entries": n_entries, "n_pages": P, "entries_per_page": E,
+                "kv_per_entry": C},
+    )
+    return pages
 
 
 def say(msg: str) -> None:
@@ -274,8 +331,8 @@ def bulk_queries(time_base: int) -> list[Query]:
 
 def write_bulk_corpus(run_dir: str, n_blocks: int, entries_per_block: int,
                       seed: int, queries: list[Query]) -> dict:
-    """Write the bulk tenant's blocks (search container + header + meta,
-    as bench.py's scale phases do) and answer every query by the
+    """Write the bulk tenant's blocks (search container + header + meta)
+    and answer every query by the
     reference scan on the way."""
     from tempo_tpu.backend.local import LocalBackend
     from tempo_tpu.backend.types import (

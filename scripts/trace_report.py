@@ -121,18 +121,19 @@ def enqueue_split(server, calls: int = 200):
         return None
     batch, pre = found
     d = batch.device
-    kernel = multiblock.dist_multi_scan_kernel
     host = (np.asarray(pre["term_keys"]), np.asarray(pre["val_ranges"]),
             *(np.uint32(min(int(pre[k]), 0xFFFFFFFF))
               for k in ("dur_lo", "dur_hi", "win_start", "win_end")))
     spec = NamedSharding(eng.mesh, PartitionSpec())
 
     def launch(params):
-        return kernel(
-            eng.mesh, d["kv_key"], d["kv_val"], d["entry_start"],
+        tk, vr, *bounds = params
+        return multiblock.batch_scan_kernel(
+            d["kv_key"], d["kv_val"], d["entry_start"],
             d["entry_end"], d["entry_dur"], d["entry_valid"],
-            d["page_block"], *params, None, None, d.get("entry_dur_res"),
-            None, None, None, n_terms=pre["n_terms"],
+            d["page_block"], tk, vr, None, *bounds, None, None,
+            d.get("entry_dur_res"), None, None, None, mesh=eng.mesh,
+            n_terms=pre["n_terms"],
             top_k=resolve_top_k(eng.top_k, 20), widths=batch.widths,
             plan=None, span_sharded=False,
             shard_tail=eng._shard_tail(batch, d), agg=None)

@@ -230,7 +230,7 @@ GUARDED_CALLS = (
                 "PACKING", "search_packed_residency"),
     # structural span staging: the disabled path must not even inspect
     # blocks for span segments, let alone stack/pad/upload them
-    GuardedCall("STRUCTURAL", ("stack_spans", "stage_single"), (),
+    GuardedCall("STRUCTURAL", ("stack_spans",), (),
                 "enabled", "STRUCTURAL", "search_structural_enabled"),
     # plan-shape stacking: group-key computation only behind the
     # stacking gate — a disabled coalescer submit stays on the exact

@@ -424,15 +424,26 @@ class JitPurityChecker(Checker):
     def _classify_call(helper: ast.AST, call: ast.Call,
                        expr_tainted) -> frozenset:
         """Helper params bound to NON-tracer actuals are static for
-        this call's analysis."""
+        this call's analysis, and so are params the call leaves at
+        their defaults (Python constants) — unless it spreads *args or
+        **kwargs, which may bind any of them."""
         params = _params(helper)
         statics = set()
+        bound = set()
         for i, arg in enumerate(call.args):
-            if i < len(params) and not expr_tainted(arg):
-                statics.add(params[i])
+            if i < len(params):
+                bound.add(params[i])
+                if not expr_tainted(arg):
+                    statics.add(params[i])
         for kw in call.keywords:
-            if kw.arg and not expr_tainted(kw.value):
-                statics.add(kw.arg)
+            if kw.arg:
+                bound.add(kw.arg)
+                if not expr_tainted(kw.value):
+                    statics.add(kw.arg)
+        spreads = any(isinstance(a, ast.Starred) for a in call.args) \
+            or any(kw.arg is None for kw in call.keywords)
+        if not spreads:
+            statics |= set(params) - bound
         return frozenset(statics)
 
     # ---- cache-key hygiene ----

@@ -109,6 +109,7 @@ def cmd_search(be, args):
     from tempo_tpu import tempopb
     from tempo_tpu.search import SearchResults
     from tempo_tpu.search.backend_search_block import BackendSearchBlock
+    from tempo_tpu.search.batcher import BlockBatcher
 
     from tempo_tpu.api.params import _duration_ms
 
@@ -123,15 +124,18 @@ def cmd_search(be, args):
         req.max_duration_ms = _duration_ms(args.max_duration)
     req.start = args.start
     req.end = args.end
-    results = SearchResults(limit=args.limit)
+    # every block a whole-container ScanJob, searched through the
+    # batcher as the server does (TempoDB.search_block): its breaker
+    # gate, host fallback and early quit at the limit
+    jobs = []
     for bid in be.list_blocks(args.tenant):
         try:
             m = be.read_block_meta(args.tenant, bid)
         except Exception:
             continue
-        BackendSearchBlock(be, m).search(req, results)
-        if results.complete:
-            break
+        jobs.append(BackendSearchBlock(be, m).scan_job())
+    results = BlockBatcher().search(jobs, req,
+                                    SearchResults(limit=args.limit))
     resp = results.response()
     from google.protobuf import json_format
 

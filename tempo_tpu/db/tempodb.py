@@ -24,7 +24,6 @@ from tempo_tpu.search import SearchResults, write_search_block
 from tempo_tpu.search.backend_search_block import BackendSearchBlock
 from tempo_tpu.search.batcher import BlockBatcher, ScanJob
 from tempo_tpu.search.columnar import PageGeometry
-from tempo_tpu.search.engine import ScanEngine
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 from tempo_tpu.observability.log import get_logger
@@ -248,8 +247,9 @@ class TempoDBConfig:
     # breakdown (build/h2d/compile/execute/d2h/lock_wait) into
     # tempo_search_dispatch_stage_seconds + /debug/profile. False is a
     # TRUE noop — dispatch sites get a shared noop record, no clock
-    # reads, no locks (the <2% overhead contract is benchmarked every
-    # round by bench.py's profile_overhead phase)
+    # reads, no locks (tests/test_observability.py
+    # test_profiler_noop_path_is_shared_and_cheap; what profiling costs
+    # when ON is not measured on the chip)
     search_profiling_enabled: bool = True
     # block_until_ready fence after each profiled kernel call: attributes
     # TRUE kernel time to the execute stage, at the cost of the async
@@ -301,7 +301,8 @@ class TempoDBConfig:
     # search_breaker_cooldown_s it half-opens and probes the device
     # with real dispatches until one succeeds (closed) or fails (open
     # again). False disables the whole robustness layer (the noop
-    # contract bench's chaos phase asserts).
+    # contract: tests/test_faults.py test_breaker_disabled_is_passthrough,
+    # test_disarmed_noop_byte_identity).
     search_breaker_enabled: bool = True
     search_breaker_fault_threshold: int = 3
     search_breaker_window_s: float = 30.0
@@ -366,7 +367,6 @@ class TempoDB:
             window_s=self.cfg.compaction_window_s,
             max_inputs=self.cfg.compaction_max_inputs,
         )
-        self.engine = ScanEngine()
         self.mesh = mesh
         # auto-mesh resolves lazily on the first search: jax.devices()
         # initializes the backend (and on TPU hosts claims the chip), which
@@ -828,8 +828,7 @@ class TempoDB:
             if bsb is None:
                 bsb = BackendSearchBlock(
                     self.backend, meta,
-                    header=self._headers.get(meta.block_id),
-                    probe_min_vals=self.cfg.search_device_probe_min_vals)
+                    header=self._headers.get(meta.block_id))
                 self._search_blocks[meta.block_id] = bsb
                 # bounded HBM cache: evict oldest staged blocks
                 while len(self._search_blocks) > self.cfg.search_cache_blocks:
@@ -1120,6 +1119,28 @@ class TempoDB:
             if qs is not None:
                 self._finalize_query_stats(qs, req.search_req, results)
         return results
+
+    def search_meta(self, meta: BlockMeta, req: tempopb.SearchRequest,
+                    results: SearchResults) -> None:
+        """One whole block, known by its meta and not (yet) by the
+        blocklist — the ingester's recently-completed leg — searched
+        through the batcher like every other: a one-block batch. Raises
+        DoesNotExist where the block has no search container.
+
+        This leg's hits overlap the blocklist's once a poll has seen
+        the block, and dedupe there by trace id; ?agg= counts could
+        not, so the leg never aggregates."""
+        from tempo_tpu.search.analytics import AGG_QUERY_TAG
+
+        if AGG_QUERY_TAG in req.tags:
+            plain = tempopb.SearchRequest()
+            plain.CopyFrom(req)
+            del plain.tags[AGG_QUERY_TAG]
+            req = plain
+        self._ensure_mesh()
+        job = self._scan_job(meta)
+        if job.n_pages > 0:
+            self.batcher.search([job], req, results)
 
     def search_blocks(self, breq: tempopb.SearchBlocksRequest) -> SearchResults:
         """A batched job request (many page-range jobs, one kernel

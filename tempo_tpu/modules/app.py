@@ -65,7 +65,8 @@ class AppConfig:
     # histograms push->searchable, freshness/backlog gauges, slow-flush
     # log, /debug/ingest. False is a true noop on the ingest path —
     # record sites branch out on one attribute read, ingest output is
-    # byte-identical (asserted by bench.py's freshness phase)
+    # byte-identical (tests/test_ingest_telemetry.py
+    # test_telemetry_off_is_byte_identical_on_the_wal)
     ingest_telemetry_enabled: bool = True
     # slow-flush JSON log threshold (seconds): a successful block
     # completion slower than this emits ONE structured line on

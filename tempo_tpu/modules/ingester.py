@@ -391,7 +391,7 @@ class TenantInstance:
                     self.tenant, meta.block_id):
                 continue
             try:
-                self.db._search_block_for(meta).search(req, results)  # noqa: SLF001
+                self.db.search_meta(meta, req, results)
             except Exception:  # noqa: BLE001
                 continue
             if results.complete:

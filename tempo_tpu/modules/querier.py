@@ -320,8 +320,8 @@ class Querier:
                 continue
         for m in self._tag_blocks(tenant):
             try:
-                sp = self.db._search_block_for(m).staged()  # noqa: SLF001
-                tags.update(sp.pages.key_dict)
+                pages = self.db._search_block_for(m).pages()  # noqa: SLF001
+                tags.update(pages.key_dict)
             except Exception:  # noqa: BLE001 — blocks without search data
                 obs.partial_results.inc(reason="backend")
                 continue
@@ -348,11 +348,11 @@ class Querier:
                 # backend read + decompress + staging for nothing
                 break
             try:
-                sp = self.db._search_block_for(m).staged()  # noqa: SLF001
+                pages = self.db._search_block_for(m).pages()  # noqa: SLF001
             except Exception:  # noqa: BLE001
                 obs.partial_results.inc(reason="backend")
                 continue
-            for s in sp.pages.values_for_key(tag):
+            for s in pages.values_for_key(tag):
                 if s not in vals:
                     size += len(s)
                     if size > lim.max_bytes_per_tag_values:

@@ -361,7 +361,7 @@ retention_deleted = Counter("tempodb_retention_deleted_total",
                             "blocks hard-deleted by retention")
 scan_dispatches = Counter(
     "tempo_search_scan_dispatches_total",
-    "scan kernel dispatches by mode (single, batched, coalesced, "
+    "scan kernel dispatches by mode (batched, coalesced, "
     "host_fallback) and by shards: the size of the mesh the launch ran "
     "over, 1 for a one-device launch or a host scan")
 topk_dispatches = Counter(
@@ -414,7 +414,7 @@ truncated_tag_entries = Counter(
 dispatch_stage_seconds = Histogram(
     "tempo_search_dispatch_stage_seconds",
     "per-dispatch stage wall time: stage=build|h2d|compile|execute|d2h|"
-    "lock_wait, mode=single|batched|coalesced|mesh|dict_probe|host_probe",
+    "lock_wait, mode=batched|coalesced|mesh|dict_probe|host_probe",
     buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1,
              5, 30))
 jit_cache_events = Counter(

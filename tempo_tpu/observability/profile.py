@@ -3,8 +3,8 @@
 PRs 1-4 (coalescing, HBM tiering, device dict probe) each had to infer
 where device time went from bench wall-clocks — there was no first-class
 visibility into the stages the TPU lift actually changes. This module
-gives every device dispatch path (single-block, multi-block batched,
-coalesced, mesh-sharded, and the dict-probe kernel) a stage breakdown:
+gives every device dispatch path (batched, coalesced, mesh-sharded,
+and the dict-probe kernel) a stage breakdown:
 
   build    host-side predicate/table build (device-param upload prep,
            query-table asarray; `mode=host_probe` records the host

@@ -1,4 +1,3 @@
 from .mesh import make_mesh, scan_mesh_axes
-from .dist_search import DistributedScanEngine
 
-__all__ = ["make_mesh", "scan_mesh_axes", "DistributedScanEngine"]
+__all__ = ["make_mesh", "scan_mesh_axes"]

@@ -23,9 +23,8 @@ cooperating pieces:
                 dispatches to recover); while it blocks,
                 ``planner.stage_veto`` / ``pipeline._use_device_probe``
                 / the batcher route everything through the existing
-                host paths and ``/status``'s device block + bench's
-                ``device_wedged`` headline read breaker state instead
-                of ad-hoc probing.
+                host paths and ``/status``'s device block reads
+                breaker state instead of ad-hoc probing.
   faults.py     the fault-injection harness proving all of the above in
                 tier-1: named faultpoints armable by config/env/test
                 fixture, compiled to a true noop when disarmed (the

@@ -20,8 +20,8 @@ host route in one place:
 
 Transitions emit ``tempo_search_device_breaker_transitions_total``,
 update the state gauge, annotate the active self-trace span, and log —
-``/status``'s device block and bench's ``device_wedged`` headline read
-:meth:`snapshot` instead of ad-hoc probing. Every booked fault is also
+``/status``'s device block reads :meth:`snapshot` instead of ad-hoc
+probing. Every booked fault is also
 logged at error level with the absorbed exception's text (rate-limited
 per kind and mode): the host route answers byte-identically, so that
 line and ``last_fault`` are where a compiler refusal or an HBM
@@ -223,8 +223,7 @@ class CircuitBreaker:
     # ---- operator surface ----
 
     def snapshot(self) -> dict[str, object]:
-        """The /status device-block + /debug/faults breaker view, and
-        what bench's ``device_wedged`` headline reads."""
+        """The /status device-block + /debug/faults breaker view."""
         with self._lock:
             now = time.monotonic()
             last: dict[str, object] | None = None

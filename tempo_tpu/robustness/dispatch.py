@@ -83,8 +83,8 @@ def _is_device_error(e: BaseException) -> bool:
 class DispatchGuard:
     """Process-wide dispatch watchdog (module singleton ``GUARD``, the
     PROFILER idiom). ``run(mode, fn)`` executes one device dispatch
-    body; ``mode`` is the profiler's dispatch mode (single | batched |
-    coalesced | mesh | dict_probe | h2d | d2h) and becomes the fault's
+    body; ``mode`` is the profiler's dispatch mode (batched | coalesced |
+    mesh | dict_probe | h2d | d2h) and becomes the fault's
     stage context."""
 
     # bounds leaked hung workers between breaker trips; the breaker

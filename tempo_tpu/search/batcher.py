@@ -7,7 +7,7 @@ CPU the per-job cost is the scan itself. On TPU the per-dispatch overhead
 (host sync + kernel launch) dwarfs the scan of a single block, so the
 batcher inverts the shape: jobs GROUP into batches
 whose pages stack along the device page axis and scan in ONE kernel call
-(`multiblock.multi_scan_kernel`; with a mesh, the shard_map variant whose
+(`multiblock.batch_scan_kernel`; with a mesh, under shard_map, where
 collectives replace the Results funnel).
 
 Properties the grouping keeps:
@@ -58,7 +58,7 @@ from .results import SearchResults
 
 def host_scan(host, mq, top_k: int):
     """The host route's execution (breaker fallback AND the ownership
-    layer's non-owner serve): run the SAME multi_scan_kernel over the
+    layer's non-owner serve): run the SAME batch_scan_kernel over the
     host-tier stacked arrays, pinned to the CPU backend — no
     wedged-device array is ever touched, no duplicate HBM copy is ever
     staged on a non-owner. Because it is
@@ -78,7 +78,7 @@ def host_scan(host, mq, top_k: int):
     import jax.numpy as jnp
 
     from .engine import cpu_pinned
-    from .multiblock import multi_scan_kernel
+    from .multiblock import batch_scan_kernel
 
     t0 = time.perf_counter()
     with cpu_pinned():
@@ -116,10 +116,10 @@ def host_scan(host, mq, top_k: int):
             entry_agg = getattr(host, "_cpu_agg_staged", None)
             if entry_agg is None:
                 entry_agg = host._cpu_agg_staged = agg_stage.cpu()
-        out = multi_scan_kernel(
+        out = batch_scan_kernel(
             dev["kv_key"], dev["kv_val"], dev["entry_start"],
             dev["entry_end"], dev["entry_dur"], dev["entry_valid"],
-            dev["page_block"], tk, vr,
+            dev["page_block"], tk, vr, None,
             jnp.uint32(mq.dur_lo), jnp.uint32(min(mq.dur_hi, 0xFFFFFFFF)),
             jnp.uint32(mq.win_start),
             jnp.uint32(min(mq.win_end, 0xFFFFFFFF)),
@@ -293,7 +293,7 @@ class QueryCoalescer:
     """Cross-request query coalescing: concurrent searches whose next
     dispatch targets the SAME staged BlockBatch stack their compiled
     queries along a query axis and execute as ONE fused
-    coalesced_scan_kernel launch — continuous batching for scans. N
+    batch_scan_kernel launch — continuous batching for scans. N
     tenants' dashboards over the same device-resident columns then cost
     ~1 dispatch per coalescing window instead of N.
 
@@ -308,7 +308,7 @@ class QueryCoalescer:
       sub-requests — which target disjoint batches and can never fuse —
       don't tax each other either. The window is only paid when another
       in-flight search could actually share this batch's dispatch.
-    - Single-query flushes go through the ordinary multi_scan_kernel so
+    - Single-query flushes launch without a query axis (scan_async) so
       they reuse its already-compiled executables.
     - Query tables pad (Q, T, R, top_k) to power-of-two buckets
       (multiblock.stack_queries), so the jit cache keys on predicate

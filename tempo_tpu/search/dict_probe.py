@@ -34,15 +34,14 @@ columnar.py's layout lesson).
 
 Mesh sharding splits the dictionary along the VALUE axis: each device
 probes its contiguous value range and the per-shard hit masks all_gather
-into the replicated global mask — the same collective shape
-parallel/dist_search.py uses for scan results.
+into the replicated global mask — the same collective shape the scan
+uses for its results (multiblock._merge_shards).
 
 The probe output (a [T, V] bool mask) feeds the scan kernel directly on
-device: engine.entry_match_mask / multiblock.multi_entry_mask test value
-membership with a mask lookup instead of the host-compiled [T,R,2] range
-compares, so no id-set ever crosses the host boundary. (bench.py's
-high-cardinality phase re-validates the mask-lookup-vs-range-compare
-tradeoff rather than assuming the old gather-serialization measurement.)
+device: multiblock.multi_entry_mask tests value membership with a mask
+lookup instead of the host-compiled [T,R,2] range compares, so no id-set
+ever crosses the host boundary. (Which of mask lookup and range compare
+wins on the chip is not measured: ROADMAP R8, `highcard.substring`.)
 """
 
 from __future__ import annotations
@@ -295,7 +294,7 @@ def dist_probe_kernel(mesh, buf, pos, off, n_real, needles, lens, empties,
     """Mesh probe: the dictionary's value axis is split across shards
     (axis 0 of the staged arrays); every device probes its value range
     and the local masks all_gather into the replicated global [T, v_pad]
-    mask — same collective shape as dist_search's result funnel."""
+    mask — same collective shape as the scan's result funnel."""
     from jax.sharding import PartitionSpec as P
     from tempo_tpu.parallel.mesh import SCAN_AXIS, shard_map_compat
 
@@ -313,7 +312,7 @@ def dist_probe_kernel(mesh, buf, pos, off, n_real, needles, lens, empties,
         out_specs=(P(), P()),
         # all_gather output is identical on every shard; the replication
         # checker can't infer it through the gather (same stance as
-        # dist_search)
+        # multiblock.batch_scan_kernel)
         check=False,
     )(buf, pos, off, n_real, needles, lens, empties)
 

@@ -51,13 +51,13 @@ from .data import (
     decode_search_data,
     search_data_matches,
 )
-from .engine import (
-    StagedPages,
-    _bucket,
-    cpu_pinned,
-    fetch_scan_out,
-    pad_page_axis,
-    scan_kernel,
+from .dict_probe import _pow2
+from .engine import DEFAULT_TOP_K, cpu_pinned, fetch_scan_out, resolve_top_k
+from .multiblock import (
+    MultiBlockEngine,
+    batch_scan_kernel,
+    compile_multi,
+    stack_host,
 )
 
 
@@ -78,66 +78,50 @@ def _tier_valid(entry_valid, n_pages, tier):
     return jnp.logical_and(entry_valid, page_live)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_terms", "top_k", "plan", "tier"))
-def hot_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                    entry_valid, n_pages, term_keys, val_ranges, dur_lo,
-                    dur_hi, win_start, win_end, span_cols=None,
-                    s_tables=None, *, n_terms, top_k, plan=None, tier=None):
-    """The hot-tier dispatch: scan_kernel over a capacity-padded rolling
-    stage. Delegation keeps it byte-identical to the backend-block scan
-    — same match mask, same masked top-k — with one prelude: the static
-    `tier` capacity descriptor masks pages beyond the traced live count
-    so a stage scanned mid-absorb never reads a stale capacity page."""
-    entry_valid = _tier_valid(entry_valid, n_pages, tier)
-    return scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                       entry_valid, term_keys, val_ranges, dur_lo, dur_hi,
-                       win_start, win_end, None, None, span_cols, s_tables,
-                       n_terms=n_terms, top_k=top_k, widths=None, plan=plan)
+@functools.partial(jax.jit, static_argnames=("n_terms", "top_k", "widths",
+                                             "plan", "tier"))
+def hot_scan_kernel(cat, n_pages, term_keys, val_ranges, dur_lo, dur_hi,
+                    win_start, win_end, span_cols=None, s_tables=None,
+                    *, n_terms, top_k, widths=None, plan=None, tier=None):
+    """The hot-tier dispatch: batch_scan_kernel over a capacity-padded
+    rolling stage, a one-block batch (`cat`: stack_host's arrays).
+    Delegation keeps it byte-identical to the backend-block scan — same
+    match mask, same masked top-k — with one prelude: the static `tier`
+    capacity descriptor masks pages beyond the traced live count so a
+    stage scanned mid-absorb never reads a stale capacity page."""
+    return batch_scan_kernel(
+        cat["kv_key"], cat["kv_val"], cat["entry_start"], cat["entry_end"],
+        cat["entry_dur"], _tier_valid(cat["entry_valid"], n_pages, tier),
+        cat["page_block"], term_keys, val_ranges, None, dur_lo, dur_hi,
+        win_start, win_end, None, None, cat.get("entry_dur_res"),
+        span_cols, s_tables, n_terms=n_terms, top_k=top_k, widths=widths,
+        plan=plan)
 
 
 class _HotStage:
-    """Epoch-cached columnar build over one entry set. Rebuilds only
-    when the epoch moved; the page axis pads to the pow2 `tier` so the
-    kernel's jit key is shape-only (see module docstring)."""
+    """Epoch-cached columnar build over one entry set, stacked as a
+    one-block batch. Rebuilds only when the epoch moved; the page axis
+    pads to the pow2 `tier` so the kernel's jit key is shape-only (see
+    module docstring)."""
 
     def __init__(self):
         self.epoch = -1
-        self.pages = None
+        self.host = None       # multiblock.HostBatch of the one block
         self.tier = 0
-        self.host = None       # capacity-padded DEVICE_ARRAYS dict
-        self.span_host = None  # staged span columns (structural), or None
-        self.span_stale = True
 
     def ensure(self, entries: list[SearchData], epoch: int):
-        if self.epoch == epoch and self.pages is not None:
-            return self.pages
+        if self.epoch == epoch and self.host is not None:
+            return self.host
         from .columnar import ColumnarPages
 
         pages = ColumnarPages.build(entries)
-        self.pages = pages
-        self.tier = _bucket(pages.n_pages)
-        self.host = pad_page_axis(pages, self.tier)
-        self.span_host = None
-        self.span_stale = True
+        self.tier = _pow2(pages.n_pages)
+        self.host = stack_host([pages], pad_to=self.tier)
         self.epoch = epoch
         from tempo_tpu.observability import metrics as obs
 
         obs.live_tier_rebuilds.inc()
-        return pages
-
-    def span_columns(self):
-        """Lazily staged structural span columns (only a structural
-        request pays the staging)."""
-        if self.span_stale:
-            from .structural import STRUCTURAL
-
-            self.span_host = None
-            if STRUCTURAL.enabled:
-                self.span_host = STRUCTURAL.stage_single(self.pages,
-                                                         self.tier)
-            self.span_stale = False
-        return self.span_host
+        return self.host
 
 
 def scan_search_data(entries: list[SearchData], req, results,
@@ -149,49 +133,42 @@ def scan_search_data(entries: list[SearchData], req, results,
     masked top-k and render path. Returns True when the scan handled
     the request (results updated; a dictionary prune counts — nothing
     could match), False when the caller must run the legacy walk."""
-    from .backend_search_block import default_engine
-    from .pipeline import compile_query
     from . import structural as _structural
 
     if not entries:
         return True
-    engine = default_engine()
-    pages = stage.ensure(entries, epoch)
-    cq = compile_query(pages.key_dict, pages.val_dict, req,
-                       cache_on=pages, host_only=True)
-    expr = _structural.structural_query(req)
-    if cq is not None and expr is not None:
-        cq.structural = _structural.compile_structural(
-            expr, [pages], cache_on=pages, host_only=True,
-            entry_kv_slots=pages.geometry.kv_per_entry)
-    if cq is None:  # dictionary prefilter pruned: no entry can match
+    host = stage.ensure(entries, epoch)
+    mq = compile_multi(host.blocks, req, cache_on=host, host_only=True)
+    if mq is None:  # dictionary prefilter pruned: no entry can match
         return True
-    top_k = engine._resolve_top_k(cq)
-    st = getattr(cq, "structural", None)
+    expr = _structural.structural_query(req)
+    if expr is not None:
+        mq.structural = _structural.compile_structural(
+            expr, host.blocks, cache_on=host, host_only=True,
+            entry_kv_slots=host.blocks[0].geometry.kv_per_entry)
+    st = mq.structural
     with cpu_pinned():
-        dev = {k: jnp.asarray(v) for k, v in stage.host.items()}
         plan = s_tables = span_dev = None
         if st is not None:
             plan = st.plan
             s_tables = tuple(jnp.asarray(t) if t is not None else None
                              for t in st.tables())
-            span_host = stage.span_columns()
-            if span_host is not None:
-                span_dev = {k: jnp.asarray(v) for k, v in span_host.items()}
+            if host.span_cat is not None:
+                span_dev = {k: jnp.asarray(v)
+                            for k, v in host.span_cat.items()}
         out = hot_scan_kernel(
-            dev["kv_key"], dev["kv_val"], dev["entry_start"],
-            dev["entry_end"], dev["entry_dur"], dev["entry_valid"],
-            jnp.int32(pages.n_pages),
-            jnp.asarray(cq.term_keys), jnp.asarray(cq.val_ranges),
-            jnp.uint32(cq.dur_lo), jnp.uint32(min(cq.dur_hi, 0xFFFFFFFF)),
-            jnp.uint32(cq.win_start),
-            jnp.uint32(min(cq.win_end, 0xFFFFFFFF)),
+            {k: jnp.asarray(v) for k, v in host.cat.items()},
+            jnp.int32(host.blocks[0].n_pages),
+            jnp.asarray(mq.term_keys), jnp.asarray(mq.val_ranges),
+            jnp.uint32(mq.dur_lo), jnp.uint32(min(mq.dur_hi, 0xFFFFFFFF)),
+            jnp.uint32(mq.win_start),
+            jnp.uint32(min(mq.win_end, 0xFFFFFFFF)),
             span_dev, s_tables,
-            n_terms=cq.n_terms, top_k=top_k, plan=plan, tier=stage.tier)
+            n_terms=mq.n_terms, top_k=resolve_top_k(DEFAULT_TOP_K, mq.limit),
+            widths=host.widths, plan=plan, tier=stage.tier)
         _, inspected, scores, idx = fetch_scan_out(out)
     results.metrics.inspected_traces += inspected
-    holder = StagedPages(device={}, n_pages=pages.n_pages, pages=pages)
-    for m in engine.results(holder, cq, scores, idx):
+    for m in MultiBlockEngine.results(host, mq, scores, idx):
         results.add(m)
     return True
 

@@ -31,7 +31,15 @@ from tempo_tpu.observability import profile
 
 from .columnar import ColumnarPages
 from .dict_probe import _pow2
-from .engine import DEFAULT_TOP_K, book_topk, latest_k, masked_topk
+from .engine import (
+    DEFAULT_TOP_K,
+    book_topk,
+    fetch_scan_out,
+    latest_k,
+    masked_topk,
+    query_device_params,
+    resolve_top_k,
+)
 from . import packing
 from .packing import duration_ok, mask_select_grouped, unpack_ids
 from .pipeline import (
@@ -752,12 +760,13 @@ def multi_entry_mask(kv_key, kv_val, entry_start, entry_end, entry_dur,
                      dur_lo, dur_hi, win_start, win_end, *, n_terms: int,
                      term_active=None, val_hits=None, block_group=None,
                      entry_dur_res=None, widths=None):
-    """The multi-block predicate: [P,E] bool mask of matching entries.
-    Like engine.entry_match_mask but term columns are selected per page
-    through the page_block index: key id and ranges become [P]-indexed
-    gathers over the SMALL [B,...] tables (cheap — B entries, not 8M).
-    Shared by the single-device kernel and the shard_map distributed
-    kernel (each shard evaluates it over its local page slice).
+    """THE predicate: [P,E] bool mask of matching entries. Term
+    columns are selected per page through the page_block index: key id
+    and ranges become [P]-indexed gathers over the SMALL [B,...] tables
+    (cheap — B entries, not 8M). Value membership is an OR over
+    inclusive [lo,hi] id ranges — pure broadcast compares, no gather
+    (pipeline.ids_to_ranges explains why). On a mesh each shard
+    evaluates it over its local page slice.
 
     `term_active` ([T] bool, optional): the query-coalescing pad axis —
     queries stacked along a query axis share one static n_terms, so a
@@ -831,377 +840,211 @@ def agg_entry_counts(mask, entry_agg, n_keys: int):
     return (edges[1:] - edges[:-1]).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_terms", "top_k", "widths",
-                                             "plan", "agg"))
-def multi_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                      entry_valid, page_block, term_keys, val_ranges,
-                      dur_lo, dur_hi, win_start, win_end,
-                      val_hits=None, block_group=None, entry_dur_res=None,
-                      span_cols=None, s_tables=None, entry_agg=None,
-                      *, n_terms: int, top_k: int, widths=None,
-                      plan=None, agg=None):
-    mask = multi_entry_mask(
-        kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
-        page_block, term_keys, val_ranges, dur_lo, dur_hi, win_start,
-        win_end, n_terms=n_terms, val_hits=val_hits,
-        block_group=block_group, entry_dur_res=entry_dur_res,
-        widths=widths,
-    )
-    if plan is not None:
-        # structural predicate (search/structural.py): verdicts fuse
-        # into the same dispatch — compiled from the static plan, never
-        # interpreted
-        from .structural import structural_entry_mask
+def _scan_pages(kv_key, kv_val, entry_start, entry_end, entry_dur,
+                entry_valid, page_block, term_keys, val_ranges, term_active,
+                dur_lo, dur_hi, win_start, win_end, val_hits, block_group,
+                entry_dur_res, struct_mask, span_cols, s_tables, entry_agg,
+                *, n_terms: int, top_k: int, widths, plan, agg):
+    """The scan of the pages one device holds: per query the verdict
+    mask, its count, the top-k of its matches and, where `agg` (static,
+    the dense key-space size K) is set, the ?agg= counts the same mask
+    gates. Returns (count, inspected, scores [k], flat idx [k][, agg
+    [K]]); `inspected` is a property of the pages, not of a query.
 
-        mask = mask & structural_entry_mask(
-            kv_key, kv_val, entry_dur, entry_valid, page_block,
-            entry_dur_res, span_cols, s_tables, plan=plan, widths=widths)
-    count = jnp.sum(mask, dtype=jnp.int32)
-    inspected = jnp.sum(entry_valid & (page_block >= 0)[:, None], dtype=jnp.int32)
-    scores, idx = masked_topk(mask, entry_start, top_k)
-    if agg is not None:
-        # `agg` (static, the dense key-space size K — part of the jit
-        # key like `plan`) adds the ?agg= reduction as one more stage
-        # gated by the SAME verdict mask
-        return (count, inspected, scores, idx,
-                agg_entry_counts(mask, entry_agg, agg))
-    return count, inspected, scores, idx
+    `term_active` decides the query axis: None is one query and its
+    tables are traced as they come; a [Q, T] array means every
+    per-query table ([Q, ...]-stacked, stack_queries) carries a leading
+    query axis and vmap lifts the verdict over it — count, scores, idx
+    and agg gain that axis, the page arrays are closed over and shared.
 
-
-@functools.partial(jax.jit,
-                   static_argnames=("mesh", "n_terms", "top_k", "widths",
-                                    "plan", "span_sharded", "shard_tail",
-                                    "agg"))
-def dist_multi_scan_kernel(mesh, kv_key, kv_val, entry_start, entry_end,
-                           entry_dur, entry_valid, page_block, term_keys,
-                           val_ranges, dur_lo, dur_hi, win_start, win_end,
-                           val_hits=None, block_group=None,
-                           entry_dur_res=None,
-                           span_cols=None, s_tables=None, entry_agg=None,
-                           *, n_terms: int, top_k: int, widths=None,
-                           plan=None, span_sharded=False,
-                           shard_tail: int = 0, agg=None):
-    """Multi-block scan sharded over the mesh's scan axis: the stacked
-    page axis (blocks × pages — the corpus 'sequence' axis, SURVEY.md §5)
-    splits across devices; the [B,...] term tables replicate; counts
-    reduce with psum and per-shard top-k candidates all_gather into a
-    global top-k — one jit call, collectives riding ICI (the TPU-native
-    Results funnel, reference results.go:38-141).
-
-    The structural predicate (plan + span_cols/s_tables) has two
-    placements, selected by the STATIC `span_sharded` flag (part of the
-    jit key, like `widths`):
-
-      - replicated span columns (legacy): the mask evaluates OUTSIDE
-        the shard_map — parent pointers index the global span axis,
-        which a page shard cannot see — and its [P, E] verdicts enter
-        the sharded region as one more page-sharded operand;
-      - segment-aligned sharded span columns
-        (search_structural_shard_spans): each trace's span run lives
-        whole on its page's shard in shard-local coordinates, so the
-        `child` gather and `desc` pointer-doubling evaluate INSIDE
-        shard_fn over the local chunk — parent joins scale with the
-        mesh, per-shard span HBM ~1/P, and only the per-trace verdict
-        feeds the existing collectives."""
-    from jax.sharding import PartitionSpec as P
-    from tempo_tpu.parallel.mesh import SCAN_AXIS
-
-    n_shards = mesh.devices.size
-    E = entry_valid.shape[1]
-    local_flat = kv_key.shape[0] // n_shards * E
-
-    struct_mask = None
-    sh_span_cols = sh_s_tables = None
-    if plan is not None and not span_sharded:
-        from .structural import structural_entry_mask
-
-        struct_mask = structural_entry_mask(
-            kv_key, kv_val, entry_dur, entry_valid, page_block,
-            entry_dur_res, span_cols, s_tables, plan=plan, widths=widths)
-    elif plan is not None:
-        sh_span_cols, sh_s_tables = span_cols, s_tables
-
-    pages_total = int(kv_key.shape[0])
-
-    def shard_fn(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                 entry_valid, page_block, term_keys, val_ranges,
-                 dur_lo, dur_hi, win_start, win_end, val_hits,
-                 block_group, entry_dur_res, struct_mask,
-                 sh_span_cols, sh_s_tables, entry_agg):
-        if shard_tail:
-            # remainder-shard layout descriptor (static, part of the
-            # jit key like `widths`): the trailing `shard_tail` pad
-            # pages live on the last shard(s); their entries are
-            # already invalid, so this mask is byte-identical — it
-            # RECORDS the ragged tail in the compiled layout
-            pp = page_block.shape[0]
-            gpage = (jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32)
-                     * pp + jnp.arange(pp, dtype=jnp.int32))
-            entry_valid = entry_valid & (
-                gpage < jnp.int32(pages_total - shard_tail))[:, None]
+    The structural predicate (static `plan`) reaches the verdict one of
+    two ways: `struct_mask`, verdicts already evaluated by the caller
+    (the mesh's replicated span layout), or `span_cols` + `s_tables`,
+    evaluated here over these pages — compiled from the plan, never
+    interpreted, in the same dispatch."""
+    def one_query(tk, vr, ta, dlo, dhi, ws, we, vh, bg, sm, st_t):
         mask = multi_entry_mask(
             kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
-            page_block, term_keys, val_ranges, dur_lo, dur_hi, win_start,
-            win_end, n_terms=n_terms, val_hits=val_hits,
-            block_group=block_group, entry_dur_res=entry_dur_res,
-            widths=widths,
-        )
-        if struct_mask is not None:
-            mask = mask & struct_mask
-        if plan is not None and span_sharded:
-            from .structural import structural_entry_mask
-
-            # shard-local evaluation: the local span chunk's
-            # parent/begin columns are already in local coordinates
-            # (shard_span_segment rebased them), so the joins and
-            # segment reductions never leave the shard
-            mask = mask & structural_entry_mask(
-                kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, sh_span_cols, sh_s_tables, plan=plan,
-                widths=widths)
-        local_count = jnp.sum(mask, dtype=jnp.int32)
-        local_inspected = jnp.sum(
-            entry_valid & (page_block >= 0)[:, None], dtype=jnp.int32)
-        scores, idx = masked_topk(mask, entry_start, top_k)
-        shard = jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32)
-        gidx = idx + shard * local_flat
-        count = jax.lax.psum(local_count, SCAN_AXIS)
-        inspected = jax.lax.psum(local_inspected, SCAN_AXIS)
-        all_scores = jax.lax.all_gather(scores, SCAN_AXIS).reshape(-1)
-        all_idx = jax.lax.all_gather(gidx, SCAN_AXIS).reshape(-1)
-        # shard after shard, each in masked_topk's order: equal start
-        # seconds come in ascending global index, and the merge keeps it
-        top_scores, top_idx = latest_k(
-            all_scores, all_idx, min(top_k, all_scores.shape[0]))
-        if agg is not None:
-            # per-shard dense counts over the local page slice psum to
-            # the global histogram — integer adds, so the distributed
-            # answer is bit-equal to the single-device one
-            agg_counts = jax.lax.psum(
-                agg_entry_counts(mask, entry_agg, agg), SCAN_AXIS)
-            return count, inspected, top_scores, top_idx, agg_counts
-        return count, inspected, top_scores, top_idx
-
-    from tempo_tpu.parallel.mesh import shard_map_compat
-
-    return shard_map_compat(
-        shard_fn, mesh=mesh,
-        # the probe hit mask + block->group map replicate like the other
-        # predicate tables (a None leaf makes its spec a no-op); the
-        # duration residual and the structural verdicts shard with the
-        # page axis. Sharded span columns split on their leading axis
-        # (the chunk-per-shard span axis / the page axis of the entry
-        # range columns); the structural parameter tables replicate.
-        # The staged ?agg= composite keys shard with their pages.
-        in_specs=(P(SCAN_AXIS),) * 7 + (P(),) * 8
-        + (P(SCAN_AXIS), P(SCAN_AXIS), P(SCAN_AXIS), P(), P(SCAN_AXIS)),
-        out_specs=(P(), P(), P(), P())
-        + ((P(),) if agg is not None else ()),
-        # all_gather+top_k yields identical values on every shard, but the
-        # replication checker can't infer it through the gather
-        check=False,
-    )(kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
-      page_block, term_keys, val_ranges, dur_lo, dur_hi, win_start,
-      win_end, val_hits, block_group, entry_dur_res, struct_mask,
-      sh_span_cols, sh_s_tables, entry_agg)
-
-
-@functools.partial(jax.jit, static_argnames=("n_terms", "top_k", "widths",
-                                             "plan", "agg"))
-def coalesced_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                          entry_valid, page_block, term_keys, val_ranges,
-                          term_active, dur_lo, dur_hi, win_start, win_end,
-                          val_hits=None, block_group=None,
-                          entry_dur_res=None, span_cols=None,
-                          s_tables=None, entry_agg=None,
-                          *, n_terms: int, top_k: int, widths=None,
-                          plan=None, agg=None):
-    """The query-axis variant of multi_scan_kernel: predicate tables are
-    [Q, ...]-stacked and vmap lifts the per-query mask + top-k over the
-    query axis — ONE dispatch serves Q concurrent requests over the same
-    staged pages. The page arrays are read once per term loop regardless
-    of Q (the scan is bandwidth-bound; queries amortize the read).
-    Returns (counts i32 [Q], inspected i32, scores i32 [Q,k],
-    flat idx i32 [Q,k]). `inspected` is query-independent (every query
-    sees the same staged pages), so it stays scalar.
-
-    `plan` (static) + `s_tables` ([Q, ...]-stacked structural parameter
-    tables) + `span_cols` (the batch's staged span columns, SHARED
-    across the query axis): plan-shape stacking — every member lowered
-    to the same plan descriptor, so vmap lifts one compiled structural
-    predicate over per-query tables, same as the legacy tables."""
-    inspected = jnp.sum(entry_valid & (page_block >= 0)[:, None],
-                        dtype=jnp.int32)
-
-    def one_query(tk, vr, ta, dlo, dhi, ws, we, vh, bg, st_t):
-        mask = multi_entry_mask(
-            kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
-            page_block, tk, vr, dlo, dhi, ws, we,
-            n_terms=n_terms, term_active=ta, val_hits=vh, block_group=bg,
+            page_block, tk, vr, dlo, dhi, ws, we, n_terms=n_terms,
+            term_active=ta, val_hits=vh, block_group=bg,
             entry_dur_res=entry_dur_res, widths=widths)
-        if plan is not None:
+        if sm is not None:
+            mask = mask & sm
+        if st_t is not None:
             from .structural import structural_entry_mask
 
-            # span_cols close over (query-invariant — vmap broadcasts);
-            # only the parameter tables map along the query axis
+            # span_cols and entry_agg close over: staged with the batch,
+            # the same for every query
             mask = mask & structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
                 entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
         count = jnp.sum(mask, dtype=jnp.int32)
+        # traced HERE though it reads no query table: between count and
+        # top-k the TPU compiler fuses this reduce with the mask's first
+        # `and`; traced ahead of the queries it is one more pass over
+        # [P, E] in the solo program (PERF.md section 6, PR 28)
+        inspected = jnp.sum(entry_valid & (page_block >= 0)[:, None],
+                            dtype=jnp.int32)
         scores, idx = masked_topk(mask, entry_start, top_k)
         if agg is not None:
-            # the staged composite keys are batch-global (entry_agg
-            # closes over, query-invariant like span_cols) — each
-            # query's verdict mask gates its own [K] dense counts
-            return (count, scores, idx,
+            return (count, inspected, scores, idx,
                     agg_entry_counts(mask, entry_agg, agg))
-        return count, scores, idx
+        return count, inspected, scores, idx
 
-    # val_hits/block_group/s_tables are [Q,...]-stacked like the other
-    # predicate tables (None vmaps as an empty pytree — no leaves)
-    if agg is not None:
-        counts, scores, idx, aggs = jax.vmap(one_query)(
-            term_keys, val_ranges, term_active, dur_lo, dur_hi,
-            win_start, win_end, val_hits, block_group, s_tables)
-        return counts, inspected, scores, idx, aggs
-    counts, scores, idx = jax.vmap(one_query)(
-        term_keys, val_ranges, term_active, dur_lo, dur_hi,
-        win_start, win_end, val_hits, block_group, s_tables)
-    return counts, inspected, scores, idx
+    # a None table is an empty pytree: no leaf to map
+    queries = (term_keys, val_ranges, term_active, dur_lo, dur_hi,
+               win_start, win_end, val_hits, block_group, struct_mask,
+               s_tables)
+    if term_active is None:
+        return one_query(*queries)
+    # `inspected` is traced once and comes out of the vmap unbatched
+    return jax.vmap(one_query, out_axes=(
+        0, None, 0, 0) + ((0,) if agg is not None else ()))(*queries)
+
+
+def _drop_shard_tail(entry_valid, pages_total: int, shard_tail: int):
+    """The remainder-shard layout (static `shard_tail`, part of the jit
+    key like `widths`): the trailing `shard_tail` pad pages live on the
+    last shard(s); their entries are already invalid, so this mask is
+    byte-identical — it RECORDS the ragged tail in the compiled
+    layout."""
+    from tempo_tpu.parallel.mesh import SCAN_AXIS
+
+    pp = entry_valid.shape[0]
+    gpage = (jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32) * pp
+             + jnp.arange(pp, dtype=jnp.int32))
+    return entry_valid & (gpage < jnp.int32(pages_total - shard_tail))[:, None]
+
+
+def _merge_shards(count, inspected, scores, idx, agg_counts, *,
+                  local_flat: int, top_k: int):
+    """The mesh's Results funnel (reference results.go:38-141) as
+    collectives riding ICI: counts and inspected psum, each shard's
+    top-k candidates all_gather into the global top-k, the per-shard
+    ?agg= counts psum to the global histogram (integer adds: bit-equal
+    to one device's). Every array may carry a leading query axis;
+    latest_k sorts along the last."""
+    from tempo_tpu.parallel.mesh import SCAN_AXIS
+
+    def gather(x):
+        # [..., k] -> [..., S * k]: shard after shard, each in
+        # masked_topk's order, so equal start seconds come in ascending
+        # global index and the merge keeps it
+        g = jax.lax.all_gather(x, SCAN_AXIS)
+        return jnp.moveaxis(g, 0, -2).reshape(*x.shape[:-1], -1)
+
+    shard = jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32)
+    gidx = idx + shard * local_flat
+    count = jax.lax.psum(count, SCAN_AXIS)
+    inspected = jax.lax.psum(inspected, SCAN_AXIS)
+    all_scores = gather(scores)
+    all_idx = gather(gidx)
+    top = latest_k(all_scores, all_idx, min(top_k, all_scores.shape[-1]))
+    return (count, inspected, *top,
+            *(jax.lax.psum(a, SCAN_AXIS) for a in agg_counts))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
                                     "agg"))
-def dist_coalesced_scan_kernel(mesh, kv_key, kv_val, entry_start, entry_end,
-                               entry_dur, entry_valid, page_block, term_keys,
-                               val_ranges, term_active, dur_lo, dur_hi,
-                               win_start, win_end, val_hits=None,
-                               block_group=None, entry_dur_res=None,
-                               span_cols=None, s_tables=None,
-                               entry_agg=None,
-                               *, n_terms: int, top_k: int, widths=None,
-                               plan=None, span_sharded=False,
-                               shard_tail: int = 0, agg=None):
-    """Coalesced scan sharded over the mesh's scan axis: the page axis
-    splits across devices, the [Q,...] query tables replicate, and the
-    per-shard per-query top-k candidates all_gather into a per-query
-    global top-k (engine.latest_k sorts along the last axis, so it
-    batches over the leading query axis).
+def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
+                      entry_valid, page_block, term_keys, val_ranges,
+                      term_active, dur_lo, dur_hi, win_start, win_end,
+                      val_hits=None, block_group=None, entry_dur_res=None,
+                      span_cols=None, s_tables=None, entry_agg=None,
+                      *, mesh=None, n_terms: int, top_k: int, widths=None,
+                      plan=None, span_sharded=False, shard_tail: int = 0,
+                      agg=None):
+    """THE scan program: every block batch, on one device or a mesh,
+    for one query or a fused group. Returns (count, inspected, scores
+    [k], flat idx [k][, agg [K]]); flat idx = page * E + entry over the
+    whole stacked page axis. What it is given decides what it traces,
+    and each of the four is its own program:
 
-    Plan-shape stacking composes with both span layouts (the static
-    `span_sharded` flag, see dist_multi_scan_kernel): with replicated
-    spans the [Q, P, E] structural verdicts vmap OUTSIDE the shard_map
-    and enter page-sharded on their second axis; with segment-aligned
-    sharded spans the vmapped evaluation runs INSIDE shard_fn over the
-    local span chunk."""
+      - `term_active` None: one query, no query axis anywhere; else the
+        per-query tables are [Q, ...]-stacked and count, scores, idx and
+        agg come back with a leading [Q] (_scan_pages). The page arrays
+        are read once per term loop regardless of Q.
+      - `mesh` None: the whole page axis on the default device, no
+        shard_map; else the stacked page axis (blocks x pages — the
+        corpus 'sequence' axis, SURVEY.md §5) splits across the mesh's
+        scan axis, the query tables replicate, each shard scans its
+        slice and _merge_shards reduces — one jit call.
+
+    On a mesh the structural predicate (plan + span_cols/s_tables) has
+    two placements, selected by the STATIC `span_sharded` flag (part of
+    the jit key, like `widths`):
+
+      - replicated span columns (legacy): the mask evaluates OUTSIDE
+        the shard_map — parent pointers index the global span axis,
+        which a page shard cannot see — and its [P, E] verdicts ([Q, P,
+        E] for a fused group) enter the sharded region as one more
+        operand split on the page axis;
+      - segment-aligned sharded span columns
+        (search_structural_shard_spans): each trace's span run lives
+        whole on its page's shard in shard-local coordinates, so the
+        `child` gather and `desc` pointer-doubling evaluate INSIDE the
+        shard over the local chunk — parent joins scale with the mesh,
+        per-shard span HBM ~1/P, and only the per-trace verdict feeds
+        the collectives."""
+    scan = functools.partial(_scan_pages, n_terms=n_terms, top_k=top_k,
+                             widths=widths, plan=plan, agg=agg)
+    if mesh is None:
+        return scan(kv_key, kv_val, entry_start, entry_end, entry_dur,
+                    entry_valid, page_block, term_keys, val_ranges,
+                    term_active, dur_lo, dur_hi, win_start, win_end,
+                    val_hits, block_group, entry_dur_res, None, span_cols,
+                    s_tables, entry_agg)
+
     from jax.sharding import PartitionSpec as P
-    from tempo_tpu.parallel.mesh import SCAN_AXIS
+    from tempo_tpu.parallel.mesh import SCAN_AXIS, shard_map_compat
 
-    n_shards = mesh.devices.size
-    E = entry_valid.shape[1]
-    local_flat = kv_key.shape[0] // n_shards * E
-
-    struct_masks = None
-    sh_span_cols = sh_s_tables = None
+    fused = term_active is not None
+    struct_mask = None
     if plan is not None and not span_sharded:
         from .structural import structural_entry_mask
 
-        struct_masks = jax.vmap(
-            lambda st_t: structural_entry_mask(
+        def verdicts(st_t):
+            return structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, span_cols, st_t, plan=plan,
-                widths=widths))(s_tables)                # [Q, P, E]
-    elif plan is not None:
-        sh_span_cols, sh_s_tables = span_cols, s_tables
+                entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
 
+        struct_mask = (jax.vmap(verdicts) if fused else verdicts)(s_tables)
+        span_cols = s_tables = None
     pages_total = int(kv_key.shape[0])
+    local_flat = pages_total // mesh.devices.size * entry_valid.shape[1]
 
     def shard_fn(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                 entry_valid, page_block, term_keys, val_ranges,
-                 term_active, dur_lo, dur_hi, win_start, win_end,
-                 val_hits, block_group, entry_dur_res, struct_masks,
-                 sh_span_cols, sh_s_tables, entry_agg):
+                 entry_valid, page_block, *rest):
         if shard_tail:
-            # remainder-shard ragged tail (see dist_multi_scan_kernel)
-            pp = page_block.shape[0]
-            gpage = (jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32)
-                     * pp + jnp.arange(pp, dtype=jnp.int32))
-            entry_valid = entry_valid & (
-                gpage < jnp.int32(pages_total - shard_tail))[:, None]
-        local_inspected = jnp.sum(
-            entry_valid & (page_block >= 0)[:, None], dtype=jnp.int32)
-
-        def one_query(tk, vr, ta, dlo, dhi, ws, we, vh, bg, sm, st_t):
-            mask = multi_entry_mask(
-                kv_key, kv_val, entry_start, entry_end, entry_dur,
-                entry_valid, page_block, tk, vr, dlo, dhi, ws, we,
-                n_terms=n_terms, term_active=ta, val_hits=vh,
-                block_group=bg, entry_dur_res=entry_dur_res,
-                widths=widths)
-            if sm is not None:
-                mask = mask & sm
-            if plan is not None and span_sharded:
-                from .structural import structural_entry_mask
-
-                mask = mask & structural_entry_mask(
-                    kv_key, kv_val, entry_dur, entry_valid, page_block,
-                    entry_dur_res, sh_span_cols, st_t, plan=plan,
-                    widths=widths)
-            count = jnp.sum(mask, dtype=jnp.int32)
-            scores, idx = masked_topk(mask, entry_start, top_k)
-            if agg is not None:
-                return (count, scores, idx,
-                        agg_entry_counts(mask, entry_agg, agg))
-            return count, scores, idx
-
-        if agg is not None:
-            counts, scores, idx, agg_local = jax.vmap(one_query)(
-                term_keys, val_ranges, term_active, dur_lo, dur_hi,
-                win_start, win_end, val_hits, block_group, struct_masks,
-                sh_s_tables)
-            agg_counts = jax.lax.psum(agg_local, SCAN_AXIS)  # [Q, K]
-        else:
-            counts, scores, idx = jax.vmap(one_query)(
-                term_keys, val_ranges, term_active, dur_lo, dur_hi,
-                win_start, win_end, val_hits, block_group, struct_masks,
-                sh_s_tables)
-        shard = jax.lax.axis_index(SCAN_AXIS).astype(jnp.int32)
-        gidx = idx + shard * local_flat
-        counts = jax.lax.psum(counts, SCAN_AXIS)
-        inspected = jax.lax.psum(local_inspected, SCAN_AXIS)
-        all_scores = jax.lax.all_gather(scores, SCAN_AXIS)   # [S, Q, k]
-        all_idx = jax.lax.all_gather(gidx, SCAN_AXIS)
-        Qn = all_scores.shape[1]
-        flat_scores = jnp.swapaxes(all_scores, 0, 1).reshape(Qn, -1)
-        flat_idx = jnp.swapaxes(all_idx, 0, 1).reshape(Qn, -1)
-        top_scores, top_idx = latest_k(                      # batched [Q,k]
-            flat_scores, flat_idx, min(top_k, flat_scores.shape[-1]))
-        if agg is not None:
-            return counts, inspected, top_scores, top_idx, agg_counts
-        return counts, inspected, top_scores, top_idx
-
-    from tempo_tpu.parallel.mesh import shard_map_compat
+            entry_valid = _drop_shard_tail(entry_valid, pages_total,
+                                           shard_tail)
+        count, inspected, scores, idx, *agg_counts = scan(
+            kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
+            page_block, *rest)
+        return _merge_shards(count, inspected, scores, idx, agg_counts,
+                             local_flat=local_flat, top_k=top_k)
 
     return shard_map_compat(
         shard_fn, mesh=mesh,
-        # stacked structural verdicts [Q, P, E] shard on the PAGE axis
-        # (second); sharded span columns on their leading axis; the
-        # stacked parameter tables replicate like the query tables; the
-        # staged ?agg= composite keys shard with their pages
+        # seven page arrays split on the page axis; nine query tables
+        # replicated (a None leaf makes its spec a no-op); then
+        # entry_dur_res, struct_mask (its page axis second behind a
+        # query axis), the sharded span columns and, last, entry_agg
+        # split with their pages, s_tables between them replicated
         in_specs=(P(SCAN_AXIS),) * 7 + (P(),) * 9
-        + (P(SCAN_AXIS), P(None, SCAN_AXIS), P(SCAN_AXIS), P(),
-           P(SCAN_AXIS)),
+        + (P(SCAN_AXIS), P(None, SCAN_AXIS) if fused else P(SCAN_AXIS),
+           P(SCAN_AXIS), P(), P(SCAN_AXIS)),
         out_specs=(P(), P(), P(), P())
         + ((P(),) if agg is not None else ()),
-        # same stance as dist_multi_scan_kernel: the gather+top_k output
-        # is replicated but the replication checker can't infer it
+        # all_gather + latest_k yields identical values on every shard,
+        # but the replication checker can't infer it through the gather
         check=False,
     )(kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
       page_block, term_keys, val_ranges, term_active, dur_lo, dur_hi,
       win_start, win_end, val_hits, block_group, entry_dur_res,
-      struct_masks, sh_span_cols, sh_s_tables, entry_agg)
+      struct_mask, span_cols, s_tables, entry_agg)
 
 
 class MultiBlockEngine:
@@ -1321,88 +1164,144 @@ class MultiBlockEngine:
         return self.place(self.stage_host(blocks))
 
     def scan_async(self, batch: BlockBatch, mq: MultiQuery):
-        """Dispatch without device→host sync; returns device arrays.
+        """Dispatch one query without device→host sync; returns device
+        arrays (count, inspected, scores [k], idx [k][, agg])."""
+        def place():
+            # uploaded once per MultiQuery, to where this engine's
+            # launches read them, and resident from then on
+            resident = getattr(mq, "_device_params", None)
+            params = query_device_params(mq, self.mesh)
+            tk, vr, *bounds = params
+            vh, bg = mq.val_hits, None
+            if vh is not None:
+                vh, bg = self._place_params((vh, mq.block_group))
+            return (tk, vr, None, *bounds, vh, bg), params is resident
 
-        Watchdog-bounded (robustness.GUARD): a hung or erroring dispatch
-        surfaces as DeviceFault (breaker fault booked) instead of
-        wedging the submitter; the batcher's drain answers through the
+        return self._launch(
+            "batched", batch, mq, place,
+            top_k=resolve_top_k(self.top_k, mq.limit),
+            tables_key=(mq.val_ranges.shape,),
+            kernel="multi", blocks=len(batch.blocks))
+
+    def scan(self, batch: BlockBatch, mq: MultiQuery):
+        return fetch_scan_out(self.scan_async(batch, mq))
+
+    def coalesced_scan_async(self, batch: BlockBatch, cq: CoalescedQuery,
+                             top_k: int):
+        """Fused multi-query dispatch without device→host sync; returns
+        device arrays (counts [Q], inspected, scores [Q,k], idx [Q,k][,
+        agg [Q,K]]). `top_k` is the GROUP k — max over the coalesced
+        requests' resolved k, so every member's limit is covered. Any
+        member requesting ?agg= turns the stage on for the dispatch;
+        non-requesters ignore their row of the [Q, K] output."""
+        def place():
+            # the stacked tables of THIS fused launch, uploaded in one
+            # put to where the launch reads them
+            vh = cq.val_hits
+            return self._place_params((
+                cq.term_keys, cq.val_ranges, cq.term_active, cq.dur_lo,
+                cq.dur_hi, cq.win_start, cq.win_end, vh,
+                None if vh is None else cq.block_group)), False
+
+        st = cq.structural
+        return self._launch(
+            "coalesced", batch, cq, place, top_k=top_k,
+            tables_key=(cq.term_keys.shape, cq.val_ranges.shape),
+            h2d=cq.term_keys.nbytes + cq.val_ranges.nbytes
+            + cq.term_active.nbytes + 16 * len(cq.dur_lo)
+            + (0 if st is None else sum(
+                int(getattr(t, "nbytes", 0)) for t in st.tables
+                if t is not None)),
+            kernel="coalesced", queries=cq.n_queries)
+
+    def _launch(self, mode: str, batch: BlockBatch, q, place, *, top_k: int,
+                tables_key: tuple, h2d: int = 0, **attrs):
+        """THE launch of batch_scan_kernel on the device(s): `q` is a
+        MultiQuery or a CoalescedQuery, `place()` puts its nine
+        per-query tables where the launch reads them and says whether
+        they were resident already. `mode` names the launch off a mesh;
+        on one every launch is a `mesh` launch.
+
+        Watchdog-bounded (robustness.GUARD): a hung or erroring
+        dispatch surfaces as DeviceFault (breaker fault booked) instead
+        of wedging the submitter — to every member's future of a fused
+        launch — and the batcher's drain answers through the
         byte-identical host path. Guard inactive = direct call."""
         from tempo_tpu.robustness import GUARD
 
-        return GUARD.run("mesh" if self.mesh is not None else "batched",
-                         lambda: self._scan_async_impl(batch, mq))
+        if self.mesh is not None:
+            mode = "mesh"
 
-    def _scan_async_impl(self, batch: BlockBatch, mq: MultiQuery):
-        from .engine import resolve_top_k
+        def run():
+            with profile.dispatch(mode) as rec:
+                d = batch.device
+                with rec.stage("build"):
+                    tables, resident = place()
+                    vh = tables[7]
+                    # structural plan (search/structural.py): static
+                    # plan in the jit key, dynamic tables uploaded once
+                    # per query (one shared plan and [Q, ...]-stacked
+                    # tables for a fused group)
+                    st = q.structural
+                    plan = None if st is None else st.plan
+                    s_tables = None if st is None else st.device_tables(
+                        self.mesh)
+                    span_cols = (batch.span_device if st is not None
+                                 else None)
+                    # ?agg= reduction (search/analytics.py): the staged
+                    # per-entry composite keys ride the dispatch; the
+                    # dense key-space size is the static plan-stage
+                    # descriptor
+                    agg_stage = q.agg_stage
+                    agg = None if agg_stage is None else agg_stage.n_keys
+                    entry_agg = (None if agg_stage is None else
+                                 agg_stage.device(self._page_sharding))
+                self._book_params(rec, resident)
+                rec.add_bytes(h2d=h2d)
+                widths = batch.widths
+                span_sharded = bool(st is not None and batch.span_sharded)
+                shard_tail = self._shard_tail(batch, d)
+                miss = rec.compile_check(
+                    (attrs["kernel"], self.mesh is not None,
+                     d["kv_key"].shape, str(d["kv_key"].dtype),
+                     str(d["kv_val"].dtype), *tables_key,
+                     None if vh is None else (tuple(vh.shape),
+                                              str(vh.dtype)),
+                     widths, q.n_terms, top_k,
+                     None if st is None else st.shape_sig(), span_sharded,
+                     shard_tail, agg,
+                     None if span_cols is None else
+                     tuple(sorted((n, tuple(a.shape))
+                                  for n, a in span_cols.items()))))
+                stage = "compile" if miss else "execute"
+                rec.set(**attrs, scan_bytes=batch.device_nbytes,
+                        shards=self.n_shards,
+                        pages_per_shard=self.pages_per_shard(batch))
+                book_topk(rec, d["entry_valid"].size // self.n_shards,
+                          top_k)
 
-        with profile.dispatch(
-                "mesh" if self.mesh is not None else "batched") as rec:
-            k = resolve_top_k(self.top_k, mq.limit)
-            d = batch.device
-            with rec.stage("build"):
-                # params uploaded once per MultiQuery (duck-typed:
-                # MultiQuery has the same param attributes CompiledQuery
-                # has), to where this engine's launches read them
-                from .engine import ScanEngine
+                def call():
+                    return batch_scan_kernel(
+                        d["kv_key"], d["kv_val"], d["entry_start"],
+                        d["entry_end"], d["entry_dur"], d["entry_valid"],
+                        d["page_block"], *tables, d.get("entry_dur_res"),
+                        span_cols, s_tables, entry_agg, mesh=self.mesh,
+                        n_terms=q.n_terms, top_k=top_k, widths=widths,
+                        plan=plan, span_sharded=span_sharded,
+                        shard_tail=shard_tail, agg=agg)
 
-                resident = getattr(mq, "_device_params", None)
-                params = ScanEngine.query_device_params(mq, self.mesh)
-                tk, vr, dlo, dhi, ws, we = params
-                vh = getattr(mq, "val_hits", None)
-                bg = None
-                if vh is not None:
-                    vh, bg = self._place_params((vh, mq.block_group))
-                # structural plan (search/structural.py): static plan in
-                # the jit key, dynamic tables uploaded once per query
-                st = getattr(mq, "structural", None)
-                plan = None if st is None else st.plan
-                s_tables = None if st is None else st.device_tables(
-                    self.mesh)
-                span_cols = (batch.span_device if st is not None
-                             else None)
-                # ?agg= reduction (search/analytics.py): the staged
-                # per-entry composite keys ride the dispatch; the dense
-                # key-space size is the static plan-stage descriptor
-                agg_stage = getattr(mq, "agg_stage", None)
-                agg = None if agg_stage is None else agg_stage.n_keys
-                entry_agg = (None if agg_stage is None
-                             else agg_stage.device(self._page_sharding))
-            self._book_params(rec, params is resident)
-            widths = batch.widths
-            args = (d["kv_key"], d["kv_val"], d["entry_start"],
-                    d["entry_end"], d["entry_dur"], d["entry_valid"],
-                    d["page_block"], tk, vr, dlo, dhi, ws, we, vh, bg,
-                    d.get("entry_dur_res"), span_cols, s_tables,
-                    entry_agg)
-            span_sharded = bool(st is not None and batch.span_sharded)
-            shard_tail = self._shard_tail(batch, d)
-            miss = rec.compile_check(
-                ("multi", self.mesh is not None, d["kv_key"].shape,
-                 str(d["kv_key"].dtype), str(d["kv_val"].dtype), vr.shape,
-                 None if vh is None else (tuple(vh.shape), str(vh.dtype)),
-                 widths, mq.n_terms, k,
-                 None if st is None else st.shape_sig(), span_sharded,
-                 shard_tail, agg,
-                 None if span_cols is None else
-                 tuple(sorted((n, tuple(a.shape))
-                              for n, a in span_cols.items()))))
-            stage = "compile" if miss else "execute"
-            rec.set(kernel="multi", blocks=len(batch.blocks),
-                    scan_bytes=batch.device_nbytes, shards=self.n_shards,
-                    pages_per_shard=self.pages_per_shard(batch))
-            book_topk(rec, d["entry_valid"].size // self.n_shards, k)
-            if self.mesh is not None:
+                if self.mesh is None:
+                    with rec.stage(stage):
+                        out = call()
+                        rec.fence(out)
+                    return out
                 from tempo_tpu.parallel import mesh as mesh_mod
 
                 # see __init__: collective ordering; time queued behind
                 # other dispatches lands in the lock_wait stage
                 with mesh_mod.locked_collective(rec):
                     with rec.stage(stage):
-                        out = dist_multi_scan_kernel(
-                            self.mesh, *args, n_terms=mq.n_terms, top_k=k,
-                            widths=widths, plan=plan,
-                            span_sharded=span_sharded,
-                            shard_tail=shard_tail, agg=agg)
+                        out = call()
                 # fence AFTER releasing the collective lock: a fenced
                 # wait under dispatch_lock would serialize every other
                 # mesh dispatch behind this kernel's completion (the
@@ -1412,118 +1311,14 @@ class MultiBlockEngine:
                 with rec.stage(stage):
                     rec.fence(out)
                 return out
-            with rec.stage(stage):
-                out = multi_scan_kernel(*args, n_terms=mq.n_terms, top_k=k,
-                                        widths=widths, plan=plan, agg=agg)
-                rec.fence(out)
-            return out
 
-    def scan(self, batch: BlockBatch, mq: MultiQuery):
-        from .engine import fetch_scan_out
+        return GUARD.run(mode, run)
 
-        return fetch_scan_out(self.scan_async(batch, mq))
-
-    def coalesced_scan_async(self, batch: BlockBatch, cq: CoalescedQuery,
-                             top_k: int):
-        """Fused multi-query dispatch without device→host sync; returns
-        device arrays (counts [Q], inspected, scores [Q,k], idx [Q,k]).
-        `top_k` is the GROUP k — max over the coalesced requests'
-        resolved k, so every member's limit is covered.
-
-        Watchdog-bounded like scan_async: a fused dispatch that faults
-        delivers DeviceFault to every member's future, and each member's
-        drain resubmits its own query on the host path."""
-        from tempo_tpu.robustness import GUARD
-
-        return GUARD.run(
-            "mesh" if self.mesh is not None else "coalesced",
-            lambda: self._coalesced_scan_async_impl(batch, cq, top_k))
-
-    def _coalesced_scan_async_impl(self, batch: BlockBatch,
-                                   cq: CoalescedQuery, top_k: int):
-        with profile.dispatch(
-                "mesh" if self.mesh is not None else "coalesced") as rec:
-            d = batch.device
-            with rec.stage("build"):
-                vh = getattr(cq, "val_hits", None)
-                bg = None if vh is None else cq.block_group
-                # the stacked tables of THIS fused launch, uploaded in
-                # one put to where the launch reads them
-                *tables, vh, bg = self._place_params((
-                    cq.term_keys, cq.val_ranges, cq.term_active,
-                    cq.dur_lo, cq.dur_hi, cq.win_start, cq.win_end,
-                    vh, bg))
-                # plan-shape stacking (structural.StackedStructural):
-                # one shared static plan, [Q,...]-stacked parameter
-                # tables uploaded once per fused dispatch
-                st = getattr(cq, "structural", None)
-                plan = None if st is None else st.plan
-                s_tables = None if st is None else st.device_tables(
-                    self.mesh)
-                span_cols = batch.span_device if st is not None else None
-                # ?agg= stage: batch-global staged keys shared across
-                # the fused query axis (any member requesting agg turns
-                # it on for the dispatch; non-requesters ignore their
-                # row of the [Q, K] output)
-                agg_stage = getattr(cq, "agg_stage", None)
-                agg = None if agg_stage is None else agg_stage.n_keys
-                entry_agg = (None if agg_stage is None
-                             else agg_stage.device(self._page_sharding))
-            self._book_params(rec, False)
-            st_bytes = 0 if st is None else sum(
-                int(getattr(t, "nbytes", 0)) for t in st.tables
-                if t is not None)
-            rec.add_bytes(h2d=cq.term_keys.nbytes + cq.val_ranges.nbytes
-                          + cq.term_active.nbytes + 16 * len(cq.dur_lo)
-                          + st_bytes)
-            widths = batch.widths
-            span_sharded = bool(st is not None and batch.span_sharded)
-            shard_tail = self._shard_tail(batch, d)
-            args = (d["kv_key"], d["kv_val"], d["entry_start"],
-                    d["entry_end"], d["entry_dur"], d["entry_valid"],
-                    d["page_block"], *tables, vh, bg,
-                    d.get("entry_dur_res"), span_cols, s_tables,
-                    entry_agg)
-            miss = rec.compile_check(
-                ("coalesced", self.mesh is not None, d["kv_key"].shape,
-                 str(d["kv_key"].dtype), str(d["kv_val"].dtype),
-                 cq.term_keys.shape, cq.val_ranges.shape,
-                 None if vh is None else (tuple(vh.shape), str(vh.dtype)),
-                 widths, cq.n_terms, top_k,
-                 None if st is None else st.shape_sig(), span_sharded,
-                 shard_tail, agg,
-                 None if span_cols is None else
-                 tuple(sorted((n, tuple(a.shape))
-                              for n, a in span_cols.items()))))
-            stage = "compile" if miss else "execute"
-            rec.set(kernel="coalesced", queries=cq.n_queries,
-                    scan_bytes=batch.device_nbytes, shards=self.n_shards,
-                    pages_per_shard=self.pages_per_shard(batch))
-            book_topk(rec, d["entry_valid"].size // self.n_shards, top_k)
-            if self.mesh is not None:
-                from tempo_tpu.parallel import mesh as mesh_mod
-
-                with mesh_mod.locked_collective(rec):
-                    with rec.stage(stage):
-                        out = dist_coalesced_scan_kernel(
-                            self.mesh, *args, n_terms=cq.n_terms,
-                            top_k=top_k, widths=widths, plan=plan,
-                            span_sharded=span_sharded,
-                            shard_tail=shard_tail, agg=agg)
-                # fence outside the collective lock (see
-                # _scan_async_impl — same lock-order stance)
-                with rec.stage(stage):
-                    rec.fence(out)
-                return out
-            with rec.stage(stage):
-                out = coalesced_scan_kernel(*args, n_terms=cq.n_terms,
-                                            top_k=top_k, widths=widths,
-                                            plan=plan, agg=agg)
-                rec.fence(out)
-            return out
-
-    def results(self, batch: BlockBatch, mq: MultiQuery,
+    @staticmethod
+    def results(batch: BlockBatch, mq: MultiQuery,
                 scores: np.ndarray, idx: np.ndarray) -> list:
+        """Map top-k flat indices back to TraceSearchMetadata; `batch`
+        is a BlockBatch or the HostBatch of a host-route scan."""
         E = batch.blocks[0].geometry.entries_per_page
         out = []
         for s, i in zip(scores.tolist(), idx.tolist()):

@@ -153,29 +153,6 @@ def pack_duration(arr: np.ndarray, dw: str):
     return (a >> s).astype(np.uint16), (a & ((1 << s) - 1)).astype(res_dt)
 
 
-def pack_columns(arrays: dict, widths) -> dict:
-    """Pack a staged column dict (engine.DEVICE_ARRAYS layout) in place
-    of its kv/duration columns; adds "entry_dur_res" for quantized
-    durations. Used by the single-block and distributed staging paths
-    (the batched path packs per block inside stack_host)."""
-    kw, vw, dw = widths
-    out = dict(arrays)
-    kv_key, kv_val = arrays["kv_key"], arrays["kv_val"]
-    if "u4" in (kw, vw) and kv_key.shape[-1] % 2:
-        # nibble packing pairs slots: pad BOTH kv columns to an even
-        # capacity so they unpack to the same slot count
-        pad = [(0, 0)] * (kv_key.ndim - 1) + [(0, 1)]
-        kv_key = np.pad(kv_key, pad, constant_values=-1)
-        kv_val = np.pad(kv_val, pad, constant_values=-1)
-    out["kv_key"] = pack_ids_array(kv_key, kw)
-    out["kv_val"] = pack_ids_array(kv_val, vw)
-    q, res = pack_duration(arrays["entry_dur"], dw)
-    out["entry_dur"] = q
-    if res is not None:
-        out["entry_dur_res"] = res
-    return out
-
-
 def logical_nbytes(n_entries_padded: int, kv_slots: int, n_keys: int,
                    n_vals: int) -> int:
     """Bytes the UNPACKED layout would pin for this many (padded)

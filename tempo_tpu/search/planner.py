@@ -483,7 +483,7 @@ class OffloadPlanner:
         if not self.enabled or self._seeding:
             return 0
         mode = rec.get("mode")
-        if mode in ("batched", "mesh", "coalesced", "single"):
+        if mode in ("batched", "mesh", "coalesced"):
             stages = rec.get("stages_ms") or {}
             sb = int((rec.get("attrs") or {}).get("scan_bytes") or 0)
             ex = stages.get("execute")
@@ -531,7 +531,7 @@ class OffloadPlanner:
         if not self.enabled or self._seeding:
             return 0
         if stage == "h2d" and nbytes \
-                and mode in ("dict_probe", "batched", "mesh", "single"):
+                and mode in ("dict_probe", "batched", "mesh"):
             self._update("h2d", seconds, nbytes)
             return 1
         return 0
@@ -634,10 +634,8 @@ def structural_node_seconds(node_bytes: dict) -> dict:
 def stage_veto(block, fp, n_shards: int = 1) -> bool:
     """True when the enabled planner places this dictionary's prefilter
     on HOST at staging time — call sites then skip packing/staging
-    entirely. The single shared stage-site decision: used by both
-    engine.stage_block_dict and multiblock._pack_batch_dicts so the
-    cost-model inputs cannot diverge between the single-block and
-    batched paths. Always False when the planner is disabled (the
+    entirely. The stage-site decision of multiblock._pack_batch_dicts.
+    Always False when the planner is disabled (the
     static-threshold behavior) — EXCEPT while the device circuit
     breaker blocks the device: then every staging is vetoed regardless
     of planner state, so a hung device is never handed a dictionary

@@ -631,14 +631,12 @@ def attributed_dispatch(qs: QueryStats | None = None,
                         fallback_wall: bool = True):
     """Attribute every profiler dispatch record finished inside the
     body to `qs` (default: the active stats), 100% — the non-fused
-    dispatch sites (batched, mesh, single, dict-probe during query
-    compile). With profiling disabled (no records), the measured wall
+    dispatch sites (batched, mesh, dict-probe during query compile). With profiling disabled (no records), the measured wall
     time of the body is attributed as stage "execute" so device-seconds
     accounting degrades gracefully instead of to zero — unless
     `fallback_wall` is False (bodies that are mostly host work and only
     SOMETIMES dispatch, like query compilation). Nests safely: a body
-    that itself runs an attributing engine (DistributedScanEngine
-    self-attributes) bills once, never twice."""
+    that itself attributes bills once, never twice."""
     from tempo_tpu.observability import profile
 
     qs = qs if qs is not None else current()

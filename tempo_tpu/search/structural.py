@@ -156,11 +156,6 @@ class StructuralGate:
             page_off += P
         return cols
 
-    def stage_single(self, pages, pad_pages: int) -> dict | None:
-        """Single-block variant of stack_spans (engine.stage)."""
-        return self.stack_spans([pages], pages.geometry.entries_per_page,
-                                pad_pages)
-
     def stack_group_key(self, batch, st) -> tuple | None:
         """THE plan-shape stacking gate: the coalescer's pending-group
         key for a structural query, or None — one attribute read when

@@ -1,7 +1,6 @@
 """Where JAX's persistent compilation cache lives: one resolver.
 
-Every entry point that compiles (cli/main, TempoDB, bench.py,
-__graft_entry__) calls `enable_compile_cache()` before its first
+Every entry point that compiles (cli/main, TempoDB, __graft_entry__) calls `enable_compile_cache()` before its first
 compile and nothing else in the tree names a cache directory. The
 location comes from outside: `JAX_COMPILATION_CACHE_DIR` when the
 operator or harness sets it, otherwise one fixed directory in the

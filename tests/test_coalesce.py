@@ -2,7 +2,7 @@
 
 Concurrent SearchRequests whose dispatches land on the same staged
 BlockBatch within the coalescing window stack along a query axis and run
-as ONE fused coalesced_scan_kernel launch. These tests pin down the
+as ONE fused batch_scan_kernel launch. These tests pin down the
 contract:
 
   - coalesced results are byte-identical to serial execution
@@ -99,8 +99,8 @@ def _rand_req(rng):
 
 
 def test_coalesced_kernel_matches_serial_dispatches():
-    """The fused kernel's per-query outputs equal N independent
-    multi_scan_kernel dispatches exactly — counts, scores AND indices."""
+    """The fused launch's per-query outputs equal N independent solo
+    launches exactly — counts, scores AND indices."""
     blocks = _blocks(3)
     eng = MultiBlockEngine(top_k=128)
     batch = eng.stage(blocks)
@@ -337,11 +337,11 @@ def test_concurrent_coalesced_results_identical_to_serial(seed):
     assert obs.coalesced_queries.value() > q0, "no fusion happened"
 
 
-def test_coalesced_against_scan_engine_oracle():
-    """Acceptance cross-check: coalesced serving results equal the same
-    queries run serially through the single-block ScanEngine.scan."""
-    from tempo_tpu.search.engine import ScanEngine
-    from tempo_tpu.search.pipeline import compile_query
+def test_coalesced_against_one_block_batches_oracle():
+    """Acceptance cross-check: coalesced serving results over a batch of
+    N blocks equal the same queries run serially over N batches of one,
+    straight on the engine."""
+    from conftest import scan_batch
 
     rng = random.Random(7)
     blocks = _blocks(3, entries=150)
@@ -350,16 +350,8 @@ def test_coalesced_against_scan_engine_oracle():
 
     def oracle(req):
         results = SearchResults.for_request(req)
-        eng = ScanEngine()
         for pages in blocks:
-            cq = compile_query(pages.key_dict, pages.val_dict, req)
-            if cq is None:
-                continue
-            from tempo_tpu.search.engine import stage
-
-            sp = stage(pages)
-            _c, _i, scores, idx = eng.scan_staged(sp, cq)
-            for m in eng.results(sp, cq, scores, idx):
+            for m in scan_batch([pages], req).metas:
                 results.add(m)
         return results
 
@@ -382,7 +374,7 @@ def test_coalesced_against_scan_engine_oracle():
         want = sorted(
             m.SerializeToString() for m in oracle(req).response().traces)
         have = sorted(m.SerializeToString() for m in got[i].response().traces)
-        assert have == want, f"query {i} diverged from ScanEngine oracle"
+        assert have == want, f"query {i} diverged from the serial oracle"
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,7 @@ contract from ISSUE 4's acceptance criteria:
     native substr_scan over random unicode dictionaries and needles
     (empty needle, multi-byte chars, needles spanning value boundaries);
   - match results byte-identical to the host path through every
-    dispatch shape: single-block, multi-block (mixed device/host
+    dispatch shape: one-block batch, multi-block (mixed device/host
     blocks), coalesced multi-query, and mesh-sharded;
   - HBM accounting covers the staged dictionary arrays, and an
     HBM-evicted batch re-uploads its dictionaries on re-stage without
@@ -26,7 +26,6 @@ from tempo_tpu import tempopb
 from tempo_tpu.search import dict_probe, pipeline
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import SearchData
-from tempo_tpu.search.engine import ScanEngine, stage
 from tempo_tpu.search.pipeline import compile_query, substring_value_ids
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
@@ -34,6 +33,9 @@ from tempo_tpu.search.multiblock import (
     stack_blocks,
     stack_queries,
 )
+
+from conftest import scan_batch, staged_dict
+from tempo_tpu.search.batcher import BlockBatcher
 
 
 @pytest.fixture(autouse=True)
@@ -163,10 +165,11 @@ def test_probe_sharded_pack_placed_unsharded_probes_every_shard():
         assert a.tolist() == substring_value_ids(vd, needle).tolist()
 
 
-def test_backend_search_block_honors_probe_threshold():
-    """The single-block path must honor cfg's threshold like the
-    batcher: <= 0 keeps the probe on the host, a small threshold stages
-    the dictionary and yields identical results."""
+def test_one_block_search_honors_probe_threshold():
+    """One block searched alone goes through the batcher, so it honors
+    cfg's threshold as every search does: <= 0 keeps the probe on the
+    host, a small threshold stages the dictionary and yields identical
+    results."""
     from tempo_tpu.backend import BlockMeta, MockBackend
     from tempo_tpu.search.backend_search_block import (
         BackendSearchBlock,
@@ -177,15 +180,20 @@ def test_backend_search_block_honors_probe_threshold():
     meta = BlockMeta(tenant_id="t1")
     write_search_block(be, meta, _corpus(200, seed=7), PageGeometry(32, 8))
     req = _mk_req({"session.id": "session-00"}, limit=500)
+    jobs = [BackendSearchBlock(be, meta).scan_job()]
 
-    off = BackendSearchBlock(be, meta, probe_min_vals=-1)
-    assert off.staged().staged_dict is None
-    r_off = off.search(req).response().SerializeToString()
+    def staged_dicts(b):
+        (entry,) = b._cache.values()
+        return entry.batch.staged_dicts
+
+    off = BlockBatcher(device_probe_min_vals=-1)
+    r_off = off.search(jobs, req).response().SerializeToString()
+    assert not staged_dicts(off)
 
     pipeline._COMPILE_CACHE.clear()
-    on = BackendSearchBlock(be, meta, probe_min_vals=1)
-    assert on.staged().staged_dict is not None
-    assert on.search(req).response().SerializeToString() == r_off
+    on = BlockBatcher(device_probe_min_vals=1)
+    assert on.search(jobs, req).response().SerializeToString() == r_off
+    assert staged_dicts(on)
 
 
 def test_probe_rejects_oversized_needle():
@@ -226,67 +234,57 @@ def _blocks(n=3, entries=150, small_tail=True):
 
 
 # ---------------------------------------------------------------------------
-# single-block engine path
+# one-block batches
 
 
-def test_single_block_device_probe_byte_identical():
+def test_one_block_batch_device_probe_byte_identical():
     pages = ColumnarPages.build(_corpus(300, seed=1), PageGeometry(64, 8))
     req = _mk_req({"session.id": "session-00"}, limit=1000)
-    eng = ScanEngine(top_k=1024)
 
-    sp_host = stage(pages, probe_min_vals=0)
-    assert sp_host.staged_dict is None
-    cq_host = compile_query(pages.key_dict, pages.val_dict, req)
-    out_host = eng.scan_staged(sp_host, cq_host)
+    host = scan_batch([pages], req, top_k=1024, probe_min_vals=0)
+    assert not host.batch.staged_dicts
+    assert host.mq.val_hits is None
 
     pipeline._COMPILE_CACHE.clear()
-    sp_dev = stage(pages, probe_min_vals=1)
-    assert sp_dev.staged_dict is not None
-    cq_dev = compile_query(pages.key_dict, pages.val_dict, req,
-                           staged_dict=sp_dev.staged_dict)
-    assert cq_dev.val_hits is not None
-    out_dev = eng.scan_staged(sp_dev, cq_dev)
+    dev = scan_batch([pages], req, top_k=1024, probe_min_vals=1)
+    assert dev.batch.staged_dicts
+    assert dev.mq.val_hits is not None
 
-    assert out_host[0] == out_dev[0] and out_host[1] == out_dev[1]
-    r_h = [(m.trace_id, m.start_time_unix_nano) for m in
-           eng.results(sp_host, cq_host, out_host[2], out_host[3])]
-    r_d = [(m.trace_id, m.start_time_unix_nano) for m in
-           eng.results(sp_dev, cq_dev, out_dev[2], out_dev[3])]
+    assert host.out[:2] == dev.out[:2]
+    r_h = [(m.trace_id, m.start_time_unix_nano) for m in host.metas]
+    r_d = [(m.trace_id, m.start_time_unix_nano) for m in dev.metas]
     assert r_h == r_d
 
     # prune parity: a needle no dictionary value contains prunes on both
     miss = _mk_req({"session.id": "zzz-absent"})
-    assert compile_query(pages.key_dict, pages.val_dict, miss,
-                         staged_dict=sp_dev.staged_dict) is None
+    assert compile_multi([pages], miss, cache_on=dev.batch) is None
+    assert compile_multi([pages], miss) is None
 
 
 def test_oversized_needle_falls_back_to_exact_host_path():
     pages = ColumnarPages.build(_corpus(120, seed=2), PageGeometry(32, 8))
-    sp = stage(pages, probe_min_vals=1)
+    sd = staged_dict(pages)
     long_needle = "x" * (dict_probe.MAX_NEEDLE_BYTES + 1)
     req = _mk_req({"session.id": long_needle, "svc": "frontend"},
                   limit=100)
     # must not raise — the whole query drops to the host scan
     cq = compile_query(pages.key_dict, pages.val_dict, req,
-                       staged_dict=sp.staged_dict)
+                       staged_dict=sd)
     assert cq is None  # nothing contains a 65-byte needle → pruned
     req2 = _mk_req({"svc": "front" + "t" * dict_probe.MAX_NEEDLE_BYTES})
     assert compile_query(pages.key_dict, pages.val_dict, req2,
-                         staged_dict=sp.staged_dict) is None
+                         staged_dict=sd) is None
 
 
 def test_exhaustive_flag_with_device_probe():
     """Under the exhaustive debug tag a missing key / empty-match term
     must scan (and match nothing), not prune — same semantics as host."""
     pages = ColumnarPages.build(_corpus(100, seed=3), PageGeometry(32, 8))
-    sp = stage(pages, probe_min_vals=1)
     req = _mk_req({"absent.key": "x",
                    pipeline.EXHAUSTIVE_SEARCH_TAG: "1"}, limit=50)
-    cq = compile_query(pages.key_dict, pages.val_dict, req,
-                       staged_dict=sp.staged_dict)
-    assert cq is not None
-    count, inspected, _, _ = ScanEngine(top_k=64).scan_staged(sp, cq)
-    assert count == 0 and inspected == 100
+    got = scan_batch([pages], req, top_k=64, probe_min_vals=1)
+    assert got.batch.staged_dicts and got.mq is not None
+    assert got.count == 0 and got.inspected == 100
 
 
 def test_compile_cache_skips_device_probe_work():
@@ -295,15 +293,15 @@ def test_compile_cache_skips_device_probe_work():
     from unittest import mock
 
     pages = ColumnarPages.build(_corpus(150, seed=4), PageGeometry(32, 8))
-    sp = stage(pages, probe_min_vals=1)
+    sd = staged_dict(pages)
     req = _mk_req({"session.id": "session-01"}, limit=20)
     with mock.patch.object(dict_probe, "probe_value_hits",
                            wraps=dict_probe.probe_value_hits) as probe:
         cq1 = compile_query(pages.key_dict, pages.val_dict, req,
-                            cache_on=pages, staged_dict=sp.staged_dict)
+                            cache_on=pages, staged_dict=sd)
         assert cq1 is not None and probe.call_count == 1
         cq2 = compile_query(pages.key_dict, pages.val_dict, req,
-                            cache_on=pages, staged_dict=sp.staged_dict)
+                            cache_on=pages, staged_dict=sd)
         assert probe.call_count == 1  # cache hit: no second dispatch
         assert cq2.val_hits is cq1.val_hits
 
@@ -491,7 +489,7 @@ def test_evicted_batch_restages_dictionaries():
                      for blk in blocks]
     assert all(p is not None for p in packed_before)
 
-    # evict the LRU group from HBM (the bench's churn scenario) — the
+    # evict the LRU group from HBM (blocklist churn) — the
     # host tier keeps the stacked arrays AND the packed dictionaries
     with b._lock:
         victim, old_entry = b._cache.popitem(last=False)
@@ -550,7 +548,7 @@ def test_batcher_concurrent_device_probe_coalesces_identically():
 
 # ---------------------------------------------------------------------------
 # satellites: fingerprint from the encoded dictionary section, bisected
-# tag-values, bench smoke
+# tag-values
 
 
 def test_dict_fingerprint_from_encoded_section():
@@ -609,20 +607,3 @@ def test_values_for_key_bisect():
     assert list(pages.values_for_key("aa")) == []  # before first key
     assert list(pages.values_for_key("cc")) == []  # between keys
     assert list(pages.values_for_key("zz")) == []  # past the end
-
-
-def test_bench_high_cardinality_device_probe_smoke():
-    """Tier-1-safe smoke of the bench's device-probe measurement at
-    small cardinality: both timings present, matches byte-identical
-    (asserted inside bench_high_cardinality)."""
-    import sys
-
-    sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
-    import bench
-
-    rate, matches, host_ms, probe = bench.bench_high_cardinality(
-        8_192, 2_000, 2, probe_min_vals=500)
-    assert rate > 0 and matches >= 0 and host_ms >= 0
-    assert probe["device_probe_ms"] is not None
-    assert probe["device_probe_rate"] is not None
-    assert probe["device_probe_stage_ms"] is not None

@@ -380,24 +380,33 @@ def test_drain_resubmit_no_double_skip_count(tmp_path):
     assert _canon(wedged) == _canon(healthy)
 
 
-def test_single_block_path_host_fallback(tmp_path):
-    """The SearchBlock/serverless path (BackendSearchBlock.search)
-    honors the breaker and falls back byte-identically on DeviceFault."""
+def test_one_block_search_host_fallback(tmp_path):
+    """One block searched by its meta (TempoDB.search_meta, the
+    ingester's recently-completed leg) is a one-block batch through the
+    batcher: it honors the breaker and falls back byte-identically on
+    DeviceFault."""
+    from tempo_tpu.search import SearchResults
+
     db = _mkdb(tmp_path, n_blocks=1)
     m = db.blocklist.metas("t")[0]
     req = _req()
-    bsb = db._search_block_for(m)
-    base = bsb.search(req).response().SerializeToString()
+
+    def search():
+        results = SearchResults.for_request(req)
+        db.search_meta(m, req, results)
+        return results.response().SerializeToString()
+
+    base = search()
     robustness.BREAKER.reset()
     with robustness.FAULTS.armed("device_dispatch_raise", count=100):
-        got = bsb.search(req).response().SerializeToString()
+        got = search()
     assert got == base
     # breaker forced open: host route, zero dispatch attempts
     for _ in range(3):
         robustness.BREAKER.record_fault("timeout")
     assert robustness.BREAKER.state == OPEN
     before = obs.scan_dispatches.value(mode="host_fallback")
-    assert bsb.search(req).response().SerializeToString() == base
+    assert search() == base
     assert obs.scan_dispatches.value(mode="host_fallback") > before
 
 

@@ -148,8 +148,7 @@ def test_push_ack_not_recorded_when_disabled(tmp_path):
 
 def test_telemetry_off_is_byte_identical_on_the_wal(tmp_path):
     """The noop contract: identical pushes produce identical WAL bytes
-    with telemetry on vs off (the bench freshness phase asserts the
-    same over the full App; this is the tier-1 fast version)."""
+    with telemetry on vs off."""
 
     def wal_bytes(enabled: bool, sub: str) -> bytes:
         ingest_telemetry.configure(enabled=enabled)

@@ -23,7 +23,7 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 from tempo_tpu.parallel import make_mesh
 from tempo_tpu.parallel import mesh as mesh_mod
-from tempo_tpu.search.engine import ScanEngine, device_scalar
+from tempo_tpu.search.engine import device_scalar, query_device_params
 
 from test_mesh_served import _tied_blocks, ask, corpus, make_app  # noqa: F401
 
@@ -93,10 +93,11 @@ def test_a_mesh_launch_transfers_nothing_under_the_lock(kind, guarded_lock,
     assert guarded_lock and all(r is not None for r in guarded_lock)
 
     # the parent's placement: device 0, uncommitted
+    from tempo_tpu.search import multiblock
+
     monkeypatch.setattr(
-        ScanEngine, "query_device_params",
-        staticmethod(lambda cq, mesh=None, real=ScanEngine
-                     .query_device_params: real(cq, None)))
+        multiblock, "query_device_params",
+        lambda mq, mesh=None: query_device_params(mq, None))
     monkeypatch.setattr(
         MultiBlockEngine, "_place_params",
         lambda self, tables: tuple(
@@ -165,8 +166,8 @@ def test_query_device_params_live_where_the_launch_runs(placements):
     device_scalar(mq.dur_lo)           # the value is memoised off a mesh
     for n in placements:
         mesh = None if n is None else make_mesh(n)
-        params = ScanEngine.query_device_params(mq, mesh)
-        assert ScanEngine.query_device_params(mq, mesh) is params
+        params = query_device_params(mq, mesh)
+        assert query_device_params(mq, mesh) is params
         assert len(params) == 6
         for got, w in zip(params, want):
             np.testing.assert_array_equal(np.asarray(got), w)
@@ -186,7 +187,7 @@ def test_off_a_mesh_the_params_are_what_they_were():
     of the tables, `jnp.uint32` scalars from the by-value memo), dtypes
     unchanged."""
     mq = _query()
-    params = ScanEngine.query_device_params(mq)
+    params = query_device_params(mq)
     assert [str(a.dtype) for a in params] == ["int32", "int32"] + ["uint32"] * 4
     assert [a.shape for a in params[2:]] == [()] * 4
     assert params[2] is device_scalar(mq.dur_lo)

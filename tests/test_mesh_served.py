@@ -100,7 +100,7 @@ def test_served_search_on_a_mesh_equals_the_reference(corpus, shards,
     app = make_app(corpus, shards, tmp_path)
     collector = tracing.CollectExporter()
     tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
-    modes = ("single", "batched", "coalesced", "host_fallback")
+    modes = ("batched", "coalesced", "host_fallback")
     before = {m: obs.scan_dispatches.value(mode=m, shards=shards)
               for m in modes}
     elsewhere = sum(obs.scan_dispatches.value(mode=m) for m in modes) \
@@ -129,7 +129,7 @@ def test_served_search_on_a_mesh_equals_the_reference(corpus, shards,
     moved = {m: obs.scan_dispatches.value(mode=m, shards=shards) - before[m]
              for m in modes}
     assert moved["batched"] + moved["coalesced"] > 0
-    assert moved["host_fallback"] == 0 and moved["single"] == 0
+    assert moved["host_fallback"] == 0
     assert sum(obs.scan_dispatches.value(mode=m) for m in modes) \
         - sum(obs.scan_dispatches.value(mode=m, shards=shards)
               for m in modes) == elsewhere

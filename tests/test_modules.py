@@ -231,6 +231,35 @@ def test_ready_and_shutdown(tmp_path):
     assert len(res.response().traces) == 3
 
 
+def test_startup_poll_failure_is_logged_not_swallowed(tmp_path, caplog):
+    """A backend broken at boot must not be silent in single-binary
+    mode: the immediate startup poll's exception is logged (as
+    microservices.py logs it) and the loops stay alive."""
+    import logging
+
+    app = _app(tmp_path)
+
+    def broken():
+        raise RuntimeError("backend down at boot")
+
+    app.poll_tick = broken
+    try:
+        with caplog.at_level(logging.ERROR, logger="tempo_tpu.app"):
+            app.run_maintenance()
+            deadline = time.time() + 5
+            while time.time() < deadline and not any(
+                    "startup maintenance tick" in r.getMessage()
+                    for r in caplog.records):
+                time.sleep(0.01)
+        hit = [r for r in caplog.records
+               if "startup maintenance tick" in r.getMessage()]
+        assert hit and hit[0].exc_info \
+            and "backend down at boot" in str(hit[0].exc_info[1])
+    finally:
+        del app.poll_tick
+        app.shutdown()
+
+
 def test_find_during_blocklist_poll_gap(tmp_path):
     """After a block completes but BEFORE the reader polls, traces must
     stay queryable via the ingester's recently-completed window

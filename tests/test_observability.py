@@ -291,8 +291,7 @@ def test_profiler_noop_path_is_shared_and_cheap(profiler_reset):
     assert prof.snapshot()["bytes"]["h2d"] == 0
 
     # overhead micro-check: 100k full noop call-sequences in well under
-    # a second — the "true noop" contract at test granularity (bench.py
-    # phase profile_overhead holds the <2% end-to-end line)
+    # a second — the "true noop" contract at test granularity
     t0 = time.perf_counter()
     for _ in range(100_000):
         r = profile.dispatch("single")
@@ -387,29 +386,26 @@ def _modes_seen():
 
 def test_all_dispatch_modes_populate_profiler(profiler_reset):
     """Acceptance: /debug/profile and the stage histogram populated for
-    single, batched, coalesced, mesh AND dict_probe dispatches."""
+    batched (of one block and of several), coalesced, mesh AND
+    dict_probe dispatches."""
     from tempo_tpu.parallel import make_mesh
     from tempo_tpu.search import dict_probe
-    from tempo_tpu.search.engine import ScanEngine, stage
     from tempo_tpu.search.multiblock import (
         MultiBlockEngine,
         compile_multi,
         stack_queries,
     )
-    from tempo_tpu.search.pipeline import compile_query
 
     req = _mk_req({"service.name": "svc-1"}, limit=20)
     blocks = [ColumnarPages.build(_corpus(100, seed=s), PageGeometry(16, 8))
               for s in range(3)]
 
-    # single
-    eng = ScanEngine(top_k=64)
-    cq = compile_query(blocks[0].key_dict, blocks[0].val_dict, req)
-    eng.scan_staged(stage(blocks[0]), cq)
-    assert "single" in _modes_seen()
+    # one block is a one-block batch: the same mode, none of its own
+    mbe = MultiBlockEngine(top_k=64)
+    mbe.scan(mbe.stage(blocks[:1]), compile_multi(blocks[:1], req))
+    assert _modes_seen() == {"batched", "host_probe"}
 
     # batched (multi-block, one device)
-    mbe = MultiBlockEngine(top_k=64)
     batch = mbe.stage(blocks)
     mq = compile_multi(blocks, req)
     mbe.scan(batch, mq)
@@ -437,7 +433,7 @@ def test_all_dispatch_modes_populate_profiler(profiler_reset):
     assert "dict_probe" in _modes_seen()
 
     snap = profile.PROFILER.snapshot()
-    for mode in ("single", "batched", "coalesced", "mesh", "dict_probe"):
+    for mode in ("batched", "coalesced", "mesh", "dict_probe"):
         stages = snap["aggregates"][mode]
         assert stages, f"mode {mode} has no stage aggregates"
         # every profiled dispatch timed its kernel call
@@ -445,7 +441,7 @@ def test_all_dispatch_modes_populate_profiler(profiler_reset):
             "h2d" in stages
     # the histogram carries the same series
     exposed = obs.dispatch_stage_seconds.expose()
-    for mode in ("single", "batched", "coalesced", "mesh", "dict_probe"):
+    for mode in ("batched", "coalesced", "mesh", "dict_probe"):
         assert f'mode="{mode}"' in exposed
     # jit-cache events observed for the fresh shapes
     assert snap["jit_cache"]["miss"] >= 4
@@ -465,15 +461,14 @@ def test_host_probe_mode_recorded(profiler_reset):
 
 
 def test_profiler_disabled_leaves_dispatch_paths_silent(profiler_reset):
-    from tempo_tpu.search.engine import ScanEngine, stage
-    from tempo_tpu.search.pipeline import compile_query
+    from tempo_tpu.search.multiblock import MultiBlockEngine, compile_multi
 
     profile.configure(enabled=False)
     block = ColumnarPages.build(_corpus(80, seed=2), PageGeometry(16, 8))
-    eng = ScanEngine(top_k=64)
-    cq = compile_query(block.key_dict, block.val_dict,
+    eng = MultiBlockEngine(top_k=64)
+    mq = compile_multi([block],
                        _mk_req({"service.name": "svc-1"}, limit=20))
-    eng.scan_staged(stage(block), cq)
+    eng.scan(eng.stage([block]), mq)
     snap = profile.PROFILER.snapshot()
     assert snap["dispatches"] == 0
     assert not snap["aggregates"]

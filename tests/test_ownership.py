@@ -128,7 +128,7 @@ def test_configure_groups_rebuilds_table():
 
 
 def test_byte_identity_on_off_all_engine_paths(tmp_path):
-    """Ownership on vs off is byte-identical on the single-block,
+    """Ownership on vs off is byte-identical on the one-block,
     batched, and coalesced paths — whether this member owns everything,
     half, or nothing (a pure non-owner serves 100% host-routed)."""
     db = _mkdb(tmp_path, n_blocks=6, search_max_batch_pages=8,
@@ -142,15 +142,22 @@ def test_byte_identity_on_off_all_engine_paths(tmp_path):
         assert _canon(db.search("t", req).response()) == base, self_id
         OWNERSHIP.reset()
 
-    # single-block path (BackendSearchBlock.search)
+    # one block by its meta (TempoDB.search_meta: a one-block batch)
+    from tempo_tpu.search import SearchResults
+
     meta = db.blocklist.metas("t")[0]
-    bsb = db._search_block_for(meta)
     sreq = _req(limit=10_000)
-    single_base = bsb.search(sreq).response().SerializeToString()
+
+    def one_block():
+        results = SearchResults.for_request(sreq)
+        db.search_meta(meta, sreq, results)
+        return results.response().SerializeToString()
+
+    single_base = one_block()
     ownership.configure(enabled=True, members="m0,m1",
                         self_id="spectator", groups=32)
     before = obs.scan_dispatches.value(mode="host_fallback")
-    assert bsb.search(sreq).response().SerializeToString() == single_base
+    assert one_block() == single_base
     assert obs.scan_dispatches.value(mode="host_fallback") > before
 
     # coalesced: concurrent same-tenant searches under ownership fuse /

@@ -278,22 +278,19 @@ def test_parity_batched_engine():
         assert on == off, req
 
 
-def test_parity_single_block_engine():
-    from tempo_tpu.search.engine import ScanEngine, stage
-    from tempo_tpu.search.pipeline import compile_query
-
-    eng = ScanEngine(top_k=64)
+def test_parity_one_block_batches():
+    """Each block alone, a one-block batch: the widths a single block's
+    own dictionaries choose (a batch of five shares the widest)."""
+    eng = MultiBlockEngine(top_k=64)
     for b in _parity_blocks():
         for req in _parity_reqs():
-            cq = compile_query(b.key_dict, b.val_dict, req)
-            if cq is None:
-                continue
-            off = _canon(eng.scan_staged(stage(b), cq))
+            off = _run_multi(eng, [b], req)
+            pipeline._COMPILE_CACHE.clear()
             packing.configure(enabled=True)
-            sp = stage(b)
-            assert sp.widths is not None
-            on = _canon(eng.scan_staged(sp, cq))
+            assert eng.stage_host([b]).widths is not None
+            on = _run_multi(eng, [b], req)
             packing.configure(enabled=False)
+            pipeline._COMPILE_CACHE.clear()
             assert on == off, req
 
 
@@ -336,23 +333,20 @@ def test_parity_mesh_engine():
         assert on == off, req
 
 
-def test_parity_dist_engine():
-    from tempo_tpu.parallel.dist_search import DistributedScanEngine
+def test_parity_mesh_one_block_batch():
+    """One block's pages sharded over the mesh (the q-width corpus)."""
     from tempo_tpu.parallel.mesh import make_mesh
-    from tempo_tpu.search.pipeline import compile_query
 
-    eng = DistributedScanEngine(make_mesh(), top_k=32)
+    eng = MultiBlockEngine(top_k=32, mesh=make_mesh())
     b = _parity_blocks()[4]
     for req in _parity_reqs():
-        cq = compile_query(b.key_dict, b.val_dict, req)
-        if cq is None:
-            continue
-        off = _canon(eng.scan_staged(eng.stage(b), cq))
+        off = _run_multi(eng, [b], req)
+        pipeline._COMPILE_CACHE.clear()
         packing.configure(enabled=True)
-        sp = eng.stage(b)
-        assert sp.widths is not None
-        on = _canon(eng.scan_staged(sp, cq))
+        assert eng.stage_host([b]).widths is not None
+        on = _run_multi(eng, [b], req)
         packing.configure(enabled=False)
+        pipeline._COMPILE_CACHE.clear()
         assert on == off, req
 
 

@@ -3,12 +3,11 @@ import numpy as np
 import pytest
 
 from tempo_tpu import tempopb
-from tempo_tpu.parallel import DistributedScanEngine, make_mesh
+from tempo_tpu.parallel import make_mesh
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import search_data_matches
-from tempo_tpu.search.engine import ScanEngine
-from tempo_tpu.search.pipeline import compile_query
 
+from conftest import scan_batch
 from tests.test_search import _corpus, _mk_req, QUERIES
 
 
@@ -19,38 +18,33 @@ def test_mesh_has_8_devices():
 
 
 @pytest.mark.parametrize("qi", [0, 2, 4, 7])
-def test_distributed_scan_matches_single_device(qi):
+def test_one_block_batch_mesh_matches_no_mesh(qi):
+    """One block is a one-block batch: its pages sharded over the mesh
+    answer what the same batch answers on one device."""
     req = QUERIES[qi]
     req.limit = 1000
     entries = _corpus(500)
     pages = ColumnarPages.build(entries, PageGeometry(32, 8))
-    cq = compile_query(pages.key_dict, pages.val_dict, req)
-    if cq is None:
+    single = scan_batch([pages], req, top_k=1024)
+    if single.mq is None:
         pytest.skip("query prunes block")
+    dist = scan_batch([pages], req, top_k=1024, mesh=make_mesh())
 
-    single = ScanEngine(top_k=1024)
-    s_count, s_inspected, _, _ = single.scan(pages, cq)
-
-    mesh = make_mesh()
-    dist = DistributedScanEngine(mesh, top_k=1024)
-    sp = dist.stage(pages)
-    d_count, d_inspected, scores, idx = dist.scan_staged(sp, cq)
-
-    assert d_count == s_count
-    assert d_inspected == s_inspected
+    assert dist.count == single.count
+    assert dist.inspected == single.inspected
+    assert dist.canon() == single.canon()
 
     expected = {sd.trace_id for sd in entries if search_data_matches(sd, req)}
-    got = {bytes.fromhex(m.trace_id) for m in dist.results(sp, cq, scores, idx)}
-    assert got == expected
+    assert dist.trace_ids == expected
 
 
-def test_distributed_stage_shards_pages():
+def test_one_block_batch_shards_pages():
+    from tempo_tpu.search.multiblock import MultiBlockEngine
+
     entries = _corpus(300)
     pages = ColumnarPages.build(entries, PageGeometry(32, 8))
-    mesh = make_mesh()
-    dist = DistributedScanEngine(mesh)
-    sp = dist.stage(pages)
-    arr = sp.device["kv_key"]
+    batch = MultiBlockEngine(mesh=make_mesh()).stage([pages])
+    arr = batch.device["kv_key"]
     assert arr.shape[0] % 8 == 0
     # each of the 8 devices holds a distinct contiguous page shard
     assert len(arr.sharding.device_set) == 8

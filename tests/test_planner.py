@@ -5,7 +5,7 @@ The contracts pinned here, from the acceptance criteria:
   - ``search_device_probe_min_vals <= 0`` forces host-only probing even
     with the planner enabled (the static threshold stays the floor);
   - planner-on vs planner-off results are byte-identical across the
-    single-block, multi-block, coalesced, and mesh dispatch paths,
+    one-block, multi-block, coalesced, and mesh dispatch paths,
     whichever side the cost model picks (both placements are exact);
   - a cold process (empty profiler aggregates) makes a sane seeded
     decision instead of crashing or staging hundreds of MB blindly;
@@ -28,7 +28,7 @@ from tempo_tpu import tempopb
 from tempo_tpu.search import dict_probe, pipeline, planner
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import SearchData
-from tempo_tpu.search.engine import ScanEngine, stage
+from tempo_tpu.search.engine import query_device_params
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     compile_multi,
@@ -36,6 +36,8 @@ from tempo_tpu.search.multiblock import (
     stack_queries,
 )
 from tempo_tpu.search.pipeline import compile_query
+
+from conftest import scan_batch, staged_dict
 
 
 @pytest.fixture(autouse=True)
@@ -111,12 +113,8 @@ def test_threshold_off_forces_host_even_with_planner_enabled():
     _force("device")  # planner would demand device everywhere
     pages = ColumnarPages.build(_corpus(200, seed=1), PageGeometry(32, 8))
 
-    sp = stage(pages, probe_min_vals=0)
-    assert sp.staged_dict is None
-    sp = stage(pages, probe_min_vals=-1)
-    assert sp.staged_dict is None
-    batch = stack_blocks([pages], probe_min_vals=0)
-    assert not batch.staged_dicts
+    assert staged_dict(pages, probe_min_vals=0) is None
+    assert staged_dict(pages, probe_min_vals=-1) is None
     # no decision was ever burned: the floor short-circuits the planner
     snap = planner.PLANNER.snapshot()
     assert snap["decisions"] == {"host": 0, "device": 0}
@@ -145,10 +143,10 @@ def test_planner_disabled_is_static_path():
     dictionary stages and the probe runs on device, no decisions."""
     planner.configure(enabled=False)
     pages = ColumnarPages.build(_corpus(200, seed=2), PageGeometry(32, 8))
-    sp = stage(pages, probe_min_vals=1)
-    assert sp.staged_dict is not None
+    sd = staged_dict(pages)
+    assert sd is not None
     cq = compile_query(pages.key_dict, pages.val_dict, _mk_req(
-        {"session.id": "session-00"}, limit=100), staged_dict=sp.staged_dict)
+        {"session.id": "session-00"}, limit=100), staged_dict=sd)
     assert cq.val_hits is not None
     assert planner.PLANNER.snapshot()["decisions"] == {"host": 0,
                                                        "device": 0}
@@ -158,34 +156,30 @@ def test_planner_disabled_is_static_path():
 # byte-identity across dispatch paths, both verdicts
 
 
-def _single_block_result(probe_min_vals):
+def _one_block_result(probe_min_vals):
     pages = ColumnarPages.build(_corpus(300, seed=3), PageGeometry(64, 8))
     req = _mk_req({"session.id": "session-00"}, limit=1000)
-    eng = ScanEngine(top_k=1024)
-    sp = stage(pages, probe_min_vals=probe_min_vals)
-    cq = compile_query(pages.key_dict, pages.val_dict, req,
-                       staged_dict=sp.staged_dict)
-    count, inspected, scores, idx = eng.scan_staged(sp, cq)
-    res = [(m.trace_id, m.start_time_unix_nano)
-           for m in eng.results(sp, cq, scores, idx)]
-    return int(count), int(inspected), res, sp, cq
+    got = scan_batch([pages], req, top_k=1024,
+                     probe_min_vals=probe_min_vals)
+    res = [(m.trace_id, m.start_time_unix_nano) for m in got.metas]
+    return int(got.count), int(got.inspected), res, got
 
 
-def test_single_block_byte_identical_both_verdicts():
+def test_one_block_batch_byte_identical_both_verdicts():
     planner.configure(enabled=False)
-    base = _single_block_result(0)[:3]
+    base = _one_block_result(0)[:3]
 
     for verdict in ("device", "host"):
         _force(verdict)
         pipeline._COMPILE_CACHE.clear()
-        count, inspected, res, sp, cq = _single_block_result(1)
+        count, inspected, res, got = _one_block_result(1)
         if verdict == "device":
-            assert sp.staged_dict is not None
-            assert cq.val_hits is not None
+            assert got.batch.staged_dicts
+            assert got.mq.val_hits is not None
         else:
             # stage-time veto: the planner kept the dictionary on host
-            assert sp.staged_dict is None
-            assert cq.val_hits is None
+            assert not got.batch.staged_dicts
+            assert got.mq.val_hits is None
         assert (count, inspected, res) == base, verdict
 
 
@@ -196,20 +190,18 @@ def test_compile_time_veto_over_staged_dict():
     planner.configure(enabled=False)
     pages = ColumnarPages.build(_corpus(250, seed=4), PageGeometry(32, 8))
     req = _mk_req({"session.id": "session-01"}, limit=500)
-    sp = stage(pages, probe_min_vals=1)  # staged while planner off
-    assert sp.staged_dict is not None
-    eng = ScanEngine(top_k=1024)
-    cq_dev = compile_query(pages.key_dict, pages.val_dict, req,
-                           staged_dict=sp.staged_dict)
-    assert cq_dev.val_hits is not None
-    out_dev = eng.scan_staged(sp, cq_dev)
+    # staged while planner off
+    dev = scan_batch([pages], req, top_k=1024, probe_min_vals=1)
+    assert dev.batch.staged_dicts
+    assert dev.mq.val_hits is not None
+    out_dev = dev.out
 
     _force("host")
     pipeline._COMPILE_CACHE.clear()
-    cq_host = compile_query(pages.key_dict, pages.val_dict, req,
-                            staged_dict=sp.staged_dict)
-    assert cq_host.val_hits is None  # vetoed at compile time
-    out_host = eng.scan_staged(sp, cq_host)
+    host = scan_batch([pages], req, engine=dev.engine, batch=dev.batch)
+    assert host.batch.staged_dicts   # the staged bytes stay
+    assert host.mq.val_hits is None  # vetoed at compile time
+    out_host = host.out
     assert out_dev[0] == out_host[0] and out_dev[1] == out_host[1]
     assert np.array_equal(out_dev[2], out_host[2])
     # the compile-site decision landed in the ring with its inputs
@@ -289,34 +281,31 @@ def test_mesh_byte_identical_both_verdicts():
         assert ids == ids_base, verdict
 
 
-def test_dist_search_staged_dict_and_identity():
-    """DistributedScanEngine's single-block mesh path stages the
+def test_one_block_batch_mesh_staged_dict_and_identity():
+    """One block as a one-block batch over the mesh stages the
     dictionary value-axis-sharded and yields host-identical results;
-    the default threshold (0) keeps its historical host-only behavior."""
-    from tempo_tpu.parallel.dist_search import DistributedScanEngine
+    a threshold of 0 keeps the probe on the host."""
     from tempo_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh()
     pages = ColumnarPages.build(_corpus(256, seed=5), PageGeometry(32, 8))
     req = _mk_req({"session.id": "session-00"}, limit=1000)
 
-    assert DistributedScanEngine(mesh).stage(pages).staged_dict is None
+    assert not MultiBlockEngine(
+        mesh=mesh, device_probe_min_vals=0).stage([pages]).staged_dicts
 
     planner.configure(enabled=False)
-    dist = DistributedScanEngine(mesh, top_k=1024, probe_min_vals=1)
-    sp = dist.stage(pages)
-    assert sp.staged_dict is not None
-    assert sp.staged_dict.mesh is mesh
-    cq = compile_query(pages.key_dict, pages.val_dict, req,
-                       staged_dict=sp.staged_dict)
-    assert cq.val_hits is not None
-    out = dist.scan_staged(sp, cq)
+    dist = scan_batch([pages], req, top_k=1024, mesh=mesh,
+                      probe_min_vals=1)
+    (sd,) = dist.batch.staged_dicts.values()
+    assert sd.mesh is mesh
+    assert dist.mq.val_hits is not None
+    out = dist.out
 
     pipeline._COMPILE_CACHE.clear()
-    eng = ScanEngine(top_k=1024)
-    sp_h = stage(pages, probe_min_vals=0)
-    cq_h = compile_query(pages.key_dict, pages.val_dict, req)
-    out_h = eng.scan_staged(sp_h, cq_h)
+    host = scan_batch([pages], req, top_k=1024, probe_min_vals=0)
+    assert host.mq.val_hits is None
+    out_h = host.out
     assert out[0] == out_h[0] and out[1] == out_h[1]
     assert np.array_equal(np.sort(out[2]), np.sort(out_h[2]))
 
@@ -532,10 +521,9 @@ def test_profiler_listener_feeds_device_rate():
     before = p.snapshot()["cost_model"]["rates"]["device_probe"][
         "observations"]
     pages = ColumnarPages.build(_corpus(150, seed=6), PageGeometry(32, 8))
-    sp = stage(pages, probe_min_vals=1)
     cq = compile_query(pages.key_dict, pages.val_dict,
                        _mk_req({"session.id": "session-01"}, limit=20),
-                       cache_on=pages, staged_dict=sp.staged_dict)
+                       cache_on=pages, staged_dict=staged_dict(pages))
     assert cq is not None and cq.val_hits is not None
     after = p.snapshot()["cost_model"]["rates"]["device_probe"][
         "observations"]
@@ -630,18 +618,18 @@ def test_device_scalar_params_shared_across_queries():
     from tempo_tpu.search.engine import device_scalar
 
     pages = ColumnarPages.build(_corpus(50, seed=7), PageGeometry(32, 8))
-    cq1 = compile_query(pages.key_dict, pages.val_dict,
+    mq1 = compile_multi([pages],
                         _mk_req({"session.id": "session-00"}, limit=20))
-    cq2 = compile_query(pages.key_dict, pages.val_dict,
-                        _mk_req({"svc": "frontend"}, limit=20))
-    p1 = ScanEngine.query_device_params(cq1)
-    p2 = ScanEngine.query_device_params(cq2)
+    mq2 = compile_multi([pages], _mk_req({"svc": "frontend"}, limit=20))
+    p1 = query_device_params(mq1)
+    p2 = query_device_params(mq2)
     for i in (2, 3, 4, 5):  # dur_lo, dur_hi, win_start, win_end
         assert p1[i] is p2[i]
     assert device_scalar(12345) is device_scalar(12345)
     # cached params still yield correct scans
-    eng = ScanEngine(top_k=64)
-    sp = stage(pages, probe_min_vals=0)
-    c1 = eng.scan_staged(sp, cq1)[0]
-    c2 = eng.scan_staged(sp, cq2)[0]
+    eng = MultiBlockEngine(top_k=64)
+    batch = eng.stage([pages])
+    c1 = eng.scan(batch, mq1)[0]
+    c2 = eng.scan(batch, mq2)[0]
+    assert query_device_params(mq1) is p1
     assert c1 >= 0 and c2 >= 0

@@ -2,7 +2,7 @@
 
 Contract under test:
 
-  - SearchMetrics population on EVERY scan path: single-block, batched
+  - SearchMetrics population on EVERY scan path: one-block batch, batched
     multi-block, coalesced (8-way concurrency), mesh-sharded — all
     report non-zero inspected counts; skipped_blocks carries time-range
     / duration / dictionary prunes with per-reason stats
@@ -98,7 +98,7 @@ def test_batched_path_populates_metrics_and_stats():
     assert "hbm_miss_cold" in d["cache"] or "hbm_hit" in d["cache"]
 
 
-def test_single_block_path_populates_metrics():
+def test_one_block_search_populates_metrics():
     from tempo_tpu.backend import MockBackend
     from tempo_tpu.backend.types import BlockMeta
     from tempo_tpu.search.backend_search_block import (
@@ -108,11 +108,12 @@ def test_single_block_path_populates_metrics():
     be = MockBackend()
     meta = BlockMeta(tenant_id="t1")
     write_search_block(be, meta, _corpus(64, seed=1), encoding="zlib")
-    bsb = BackendSearchBlock(be, meta)
+    batcher = BlockBatcher()
+    jobs = [BackendSearchBlock(be, meta).scan_job()]
     req = _mk_req({"service.name": "svc-1"}, limit=100)
     qs = query_stats.begin("t1", req)
     with query_stats.activate(qs):
-        results = bsb.search(req)
+        results = batcher.search(jobs, req)
     d = qs.finish()
     m = results.metrics
     assert m.inspected_blocks == 1 and m.inspected_traces > 0
@@ -123,7 +124,8 @@ def test_single_block_path_populates_metrics():
     # dictionary prune: a tag value no dictionary contains
     qs2 = query_stats.begin("t1", req)
     with query_stats.activate(qs2):
-        r2 = bsb.search(_mk_req({"service.name": "nope-xyz"}, limit=10))
+        r2 = batcher.search(
+            jobs, _mk_req({"service.name": "nope-xyz"}, limit=10))
     d2 = qs2.finish()
     assert r2.metrics.skipped_blocks == 1
     assert d2["skipped_blocks"] == {"dict": 1}
@@ -168,19 +170,23 @@ def test_mesh_path_populates_metrics():
     assert d["device_stages_ms"]
 
 
-def test_dist_engine_attributes_to_active_stats():
-    from tempo_tpu.parallel import DistributedScanEngine, make_mesh
-    from tempo_tpu.search.pipeline import compile_query
+def test_mesh_launch_attributes_to_active_stats():
+    """A mesh launch straight on the engine, inside an attributing
+    body (as the batcher's dispatch sites are), bills the active
+    stats."""
+    from tempo_tpu.parallel import make_mesh
+    from tempo_tpu.search.multiblock import MultiBlockEngine, compile_multi
     from tests.test_coalesce import _corpus
     from tempo_tpu.search import ColumnarPages, PageGeometry
 
     pages = ColumnarPages.build(_corpus(128, seed=3), PageGeometry(32, 8))
-    eng = DistributedScanEngine(make_mesh(), top_k=64)
-    cq = compile_query(pages.key_dict, pages.val_dict,
+    eng = MultiBlockEngine(top_k=64, mesh=make_mesh())
+    batch = eng.stage([pages])
+    mq = compile_multi([pages],
                        _mk_req({"service.name": "svc-1"}, limit=20))
     qs = query_stats.begin("t1", None)
-    with query_stats.activate(qs):
-        count, inspected, _s, _i = eng.scan(pages, cq)
+    with query_stats.activate(qs), query_stats.attributed_dispatch(qs):
+        count, inspected, _s, _i = eng.scan(batch, mq)
     assert inspected > 0
     assert qs.device_seconds > 0
     assert qs.dispatches >= 1
@@ -648,8 +654,7 @@ def test_request_scope_does_not_book_tenant_counters():
 def test_nested_attribution_bills_once():
     """A body that itself runs an attributing engine must not be
     double-billed: the inner context attributes, the outer skips its
-    wall fallback (DistributedScanEngine self-attributes inside
-    BackendSearchBlock's attributed scan)."""
+    wall fallback."""
     qs = query_stats.QueryStats("t1")
     with query_stats.attributed_dispatch(qs):
         with query_stats.attributed_dispatch(qs):

@@ -178,42 +178,35 @@ def test_mesh_remainder_layout_with_sharded_spans():
     assert got_on == got_off
 
 
-def test_dist_engine_remainder_descriptor_byte_identical():
-    """DistributedScanEngine already stages minimally; under the gate
-    the ragged tail enters the jit key as shard_tail — results stay
-    byte-identical to the gate-off compile."""
+def test_one_block_batch_remainder_descriptor_byte_identical():
+    """One block as a one-block batch over the mesh: under the gate its
+    pages stage minimally and the ragged tail enters the jit key as
+    shard_tail — results stay byte-identical to the gate-off compile."""
     import jax
 
     if len(jax.devices()) < 2:
         pytest.skip("needs multiple (forced host) devices")
-    from tempo_tpu.parallel import DistributedScanEngine, make_mesh
-    from tempo_tpu.search.pipeline import compile_query
+    from conftest import scan_batch
+    from tempo_tpu.parallel import make_mesh
 
     entries = _corpus(15, n=130)
     pages = ColumnarPages.build(entries, G_SMALL)
-    eng = DistributedScanEngine(make_mesh(), top_k=512)
-    sp = eng.stage(pages)
+    staged_pages = {}
     for remainder in (False, True):
         STRUCTURAL.remainder_pages = remainder
         try:
+            staged = {}
             for src in _ACCEPTANCE_TRIPLE:
                 expr = ir.parse(src)
-                req = _mk_req(expr)
-                cq = compile_query(pages.key_dict, pages.val_dict, req,
-                                   cache_on=pages)
-                cq.structural = compile_structural(expr, [pages],
-                                                   cache_on=pages)
-                count, _ins, scores, idx = eng.scan_staged(sp, cq)
+                got = scan_batch([pages], _mk_req(expr), top_k=512,
+                                 mesh=make_mesh(), structural=expr,
+                                 **staged)
+                staged = {"engine": got.engine, "batch": got.batch}
                 want = _expected_ids(expr, entries)
-                E = G_SMALL.entries_per_page
-                got = set()
-                for s, i in zip(scores.tolist(), idx.tolist()):
-                    if s < 0:
-                        break
-                    p, e = divmod(i, E)
-                    if p < pages.n_pages:
-                        got.add(bytes(pages.trace_ids[p, e]))
-                assert got == want and count == len(want), \
+                assert got.trace_ids == want and got.count == len(want), \
                     (src, remainder)
+            staged_pages[remainder] = int(
+                got.batch.device["kv_key"].shape[0])
         finally:
             STRUCTURAL.remainder_pages = False
+    assert staged_pages[True] <= staged_pages[False]

@@ -19,10 +19,12 @@ from tempo_tpu.search import (
     write_search_block,
 )
 from tempo_tpu.search.data import SearchData, search_data_matches
-from tempo_tpu.search.engine import ScanEngine, stage
+
 from tempo_tpu.search.pipeline import compile_query, substring_value_ids
 from tempo_tpu.utils.ids import random_trace_id
 from tempo_tpu.utils.test_data import make_trace
+
+from conftest import scan_batch
 
 
 def _mk_req(tags=None, **kw):
@@ -161,17 +163,13 @@ def test_engine_matches_host_oracle(qi):
     pages = ColumnarPages.build(entries, PageGeometry(64, 8))
     expected = {sd.trace_id for sd in entries if search_data_matches(sd, req)}
 
-    cq = compile_query(pages.key_dict, pages.val_dict, req)
-    if cq is None:
+    got = scan_batch([pages], req, top_k=1024)
+    if got.mq is None:
         assert not expected
         return
-    eng = ScanEngine(top_k=1024)
-    count, inspected, scores, idx = eng.scan(pages, cq)
-    assert count == len(expected)
-    assert inspected == 500
-    sp = stage(pages)
-    got = {bytes.fromhex(m.trace_id) for m in eng.results(sp, cq, scores, idx)}
-    assert got == expected
+    assert got.count == len(expected)
+    assert got.inspected == 500
+    assert got.trace_ids == expected
 
 
 def test_engine_topk_ordering_and_limit():
@@ -179,11 +177,7 @@ def test_engine_topk_ordering_and_limit():
     pages = ColumnarPages.build(entries, PageGeometry(64, 8))
     req = _mk_req({"service.name": "frontend"})
     req.limit = 5
-    cq = compile_query(pages.key_dict, pages.val_dict, req)
-    eng = ScanEngine(top_k=128)
-    sp = stage(pages)
-    count, _, scores, idx = eng.scan_staged(sp, cq)
-    metas = eng.results(sp, cq, scores, idx)
+    metas = scan_batch([pages], req, top_k=128).metas
     assert len(metas) == 5
     starts = [m.start_time_unix_nano for m in metas]
     assert starts == sorted(starts, reverse=True)  # most recent first
@@ -196,10 +190,13 @@ def test_backend_search_block_end_to_end():
     hdr = write_search_block(be, meta, entries, PageGeometry(64, 8))
     assert hdr["n_entries"] == 400
 
-    bsb = BackendSearchBlock(be, meta)
+    from tempo_tpu.search.batcher import BlockBatcher
+
+    batcher = BlockBatcher()
+    jobs = [BackendSearchBlock(be, meta).scan_job()]
     req = _mk_req({"service.name": "checkout"})
     req.limit = 10
-    res = bsb.search(req)
+    res = batcher.search(jobs, req)
     resp = res.response()
     assert 0 < len(resp.traces) <= 10
     assert resp.metrics.inspected_blocks == 1
@@ -208,11 +205,12 @@ def test_backend_search_block_end_to_end():
         assert m.root_service_name == "checkout"
 
     # pruned by dictionary prefilter: absent key never touches the device
-    res2 = bsb.search(_mk_req({"absent.key": "x"}))
+    res2 = batcher.search(jobs, _mk_req({"absent.key": "x"}))
     assert res2.metrics.skipped_blocks == 1
 
     # pruned by header time range
-    res3 = bsb.search(_mk_req({}, start=1_700_000_000, end=1_700_000_100))
+    res3 = batcher.search(
+        jobs, _mk_req({}, start=1_700_000_000, end=1_700_000_100))
     assert res3.metrics.skipped_blocks == 1
 
 
@@ -265,12 +263,8 @@ def test_engine_limit_above_default_topk():
     pages = ColumnarPages.build(entries, PageGeometry(64, 8))
     req = _mk_req({"service.name": "frontend"})
     req.limit = 400
-    cq = compile_query(pages.key_dict, pages.val_dict, req)
-    eng = ScanEngine(top_k=16)  # deliberately tiny default
-    sp = stage(pages)
-    count, _, scores, idx = eng.scan_staged(sp, cq)
-    metas = eng.results(sp, cq, scores, idx)
-    assert len(metas) == count  # every match surfaced, not 16
+    got = scan_batch([pages], req, top_k=16)  # deliberately tiny default
+    assert len(got.metas) == got.count  # every match surfaced, not 16
 
 
 def test_columnar_adaptive_kv_capacity():
@@ -282,9 +276,7 @@ def test_columnar_adaptive_kv_capacity():
     assert pages.geometry.kv_per_entry == 16  # next pow2 of 11
     assert pages.header["truncated_entries"] == 0
     req = _mk_req({"k10": "v10"})
-    cq = compile_query(pages.key_dict, pages.val_dict, req)
-    count, _, _, _ = ScanEngine().scan(pages, cq)
-    assert count == 1
+    assert scan_batch([pages], req).count == 1
     # cap still enforced
     pages2 = ColumnarPages.build([wide], PageGeometry(4, 8))
     assert pages2.geometry.kv_per_entry == 8
@@ -437,7 +429,9 @@ def test_compile_cache_skips_dictionary_probe():
     from tempo_tpu.search import pipeline
     from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
     pages = ColumnarPages.build(_corpus(50), PageGeometry(16, 8))
-    req = _mk_req({"service.name": "front"})
+    # a needle of this test's own: the compile cache is process-wide,
+    # keyed by dictionary content, and other tests share this corpus
+    req = _mk_req({"service.name": "fronten"})
     req.limit = 5
 
     with mock.patch.object(pipeline, "substring_value_ids",
@@ -447,7 +441,7 @@ def test_compile_cache_skips_dictionary_probe():
         n_cold = probe.call_count
         assert n_cold >= 1
         # same tags, different scalars -> cache hit, fresh scalars
-        req2 = _mk_req({"service.name": "front"})
+        req2 = _mk_req({"service.name": "fronten"})
         req2.limit = 99
         req2.min_duration_ms = 123
         cq2 = pipeline.compile_query(pages.key_dict, pages.val_dict, req2,
@@ -504,15 +498,10 @@ def test_engine_randomized_differential_vs_oracle():
 
         expected = {sd.trace_id for sd in entries
                     if search_data_matches(sd, req)}
-        cq = compile_query(pages.key_dict, pages.val_dict, req)
-        if cq is None:
+        got = scan_batch([pages], req, top_k=1024)
+        if got.mq is None:
             assert not expected, (round_, tags, kw)
             continue
-        eng = ScanEngine(top_k=1024)
-        count, inspected, scores, idx = eng.scan(pages, cq)
-        assert count == len(expected), (round_, tags, kw)
-        assert inspected == len(entries)
-        sp = stage(pages)
-        got = {bytes.fromhex(m.trace_id)
-               for m in eng.results(sp, cq, scores, idx)}
-        assert got == expected, (round_, tags, kw)
+        assert got.count == len(expected), (round_, tags, kw)
+        assert got.inspected == len(entries)
+        assert got.trace_ids == expected, (round_, tags, kw)

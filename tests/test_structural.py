@@ -36,6 +36,8 @@ from tempo_tpu.search.structural import (
     structural_query,
 )
 
+from conftest import scan_batch
+
 E_GEO = PageGeometry(entries_per_page=64, kv_per_entry=8)
 
 _SVCS = ["api", "db", "auth", "cache", "web"]
@@ -445,9 +447,8 @@ def test_differential_fuzz_compiled_vs_host(packed):
 def _check_stacked(entries, template, rng, packed: bool, mesh=None,
                    n_variants: int = 5):
     """Plan-shape stacking differential: a random same-shape query
-    group answers bit-for-bit identically fused (stack_queries +
-    coalesced kernel), solo (multi_scan_kernel), and on the host
-    reference evaluator. Returns the group size actually stacked."""
+    group answers bit-for-bit identically fused (stack_queries + one
+    launch), solo (scan_async) and on the host reference evaluator. Returns the group size actually stacked."""
     from tempo_tpu.search.engine import fetch_coalesced_out
     from tempo_tpu.search.multiblock import stack_queries
 
@@ -673,119 +674,79 @@ def test_mesh_dist_path_matches_host():
     _check_paths(entries, exprs, packed=False, mesh=make_mesh())
 
 
-def test_distributed_scan_engine_path():
-    """The `dist` path: DistributedScanEngine shards one block's pages
-    over the mesh; span columns replicate and the structural verdict
-    enters the sharded scan page-sharded."""
+def test_one_block_batch_mesh_path():
+    """One block as a one-block batch over the mesh: its pages shard,
+    span columns replicate and the structural verdict enters the
+    sharded scan page-sharded."""
     import jax
 
     if len(jax.devices()) < 2:
         pytest.skip("needs multiple (forced host) devices")
-    from tempo_tpu.parallel import DistributedScanEngine, make_mesh
-    from tempo_tpu.search.pipeline import compile_query
+    from tempo_tpu.parallel import make_mesh
 
     entries = _corpus(45, n=100)
     pages = ColumnarPages.build(entries, E_GEO)
-    eng = DistributedScanEngine(make_mesh(), top_k=512)
-    sp = eng.stage(pages)
-    assert sp.span_device is not None
+    staged = {}
     for src in (_ACCEPTANCE_TRIPLE):
         expr = ir.parse(src)
-        req = _mk_req(expr)
-        cq = compile_query(pages.key_dict, pages.val_dict, req,
-                           cache_on=pages)
-        cq.structural = compile_structural(expr, [pages], cache_on=pages)
-        count, _ins, scores, idx = eng.scan_staged(sp, cq)
+        got = scan_batch([pages], _mk_req(expr), top_k=512,
+                         mesh=make_mesh(), structural=expr, **staged)
+        staged = {"engine": got.engine, "batch": got.batch}
+        assert got.batch.span_device is not None
+        assert not got.batch.span_sharded
         want = _expected_ids(expr, entries)
-        E = E_GEO.entries_per_page
-        got = set()
-        for s, i in zip(scores.tolist(), idx.tolist()):
-            if s < 0:
-                break
-            p, e = divmod(i, E)
-            if p < pages.n_pages:
-                got.add(bytes(pages.trace_ids[p, e]))
-        assert got == want and count == len(want), src
+        assert got.trace_ids == want and got.count == len(want), src
 
 
-def test_distributed_scan_engine_sharded_spans():
-    """The `dist` path with search_structural_shard_spans: span columns
+def test_one_block_batch_mesh_sharded_spans():
+    """The mesh path with search_structural_shard_spans: span columns
     stage chunk-per-shard (span_sharded=True) and the acceptance triple
     answers byte-identically to the host reference."""
     import jax
 
     if len(jax.devices()) < 2:
         pytest.skip("needs multiple (forced host) devices")
-    from tempo_tpu.parallel import DistributedScanEngine, make_mesh
-    from tempo_tpu.search.pipeline import compile_query
+    from tempo_tpu.parallel import make_mesh
 
     entries = _corpus(46, n=600)
     pages = ColumnarPages.build(entries, E_GEO)
     STRUCTURAL.shard_spans = True
     try:
-        eng = DistributedScanEngine(make_mesh(), top_k=1024)
-        sp = eng.stage(pages)
-        assert sp.span_device is not None and sp.span_sharded
+        staged = {}
         for src in _ACCEPTANCE_TRIPLE:
             expr = ir.parse(src)
-            req = _mk_req(expr)
-            cq = compile_query(pages.key_dict, pages.val_dict, req,
-                               cache_on=pages)
-            cq.structural = compile_structural(expr, [pages],
-                                               cache_on=pages)
-            count, _ins, scores, idx = eng.scan_staged(sp, cq)
+            got = scan_batch([pages], _mk_req(expr), top_k=1024,
+                             mesh=make_mesh(), structural=expr, **staged)
+            staged = {"engine": got.engine, "batch": got.batch}
+            assert (got.batch.span_device is not None
+                    and got.batch.span_sharded)
             want = _expected_ids(expr, entries)
-            E = E_GEO.entries_per_page
-            got = set()
-            for s, i in zip(scores.tolist(), idx.tolist()):
-                if s < 0:
-                    break
-                p, e = divmod(i, E)
-                if p < pages.n_pages:
-                    got.add(bytes(pages.trace_ids[p, e]))
-            assert got == want and count == len(want), src
+            assert got.trace_ids == want and got.count == len(want), src
     finally:
         STRUCTURAL.shard_spans = False
 
 
-def test_single_block_engine_path():
-    from tempo_tpu.search.engine import ScanEngine, stage
-    from tempo_tpu.search.pipeline import compile_query
-
+def test_one_block_batch_path():
     entries = _corpus(41, n=90)
     pages = ColumnarPages.build(entries, E_GEO)
-    eng = ScanEngine(top_k=512)
-    sp = stage(pages)
-    assert sp.span_device is not None
-    E = E_GEO.entries_per_page
+    staged = {}
     for src in _ACCEPTANCE_TRIPLE + (
             '{"count": {"of": {"child": {"parent": {"kind": "server"}, '
             '"child": {"dur": {"min_ms": 50}}}}, "op": ">=", "n": 1}}',):
         expr = ir.parse(src)
         req = _mk_req(expr)
-        cq = compile_query(pages.key_dict, pages.val_dict, req,
-                           cache_on=pages)
-        cq.structural = compile_structural(expr, [pages], cache_on=pages)
-        count, _ins, scores, idx = eng.scan_staged(sp, cq)
+        got = scan_batch([pages], req, top_k=512, structural=expr,
+                         **staged)
+        staged = {"engine": got.engine, "batch": got.batch}
+        assert got.batch.span_device is not None
         want = _expected_ids(expr, entries)
-        got = set()
-        for s, i in zip(scores.tolist(), idx.tolist()):
-            if s < 0:
-                break
-            p, e = divmod(i, E)
-            got.add(bytes(pages.trace_ids[p, e]))
-        assert got == want and count == len(want), src
+        assert got.trace_ids == want and got.count == len(want), src
 
-        # single-block host route (breaker fallback): byte-identical
-        from tempo_tpu.search.backend_search_block import host_scan_single
-
-        cq_h = compile_query(pages.key_dict, pages.val_dict, req,
-                             cache_on=pages, host_only=True)
-        cq_h.structural = compile_structural(expr, [pages],
-                                             cache_on=pages,
-                                             host_only=True)
-        hcount, _hi, _hs, _hx = host_scan_single(pages, cq_h, 512)
-        assert hcount == len(want), src
+        # the host route (breaker fallback): byte-identical
+        host = scan_batch([pages], req, top_k=512, structural=expr,
+                          host_only=True)
+        assert host.count == len(want), src
+        assert host.trace_ids == want, src
 
 
 # ---------------------------------------------- serving path (TempoDB)
