@@ -62,7 +62,9 @@ class TempoDBConfig:
     search_cache_blocks: int = 64         # open search-block objects kept
     # serving-path batching (the TPU inversion of the reference's per-job
     # fan-out, searchsharding.go): blocks group into one kernel dispatch
-    search_max_batch_pages: int = 4096    # pages stacked per dispatch
+    # pages per DEVICE and dispatch: a mesh of s devices splits a group's
+    # page axis s ways, so the batcher groups s times as many pages
+    search_max_batch_pages: int = 4096
     search_batch_cache_bytes: int = 4 << 30   # staged-batch HBM budget
     # host-RAM overflow tier for stacked batches: HBM-evicted batches
     # re-stage with one H2D copy instead of IO+decompress+restack.
@@ -535,6 +537,12 @@ class TempoDB:
                     self.batcher.engine.n_shards = int(self.mesh.devices.size)
             self._mesh_resolved = True
 
+    def _plan(self, jobs: list) -> list:
+        """The batcher's groups for `jobs`, never planned before the
+        mesh is resolved: the group cap counts the mesh's devices."""
+        self._ensure_mesh()
+        return self.batcher.plan(jobs)
+
     # ------------------------------------------------------------------
     # Writer
 
@@ -688,7 +696,7 @@ class TempoDB:
                         jobs.append(self._scan_job(m))
                     except DoesNotExist:
                         continue
-                groups = self.batcher.plan(jobs)
+                groups = self._plan(jobs)
                 staged += self.batcher.prewarm(groups, stop=stop)
             # job planning above read EVERY live block's header — persist
             # the now-complete rollup set for the next process
@@ -740,7 +748,7 @@ class TempoDB:
                 for hit in cached:
                     if OWNERSHIP.generation != gen:
                         return  # a newer rebalance superseded this one
-                    groups = self.batcher.plan(list(hit[1]))
+                    groups = self._plan(list(hit[1]))
                     # prewarm() itself skips non-owned groups; no
                     # compile warm — the new owner wants residency, the
                     # jit cache is already hot for these shapes
@@ -777,7 +785,7 @@ class TempoDB:
         for hit in cached:
             if OWNERSHIP.generation != gen:
                 return  # a rebalance superseded this promotion
-            groups = self.batcher.plan(list(hit[1]))
+            groups = self._plan(list(hit[1]))
             mine = [g for g in groups
                     if OWNERSHIP.group_of(str(g[0].key[0])) == group]
             if mine:
@@ -1175,7 +1183,9 @@ class TempoDB:
                tuple((j.block_id, j.start_page, j.pages_to_search,
                       j.encoding, j.version, j.data_encoding)
                      for j in breq.jobs))
-        epoch = self.blocklist.epoch()
+        # the cached plan is a function of the group cap too (the mesh,
+        # resolved above, sets it): one generation with the blocklist's
+        epoch = (self.blocklist.epoch(), self.batcher.group_cap())
         hit = self._breq_jobs_cache.get(sig)
         if hit is not None and hit[0] == epoch:
             jobs, fallback, missing, groups = hit[1], hit[2], hit[3], hit[4]
@@ -1202,7 +1212,7 @@ class TempoDB:
                 if promoted:
                     jobs = jobs + promoted
                     fallback, missing = still_fb, still_miss
-                    groups = self.batcher.plan(jobs)
+                    groups = self._plan(jobs)
                     self._breq_jobs_cache.put(
                         sig, (epoch, jobs, fallback, missing, groups))
         else:
@@ -1234,7 +1244,7 @@ class TempoDB:
             # the group plan is a pure function of the job list — cached
             # WITH it, so the per-query batcher path neither re-sorts 10K
             # jobs nor hashes a plan key
-            groups = self.batcher.plan(jobs)
+            groups = self._plan(jobs)
             self._breq_jobs_cache.put(
                 sig, (epoch, jobs, fallback, missing, groups))
         self.batcher.search(jobs, breq.search_req, results, groups=groups)

@@ -22,6 +22,13 @@ Properties the grouping keeps:
 - **bucketed**: only jobs sharing page geometry (E entries/page, C kv
   slots) stack together — static shapes per bucket mean XLA compiles once
   per (bucket, n_terms, top_k).
+- **sized per device**: a group is what ONE device scans in a launch.
+  It closes at `max_batch_pages` pages for each device that reads it
+  (`group_cap`: `max_batch_pages * engine.n_shards`, read when a plan is
+  made), so a mesh of s devices groups s times as many pages and every
+  device scans in one launch exactly what a one-chip engine scans in
+  one. The host's price per launch is fixed; a cap counted over the
+  whole mesh bought 1/s of the device work for it.
 - **prune-aware without cache churn**: header- or dictionary-pruned jobs
   stay IN the staged batch (composition never depends on the query); the
   compiled query neutralizes them (key id -1 → no page can match) and
@@ -792,21 +799,36 @@ class BlockBatcher:
     # ------------------------------------------------------------------
     # planning
 
-    def _cuts(self, j: ScanJob) -> bool:
+    def group_cap(self) -> int:
+        """Pages a group may hold: `max_batch_pages` for each device
+        that reads it. A mesh splits a group's page axis over its
+        devices, so a group of this size costs every device the launch
+        a one-chip engine makes at `max_batch_pages`, for the one fixed
+        host price of a launch. Read when a plan is made, never before:
+        the mesh is attached after the batcher is built
+        (`TempoDB._ensure_mesh`)."""
+        return self.max_batch_pages * self.engine.n_shards
+
+    @staticmethod
+    def _cuts(j: ScanJob, cap: int) -> bool:
         """Content-defined group boundary: depends ONLY on this job's key
-        and size, never on neighbors, so group composition is a local
-        property. Cut probability 1/divisor makes the expected group
-        ~max_batch_pages/2, leaving headroom so churn rarely propagates
-        through the hard page cap to the next anchor. plan() additionally
-        guards cuts behind a min group size (max_batch_pages/4, the CDC
-        min-chunk-size trick) so groups never fragment below batching
-        efficiency."""
+        and size (and the group cap), never on neighbors, so group
+        composition is a local property. Cut probability 1/divisor
+        makes the expected group ~cap/2, leaving headroom so churn
+        rarely propagates through the hard page cap to the next anchor.
+        plan() additionally guards cuts behind a min group size (cap/4,
+        the CDC min-chunk-size trick) so groups never fragment below
+        batching efficiency. Where a job's pages divide the cap (64
+        into 4,096) the divisor grows with the mesh by whole multiples,
+        so a mesh's anchors are a subset of one chip's."""
         import zlib
 
-        divisor = max(2, self.max_batch_pages // (2 * max(1, j.n_pages)))
+        divisor = max(2, cap // (2 * max(1, j.n_pages)))
         return zlib.crc32(repr(j.key).encode()) % divisor == 0
 
     def plan(self, jobs: list[ScanJob]) -> list[list[ScanJob]]:
+        cap = self.group_cap()
+        min_pages = cap // 4
         buckets: dict[tuple, list[ScanJob]] = {}
         for j in sorted(jobs, key=lambda j: j.key):
             buckets.setdefault(j.geometry, []).append(j)
@@ -814,10 +836,10 @@ class BlockBatcher:
         for _geo, js in sorted(buckets.items()):
             cur: list[ScanJob] = []
             cur_pages = 0
-            min_pages = self.max_batch_pages // 4
             for j in js:
-                if cur and (cur_pages + j.n_pages > self.max_batch_pages
-                            or (cur_pages >= min_pages and self._cuts(j))):
+                if cur and (cur_pages + j.n_pages > cap
+                            or (cur_pages >= min_pages
+                                and self._cuts(j, cap))):
                     groups.append(cur)
                     cur, cur_pages = [], 0
                 cur.append(j)
@@ -1247,8 +1269,11 @@ class BlockBatcher:
         if groups is None and plan_key is not None:
             # one entry per plan_key[0] (tenant): a stale generation is
             # never hittable again (the epoch only moves forward), so
-            # keeping it would just pin 10K dead ScanJobs
-            tenant_key, gen = plan_key[0], plan_key[1:]
+            # keeping it would just pin 10K dead ScanJobs. The cap is
+            # part of the generation: a plan made before the mesh was
+            # attached must not outlive it
+            tenant_key = plan_key[0]
+            gen = (*plan_key[1:], self.group_cap())
             with self._lock:
                 hit = self._plan_cache.get(tenant_key)
                 if hit is not None and hit[0] == gen:

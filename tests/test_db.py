@@ -236,7 +236,8 @@ def test_search_batched_pipeline(tmp_path):
     groups, and zero dispatches for fully pruned queries."""
     from tempo_tpu.search.multiblock import MultiBlockEngine
 
-    db = _db(tmp_path)
+    # one device: the cap set below counts pages per device
+    db = _db(tmp_path, auto_mesh=False)
     for b in range(5):
         _ingest(db, "t1", 6, seed_base=b * 100)
     db.poll()
@@ -283,6 +284,7 @@ def test_search_batched_pipeline(tmp_path):
         small.limit = 3
         r = db.search("t1", small)
         assert r.complete and len(r.response().traces) >= 3
+        assert dispatches and set(dispatches) == {1}  # a block a group
         assert len(dispatches) < 5  # stopped early
 
         # fully pruned query (future time window): no device work at all
@@ -451,7 +453,9 @@ def test_batcher_cache_hits_survive_blocklist_churn(tmp_path):
 
 
 def _run_churn_body(tmp_path, obs):
-    db = _db(tmp_path)
+    # one device: the cap counts pages per device, and the 8 virtual
+    # devices as one mesh would put all 13 one-page blocks in one group
+    db = _db(tmp_path, auto_mesh=False)
     db.batcher.max_batch_pages = 8  # force multiple groups (1 page/block)
     for b in range(12):
         _ingest(db, "t1", 4, seed_base=b * 50)
@@ -654,7 +658,7 @@ def test_host_tier_survives_hbm_eviction(tmp_path):
 
 def test_host_tier_budget_evicts(tmp_path):
     """The host tier honors its byte budget."""
-    db = _db(tmp_path)
+    db = _db(tmp_path, auto_mesh=False)  # the cap below is per device
     for b in range(4):
         _ingest(db, "t1", 4, seed_base=b * 50)
     db.poll()
@@ -670,7 +674,7 @@ def test_host_tier_budget_evicts(tmp_path):
 def test_staging_prefetch_results_identical(tmp_path):
     """With multiple groups the one-slot staging lookahead must not
     change results or metrics vs a cold single-threaded pass."""
-    db = _db(tmp_path)
+    db = _db(tmp_path, auto_mesh=False)  # the cap below is per device
     db.batcher.max_batch_pages = 8  # force several groups
     for b in range(10):
         _ingest(db, "t1", 4, seed_base=b * 30)

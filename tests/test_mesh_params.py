@@ -209,7 +209,10 @@ def test_placed_once_then_reused_and_charged_once_per_device(
                    if r["ref"].get("exhaustive"))
     bytes_by_shards = {}
     for shards in (1, 4):
-        app = make_app(corpus, shards, tmp_path / str(shards))
+        # 12 pages a group on either: the same groups, so the same
+        # tables, and only the placement differs
+        app = make_app(corpus, shards, tmp_path / str(shards),
+                       pages=12 // shards)
         collector = tracing.CollectExporter()
         tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
         before = {r: obs.mesh_param_placements.value(result=r)

@@ -6,8 +6,10 @@ generator), searched through the HTTP handlers of one App whose
 reader's batcher shards every staged group over a mesh of 1, 2, 4 or 8
 devices; every answer is held to `chipbench/reference.py` by the
 benchmark's own `check` (exact counts, `inspectedTraces`, match sets).
-The groups are ragged (3 or 6 pages, padded to a power of two and to
-the mesh), so on every mesh a shard's pages are partly or wholly padding. Then the merge across
+The cap is 12 pages a device, so the groups grow with the mesh (6 of 3
+or 6 pages on one device, 15 and 6 pages on four, all 21 on eight) and
+are ragged: padded to a power of two and to the mesh, so on every mesh
+a shard's pages are partly or wholly padding. Then the merge across
 shards under dense ties, solo and fused, against the full-sort order;
 and what a mesh launch leaves in the counters and spans.
 """
@@ -61,10 +63,15 @@ def corpus(tmp_path_factory):
     return {"dir": str(root), "manifest": manifest, "requests": requests}
 
 
-def make_app(corpus, shards, tmp_path):
+PAGES = 12          # `search_max_batch_pages`: four 3-page blocks a device
+
+
+def make_app(corpus, shards, tmp_path, pages=PAGES):
     """One App on the corpus whose reader shards over `shards` devices:
     what `TempoDB._ensure_mesh` does for all visible devices, for a
-    mesh of a chosen size (1 = no mesh, as on a one-chip host)."""
+    mesh of a chosen size (1 = no mesh, as on a one-chip host). `pages`
+    is `search_max_batch_pages`, pages per device: a group holds up to
+    `pages * shards`."""
     from tempo_tpu.db.tempodb import TempoDBConfig
     from tempo_tpu.modules import App, AppConfig
     from tempo_tpu.parallel import make_mesh
@@ -73,7 +80,7 @@ def make_app(corpus, shards, tmp_path):
         backend={"backend": "local",
                  "local": {"path": corpus["dir"] + "/blocks"}},
         wal_dir=str(tmp_path / "wal"),
-        db=TempoDBConfig(auto_mesh=False, search_max_batch_pages=12)))
+        db=TempoDBConfig(auto_mesh=False, search_max_batch_pages=pages)))
     db = app.reader_db
     if shards > 1:
         db.mesh = make_mesh(shards)
@@ -151,6 +158,13 @@ def test_served_search_on_a_mesh_equals_the_reference(corpus, shards,
         # page a shard
         staged = s.attributes["pages_per_shard"] * shards
         assert staged >= shards and staged & (staged - 1) == 0
+        # ... and at most the cap on each device
+        assert s.attributes["pages_per_shard"] <= PAGES
+    if shards > 1:
+        # the cap counts pages per device: a mesh's groups are wider
+        # than one device's, and the answers above came from them
+        assert max(s.attributes["pages_per_shard"]
+                   for s in launches) * shards > PAGES
     waits = [s for s in spans if s.name == "dispatch.lock_wait"]
     if shards > 1:
         # one collective lock, and the wait for it is a span of its own
@@ -161,6 +175,35 @@ def test_served_search_on_a_mesh_equals_the_reference(corpus, shards,
         assert {s.attributes["mode"] for s in executes} == {"mesh"}
     else:
         assert not waits
+
+
+def test_launches_per_search_fall_with_the_shards(corpus, tmp_path):
+    """One search that scans the tenant, alone: a launch for each group,
+    and the groups grow with the mesh (7 blocks of 3 pages under a cap
+    of 12 pages a device: 6 groups, 3, 2), so four devices answer in a
+    third of one device's launches, and answer the same."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    request = next(r for r in corpus["requests"]
+                   if r["ref"].get("exhaustive"))
+    launches, groups = {}, {}
+    for shards in (1, 2, 4):
+        app = make_app(corpus, shards, tmp_path / str(shards))
+        collector = tracing.CollectExporter()
+        tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
+        before = obs.scan_dispatches.value(shards=shards)
+        try:
+            answer = ask(HTTPApi(app, multitenancy=True), request)
+        finally:
+            tracing.set_tracer(None)
+            app.shutdown()
+        ok, why = op.check(request, answer, corpus["manifest"])
+        assert ok, (shards, why)
+        launches[shards] = obs.scan_dispatches.value(shards=shards) - before
+        groups[shards] = max(s.attributes["groups"] for s in collector.spans
+                             if s.name == "batcher.Search")
+    assert launches == groups == {1: 6, 2: 3, 4: 2}
 
 
 def _tied_blocks(n_blocks, per_block):
