@@ -25,6 +25,12 @@ line and writes it to
                in use and peak per device, and from the profiler's
                trace each device's busy seconds and the collective ops
                by name (`collective_share.mesh`'s own rule)
+  staging      what the staged-batch cache did: its events in the window
+               and since the start by result (`hit`, `miss`, `evict`,
+               `host_hit`, `host_miss`, `host_evict`), bytes evicted,
+               the HBM gauge, its high water and the host tier's, the
+               `h2d` stage, `batcher.stage` by `cache` and the
+               `batcher.place` spans, completed searches by template
   enqueue_split  what the kernel call of a solo mesh launch, which is what
                the collective lock is held for, costs the host, by where
                the query's parameters are (jit places arguments in C++,
@@ -63,6 +69,7 @@ import collections
 import gzip
 import json
 import os
+import resource
 import sys
 import time
 
@@ -248,6 +255,53 @@ def mesh_facts(view: dict) -> dict:
     return out
 
 
+def staging_facts(view: dict) -> dict:
+    """The `staging` block: what the staged-batch cache did."""
+    from chipbench.lib import metric_sum
+
+    cache = "tempo_search_batch_cache_events_total"
+    stage = "tempo_search_dispatch_stage_seconds"
+    after = view["counters"]["after"]
+    kinds = ("hit", "miss", "evict", "host_hit", "host_miss", "host_evict")
+    by_cache: dict = {}
+    for s in view["spans"]:
+        if s["name"] == "batcher.stage":
+            by_cache.setdefault(s["attributes"].get("cache"), []).append(
+                (s["end_ns"] - s["start_ns"]) / 1e6)
+    places = [s for s in view["spans"] if s["name"] == "batcher.place"]
+    return {
+        "events_in_window": {k: delta(view, cache, result=k) for k in kinds},
+        "events_since_start": {k: metric_sum(after, cache, result=k)
+                               for k in kinds},
+        # absent on a tree that has no such counter or gauge: they read 0
+        "evicted_bytes_in_window": delta(
+            view, "tempo_search_hbm_evicted_bytes_total"),
+        "hbm_cache_bytes": metric_sum(after, "tempo_search_hbm_cache_bytes"),
+        "hbm_cache_peak_bytes": metric_sum(
+            after, "tempo_search_hbm_cache_peak_bytes"),
+        "host_cache_bytes": metric_sum(after,
+                                       "tempo_search_host_cache_bytes"),
+        "h2d_stage_in_window": {
+            "puts": delta(view, stage + "_count", stage="h2d"),
+            "seconds": delta(view, stage + "_sum", stage="h2d"),
+            "bytes": delta(view, "tempo_search_h2d_bytes_total")},
+        "stage_ms_by_cache": {str(k): {
+            "n": len(v), "mean": sum(v) / len(v), "max": max(v)}
+            for k, v in by_cache.items()},
+        "place_spans": {
+            "n": len(places),
+            "bytes": sum(s["attributes"].get("bytes", 0) for s in places),
+            "seconds": sum(s["end_ns"] - s["start_ns"]
+                           for s in places) / 1e9},
+        # the process's high water of resident memory (Linux: KiB)
+        "max_rss_bytes": resource.getrusage(
+            resource.RUSAGE_SELF).ru_maxrss * 1024,
+        "searches_by_op": dict(collections.Counter(
+            view["requests"][r["i"]]["name"] for r in view["records"]
+            if r["status"] == 200)),
+    }
+
+
 def report(view: dict, e2e_names: list) -> dict:
     out: dict = {"workload": view["workload"], "end_to_end": {}}
     for name in e2e_names:
@@ -267,6 +321,7 @@ def report(view: dict, e2e_names: list) -> dict:
             s["attributes"].get("topk", "absent") for s in spans
             if s["name"] == "dispatch.execute"))}
     out["mesh"] = mesh_facts(view)
+    out["staging"] = staging_facts(view)
     traces = sp.searches(spans)
     if not traces:
         return out

@@ -428,6 +428,16 @@ d2h_bytes = Counter("tempo_search_d2h_bytes_total",
                     "bytes fetched device->host (scan results/demux)")
 hbm_cache_bytes = Gauge("tempo_search_hbm_cache_bytes",
                         "staged-batch HBM cache occupancy (bytes)")
+hbm_cache_peak_bytes = Gauge(
+    "tempo_search_hbm_cache_peak_bytes",
+    "high water of tempo_search_hbm_cache_bytes since the process "
+    "started: how far the staged-batch cache ever stood over "
+    "search_batch_cache_bytes (pinned in-flight groups)")
+hbm_evicted_bytes = Counter(
+    "tempo_search_hbm_evicted_bytes_total",
+    "bytes of staged batches dropped from the HBM cache (LRU pressure "
+    "and ownership rebalances); the events are "
+    "batch_cache_events{result=evict}")
 host_cache_bytes = Gauge("tempo_search_host_cache_bytes",
                          "host-RAM stacked-batch tier occupancy (bytes)")
 probe_dict_bytes = Gauge("tempo_search_probe_dict_bytes",

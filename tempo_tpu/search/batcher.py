@@ -11,14 +11,21 @@ whose pages stack along the device page axis and scan in ONE kernel call
 collectives replace the Results funnel).
 
 Properties the grouping keeps:
-- **stable AND churn-local**: jobs sort by (block id, page range) and
-  group boundaries are content-defined — a job starts a new group based
-  only on a stable hash of its own key (like content-defined chunking in
-  dedup stores) — so the same blocklist yields the same groups query
-  after query, and a block arriving or leaving the blocklist reshapes
-  only its own neighborhood up to the next hash anchor: O(1) cached
-  batches invalidate per poll instead of every group downstream of the
-  new uuid's sort position.
+- **stable AND churn-local**: jobs sort by (header start time, block
+  id, page range) and group boundaries are content-defined — a job
+  starts a new group based only on a stable hash of its own key (like
+  content-defined chunking in dedup stores) — so the same blocklist
+  yields the same groups query after query, and a block arriving or
+  leaving the blocklist reshapes only its own neighborhood up to the
+  next hash anchor: O(1) cached batches invalidate per poll instead of
+  every group downstream of the new block's sort position.
+- **cut in time, like the windows**: block ids are uuids, so an id
+  order scatters the blocks a time window keeps over every group and a
+  group with one live block is staged whole. In start-time order a
+  window's blocks are neighbours: it touches the groups its hours lie
+  in, the header prune skips the others before any staging, a flushed
+  block lands in the newest group, and under an HBM budget smaller
+  than the tenant the LRU keeps the hours that are asked for.
 - **bucketed**: only jobs sharing page geometry (E entries/page, C kv
   slots) stack together — static shapes per bucket mean XLA compiles once
   per (bucket, n_terms, top_k).
@@ -167,7 +174,7 @@ class ScanJob:
         return int(self.header.get("compressed_size", 0) * self.n_pages / total)
 
 
-@dataclass
+@dataclass(eq=False)         # an entry is itself: `==` would compare arrays
 class _CachedBatch:
     batch: object           # multiblock.BlockBatch
     nbytes: int
@@ -180,14 +187,18 @@ class _CachedBatch:
     # request's predicate (header prune, per-block compile tables, metric
     # sums) — repeated queries over a 10K-block blocklist must not pay
     # O(blocks) python per query (VERDICT r2 #1). Keyed by the full
-    # predicate signature; bounded LRU.
+    # predicate signature; bounded LRU. An eviction hands what of it
+    # holds no device state to the host-tier entry (`_keep_memo_locked`)
+    # and the next stage of the group starts from there.
     query_cache: OrderedDict = field(default_factory=OrderedDict)
-    # HBM pin count: searches holding this batch (between acquisition and
-    # their final drain). Eviction skips pinned entries so budget
-    # pressure from one tenant never drops a batch another request is
-    # actively scanning — its device arrays would survive via the
-    # in-flight references anyway, but the budget would double-pay when
-    # the next query immediately re-stages it
+    # HBM pin count: searches holding this batch in flight, from when
+    # they take it (`_staged(pin=True)`, their look-ahead included) to
+    # the drain of THEIR dispatch over it — not to the end of the
+    # search, or a tenant-wide search pins the tenant and the budget
+    # bounds nothing. Eviction skips pinned entries so budget pressure
+    # never drops a batch a request is actively scanning — its device
+    # arrays would survive via the in-flight references anyway, but the
+    # budget would double-pay when the next query re-stages it
     pins: int = 0
 
 
@@ -735,6 +746,7 @@ class BlockBatcher:
         self.io_workers = io_workers
         self._cache: OrderedDict[tuple, _CachedBatch] = OrderedDict()
         self._cache_total = 0
+        self._cache_peak = 0        # high water of _cache_total, as published
         self._probe_dict_total = 0  # staged-dict bytes across _cache
         # logical (unpacked-layout) bytes across both tiers — the other
         # half of the packed-residency accounting split: budgets charge
@@ -830,7 +842,8 @@ class BlockBatcher:
         cap = self.group_cap()
         min_pages = cap // 4
         buckets: dict[tuple, list[ScanJob]] = {}
-        for j in sorted(jobs, key=lambda j: j.key):
+        for j in sorted(jobs, key=lambda j: (
+                j.header.get("min_start_s") or 0, j.key)):
             buckets.setdefault(j.geometry, []).append(j)
         groups = []
         for _geo, js in sorted(buckets.items()):
@@ -864,6 +877,11 @@ class BlockBatcher:
         totals (the _cache_total idiom) — this must stay O(1), it runs
         on every stage/evict under the global lock."""
         obs.hbm_cache_bytes.set(self._cache_total)
+        if self._cache_total > self._cache_peak:
+            # what the gauge above ever showed: a scrape at the ends of
+            # an interval cannot see an overshoot inside it
+            self._cache_peak = self._cache_total
+            obs.hbm_cache_peak_bytes.set(self._cache_peak)
         obs.host_cache_bytes.set(self._host_total)
         obs.probe_dict_bytes.set(self._probe_dict_total)
         obs.hbm_logical_bytes.set(self._cache_logical)
@@ -881,6 +899,25 @@ class BlockBatcher:
             self._host_total -= self._cpu_staged_bytes.pop(k, 0)
             obs.batch_cache_events.inc(result="host_evict")
 
+    def _keep_memo_locked(self, gkey: tuple, old: _CachedBatch) -> None:
+        """An evicted batch's prepare memo outlives it on the host-tier
+        entry — caller holds self._lock. The memo is host work (the
+        per-block predicate compile), and a tenant larger than its HBM
+        budget would pay it again at every re-stage: on a v5e that was
+        39 % of all lookups and the largest span of a search. What
+        holds device state stays behind: a predicate's uploaded tables
+        (HBM the eviction just gave back; the next dispatch uploads
+        them again), and whole entries compiled against the batch's
+        staged dictionaries or a structural plan."""
+        host = self._host_cache.get(gkey)
+        if host is None:
+            return
+        host.query_memo = OrderedDict(
+            (sig, {k: v for k, v in pre.items()
+                   if k not in ("device_params", "device_params_bytes")})
+            for sig, pre in old.query_cache.items()
+            if pre.get("val_hits") is None and pre.get("structural") is None)
+
     def _drop_hbm_locked(self, gkey: tuple) -> None:
         """Remove one staged batch and release its budget charge —
         caller holds self._lock. The single eviction primitive shared by
@@ -890,10 +927,12 @@ class BlockBatcher:
         old = self._cache.pop(gkey, None)
         if old is None:
             return
+        self._keep_memo_locked(gkey, old)
         self._cache_total -= old.nbytes
         self._cache_logical -= old.logical
         self._probe_dict_total -= self._dict_bytes(old.batch)
         obs.batch_cache_events.inc(result="evict")
+        obs.hbm_evicted_bytes.inc(old.nbytes)
 
     def _evict_hbm_locked(self) -> None:
         """LRU-evict staged batches until the HBM budget holds — caller
@@ -995,7 +1034,32 @@ class BlockBatcher:
             })
         return out
 
-    def _staged(self, group: list[ScanJob]) -> _CachedBatch:
+    def _unpin_locked(self, entries) -> None:
+        """Give back pins taken by `_staged(pin=True)` — caller holds
+        self._lock. What the pins held over budget goes now: first the
+        ownership-rebalance deferrals (exactly-once, identity-checked),
+        then ordinary LRU pressure."""
+        for c in entries:
+            c.pins -= 1
+        self._run_deferred_evictions_locked()
+        self._evict_hbm_locked()
+
+    def _unpin_unused(self, fut) -> None:
+        """Done-callback of a look-ahead no search came back for."""
+        if fut.exception() is None:
+            entry = fut.result()     # done: returns at once
+            with self._lock:
+                self._unpin_locked((entry,))
+
+    def _staged(self, group: list[ScanJob], pin: bool = False,
+                parent=None) -> _CachedBatch:
+        """The group's staged batch, from the HBM cache or staged now.
+        `pin` takes a pin under the same lock that finds or inserts the
+        entry, so no eviction pass can drop what the caller is about to
+        scan (its own insert's included); the caller gives it back
+        through `_unpin_locked`. `parent` is the span context a
+        look-ahead thread writes `batcher.place` under (a stage on the
+        searching thread finds its `batcher.Search` current)."""
         key = tuple(j.key for j in group)
         while True:
             with self._lock:
@@ -1003,6 +1067,8 @@ class BlockBatcher:
                 if hit is not None:
                     self._cache.move_to_end(key)
                     obs.batch_cache_events.inc(result="hit")
+                    if pin:
+                        hit.pins += 1
                     return hit
                 ev = self._staging.get(key)
                 if ev is None:
@@ -1019,17 +1085,29 @@ class BlockBatcher:
             # into a device that stopped answering raises DeviceFault
             # (breaker fault booked) and the caller answers through the
             # host route
+            t0 = tracing.now_ns()
             batch = robustness.GUARD.run(
                 "h2d", lambda: self.engine.place(host))
+            if tracing.get_tracer() is not None:
+                # the put alone, fenced (place_batch waits for the
+                # arrays): H2D apart from `_load_host`'s IO and stacking
+                tracing.record_span(
+                    "batcher.place", t0, tracing.now_ns(),
+                    parent=parent or tracing.current_span().context,
+                    bytes=int(batch.device_nbytes), blocks=len(group))
             # batch.nbytes covers the stacked page arrays AND any staged
             # probe dictionaries — both live in HBM under this budget
             # (physical/packed bytes; the logical twin feeds the gauges)
             nbytes = int(batch.nbytes)
             entry = _CachedBatch(batch=batch, nbytes=nbytes,
                                  logical=int(batch.logical_nbytes),
-                                 jobs=list(group))
+                                 jobs=list(group), pins=int(pin))
             with self._lock:
                 obs.batch_cache_events.inc(result="miss")
+                # what the last eviction of this group kept of its memo
+                if host.query_memo is not None:
+                    entry.query_cache, host.query_memo = (
+                        host.query_memo, None)
                 prev = self._cache.pop(key, None)
                 if prev is not None:
                     self._cache_total -= prev.nbytes
@@ -1223,17 +1301,28 @@ class BlockBatcher:
 
         Concurrent calls coalesce: dispatches landing on the same staged
         batch within the coalescing window fuse into one multi-query
-        kernel launch (see QueryCoalescer). Batches a search is actively
-        scanning are pinned in the HBM cache for its duration."""
+        kernel launch (see QueryCoalescer). A batch is pinned in the HBM
+        cache while this search has it in flight (staged ahead, taken,
+        dispatched and not yet drained): the cache stands over budget by
+        at most `pipeline_depth` + 1 groups for each concurrent search."""
         with self._lock:
             self._unplanned += 1
-        pinned: list[_CachedBatch] = []
+        pinned: list[_CachedBatch] = []   # pins held now, one per entry
+        prefetched: dict = {}        # gkey -> (look-ahead future, event)
         interest: list[tuple] = []   # gkeys registered once planned
         planned = [False]
         try:
             return self._search_impl(jobs, req, results, plan_key, groups,
-                                     pinned, interest, planned)
+                                     pinned, prefetched, interest, planned)
         finally:
+            # an early quit or an exception leaves a look-ahead pending:
+            # cancel it so a not-yet-started stage doesn't burn
+            # IO+decompress+H2D (and possibly evict a hotter batch) for a
+            # group nobody needs; an already-running one completes via
+            # _staged's dedupe and gives its pin back when it does
+            for f, _ev in prefetched.values():
+                if not f.cancel():
+                    f.add_done_callback(self._unpin_unused)
             with self._lock:
                 if planned[0]:
                     for k in interest:
@@ -1244,18 +1333,13 @@ class BlockBatcher:
                             self._interest[k] = n
                 else:  # died before the plan resolved
                     self._unplanned -= 1
-                for c in pinned:
-                    c.pins -= 1
-                # evictions deferred by pins run now that they dropped:
-                # first the ownership-rebalance deferrals (exactly-once,
-                # identity-checked), then ordinary LRU pressure
-                self._run_deferred_evictions_locked()
-                self._evict_hbm_locked()
+                # whatever an exception or an early quit left in flight
+                self._unpin_locked(pinned)
 
     def _search_impl(self, jobs: list[ScanJob], req,
                      results: SearchResults | None,
                      plan_key, groups: list | None,
-                     pinned: list, interest: list,
+                     pinned: list, prefetched: dict, interest: list,
                      planned: list) -> SearchResults:
         from .pipeline import is_exhaustive
 
@@ -1323,6 +1407,13 @@ class BlockBatcher:
                     sp.set_attribute(key, val)
                 sp.end(t1)
 
+        def release(cached):
+            """This search is done with `cached`: its pin goes, and with
+            it whatever the pin held over budget."""
+            pinned.remove(cached)
+            with self._lock:
+                self._unpin_locked((cached,))
+
         def drain_one():
             t0 = tracing.now_ns()
             item = inflight.popleft()
@@ -1334,6 +1425,7 @@ class BlockBatcher:
             try:
                 drain(dspan, *item)
             finally:
+                release(item[2])
                 t1 = tracing.now_ns()
                 stages["drain"] += (t1 - t0) / 1e9
                 dspan.end(t1)
@@ -1656,8 +1748,6 @@ class BlockBatcher:
             book("header_prune", t0, gi, group)
             return reasons
 
-        prefetched: dict = {}
-
         def submit_prefetch(from_idx):
             """One-slot staging lookahead: stage the NEXT live group in a
             background thread while this group's kernel runs — H2D
@@ -1681,7 +1771,9 @@ class BlockBatcher:
                     host_res = k in self._host_cache
                 if not resident and k not in prefetched:
                     prefetched[k] = (
-                        self._prefetcher.submit(self._staged, g),
+                        self._prefetcher.submit(
+                            self._staged, g, True,
+                            span.context if span.recording else None),
                         "hbm_miss_host_hit" if host_res
                         else "hbm_miss_cold")
                 return
@@ -1752,7 +1844,9 @@ class BlockBatcher:
                     # breaker's host route instead
                     obs.hbm_owner_routed.inc(route="owner")
                 # memo lookup needs the staged batch's identity; the memo
-                # itself lives on the cached batch so it dies with it
+                # itself lives on the cached batch and, while the group is
+                # evicted, on its host-tier entry (`HostBatch.query_memo`):
+                # it dies with the last of the two
                 t0 = tracing.now_ns()
                 pf = prefetched.pop(gkey, None)
                 fut_staged, pf_event = pf if pf is not None else (None, None)
@@ -1773,9 +1867,11 @@ class BlockBatcher:
                                             if gkey in self._host_cache
                                             else "hbm_miss_cold"))
                 try:
+                    # pinned from here (a look-ahead took its pin when it
+                    # staged) until this group's own drain
                     cached = (fut_staged.result()
                               if fut_staged is not None
-                              else self._staged(group))
+                              else self._staged(group, pin=True))
                 except robustness.DeviceFault:
                     # the staging H2D hit the wedged device (fault
                     # booked): host tier already holds the stacked
@@ -1789,8 +1885,6 @@ class BlockBatcher:
                     if _event != "hbm_hit" and cached.batch.staged_dicts:
                         qs.add_cache("probe_dict_staged",
                                      len(cached.batch.staged_dicts))
-                with self._lock:
-                    cached.pins += 1
                 pinned.append(cached)
                 submit_prefetch(gi + 1)
                 with self._lock:
@@ -1830,6 +1924,7 @@ class BlockBatcher:
                         qs.add_skip(r, n)
                 if pre["all_skip"]:
                     results.metrics.skipped_blocks += pre["skipped"]
+                    release(cached)
                     continue
                 from .multiblock import MultiQuery
 
@@ -1892,6 +1987,7 @@ class BlockBatcher:
                         # resubmit must not re-book them. Interest for
                         # this gkey is released by the outer finally.
                         book("dispatch", t0, gi, group)
+                        release(cached)
                         host_route(gi, group, gkey, hdr_reasons,
                                    book_skips=False)
                         continue
@@ -1918,15 +2014,9 @@ class BlockBatcher:
                     drain_one()
             while inflight:
                 if results.complete:
-                    inflight.clear()
+                    inflight.clear()   # their pins: search()'s finally
                     break
                 drain_one()
-            # early quit leaves a lookahead pending: cancel it so a
-            # not-yet-started stage doesn't burn IO+decompress+H2D (and
-            # possibly evict a hotter batch) for a group nobody needs; an
-            # already-running one completes harmlessly via _staged dedupe
-            for f, _ev in prefetched.values():
-                f.cancel()
             span.set_attributes(groups=len(groups), scan_dispatches=dispatches,
                                 inspected_blocks=results.metrics.inspected_blocks,
                                 skipped_blocks=results.metrics.skipped_blocks)

@@ -50,6 +50,7 @@ from .pipeline import (
     UINT32_MAX,
 )
 
+import copy
 import functools
 
 
@@ -131,7 +132,11 @@ class HostBatch:
     only the HBM half: the host copy keeps serving routed-away queries."""
     cat: dict                       # stacked host arrays incl. page_block
     page_block: np.ndarray
-    blocks: list                    # list[ColumnarPages]
+    # list[ColumnarPages], for result rendering + query compile. Where
+    # `cat` owns its memory in the plain layout these are copies that
+    # read the stacked columns from `cat` (`_blocks_over`), so the entry
+    # does not pin the containers' decoded buffers a second time
+    blocks: list
     page_offset: list
     # dict fingerprint -> dict_probe.PackedDeviceDict: the host half of
     # the device-probe staging, packed once per distinct dictionary and
@@ -147,6 +152,12 @@ class HostBatch:
     # structural span columns, host tier (see BlockBatch.span_device):
     # the host-fallback scan runs the same structural kernel over these
     span_cat: dict | None = None
+    # bytes of `blocks`' columns that are views of `cat`: counted once
+    aliased_nbytes: int = 0
+    # the prepare memo of the group's last staged batch, kept across an
+    # HBM eviction (batcher._keep_memo_locked) and taken back by the
+    # next stage: host objects only, small beside the columns, uncharged
+    query_memo: object | None = None
 
     @property
     def cat_nbytes(self) -> int:
@@ -161,16 +172,18 @@ class HostBatch:
         the logical side of the host-tier accounting split."""
         return int((self.cat_logical_nbytes or self.cat_nbytes)
                    + sum(b.nbytes for b in self.blocks)
+                   - self.aliased_nbytes
                    + sum(d.nbytes for d in self.packed_dicts.values()))
 
     @property
     def nbytes(self) -> int:
-        # the entry pins BOTH the stacked copies and each block's source
-        # ColumnarPages (needed for result rendering + query compile) —
-        # budget against real RAM, not just the cat arrays, or a 32 GB
-        # budget pins ~64 GB (code-review r4)
+        # the entry pins BOTH the stacked copies and what each block
+        # keeps beside them (needed for result rendering + query
+        # compile) — budget against real RAM, not just the cat arrays,
+        # or a 32 GB budget pins ~64 GB (code-review r4)
         return int(self.cat_nbytes
                    + sum(b.nbytes for b in self.blocks)
+                   - self.aliased_nbytes
                    + sum(d.nbytes for d in self.packed_dicts.values()))
 
     @property
@@ -226,6 +239,41 @@ def _pack_batch_dicts(blocks: list[ColumnarPages],
             out[fp] = b._device_dict_packed = dict_probe.pack_device_dict(
                 b.val_dict, n_shards=S, fingerprint=fp)
     return out
+
+
+# the columns stack_host stacks without changing a value (the kv pair
+# narrowed, the rest as they are) in the plain layout
+_STACKED = ("kv_key", "kv_val", "entry_start", "entry_end", "entry_dur",
+            "entry_valid")
+
+
+def _blocks_over(cat: dict, blocks: list[ColumnarPages],
+                 page_offset: list) -> tuple[list, int]:
+    """The blocks as the host tier keeps them: each a shallow copy (the
+    memos set on the container come along) whose stacked columns are
+    views of `cat` and whose other arrays are its own. A decoded
+    container's arrays all view ONE buffer (`ColumnarPages.from_bytes`),
+    so a block kept as it came pins that buffer whole beside the stacked
+    copy: 10.7 MB a block of 65,536 entries for the 1.5 MB still read
+    from it, 3.7x the group's HBM bytes in host RAM. Returns the blocks
+    and the bytes they share with `cat`."""
+    out, aliased = [], 0
+    for b, off in zip(blocks, page_offset):
+        slim = copy.copy(b)
+        for name, _ in b._ARRAYS + b._SPAN_ARRAYS:
+            arr = getattr(b, name)
+            if arr is None:
+                continue
+            if name in _STACKED:
+                view = cat[name][off:off + b.n_pages]
+                if view.ndim == 3:      # a group pads to its widest block
+                    view = view[:, :, :arr.shape[2]]
+                aliased += view.nbytes
+            else:
+                view = arr if arr.base is None else arr.copy()
+            setattr(slim, name, view)
+        out.append(slim)
+    return out, aliased
 
 
 def stack_host(blocks: list[ColumnarPages],
@@ -299,7 +347,8 @@ def stack_host(blocks: list[ColumnarPages],
             arrays[name].append(arr)
         page_block.extend([bi] * P)
         total += P
-    if len(blocks) == 1 and not (pad_to and pad_to > total):
+    one_view = len(blocks) == 1 and not (pad_to and pad_to > total)
+    if one_view:
         # single-block fast path: the block already matches the bucket
         # shape, so the concatenate below would be a pure copy of every
         # column — serve views of the (possibly just-transformed)
@@ -339,11 +388,17 @@ def stack_host(blocks: list[ColumnarPages],
         span_cat = STRUCTURAL.stack_spans(blocks, E,
                                           int(page_block.shape[0]))
     entries_padded = int(page_block.shape[0]) * E
+    packed_dicts = _pack_batch_dicts(blocks, probe_min_vals,
+                                     n_shards=n_shards)
+    aliased = 0
+    if widths is None and not one_view:
+        # `cat` is a copy in the blocks' own ids (not the one-block view,
+        # not a packed layout): the blocks can read from it
+        blocks, aliased = _blocks_over(cat, blocks, page_offset)
     return HostBatch(cat=cat, page_block=page_block, blocks=blocks,
-                     page_offset=page_offset,
-                     packed_dicts=_pack_batch_dicts(blocks, probe_min_vals,
-                                                    n_shards=n_shards),
+                     page_offset=page_offset, packed_dicts=packed_dicts,
                      widths=widths, span_cat=span_cat,
+                     aliased_nbytes=aliased,
                      cat_logical_nbytes=(
                          packing.logical_nbytes(entries_padded, C0,
                                                 n_keys, n_vals)
@@ -375,6 +430,10 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
         dev = mesh_mod.put(cat, sharding)
     else:
         dev = {k: jnp.asarray(v) for k, v in cat.items()}
+    # fenced: a put returns before the bytes are on the device, and an
+    # unfenced stamp times the enqueue. Nothing can scan the batch
+    # before they are, so the wait is moved here, not added
+    jax.block_until_ready(dev)
     # page-array H2D only; the dictionary placement below times itself
     # (mode=dict_probe) inside place_device_dict
     profile.observe_stage("h2d", mode, time.perf_counter() - t0,

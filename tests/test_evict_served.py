@@ -1,0 +1,447 @@
+"""The served search path with HBM as a cache: a tenant several times
+its staged-batch budget, held to the plain reference while its groups
+are evicted and staged again between and during searches.
+
+What the cell `share16.evict` checks on the chip, at a small size on
+the CPU: a seeded `otel_blocks` corpus of 48 blocks over 24 h (half an
+hour a block), a cap of 8 pages a group (4 blocks, two hours: 12 groups
+of 0.5 MB) and a budget of 1.2 MB, which holds two. Every answer goes
+through the HTTP handlers of one App and is held to
+`chipbench/reference.py` by the benchmark's own `check`. Then what the
+budget promises (the cache's high water under concurrent tenant-wide
+searches), what a window costs (the groups its hours lie in, not all of
+them), and that the plan is the same jobs whatever order they come in.
+"""
+
+import base64
+import json
+import random
+import time
+import urllib.parse
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+import pytest
+
+from tempo_tpu.observability import metrics as obs
+from tempo_tpu.observability import tracing
+from tempo_tpu.search.batcher import BlockBatcher, ScanJob
+
+BLOCKS, PAGES_A_BLOCK, CAP = 48, 2, 8
+BUDGET = 1_200_000
+CORPUS = {
+    "generator": "otel_blocks", "tenant": "evicttest", "config_name": "evict",
+    "blocks": BLOCKS, "entries_per_block": PAGES_A_BLOCK * 1024,
+    "services": 200, "routes": 500, "rpc_methods": 300, "pods": 2000,
+    "customers": 10000, "span_names": 400, "zipf_s": 1.1,
+    "dur_median_ms": 40, "dur_sigma": 1.787,
+    "time_base": 1700000000, "time_span_s": 86400, "time_overlap": 0.1,
+}
+HUNT = {"op": "search", "variants": 2, "limit": 20,
+        "tags": {"service.name": {"draw": "strata"},
+                 "http.status_code": {"fixed": "500"}},
+        "min_duration_quantile": "0.9"}
+NEWEST = CORPUS["time_base"] + CORPUS["time_span_s"]
+HOUR = 3600
+
+
+@pytest.fixture(scope="module")
+def corpus(tmp_path_factory):
+    from chipbench.generators import otel_blocks
+
+    root = tmp_path_factory.mktemp("evictcorpus")
+    with ThreadPoolExecutor(4) as pool:
+        manifest = otel_blocks.generate(CORPUS, 2**31 + 30,
+                                        str(root / "blocks"), pool)
+    return {"dir": str(root), "manifest": manifest}
+
+
+def hunts(corpus, seed, **template):
+    from chipbench.ops import search as op
+
+    return op.build(dict(HUNT, **template), corpus["manifest"],
+                    np.random.default_rng(seed))
+
+
+def windowed(requests, age_h, hours):
+    """The requests over the `hours` that end `age_h` hours before the
+    newest data, as `chipbench/ops/search_aged.py` places a window."""
+    end = NEWEST - age_h * HOUR
+    out = []
+    for r in requests:
+        r = dict(r, ref=dict(r["ref"], start=end - hours * HOUR, end=end))
+        r["path"] += f"&start={end - hours * HOUR}&end={end}"
+        out.append(r)
+    return out
+
+
+KINDS = {
+    "one-group-window": lambda c: [
+        r for age in (0, 7, 15, 22) for r in windowed(hunts(c, age), age, 2)],
+    "six-hour-window": lambda c: [
+        r for age in (0, 9, 18) for r in windowed(hunts(c, age), age, 6)],
+    "whole-tenant": lambda c: windowed(hunts(c, 3, variants=3), 0, 24),
+    "exhaustive": lambda c: hunts(c, 4, exhaustive=True),
+}
+
+
+@pytest.fixture
+def app(corpus, tmp_path, monkeypatch):
+    """One App on the corpus: groups of four blocks (only the cap closes
+    a group: anchors are `test_group_cap.py`'s), a budget of two."""
+    from tempo_tpu.db.tempodb import TempoDBConfig
+    from tempo_tpu.modules import App, AppConfig
+
+    monkeypatch.setattr(BlockBatcher, "_cuts",
+                        staticmethod(lambda j, cap: False))
+    app = App(AppConfig(
+        backend={"backend": "local",
+                 "local": {"path": corpus["dir"] + "/blocks"}},
+        wal_dir=str(tmp_path / "wal"),
+        db=TempoDBConfig(auto_mesh=False, search_max_batch_pages=CAP,
+                         search_batch_cache_bytes=BUDGET)))
+    app.poll_tick()
+    yield app
+    app.shutdown()
+
+
+def ask(api, request):
+    path, _, qs = request["path"].partition("?")
+    code, body = api.handle("GET", path, dict(urllib.parse.parse_qsl(qs)),
+                            request["headers"])
+    return {"status": code,
+            "body": base64.b64encode(json.dumps(body).encode()).decode()}
+
+
+def events(result):
+    return obs.batch_cache_events.value(result=result)
+
+
+def settle(batcher, timeout=10.0):
+    """Look-aheads that no search came back for give their pins back
+    when they finish: wait for that, then return the pins still held."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        with batcher._lock:
+            held = sum(c.pins for c in batcher._cache.values())
+            staging = len(batcher._staging)
+        if not held and not staging:
+            return 0
+        time.sleep(0.02)
+    return held
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_served_answers_under_eviction_equal_the_reference(corpus, app,
+                                                           kind):
+    """Alone and then eight at a time, each kind after a tenant-wide
+    search has pushed its groups out: every answer is the reference's,
+    groups were evicted and staged again while it was made, and nothing
+    stays pinned."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    requests = KINDS[kind](corpus)
+    flush = windowed(hunts(corpus, 99, variants=1), 0, 24)[0]
+    assert ask(api, flush)["status"] == 200
+    evicted, missed = events("evict"), events("miss")
+    on_host = obs.scan_dispatches.value(mode="host_fallback")
+    answers = []
+    for r in requests:
+        answers.append(ask(api, r))
+        ask(api, flush)
+    with ThreadPoolExecutor(8) as pool:
+        answers += list(pool.map(lambda r: ask(api, r), requests * 2))
+    for r, a in zip(requests * 3, answers):
+        ok, why = op.check(r, a, corpus["manifest"])
+        assert ok, (kind, r["path"], why)
+    assert events("evict") > evicted and events("miss") > missed
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    assert obs.scan_dispatches.value(mode="host_fallback") == on_host
+    if kind == "exhaustive":
+        docs = [json.loads(base64.b64decode(a["body"])) for a in answers]
+        assert all(int(d["metrics"]["inspectedTraces"])
+                   == corpus["manifest"]["entries"] for d in docs)
+
+
+@pytest.mark.parametrize("callers", (1, 2, 8))
+def test_the_cache_stands_over_budget_by_what_is_in_flight(corpus, app,
+                                                           callers):
+    """Tenant-wide searches, `callers` at a time, over 12 groups against
+    a budget of 2: the high water of the cache is at most the budget
+    plus `pipeline_depth` + 1 groups for each caller (one caller: 2.7 of
+    the tenant's 6 MB), never the tenant, which search-long pins held
+    whole however few the callers (eight walk it together and read 7-8
+    groups of the 12), and once they are done the cache is back under
+    its budget."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    batcher = app.reader_db.batcher
+    requests = windowed(hunts(corpus, 5, variants=callers), 0, 24)
+    for _ in range(2):
+        missed = events("miss")
+        with ThreadPoolExecutor(callers) as pool:
+            answers = list(pool.map(lambda r: ask(api, r), requests))
+        for r, a in zip(requests, answers):
+            ok, why = op.check(r, a, corpus["manifest"])
+            assert ok, (r["path"], why)
+    assert settle(batcher) == 0
+    # callers walk the tenant together: a group is staged for all of
+    # them, not once for each (their own uncounted copies)
+    assert events("miss") - missed <= 3 * (BLOCKS // 4)
+    with batcher._lock:
+        group = max(c.nbytes for c in batcher._cache.values())
+        total, peak = batcher._cache_total, batcher._cache_peak
+    tenant = BLOCKS // 4 * group
+    allowance = callers * (batcher.pipeline_depth + 1) * group
+    assert BUDGET < peak <= BUDGET + allowance
+    assert peak < tenant
+    assert total <= BUDGET
+    assert obs.hbm_cache_peak_bytes.value() >= peak
+    assert obs.hbm_cache_bytes.value() == total
+
+
+@pytest.mark.parametrize("age_h", (0, 11, 22))
+def test_a_window_takes_the_groups_its_hours_lie_in(corpus, app, age_h):
+    """A two-hour window is one group long: it takes one to three
+    groups of the twelve (its hours, the 10 % overlap of their
+    neighbours), and the header prune skips the others before staging."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 6, variants=1), age_h, 2)
+    before = events("hit") + events("miss")
+    a = ask(api, r)
+    ok, why = op.check(r, a, corpus["manifest"])
+    assert ok, why
+    assert 1 <= events("hit") + events("miss") - before <= 3
+    doc = json.loads(base64.b64decode(a["body"]))
+    assert int(doc["metrics"]["skippedBlocks"]) >= BLOCKS - 12
+
+
+def test_a_group_staged_among_pinned_ones_keeps_its_place(corpus, app):
+    """Three groups taken against a budget of two, none given back: the
+    third is not evicted by its own insert (it would be scanned as a
+    copy the budget no longer counts, and staged again by the next
+    search: on a v5e that filled the chip), the cache stands over its
+    budget by exactly what is pinned and says so, and it is back under
+    the budget once the pins go."""
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 9, variants=1), 0, 2)
+    assert ask(api, r)["status"] == 200           # makes the plan
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    (*_job_lists, groups), = app.reader_db._breq_jobs_cache.values()
+    taken = [batcher._staged(g, pin=True) for g in groups[:3]]
+    with batcher._lock:
+        keys = [tuple(j.key for j in g) for g in groups[:3]]
+        assert [batcher._cache.get(k) for k in keys] == taken
+        assert [c.pins for c in taken] == [1, 1, 1]
+        held = sum(c.nbytes for c in taken)
+        assert BUDGET < held <= batcher._cache_total
+        assert batcher._cache_peak >= held
+        batcher._unpin_locked(taken)
+        assert batcher._cache_total <= BUDGET
+        assert [c.pins for c in taken] == [0, 0, 0]
+        assert batcher._cache.get(keys[2]) is taken[2]   # the newest stays
+
+
+def _jobs(n, rng):
+    """Blocks written in time order under ids that say nothing of it."""
+    return [ScanJob(key=(f"{rng.getrandbits(128):032x}", 0, 2),
+                    pages_fn=None, header={"min_start_s": 1000 + 60 * i,
+                                           "max_end_s": 1070 + 60 * i},
+                    n_pages=2, n_entries=2048, geometry=(1024, 16))
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("shards", (1, 2, 4))
+def test_a_plan_is_the_same_jobs_whatever_their_order(shards):
+    """Every job once, the same groups whatever order the jobs come in,
+    in time order within and across groups; a job without a header time
+    sorts first, by its key, as all jobs did before."""
+    rng = random.Random(30)
+    b = BlockBatcher(max_batch_pages=16)
+    b.engine.n_shards = shards
+    jobs = _jobs(200, rng)
+    untimed = [ScanJob(key=(f"old-{i:03d}", 0, 2), pages_fn=None, header={},
+                       n_pages=2, n_entries=2048, geometry=(1024, 16))
+               for i in range(5)]
+    want = b.plan(jobs + untimed)
+    flat = [j for g in want for j in g]
+    assert sorted(j.key for j in flat) == sorted(
+        j.key for j in jobs + untimed)
+    assert flat[:5] == untimed and flat[5:] == jobs
+    assert max(sum(j.n_pages for j in g) for g in want) <= 16 * shards
+    for _ in range(3):
+        shuffled = jobs + untimed
+        rng.shuffle(shuffled)
+        got = b.plan(shuffled)
+        assert [[j.key for j in g] for g in got] == [
+            [j.key for j in g] for g in want]
+    # a window of consecutive blocks lies in a few consecutive groups
+    # (in id order its ten blocks would lie in up to ten of them)
+    live = {j.key for j in jobs[90:100]}
+    touched = [i for i, g in enumerate(want)
+               if any(j.key in live for j in g)]
+    assert touched == list(range(touched[0], touched[-1] + 1))
+    assert len(touched) <= 4 and 3 * len(touched) <= len(want)
+
+
+def test_an_early_quit_gives_its_look_ahead_back(corpus, app):
+    """A search that fills its limit in the first group leaves a
+    look-ahead staging the next: its pin goes when it finishes, and the
+    answer is `limit` true matches."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = op.build({"op": "search", "variants": 1, "limit": 5,
+                     "tags": {"http.method": {"fixed": "GET"}}},
+                    corpus["manifest"], np.random.default_rng(7))
+    for _ in range(3):
+        a = ask(api, r)
+        ok, why = op.check(r, a, corpus["manifest"])
+        assert ok, why
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    with batcher._lock:
+        assert batcher._cache_total <= BUDGET
+
+
+def test_the_prepare_memo_outlives_the_hbm_copy(corpus, app):
+    """A tenant-wide search twice over 12 groups against a budget of 2:
+    the second pass stages ten of them again and compiles nothing, its
+    memo came back from the host tier with the group (without the
+    predicate's uploaded tables, which were HBM), and the answer is the
+    reference's both times."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 10, variants=1), 0, 24)
+    first = ask(api, r)
+    staged = events("miss")
+    hit, miss = (obs.prepare_memo.value(result=k) for k in ("hit", "miss"))
+    second = ask(api, r)
+    for a in (first, second):
+        ok, why = op.check(r, a, corpus["manifest"])
+        assert ok, why
+    assert events("miss") - staged >= BLOCKS // 4 - 2
+    assert obs.prepare_memo.value(result="miss") == miss
+    assert obs.prepare_memo.value(result="hit") - hit == BLOCKS // 4
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    with batcher._lock:
+        kept = [h.query_memo for h in batcher._host_cache.values()
+                if h.query_memo is not None]
+        assert kept and all("device_params" not in pre
+                            for memo in kept for pre in memo.values())
+        assert batcher._cache_total == sum(
+            c.nbytes for c in batcher._cache.values()) <= BUDGET
+
+
+def test_a_restage_says_what_it_moved(corpus, app):
+    """The instrumentation of a re-stage: `batcher.place` spans carry
+    the bytes of the put, `batcher.stage` says `hbm_miss_host_hit`, the
+    evicted bytes are counted, and the readers of the cell find them."""
+    from chipbench.tests.test_span_layers import reader
+    from chipbench.run import span_dicts
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 8, variants=1), 0, 24)
+    assert ask(api, r)["status"] == 200          # cold: from the store
+    gone = obs.hbm_evicted_bytes.value()
+    collector = tracing.CollectExporter()
+    tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
+    try:
+        assert ask(api, r)["status"] == 200      # again: from the host tier
+    finally:
+        tracing.set_tracer(None)
+    assert settle(app.reader_db.batcher) == 0
+    spans = span_dicts(collector.spans)
+    places = [s for s in spans if s["name"] == "batcher.place"]
+    searches = {s["span_id"] for s in spans if s["name"] == "batcher.Search"}
+    assert places and all(s["parent_id"] in searches for s in places)
+    assert all(s["attributes"]["bytes"] > 400_000
+               and s["attributes"]["blocks"] == 4 for s in places)
+    restaged = [s for s in spans if s["name"] == "batcher.stage"
+                and s["attributes"]["cache"] == "hbm_miss_host_hit"]
+    assert len(restaged) >= len(places) >= BLOCKS // 4 - 2
+    assert obs.hbm_evicted_bytes.value() - gone >= sum(
+        s["attributes"]["bytes"] for s in places) - 2 * 600_000
+    run = {"spans": spans}
+    assert reader("h2d_gbytes_per_s.evict")(run) > 0
+    assert reader("restage_ms.evict")(run) > 0
+
+
+def _decoded(seed, geometry):
+    """A block as the store gives it back: every array a view of one
+    decoded buffer (`ColumnarPages.from_bytes`)."""
+    from tempo_tpu.search.columnar import ColumnarPages
+    from tempo_tpu.search.data import SearchData
+
+    rng = random.Random(seed)
+    entries = []
+    for i in range(40):
+        sd = SearchData(trace_id=bytes([seed, i]) * 8)
+        sd.start_s = 1_600_000_000 + i
+        sd.end_s = sd.start_s + rng.randint(0, 10)
+        sd.dur_ms = rng.randint(1, 30_000)
+        sd.root_service = rng.choice(["frontend", "checkout", "cart"])
+        sd.root_name = "GET /"
+        sd.kvs = {"service.name": {sd.root_service},
+                  "region": {rng.choice(["us-east-1", "eu-west-1"])}}
+        entries.append(sd)
+    return ColumnarPages.from_bytes(
+        ColumnarPages.build(entries, geometry).to_bytes())
+
+
+@pytest.mark.parametrize("layout", ("stacked", "one_block", "packed"))
+def test_a_host_tier_entry_holds_each_column_once(layout, monkeypatch):
+    """Stacked in the plain layout, the entry's blocks read the stacked
+    columns from the stacked copy and own the rest (trace ids, root
+    names), so no block pins its decoded buffer beside the copy: the
+    entry is charged the copy and what the blocks own, and the values
+    are the source's. One block alone is served as views of itself and a
+    packed layout has other values: both keep their blocks as they
+    came."""
+    from tempo_tpu.search import packing
+    from tempo_tpu.search.columnar import PageGeometry
+    from tempo_tpu.search.multiblock import _STACKED, stack_host
+
+    if layout == "packed":
+        monkeypatch.setattr(packing.PACKING, "enabled", True)
+    source = [_decoded(s, PageGeometry(8, 8))
+              for s in range(1 if layout == "one_block" else 3)]
+    host = stack_host(source)
+    if layout != "stacked":
+        if layout == "packed":
+            assert host.widths is not None
+        assert host.aliased_nbytes == 0
+        assert all(b is s for b, s in zip(host.blocks, source))
+        return
+    own = 0
+    for b, s, off in zip(host.blocks, source, host.page_offset):
+        assert b is not s and b.val_dict is s.val_dict
+        assert b._dict_section_sha == s._dict_section_sha   # memos come along
+        for name, _ in s._ARRAYS:
+            got, want = getattr(b, name), getattr(s, name)
+            assert np.array_equal(got, want), name
+            if name in _STACKED:
+                assert np.shares_memory(got, host.cat[name]), name
+            else:
+                assert got.base is None and not np.shares_memory(got, want)
+                own += got.nbytes
+    assert host.aliased_nbytes == sum(
+        getattr(b, n).nbytes for b in host.blocks for n in _STACKED)
+    assert host.nbytes == host.cat_nbytes + own
+    assert host.nbytes < sum(s.nbytes for s in source)
