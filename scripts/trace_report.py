@@ -30,7 +30,11 @@ line and writes it to
                `host_hit`, `host_miss`, `host_evict`), bytes evicted,
                the HBM gauge, its high water and the host tier's, the
                `h2d` stage, `batcher.stage` by `cache` and the
-               `batcher.place` spans, completed searches by template
+               `batcher.place` spans, completed searches by template;
+               the groups searches took by the rule that chose each
+               (`tempo_search_group_picks_total{pick}`: `resident`,
+               `joined` another search's put, `staged` it), their
+               shares, and puts per completed search
   enqueue_split  what the kernel call of a solo mesh launch, which is what
                the collective lock is held for, costs the host, by where
                the query's parameters are (jit places arguments in C++,
@@ -269,8 +273,18 @@ def staging_facts(view: dict) -> dict:
             by_cache.setdefault(s["attributes"].get("cache"), []).append(
                 (s["end_ns"] - s["start_ns"]) / 1e6)
     places = [s for s in view["spans"] if s["name"] == "batcher.place"]
+    # absent on a tree that has no such counter: the three read 0
+    picks = {k: delta(view, "tempo_search_group_picks_total", pick=k)
+             for k in ("resident", "joined", "staged")}
+    visits = sum(picks.values())
+    done = sum(r["status"] == 200 for r in view["records"])
     return {
         "events_in_window": {k: delta(view, cache, result=k) for k in kinds},
+        "picks_in_window": picks,
+        "pick_shares": ({k: v / visits for k, v in picks.items()}
+                        if visits else None),
+        "puts_per_search": (delta(view, cache, result="miss") / done
+                            if done else None),
         "events_since_start": {k: metric_sum(after, cache, result=k)
                                for k in kinds},
         # absent on a tree that has no such counter or gauge: they read 0

@@ -378,6 +378,13 @@ mesh_param_placements = Counter(
     "launch's stacked tables); never moves off a mesh")
 batch_cache_events = Counter("tempo_search_batch_cache_events_total",
                              "staged-batch HBM cache hits/misses/evictions")
+group_picks = Counter(
+    "tempo_search_group_picks_total",
+    "groups a search took on the device route, by the rule that chose "
+    "each from the cache as it was at that step: pick=resident (in HBM), "
+    "pick=joined (none resident; it waited on a put another search had "
+    "under way) or pick=staged (neither: this search, or its own "
+    "look-ahead, staged it)")
 coalesced_queries = Counter(
     "tempo_search_coalesced_queries_total",
     "queries served through fused multi-query scan dispatches; the "

@@ -775,11 +775,12 @@ class BlockBatcher:
         self._prune_cache: OrderedDict = OrderedDict()
         self._plan_cache: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
-        # staging lookahead: stages group i+1 while group i's kernel
-        # runs, overlapping H2D with compute (double-buffering). More
-        # than one thread so CONCURRENT searches' lookaheads don't
-        # serialize behind each other (each search still submits one at
-        # a time; _staged dedupes racing stages)
+        # staging lookahead: stages a search's next missing group while
+        # the groups it takes are scanned, overlapping H2D with compute
+        # (double-buffering). More than one thread so CONCURRENT
+        # searches' lookaheads don't serialize behind each other (each
+        # search still submits one at a time; _staged dedupes racing
+        # stages)
         import concurrent.futures
         self._prefetcher = concurrent.futures.ThreadPoolExecutor(
             max_workers=4, thread_name_prefix="stage-prefetch")
@@ -1051,6 +1052,18 @@ class BlockBatcher:
             with self._lock:
                 self._unpin_locked((entry,))
 
+    def _resident_locked(self, key: tuple, pin: bool):
+        """The group's resident entry, touched, counted as a hit and,
+        with `pin`, pinned; None where the group is not resident —
+        caller holds self._lock."""
+        hit = self._cache.get(key)
+        if hit is not None:
+            self._cache.move_to_end(key)
+            obs.batch_cache_events.inc(result="hit")
+            if pin:
+                hit.pins += 1
+        return hit
+
     def _staged(self, group: list[ScanJob], pin: bool = False,
                 parent=None) -> _CachedBatch:
         """The group's staged batch, from the HBM cache or staged now.
@@ -1063,12 +1076,8 @@ class BlockBatcher:
         key = tuple(j.key for j in group)
         while True:
             with self._lock:
-                hit = self._cache.get(key)
+                hit = self._resident_locked(key, pin)
                 if hit is not None:
-                    self._cache.move_to_end(key)
-                    obs.batch_cache_events.inc(result="hit")
-                    if pin:
-                        hit.pins += 1
                     return hit
                 ev = self._staging.get(key)
                 if ev is None:
@@ -1372,13 +1381,13 @@ class BlockBatcher:
         # plan is final: declare which batches this search will scan so
         # the coalescer can tell a real same-batch peer from an unrelated
         # concurrent search (which must not make us wait out a window)
+        gkeys = [tuple(j.key for j in g) for g in groups]
         with self._lock:
             self._unplanned -= 1
             planned[0] = True
-            for g in groups:
-                k = tuple(j.key for j in g)
+            for k in gkeys:
                 self._interest[k] = self._interest.get(k, 0) + 1
-                interest.append(k)
+            interest.extend(gkeys)
         inflight: deque = deque()
         dispatches = 0
         # per-stage wall time for the LAST search, exposed at /debug/scan
@@ -1390,22 +1399,19 @@ class BlockBatcher:
                   "dispatch": 0.0, "drain": 0.0, "host_fallback": 0.0}
         t_search0 = tracing.now_ns()
 
-        def book(stage, t0, gi, group, key=None, val=None):
+        def book(stage, t0, gi, **attrs):
             """One stage interval ends now. Its seconds go to the stage
             sums; in a traced search the same two stamps make the
             `batcher.<stage>` child of `batcher.Search` (`span`, bound
-            below, before any stage runs)."""
+            below, before any stage runs). `gi` is the group's index in
+            the plan, whenever the walk took it."""
             t1 = tracing.now_ns()
             stages[stage] += (t1 - t0) / 1e9
             if span.recording:
-                sp = tracing.start_span(
+                tracing.record_span(
                     "batcher.stage" if stage == "staging"
-                    else "batcher." + stage,
-                    parent=span.context, start_ns=t0, group=gi,
-                    blocks=len(group))
-                if key is not None:
-                    sp.set_attribute(key, val)
-                sp.end(t1)
+                    else "batcher." + stage, t0, t1, parent=span.context,
+                    group=gi, blocks=len(groups[gi]), **attrs)
 
         def release(cached):
             """This search is done with `cached`: its pin goes, and with
@@ -1471,9 +1477,7 @@ class BlockBatcher:
                 # each member's drain resubmits its own query here.
                 # book_skips=False: the main loop already counted this
                 # group's skipped blocks/reasons at prepare time.
-                host_route(gi, cached.jobs, gkey,
-                           hdr_reasons_for(gi, cached.jobs),
-                           book_skips=False)
+                host_route(gi, book_skips=False)
                 return
             t1d = tracing.now_ns()
             d2h_s = (t1d - t0d) / 1e9
@@ -1625,7 +1629,7 @@ class BlockBatcher:
         # state
         want_agg = ANALYTICS.enabled and agg_requested(req)
 
-        def host_route(gi, group, gkey, hdr_reasons, book_skips=True):
+        def host_route(gi, book_skips=True):
             """Scan one group ENTIRELY on the host path: this member is
             not the group's owner (owner-routed HBM), the breaker is
             open/half-open without a probe token, or this group's device
@@ -1640,6 +1644,7 @@ class BlockBatcher:
             re-booking would inflate skipped_blocks and break the
             wedged-vs-healthy identity whenever a block dict-prunes."""
             t0 = tracing.now_ns()
+            group, gkey, hdr_reasons = groups[gi], gkeys[gi], reasons[gi]
             try:
                 host = self._host_batch(group)
                 skip = [r is not None for r in hdr_reasons]
@@ -1720,82 +1725,188 @@ class BlockBatcher:
                 if agg_counts:
                     results.add_agg(mq.agg_stage.decode(agg_counts[0]))
             finally:
-                book("host_fallback", t0, gi, group)
+                book("host_fallback", t0, gi)
 
-        def hdr_reasons_for(gi, group):
-            """Header-only prune BEFORE staging: a decidably-dead group
-            (time window, tag rollup) costs no IO and no HBM. Returns
-            the per-job skip REASON list (None = scan it) — truthiness
-            keeps `all(...)`/`any(...)` semantics of the old bool list
-            while the why survives into the query stats. Memoized so
-            repeats are O(1): only a miss, where the headers are read,
-            writes a `batcher.header_prune` span."""
+        # what the header prune decided, by plan index, once it is known
+        # to this search: the per-job skip REASON list (None = scan the
+        # job), and whether the group is live (a group whose every job
+        # has a reason is dead and costs no IO and no HBM). Decided
+        # lazily, as far as the walk has to look: a search that fills
+        # its limit in its first groups never reads the headers of the
+        # rest
+        reasons: list = [None] * len(groups)
+        live: list = [None] * len(groups)
+
+        def header_known_locked(gi):
+            """Is group `gi` live, if this search, or the memo of an
+            earlier one with its predicate, has decided it; else None —
+            caller holds self._lock."""
+            if live[gi] is None:
+                why = self._prune_cache.get((gkeys[gi], sig))
+                if why is not None:
+                    self._prune_cache.move_to_end((gkeys[gi], sig))
+                    reasons[gi], live[gi] = why, not all(why)
+            return live[gi]
+
+        def decide_header(gi):
+            """Header-only prune of a group no memo knows: read its
+            blocks' headers (time window, duration rollup) and keep the
+            answer for every later search with this predicate. Only
+            this, a miss, writes a `batcher.header_prune` span."""
             t0 = tracing.now_ns()
-            gkey = tuple(j.key for j in group)
+            why = [block_header_skip_reason(j.header, req)
+                   for j in groups[gi]]
+            reasons[gi], live[gi] = why, not all(why)
             with self._lock:
-                reasons = self._prune_cache.get((gkey, sig))
-                if reasons is not None:
-                    self._prune_cache.move_to_end((gkey, sig))
-            if reasons is not None:
-                stages["header_prune"] += (tracing.now_ns() - t0) / 1e9
-                return reasons
-            reasons = [block_header_skip_reason(j.header, req)
-                       for j in group]
-            with self._lock:
-                self._prune_cache[(gkey, sig)] = reasons
+                self._prune_cache[(gkeys[gi], sig)] = why
                 while len(self._prune_cache) > _PRUNE_CACHE_MAX:
                     self._prune_cache.popitem(last=False)
-            book("header_prune", t0, gi, group)
-            return reasons
+            book("header_prune", t0, gi)
 
-        def submit_prefetch(from_idx):
-            """One-slot staging lookahead: stage the NEXT live group in a
-            background thread while this group's kernel runs — H2D
-            overlaps compute (double-buffering; _staged's dedupe makes a
-            racing inline stage safe). The cache event is judged NOW:
-            by the time the main loop reaches a prefetched group, the
-            prefetch has inserted it into the caches and residency
-            would misread this query's own cold stage as a hit."""
-            if robustness.BREAKER.blocking():
-                return  # no lookahead H2D at a blocked device
-            for gi in range(from_idx, len(groups)):
-                g = groups[gi]
-                if all(hdr_reasons_for(gi, g)):
-                    continue
-                k = tuple(j.key for j in g)
-                if OWNERSHIP.enabled:
-                    if not OWNERSHIP.owns_group(k):
-                        continue  # non-owned: host route, never staged
+        def owned(gi):
+            """Is group `gi` this member's to hold in HBM: one owned
+            elsewhere takes the host route, in plan order, and is never
+            resident, joined or staged ahead."""
+            if OWNERSHIP.enabled:
+                return OWNERSHIP.owns_group(gkeys[gi])
+            return True
+
+        def miss_event_locked(gkey):
+            return ("hbm_miss_host_hit" if gkey in self._host_cache
+                    else "hbm_miss_cold")
+
+        def claim_locked(gi, resident):
+            """The walk takes group `gi` — caller holds self._lock, the
+            one that chose it. Returns (gi, pick, entry, future, event):
+            `pick` is who pays the put, nobody (`resident`), another
+            search (`joined`) or this one (`staged`: here, or by its
+            look-ahead, `future`); `entry` the resident entry, pinned,
+            or None (stage it); `event` the cache event as this search
+            saw it (the global counters cannot say whose re-stage it
+            was). A look-ahead's group keeps the event judged when it
+            was submitted: the look-ahead has since inserted the batch,
+            and residency now would report this search's own cold stage
+            as a hit."""
+            gkey = gkeys[gi]
+            fut, event = prefetched.pop(gkey, (None, None))
+            if fut is not None and not fut.cancel():
+                return gi, "staged", None, fut, event
+            # no look-ahead, or one that never ran: as if unasked
+            if resident:
+                entry = self._resident_locked(gkey, pin=True)
+                pinned.append(entry)
+                return gi, "resident", entry, None, "hbm_hit"
+            pick = "joined" if gkey in self._staging else "staged"
+            return gi, pick, None, None, miss_event_locked(gkey)
+
+        def take_next():
+            """The search's next group, chosen from the cache as it is
+            NOW, not as it was when the search began: of the live groups
+            it has not taken, the first in plan order that is resident;
+            if none is, the first that another thread is staging (the
+            search waits on that put, `_staged` does not make a second);
+            else the first in plan order, staged by this search.
+            Concurrent searches over a tenant larger than the budget so
+            walk towards what is resident and share each other's puts,
+            where each walking a list fixed at its start staged the
+            same group once apiece and lost, to the others' evictions,
+            residents it had not reached. A resident pick is pinned
+            under the lock that chose it. Where every group is resident
+            this yields plan order at every step. Dead groups the scan
+            passes are booked as skipped and leave `remaining`. Returns
+            what `claim_locked` does, or None where no live group
+            remains."""
+            while True:
+                dead, undecided, taken = [], None, None
                 with self._lock:
-                    resident = k in self._cache
-                    host_res = k in self._host_cache
-                if not resident and k not in prefetched:
-                    prefetched[k] = (
-                        self._prefetcher.submit(
-                            self._staged, g, True,
-                            span.context if span.recording else None),
-                        "hbm_miss_host_hit" if host_res
-                        else "hbm_miss_cold")
-                return
+                    blocked = robustness.BREAKER.blocking()
+                    resident = first = joined = None
+                    for i in remaining:
+                        alive = header_known_locked(i)
+                        if alive is None:
+                            undecided = i
+                            break
+                        if not alive:
+                            dead.append(i)
+                            continue
+                        if first is None:
+                            first = i
+                        if blocked:
+                            break   # the host route: plan order, no pin
+                        if not owned(i):
+                            continue
+                        if gkeys[i] in self._cache:
+                            resident = i
+                            break
+                        if joined is None and (gkeys[i] in self._staging
+                                               or gkeys[i] in prefetched):
+                            joined = i
+                    if undecided is None and first is not None:
+                        taken = (claim_locked(resident, True)
+                                 if resident is not None else
+                                 claim_locked(first if joined is None
+                                              else joined, False))
+                for i in dead:
+                    remaining.remove(i)
+                    results.metrics.skipped_blocks += len(reasons[i])
+                    if qs is not None:
+                        for r in reasons[i]:
+                            qs.add_skip(r)
+                if undecided is not None:
+                    decide_header(undecided)
+                    continue
+                if taken is not None:
+                    remaining.remove(taken[0])
+                return taken
 
-        # HBM-resident groups dispatch FIRST: an evicted group's re-stage
-        # (H2D-bound) then overlaps the residents' scans via the
-        # lookahead instead of serializing in front of them — and an
-        # early-quit on the limit can skip the transfer entirely.
-        # Deliberate tradeoff: under
-        # an early-quit the SCANNED subset (and so the returned set when
-        # limit truncates) depends on cache residency — same stance as
-        # the reference's goroutine fan-out, where the quit channel
-        # freezes whichever jobs happened to finish first
-        # (modules/frontend/searchsharding.go + results.go quit).
-        with self._lock:
-            _res = set(self._cache)
-        if 0 < len(_res):
-            groups = sorted(
-                groups, key=lambda g: tuple(j.key for j in g) not in _res)
+        def submit_prefetch():
+            """One-slot staging look-ahead: once no group the search has
+            not taken is resident, stage in a background thread the
+            first of them that nobody is staging, while the group just
+            taken is scanned (H2D overlaps compute). Not before: while
+            the walk has residents to take, a put would make the LRU
+            drop a group, as likely as not one this search has not
+            reached, and whoever stages the missing group meanwhile
+            stages it for this search too (on a v5e a look-ahead from
+            the search's first step made 0.98 puts a search where this
+            makes 0.39, PERF.md section 6). A resident group whose
+            headers nobody has read counts as one the walk may take.
+            One slot: nothing new is asked for until the walk took what
+            the last one staged. The group is pinned by the put
+            (`_staged(pin=True)`), for the search that asked."""
+            if prefetched or robustness.BREAKER.blocking():
+                return  # no lookahead H2D at a blocked device
+            while True:
+                with self._lock:
+                    gi = None
+                    for i in remaining:
+                        if header_known_locked(i) is False or not owned(i):
+                            continue
+                        if gkeys[i] in self._cache:
+                            return
+                        if gi is None and gkeys[i] not in self._staging:
+                            gi = i
+                    if gi is None:
+                        return
+                    event = miss_event_locked(gkeys[gi])
+                if live[gi]:
+                    break
+                decide_header(gi)
+            prefetched[gkeys[gi]] = (
+                self._prefetcher.submit(
+                    self._staged, groups[gi], True,
+                    span.context if span.recording else None),
+                event)
 
         with tracing.start_span("batcher.Search") as span:
-            for gi, group in enumerate(groups):
+            # plan indices not taken yet, in plan order. Under an early
+            # quit the SCANNED subset (and so the returned set when limit
+            # truncates) depends on cache residency at each step — same
+            # stance as the reference's goroutine fan-out, where the quit
+            # channel freezes whichever jobs happened to finish first
+            # (modules/frontend/searchsharding.go + results.go quit)
+            remaining = list(range(len(groups)))
+            while remaining:
                 if results.complete:
                     break
                 if robustness.deadline.expired():
@@ -1805,14 +1916,11 @@ class BlockBatcher:
                     results.metrics.partial = True
                     obs.partial_results.inc(reason="deadline")
                     break
-                gkey = tuple(j.key for j in group)
-                hdr_reasons = hdr_reasons_for(gi, group)
-                if all(hdr_reasons):
-                    results.metrics.skipped_blocks += len(group)
-                    if qs is not None:
-                        for r in hdr_reasons:
-                            qs.add_skip(r)
-                    continue
+                taken = take_next()
+                if taken is None:
+                    break   # what remained was dead
+                gi, pick, cached, fut_staged, _event = taken
+                group, gkey, hdr_reasons = groups[gi], gkeys[gi], reasons[gi]
                 if OWNERSHIP.enabled:
                     # owner-routed HBM: a group this member doesn't own
                     # serves from the byte-identical host route — a
@@ -1829,13 +1937,19 @@ class BlockBatcher:
                         obs.hbm_owner_routed.inc(route="non_owner_host")
                         if qs is not None:
                             qs.add_cache("non_owner_route")
-                        host_route(gi, group, gkey, hdr_reasons)
+                        host_route(gi)
                         continue
                 if not robustness.BREAKER.allow_device():
                     # breaker open (or half-open with its probe tokens
                     # spent): this group runs the byte-identical host
                     # route — no staging put, no device dispatch
-                    host_route(gi, group, gkey, hdr_reasons)
+                    if cached is not None:
+                        release(cached)   # it opened since the pick
+                    if fut_staged is not None:
+                        # a look-ahead from before it opened: search()'s
+                        # finally gives its pin back
+                        prefetched[gkey] = (fut_staged, _event)
+                    host_route(gi)
                     continue
                 if OWNERSHIP.enabled:
                     # counted AFTER the breaker gate: route=owner means
@@ -1848,45 +1962,29 @@ class BlockBatcher:
                 # evicted, on its host-tier entry (`HostBatch.query_memo`):
                 # it dies with the last of the two
                 t0 = tracing.now_ns()
-                pf = prefetched.pop(gkey, None)
-                fut_staged, pf_event = pf if pf is not None else (None, None)
-                _event = None
-                if qs is not None or span.recording:
-                    # cache behavior as THIS query saw it (the global
-                    # batch_cache_events counters can't say whose re-stage
-                    # it was). A prefetched group carries the event judged
-                    # at SUBMIT time — its own lookahead has since
-                    # inserted the batch, so reading residency here would
-                    # report this query's cold stage as a hit.
-                    if pf_event is not None:
-                        _event = pf_event
-                    else:
-                        with self._lock:
-                            _event = ("hbm_hit" if gkey in self._cache
-                                      else ("hbm_miss_host_hit"
-                                            if gkey in self._host_cache
-                                            else "hbm_miss_cold"))
-                try:
-                    # pinned from here (a look-ahead took its pin when it
-                    # staged) until this group's own drain
-                    cached = (fut_staged.result()
-                              if fut_staged is not None
-                              else self._staged(group, pin=True))
-                except robustness.DeviceFault:
-                    # the staging H2D hit the wedged device (fault
-                    # booked): host tier already holds the stacked
-                    # arrays, answer from there
-                    book("staging", t0, gi, group)
-                    host_route(gi, group, gkey, hdr_reasons)
-                    continue
-                book("staging", t0, gi, group, "cache", _event)
+                if cached is None:
+                    try:
+                        # pinned from here (a look-ahead took its pin
+                        # when it staged) until this group's own drain
+                        cached = (fut_staged.result()
+                                  if fut_staged is not None
+                                  else self._staged(group, pin=True))
+                    except robustness.DeviceFault:
+                        # the staging H2D hit the wedged device (fault
+                        # booked): host tier already holds the stacked
+                        # arrays, answer from there
+                        book("staging", t0, gi)
+                        host_route(gi)
+                        continue
+                    pinned.append(cached)
+                book("staging", t0, gi, cache=_event, pick=pick)
+                obs.group_picks.inc(pick=pick)
                 if qs is not None:
                     qs.add_cache(_event)
                     if _event != "hbm_hit" and cached.batch.staged_dicts:
                         qs.add_cache("probe_dict_staged",
                                      len(cached.batch.staged_dicts))
-                pinned.append(cached)
-                submit_prefetch(gi + 1)
+                submit_prefetch()
                 with self._lock:
                     pre = cached.query_cache.get(sig)
                     if pre is not None:
@@ -1904,8 +2002,7 @@ class BlockBatcher:
                         pre = prepare(group, cached.batch,
                                       [r is not None for r in hdr_reasons],
                                       hdr_reasons)
-                    book("prepare", t0, gi, group, "terms",
-                         pre.get("n_terms", 0))
+                    book("prepare", t0, gi, terms=pre.get("n_terms", 0))
                     with self._lock:
                         cached.query_cache[sig] = pre
                         while len(cached.query_cache) > _QUERY_CACHE_MAX:
@@ -1986,12 +2083,11 @@ class BlockBatcher:
                         # skips were already counted above, so the
                         # resubmit must not re-book them. Interest for
                         # this gkey is released by the outer finally.
-                        book("dispatch", t0, gi, group)
+                        book("dispatch", t0, gi)
                         release(cached)
-                        host_route(gi, group, gkey, hdr_reasons,
-                                   book_skips=False)
+                        host_route(gi, book_skips=False)
                         continue
-                book("dispatch", t0, gi, group)
+                book("dispatch", t0, gi)
                 dispatches += 1
                 inflight.append((gi, gkey, cached, mq, pre, fut))
                 # this search never returns to this batch: release its

@@ -16,6 +16,7 @@ them), and that the plan is the same jobs whatever order they come in.
 import base64
 import json
 import random
+import threading
 import time
 import urllib.parse
 from concurrent.futures import ThreadPoolExecutor
@@ -66,7 +67,7 @@ def hunts(corpus, seed, **template):
 def windowed(requests, age_h, hours):
     """The requests over the `hours` that end `age_h` hours before the
     newest data, as `chipbench/ops/search_aged.py` places a window."""
-    end = NEWEST - age_h * HOUR
+    end = int(NEWEST - age_h * HOUR)
     out = []
     for r in requests:
         r = dict(r, ref=dict(r["ref"], start=end - hours * HOUR, end=end))
@@ -85,10 +86,7 @@ KINDS = {
 }
 
 
-@pytest.fixture
-def app(corpus, tmp_path, monkeypatch):
-    """One App on the corpus: groups of four blocks (only the cap closes
-    a group: anchors are `test_group_cap.py`'s), a budget of two."""
+def _app(corpus, tmp_path, monkeypatch, budget):
     from tempo_tpu.db.tempodb import TempoDBConfig
     from tempo_tpu.modules import App, AppConfig
 
@@ -99,8 +97,24 @@ def app(corpus, tmp_path, monkeypatch):
                  "local": {"path": corpus["dir"] + "/blocks"}},
         wal_dir=str(tmp_path / "wal"),
         db=TempoDBConfig(auto_mesh=False, search_max_batch_pages=CAP,
-                         search_batch_cache_bytes=BUDGET)))
+                         search_batch_cache_bytes=budget)))
     app.poll_tick()
+    return app
+
+
+@pytest.fixture
+def app(corpus, tmp_path, monkeypatch):
+    """One App on the corpus: groups of four blocks (only the cap closes
+    a group: anchors are `test_group_cap.py`'s), a budget of two."""
+    app = _app(corpus, tmp_path, monkeypatch, BUDGET)
+    yield app
+    app.shutdown()
+
+
+@pytest.fixture
+def roomy(corpus, tmp_path, monkeypatch):
+    """The same App with a budget that holds the whole tenant."""
+    app = _app(corpus, tmp_path, monkeypatch, 16 * BUDGET)
     yield app
     app.shutdown()
 
@@ -131,13 +145,16 @@ def settle(batcher, timeout=10.0):
     return held
 
 
+@pytest.mark.parametrize("callers", (3, 8))
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_served_answers_under_eviction_equal_the_reference(corpus, app,
-                                                           kind):
-    """Alone and then eight at a time, each kind after a tenant-wide
-    search has pushed its groups out: every answer is the reference's,
-    groups were evicted and staged again while it was made, and nothing
-    stays pinned."""
+                                                           kind, callers):
+    """Alone and then `callers` at a time (eight: eight tenant-wide
+    searches walk twelve groups through a budget of two together, each
+    by what is resident at its every step), each kind after a
+    tenant-wide search has pushed its groups out: every answer is the
+    reference's, groups were evicted and staged again while it was made,
+    and nothing stays pinned."""
     from chipbench.ops import search as op
     from tempo_tpu.api import HTTPApi
 
@@ -151,9 +168,11 @@ def test_served_answers_under_eviction_equal_the_reference(corpus, app,
     for r in requests:
         answers.append(ask(api, r))
         ask(api, flush)
-    with ThreadPoolExecutor(8) as pool:
-        answers += list(pool.map(lambda r: ask(api, r), requests * 2))
-    for r, a in zip(requests * 3, answers):
+    together = [requests[i % len(requests)]
+                for i in range(max(callers, 2 * len(requests)))]
+    with ThreadPoolExecutor(callers) as pool:
+        answers += list(pool.map(lambda r: ask(api, r), together))
+    for r, a in zip(requests + together, answers):
         ok, why = op.check(r, a, corpus["manifest"])
         assert ok, (kind, r["path"], why)
     assert events("evict") > evicted and events("miss") > missed
@@ -381,6 +400,291 @@ def test_a_restage_says_what_it_moved(corpus, app):
     run = {"spans": spans}
     assert reader("h2d_gbytes_per_s.evict")(run) > 0
     assert reader("restage_ms.evict")(run) > 0
+
+
+def traced(api, request):
+    """One request with a tracer installed: its answer and the groups
+    its search took, in the order it took them, as (plan index, `pick`,
+    `cache`) of its `batcher.stage` spans."""
+    collector = tracing.CollectExporter()
+    tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
+    try:
+        answer = ask(api, request)
+    finally:
+        tracing.set_tracer(None)
+    stages = sorted((s for s in collector.spans if s.name == "batcher.stage"),
+                    key=lambda s: s.start_ns)
+    return answer, [(s.attributes["group"], s.attributes["pick"],
+                     s.attributes["cache"]) for s in stages]
+
+
+def wait_until(condition, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while not condition():
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+
+
+def picks():
+    return {k: obs.group_picks.value(pick=k)
+            for k in ("resident", "joined", "staged")}
+
+
+def plan_of(app):
+    """The tenant's groups in plan order (a search has made the plan)."""
+    (*_job_lists, groups), = app.reader_db._breq_jobs_cache.values()
+    return groups, [tuple(j.key for j in g) for g in groups]
+
+
+@pytest.mark.parametrize("quits", (False, True), ids=("to-the-end", "on-limit"))
+def test_with_every_group_resident_the_walk_is_plan_order(corpus, roomy,
+                                                          quits):
+    """A budget that holds the tenant: rule 1 finds the first remaining
+    group resident at every step, so a search takes its groups in plan
+    order, as the walk over a list sorted once at its start did; one
+    that fills its limit stops where that one stopped, a few groups in,
+    and the look-ahead has nothing to stage."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(roomy, multitenancy=True)
+    if quits:
+        (r,) = op.build({"op": "search", "variants": 1, "limit": 5,
+                         "tags": {"http.method": {"fixed": "GET"}}},
+                        corpus["manifest"], np.random.default_rng(7))
+    else:
+        (r,) = windowed(hunts(corpus, 12, variants=1), 0, 24)
+    flush = windowed(hunts(corpus, 99, variants=1), 0, 24)[0]
+    assert ask(api, flush)["status"] == 200     # stages all twelve, once
+    staged, evicted = events("miss"), events("evict")
+    before = picks()
+    a, walk = traced(api, r)
+    ok, why = op.check(r, a, corpus["manifest"])
+    assert ok, why
+    n = BLOCKS // 4
+    assert [g for g, _p, _c in walk] == list(range(len(walk)))
+    assert len(walk) <= roomy.reader_db.batcher.pipeline_depth + 1 \
+        if quits else len(walk) == n
+    assert {(p, c) for _g, p, c in walk} == {("resident", "hbm_hit")}
+    assert picks() == dict(before, resident=before["resident"] + len(walk))
+    assert (events("miss"), events("evict")) == (staged, evicted)
+    assert settle(roomy.reader_db.batcher) == 0
+
+
+def test_a_group_that_arrives_is_taken_before_one_to_be_staged(
+        corpus, app, monkeypatch):
+    """Groups 10 and 11 resident, a tenant-wide search under way, and
+    group 9 put into HBM by someone else while it stages its first
+    missing group: the search takes 9 next, as a resident, before any
+    of the eight it would have to stage (a list fixed at its start had
+    it last of twelve, after nine puts)."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 13, variants=1), 0, 24)
+    assert ask(api, r)["status"] == 200           # leaves 10 and 11
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    groups, gkeys = plan_of(app)
+    with batcher._lock:
+        assert set(batcher._cache) == set(gkeys[10:])
+    real, guest = batcher._staged, []
+
+    def staged(group, pin=False, parent=None):
+        """The search's first put brings group 9 with it, pinned."""
+        entry = real(group, pin, parent)
+        if not guest:
+            guest.append(real(groups[9], True))
+        return entry
+
+    monkeypatch.setattr(batcher, "_staged", staged)
+    missed = events("miss")
+    a, walk = traced(api, r)
+    ok, why = op.check(r, a, corpus["manifest"])
+    assert ok, why
+    with batcher._lock:
+        batcher._unpin_locked(guest)
+    order = [g for g, _p, _c in walk]
+    assert sorted(order) == list(range(12))
+    assert order == [10, 11, 0, 9] + list(range(1, 9))
+    assert walk[3][1:] == ("resident", "hbm_hit")
+    assert events("miss") - missed == 10      # 0 to 8, and the guest
+    assert settle(batcher) == 0
+
+
+def test_a_group_another_search_is_staging_is_joined(corpus, app,
+                                                     monkeypatch):
+    """Two searches of one hour that lies inside group 0, the second
+    begun while the first's put is under way: the second waits on that
+    put (`pick=joined`) and the group is staged once for the two."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    first, second = windowed(hunts(corpus, 14, variants=2), 22.5, 1)
+    flush = windowed(hunts(corpus, 99, variants=1), 0, 24)[0]
+    assert ask(api, flush)["status"] == 200       # group 0 is long gone
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    place, real = batcher.engine.place, batcher._staged
+    gate, waiting = threading.Event(), threading.Event()
+
+    def staged(group, pin=False, parent=None):
+        if batcher._staging:        # the second search, about to wait
+            waiting.set()
+        return real(group, pin, parent)
+
+    def held(host):
+        assert gate.wait(30)
+        return place(host)
+
+    monkeypatch.setattr(batcher, "_staged", staged)
+    monkeypatch.setattr(batcher.engine, "place", held)
+    before, missed = picks(), events("miss")
+    visits = events("hit") + missed
+    with ThreadPoolExecutor(2) as pool:
+        one = pool.submit(ask, api, first)
+        wait_until(lambda: batcher._staging)
+        two = pool.submit(ask, api, second)
+        assert waiting.wait(30)
+        gate.set()
+        answers = [one.result(), two.result()]
+    for r, a in zip((first, second), answers):
+        ok, why = op.check(r, a, corpus["manifest"])
+        assert ok, why
+    assert events("miss") - missed == 1
+    assert events("hit") + events("miss") - visits == 2
+    assert picks() == dict(before, staged=before["staged"] + 1,
+                           joined=before["joined"] + 1)
+    assert settle(batcher) == 0
+
+
+def test_the_look_ahead_stages_only_what_nobody_holds_or_stages(
+        corpus, app, monkeypatch):
+    """Groups 10 and 11 resident and group 9's put held by another
+    thread while a tenant-wide search runs: the look-ahead asks for
+    nothing while the walk has a resident to take, then for one group at
+    a time, in plan order, never for a resident one nor for group 9; the
+    search takes group 9 last, by joining that put, and every group was
+    staged once."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 15, variants=1), 0, 24)
+    assert ask(api, r)["status"] == 200           # leaves 10 and 11
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    groups, gkeys = plan_of(app)
+    place, real, submit = (batcher.engine.place, batcher._staged,
+                           batcher._prefetcher.submit)
+    gate, asked, guest = threading.Event(), [], []
+    with batcher._lock:
+        theirs = batcher._host_cache[gkeys[9]]
+
+    def held(host):
+        if host is theirs:
+            assert gate.wait(30)
+        return place(host)
+
+    def staged(group, pin=False, parent=None):
+        if group is groups[9] and threading.current_thread().name != "other":
+            gate.set()              # the search came to join: let it land
+        return real(group, pin, parent)
+
+    def ahead(fn, group, *args):
+        key = tuple(j.key for j in group)
+        with batcher._lock:
+            asked.append((gkeys.index(key), key in batcher._cache,
+                          key in batcher._staging,
+                          sum(c.pins for c in batcher._cache.values())))
+        return submit(fn, group, *args)
+
+    monkeypatch.setattr(batcher.engine, "place", held)
+    monkeypatch.setattr(batcher, "_staged", staged)
+    monkeypatch.setattr(batcher._prefetcher, "submit", ahead)
+    other = threading.Thread(
+        target=lambda: guest.append(real(groups[9], True)), name="other")
+    other.start()
+    wait_until(lambda: batcher._staging)
+    missed, before = events("miss"), picks()
+    a, walk = traced(api, r)
+    other.join(30)
+    assert not other.is_alive()
+    with batcher._lock:
+        batcher._unpin_locked(guest)
+    ok, why = op.check(r, a, corpus["manifest"])
+    assert ok, why
+    assert [g for g, _p, _c in walk] == [10, 11] + list(range(10))
+    assert [p for _g, p, _c in walk] == (["resident"] * 2 + ["staged"] * 9
+                                         + ["joined"])
+    assert [g for g, *_ in asked] == list(range(9))
+    assert not any(resident or staging for _g, resident, staging, _ in asked)
+    # the first is asked for when the walk has taken its last resident
+    assert asked[0][3] == 2
+    assert events("miss") - missed == 10
+    assert {k: v - before[k] for k, v in picks().items()} == {
+        "resident": 2, "staged": 9, "joined": 1}
+    assert settle(batcher) == 0
+
+
+def test_a_look_ahead_that_never_ran_costs_nothing(corpus, app):
+    """The look-ahead's threads all busy: what the search asks for never
+    starts, the walk reaches each group first, takes the request back
+    and stages the group itself, once; nothing stays pinned."""
+    from chipbench.ops import search as op
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    (r,) = windowed(hunts(corpus, 16, variants=1), 0, 24)
+    assert ask(api, r)["status"] == 200
+    batcher = app.reader_db.batcher
+    assert settle(batcher) == 0
+    gate = threading.Event()
+    busy = [batcher._prefetcher.submit(gate.wait, 30) for _ in range(4)]
+    try:
+        missed, before = events("miss"), picks()
+        a, walk = traced(api, r)
+    finally:
+        gate.set()
+    assert all(f.result() for f in busy)
+    ok, why = op.check(r, a, corpus["manifest"])
+    assert ok, why
+    assert [g for g, _p, _c in walk] == [10, 11] + list(range(10))
+    assert [p for _g, p, _c in walk] == ["resident"] * 2 + ["staged"] * 10
+    assert events("miss") - missed == 10
+    assert settle(batcher) == 0
+
+
+@pytest.mark.parametrize("callers", (1, 6))
+def test_the_picks_sum_to_the_group_visits(corpus, app, callers):
+    """`tempo_search_group_picks_total` moves once a group visit: over
+    searches that run to their end its three series sum to the cache's
+    hits and misses, each `batcher.stage` span carries the series it
+    moved, and `/metrics` shows it."""
+    from tempo_tpu.api import HTTPApi
+
+    api = HTTPApi(app, multitenancy=True)
+    requests = windowed(hunts(corpus, 17, variants=callers), 0, 24)
+    before, visits = picks(), events("hit") + events("miss")
+    if callers == 1:
+        a, walk = traced(api, requests[0])
+        assert a["status"] == 200 and len(walk) == BLOCKS // 4
+        for k in before:
+            assert picks()[k] - before[k] == sum(p == k for _g, p, _c in walk)
+    else:
+        with ThreadPoolExecutor(callers) as pool:
+            assert all(a["status"] == 200 for a in pool.map(
+                lambda r: ask(api, r), requests))
+    assert settle(app.reader_db.batcher) == 0
+    moved = sum(picks().values()) - sum(before.values())
+    assert moved == callers * (BLOCKS // 4)
+    assert moved == events("hit") + events("miss") - visits
+    code, text = api.handle("GET", "/metrics", {}, {})
+    assert code == 200
+    for k in before:
+        assert f'tempo_search_group_picks_total{{pick="{k}"}}' in str(text)
 
 
 def _decoded(seed, geometry):
