@@ -35,6 +35,19 @@ line and writes it to
                (`tempo_search_group_picks_total{pick}`: `resident`,
                `joined` another search's put, `staged` it), their
                shares, and puts per completed search
+  probe        what the dictionary probe and the membership test did:
+               probes in the window and since the start by path
+               (`tempo_search_dict_probes_total{path}`: `device`,
+               `host`, `cached`), the `dict_probe` dispatches' `execute`
+               stage since the start, launch members by membership
+               (`tempo_search_scan_membership_total{path}`: `range`,
+               `mask`), the `dispatch.execute` spans by `membership`,
+               the `dict_probe.probe` spans by `path` and `membership`
+               with their longest `runs_max`, the HBM that dictionaries
+               and hit masks hold, and from the profiler's trace the
+               scan programs apart: `batch_scan_kernel` (compares only)
+               and `mask_scan_kernel` (takes `val_hits`), calls and ms a
+               launch each, and the probe program
   enqueue_split  what the kernel call of a solo mesh launch, which is what
                the collective lock is held for, costs the host, by where
                the query's parameters are (jit places arguments in C++,
@@ -316,6 +329,56 @@ def staging_facts(view: dict) -> dict:
     }
 
 
+def probe_facts(view: dict) -> dict:
+    """The `probe` block: dictionary probes and the membership test.
+    On a tree without these counters and spans everything reads 0."""
+    from chipbench.lib import metric_sum
+
+    after = view["counters"]["after"]
+    probes = "tempo_search_dict_probes_total"
+    members = "tempo_search_scan_membership_total"
+    spans = [s["attributes"] for s in view["spans"]
+             if s["name"] == "dict_probe.probe"]
+    out = {
+        "probes_in_window": {k: delta(view, probes, path=k)
+                             for k in ("device", "host", "cached")},
+        "probes_since_start": {k: metric_sum(after, probes, path=k)
+                               for k in ("device", "host", "cached")},
+        "device_probe_ms_since_start": bench_run.load_reader(
+            "layers", "probe_ms.highcard").compute(view),
+        "launch_members_in_window": {k: delta(view, members, path=k)
+                                     for k in ("range", "mask")},
+        "execute_spans_by_membership": dict(collections.Counter(
+            s["attributes"].get("membership", "absent")
+            for s in view["spans"] if s["name"] == "dispatch.execute")),
+        "probe_spans": {
+            "by_path": dict(collections.Counter(
+                a.get("path") for a in spans)),
+            "by_membership": dict(collections.Counter(
+                a.get("membership") for a in spans)),
+            "runs_max": max((a.get("runs_max", 0) for a in spans),
+                            default=None)},
+        "hbm": {"dict_bytes": metric_sum(
+            after, "tempo_search_probe_dict_bytes"),
+                "mask_bytes": metric_sum(
+            after, "tempo_search_probe_mask_bytes"),
+                "mask_peak_bytes": metric_sum(
+            after, "tempo_search_probe_mask_peak_bytes"),
+                "cache_bytes": metric_sum(
+            after, "tempo_search_hbm_cache_bytes")}}
+    trace = view.get("trace")
+    if trace:
+        for key, part in (("range_program", "batch_scan_kernel"),
+                          ("mask_program", "mask_scan_kernel"),
+                          ("probe_program", "probe_kernel")):
+            calls = sum(v for k, v in trace["program_calls"].items()
+                        if part in k)
+            ns = sum(v for k, v in trace["programs_ns"].items() if part in k)
+            out[key] = {"calls": calls,
+                        "ms_per_launch": ns / calls / 1e6 if calls else None}
+    return out
+
+
 def report(view: dict, e2e_names: list) -> dict:
     out: dict = {"workload": view["workload"], "end_to_end": {}}
     for name in e2e_names:
@@ -336,6 +399,7 @@ def report(view: dict, e2e_names: list) -> dict:
             if s["name"] == "dispatch.execute"))}
     out["mesh"] = mesh_facts(view)
     out["staging"] = staging_facts(view)
+    out["probe"] = probe_facts(view)
     traces = sp.searches(spans)
     if not traces:
         return out

@@ -450,6 +450,32 @@ host_cache_bytes = Gauge("tempo_search_host_cache_bytes",
 probe_dict_bytes = Gauge("tempo_search_probe_dict_bytes",
                          "HBM held by staged device-probe dictionaries "
                          "across resident batches (bytes)")
+probe_mask_bytes = Gauge(
+    "tempo_search_probe_mask_bytes",
+    "HBM pinned by device-probe hit masks (a term whose hits are more "
+    "than dict_probe.R_MAX runs of its sorted dictionary), "
+    "held_by=probe_cache (the [T, v_pad] products of the compile cache, "
+    "at most 8 a dictionary, charged to no budget) or held_by=memo (the "
+    "[G, T, Vmax] stacks of the batcher's prepare memo, charged to "
+    "their staged batch under search_batch_cache_bytes)")
+probe_mask_peak_bytes = Gauge(
+    "tempo_search_probe_mask_peak_bytes",
+    "high water of tempo_search_probe_mask_bytes, both holders summed, "
+    "since the process started")
+dict_probes = Counter(
+    "tempo_search_dict_probes_total",
+    "substring probes of one value dictionary at query compile, by "
+    "path=device (the staged dictionary's kernel), path=host (numpy or "
+    "the native memmem walk: dictionaries under "
+    "search_device_probe_min_vals, the breaker's host route, an "
+    "oversized needle) or path=cached (the compile cache had the "
+    "product)")
+scan_membership = Counter(
+    "tempo_search_scan_membership_total",
+    "members of scan launches by how the launch tests value "
+    "membership: path=range (compares against [lo, hi] id ranges, no "
+    "gather) or path=mask (a gather from the device probe's hit mask "
+    "for every slot of every entry); one for each launch and member")
 hbm_logical_bytes = Gauge("tempo_search_hbm_logical_bytes",
                           "unpacked-layout equivalent of the staged-batch "
                           "HBM occupancy — equals tempo_search_hbm_cache_"

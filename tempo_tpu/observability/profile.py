@@ -461,7 +461,8 @@ class DispatchProfiler:
                 span.set_attribute("bytes", rec.d2h_bytes)
             if stage in ("compile", "execute") and rec.jit is not None:
                 span.set_attribute("jit_cache", rec.jit)
-                for key in ("topk", "shards", "pages_per_shard", "params"):
+                for key in ("topk", "shards", "pages_per_shard", "params",
+                            "membership"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
             span.end(end_ns)

@@ -65,8 +65,10 @@ from .analytics import ANALYTICS, agg_requested
 from .engine import DEFAULT_TOP_K, fetch_coalesced_out, resolve_top_k, \
     start_fetch
 from .ownership import OWNERSHIP
-from .multiblock import MultiBlockEngine, compile_multi, stack_queries
-from .pipeline import block_header_skip_reason
+from .multiblock import (
+    WIDE_RANGES, MultiBlockEngine, compile_multi, stack_queries,
+)
+from .pipeline import MASK_BYTES, block_header_skip_reason, probe_summary
 from .results import SearchResults
 
 
@@ -200,6 +202,9 @@ class _CachedBatch:
     # arrays would survive via the in-flight references anyway, but the
     # budget would double-pay when the next query re-stages it
     pins: int = 0
+    # device hit masks ([G, T, Vmax] stacks) the prepare memo pins, part
+    # of `nbytes` and published as probe_mask_bytes{held_by="memo"}
+    mask_bytes: int = 0
 
 
 _QUERY_CACHE_MAX = 32
@@ -422,6 +427,19 @@ class QueryCoalescer:
             # would make the no-agg hot path's compiled shape depend on
             # whichever member happened to join the window
             key = key + ("agg",)
+        if mq.val_hits is not None:
+            # a member that brings a hit mask groups apart from those
+            # that bring ranges only: one mask in a fused launch gives
+            # every member a [G, T, Vmax] row and a gather for every
+            # slot of every entry (0.73 s a member and term for 4,096
+            # pages on a v5e, against milliseconds of compares)
+            key = key + ("mask",)
+        elif mq.val_ranges.shape[2] > WIDE_RANGES:
+            # and so do members of many ranges a term: a fused launch
+            # pads every member to its widest, and at 512 ranges the
+            # compares cost 61 ms a member and term where up to 64 they
+            # cost under 9 (PERF.md section 6, PR 33)
+            key = key + ("wide",)
         flush_now = None
         with self._lock:
             grp = self._pending.get(key)
@@ -932,6 +950,7 @@ class BlockBatcher:
         self._cache_total -= old.nbytes
         self._cache_logical -= old.logical
         self._probe_dict_total -= self._dict_bytes(old.batch)
+        MASK_BYTES.add("memo", -old.mask_bytes)
         obs.batch_cache_events.inc(result="evict")
         obs.hbm_evicted_bytes.inc(old.nbytes)
 
@@ -1122,6 +1141,7 @@ class BlockBatcher:
                     self._cache_total -= prev.nbytes
                     self._cache_logical -= prev.logical
                     self._probe_dict_total -= self._dict_bytes(prev.batch)
+                    MASK_BYTES.add("memo", -prev.mask_bytes)
                 self._cache[key] = entry
                 self._cache_total += nbytes
                 self._cache_logical += entry.logical
@@ -1203,6 +1223,7 @@ class BlockBatcher:
                 self._cache_total -= old.nbytes
                 self._cache_logical -= old.logical
                 self._probe_dict_total -= self._dict_bytes(old.batch)
+                MASK_BYTES.add("memo", -old.mask_bytes)
                 # a pending rebalance deferral for a dead block's batch
                 # is satisfied by this removal — keeping the marker
                 # would double-evict whatever re-stages under the key
@@ -1407,11 +1428,20 @@ class BlockBatcher:
             the plan, whenever the walk took it."""
             t1 = tracing.now_ns()
             stages[stage] += (t1 - t0) / 1e9
+            probes = attrs.pop("probes", None)
             if span.recording:
-                tracing.record_span(
+                child = tracing.start_span(
                     "batcher.stage" if stage == "staging"
-                    else "batcher." + stage, t0, t1, parent=span.context,
-                    group=gi, blocks=len(groups[gi]), **attrs)
+                    else "batcher." + stage, parent=span.context,
+                    start_ns=t0, group=gi, blocks=len(groups[gi]), **attrs)
+                if probes is not None:
+                    # the compile over the group's distinct dictionaries,
+                    # inside `batcher.prepare` (compile_multi's stamps)
+                    p0, p1, probed = probes
+                    tracing.record_span("dict_probe.probe", p0, p1,
+                                        parent=child.context,
+                                        **probe_summary(probed))
+                child.end(t1)
 
         def release(cached):
             """This search is done with `cached`: its pin goes, and with
@@ -1601,6 +1631,7 @@ class BlockBatcher:
                 "val_ranges": mq.val_ranges,
                 "val_hits": mq.val_hits,
                 "block_group": mq.block_group,
+                "probes": mq.probes,
                 "structural": st,
                 "n_terms": mq.n_terms,
                 "dur_lo": mq.dur_lo, "dur_hi": mq.dur_hi,
@@ -2002,12 +2033,25 @@ class BlockBatcher:
                         pre = prepare(group, cached.batch,
                                       [r is not None for r in hdr_reasons],
                                       hdr_reasons)
-                    book("prepare", t0, gi, terms=pre.get("n_terms", 0))
+                    book("prepare", t0, gi, terms=pre.get("n_terms", 0),
+                         probes=pre.pop("probes", None))
+                    # a hit mask the memo keeps is HBM like a predicate's
+                    # uploaded tables: charged to the batch, so the
+                    # budget sees it and an eviction gives it back
+                    mb = int(getattr(pre.get("val_hits"), "nbytes", 0))
+                    pre["mask_bytes"] = mb
                     with self._lock:
                         cached.query_cache[sig] = pre
+                        resident = self._cache.get(gkey) is cached
+                        cached.nbytes += mb
+                        if resident:
+                            cached.mask_bytes += mb
+                            self._cache_total += mb
+                            MASK_BYTES.add("memo", mb)
                         while len(cached.query_cache) > _QUERY_CACHE_MAX:
                             _, old = cached.query_cache.popitem(last=False)
-                            dpb = old.get("device_params_bytes", 0)
+                            dpb = (old.get("device_params_bytes", 0)
+                                   + old["mask_bytes"])
                             cached.nbytes -= dpb
                             # the shared budget only tracks batches still
                             # resident: a concurrent eviction already
@@ -2016,6 +2060,10 @@ class BlockBatcher:
                             # double-subtract and drift the budget
                             if self._cache.get(gkey) is cached:
                                 self._cache_total -= dpb
+                                cached.mask_bytes -= old["mask_bytes"]
+                                MASK_BYTES.add("memo", -old["mask_bytes"])
+                        if mb and resident:
+                            self._evict_hbm_locked()
                 if qs is not None:
                     for r, n in pre.get("skip_reasons", {}).items():
                         qs.add_skip(r, n)

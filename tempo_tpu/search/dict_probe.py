@@ -37,11 +37,18 @@ probes its contiguous value range and the per-shard hit masks all_gather
 into the replicated global mask — the same collective shape the scan
 uses for its results (multiblock._merge_shards).
 
-The probe output (a [T, V] bool mask) feeds the scan kernel directly on
-device: multiblock.multi_entry_mask tests value membership with a mask
-lookup instead of the host-compiled [T,R,2] range compares, so no id-set
-ever crosses the host boundary. (Which of mask lookup and range compare
-wins on the chip is not measured: ROADMAP R8, `highcard.substring`.)
+The probe yields a [T, V] bool hit mask and, in the same dispatch, the
+mask's runs over the sorted value ids (_hit_runs: how many a term, and
+the first R_MAX as inclusive [lo, hi] ids). Where every term's hits are
+at most R_MAX runs (an id typed whole, a prefix, a fragment of a few
+letters) the product that leaves here is those ranges, a few ints: the
+block is scanned by the range compares exactly as a host-compiled block
+is, no mask is kept and the launch gathers nothing. Past R_MAX the mask
+stays on the device and multiblock.multi_entry_mask tests membership
+with a lookup in it, one gather for every slot of every entry and term.
+On a v5e (PERF.md section 6, PR 33) that gather costs 0.73 s a term for
+4,096 pages where the compares cost 1.9 ms at one range and 120 ms at
+1,024: the mask is the fallback, not the fast path.
 """
 
 from __future__ import annotations
@@ -68,6 +75,21 @@ DEVICE_PROBE_MIN_VALS = 50_000
 # unroll factor is bounded to keep compiles small. Tag needles are
 # short in practice (service names, ids); 64 bytes covers them.
 MAX_NEEDLE_BYTES = 64
+
+# A term whose hits are at most this many runs of the sorted dictionary
+# (a run: consecutive value ids that all hit; a prefix of sorted strings
+# is always ONE) leaves the probe as inclusive [lo, hi] id ranges, a few
+# ints, and its block is scanned by the range compares like any
+# host-compiled block; past it the [T, v_pad] mask stays on the device
+# and the scan gathers from it. Set from a v5e (scripts/membership_bench
+# .py, PR 33: a solo launch of 4,096 pages x 1,024 entries x 16 slots,
+# int32 ids, ms at T = 1 | 2 terms): R = 1 1.9 | 2.4, 16 5.0 | 8.5, 64 8.8 |
+# 16.3, 256 31 | 61, 512 61 | 120, 1,024 120 | 238; the mask 731 | 1,853.
+# The mask's gather (one lookup a slot and term) loses to R pairs of
+# compares at every R measured, by 6.1x at the last, so the constant is
+# the largest R measured, not a crossing. Past 64 the compares grow by
+# 0.117 ms a range, which would meet the mask near 6,000: open.
+R_MAX = 1024
 
 
 def _pow2(n: int) -> int:
@@ -231,6 +253,21 @@ def stage_val_dict(val_dict: list, n_shards: int = 1, mesh=None,
 # kernels
 
 
+def _cumsum_pow2(x):
+    """jnp.cumsum of an int32 [N], N a power of two, in two levels: along
+    the rows of its [N / 1024, 1024] view, then over the rows' totals.
+    The same sums; the reason is the compiler: one scan over the 1 MiB
+    of a 53,000-value dictionary takes the TPU toolchain 18 s to compile
+    here and 32-36 s beside a serving process on a v5e's host, past the
+    30 s watchdog of the dispatch that first runs it (a device fault and
+    a host probe after every cold start); two short scans take under one."""
+    n = x.shape[0]
+    b = min(n, 1024)
+    rows = jnp.cumsum(x.reshape(n // b, b), axis=1)
+    before = jnp.cumsum(rows[:, -1]) - rows[:, -1]
+    return (rows + before[:, None]).reshape(n)
+
+
 def _probe_core(buf, pos, off, n_real, needles, lens, empties,
                 *, n_needle_max: int):
     """hits bool [T, V] over ONE shard's byte buffer.
@@ -258,7 +295,7 @@ def _probe_core(buf, pos, off, n_real, needles, lens, empties,
         # offset differencing (one monotone [V] gather, no scatter)
         c = jnp.concatenate([
             jnp.zeros((1,), jnp.int32),
-            jnp.cumsum(acc.astype(jnp.int32)),
+            _cumsum_pow2(acc.astype(jnp.int32)),
         ])
         hits = (c[off[1:]] - c[off[:-1]]) > 0
         # empty needle: every real value matches (host semantics —
@@ -270,31 +307,55 @@ def _probe_core(buf, pos, off, n_real, needles, lens, empties,
     return jax.vmap(one_term)(needles, lens, empties)
 
 
-@functools.partial(jax.jit, static_argnames=("n_needle_max",))
+def _hit_runs(hits, r_max: int):
+    """The runs of a hit mask over the sorted value ids: (n_runs int32
+    [T], bounds int32 [T, r_max, 2]) with the first `r_max` runs as
+    inclusive [lo, hi] ids and [1, 0] (matches nothing) behind them:
+    pipeline.ids_to_ranges on the device. The r-th run starts where the
+    count of 0->1 edges reaches r and ends where the count of 1->0
+    edges does: two cumsums and 2 x r_max binary searches a term."""
+    h = hits.astype(jnp.int32)
+    prev = jnp.pad(h, ((0, 0), (1, 0)))[:, :-1]
+    nxt = jnp.pad(h, ((0, 0), (0, 1)))[:, 1:]
+    starts = jnp.cumsum(h & (1 - prev), axis=1)
+    ends = jnp.cumsum(h & (1 - nxt), axis=1)
+    r = jnp.arange(1, r_max + 1, dtype=jnp.int32)
+    find = jax.vmap(lambda c: jnp.searchsorted(c, r, side="left"))
+    n_runs = starts[:, -1]
+    real = r[None, :] <= n_runs[:, None]
+    lo = jnp.where(real, find(starts), 1).astype(jnp.int32)
+    hi = jnp.where(real, find(ends), 0).astype(jnp.int32)
+    return n_runs, jnp.stack([lo, hi], axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("n_needle_max", "r_max"))
 def probe_kernel(buf, pos, off, n_real, needles, lens, empties,
-                 *, n_needle_max: int):
+                 *, n_needle_max: int, r_max: int = R_MAX):
     """Single-device probe over [S, ...] staged arrays — EVERY shard is
     probed (vmapped) and reassembled in shard order, so a dictionary
     packed for an S-way mesh but placed unsharded (place_batch's
     mismatch fallback) still yields the full [T, v_pad] mask, just
     without the parallelism. Returns (hits bool [T, v_pad],
-    any_hits bool [T])."""
+    any_hits bool [T], n_runs int32 [T], bounds int32 [T, r_max, 2]):
+    the mask, and the same hits as runs of the sorted ids (_hit_runs)."""
     local = jax.vmap(
         lambda b, p, o, nr: _probe_core(b, p, o, nr, needles, lens,
                                         empties,
                                         n_needle_max=n_needle_max)
     )(buf, pos, off, n_real)                           # [S, T, v_shard]
     hits = jnp.swapaxes(local, 0, 1).reshape(needles.shape[0], -1)
-    return hits, hits.any(axis=1)
+    return (hits, hits.any(axis=1), *_hit_runs(hits, r_max))
 
 
-@functools.partial(jax.jit, static_argnames=("mesh", "n_needle_max"))
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "n_needle_max", "r_max"))
 def dist_probe_kernel(mesh, buf, pos, off, n_real, needles, lens, empties,
-                      *, n_needle_max: int):
+                      *, n_needle_max: int, r_max: int = R_MAX):
     """Mesh probe: the dictionary's value axis is split across shards
     (axis 0 of the staged arrays); every device probes its value range
     and the local masks all_gather into the replicated global [T, v_pad]
-    mask — same collective shape as the scan's result funnel."""
+    mask — same collective shape as the scan's result funnel. The runs
+    are read off the gathered mask, on every device alike."""
     from jax.sharding import PartitionSpec as P
     from tempo_tpu.parallel.mesh import SCAN_AXIS, shard_map_compat
 
@@ -304,12 +365,12 @@ def dist_probe_kernel(mesh, buf, pos, off, n_real, needles, lens, empties,
                             n_needle_max=n_needle_max)     # [T, v_shard]
         all_h = jax.lax.all_gather(local, SCAN_AXIS)       # [S, T, vs]
         hits = jnp.swapaxes(all_h, 0, 1).reshape(local.shape[0], -1)
-        return hits, hits.any(axis=1)
+        return (hits, hits.any(axis=1), *_hit_runs(hits, r_max))
 
     return shard_map_compat(
         shard_fn, mesh=mesh,
         in_specs=(P(SCAN_AXIS),) * 4 + (P(),) * 3,
-        out_specs=(P(), P()),
+        out_specs=(P(),) * 4,
         # all_gather output is identical on every shard; the replication
         # checker can't infer it through the gather (same stance as
         # multiblock.batch_scan_kernel)
@@ -318,16 +379,22 @@ def dist_probe_kernel(mesh, buf, pos, off, n_real, needles, lens, empties,
 
 
 def probe_value_hits(ddev: DeviceDict, needles: list[bytes]):
+    """The mask half of `probe_values`: (hits, any_hits)."""
+    return probe_values(ddev, needles)[:2]
+
+
+def probe_values(ddev: DeviceDict, needles: list[bytes]):
     """Run the device probe for a list of utf-8 needles against a staged
-    dictionary. Returns (hits [T, v_pad] bool, any_hits [T] bool) DEVICE
-    arrays — nothing synchronizes to host here; callers fetch any_hits
-    (a few bytes) only when they need prune decisions.
+    dictionary. Returns (hits [T, v_pad] bool, any_hits [T] bool, n_runs
+    [T] int32, bounds [T, R_MAX, 2] int32) DEVICE arrays — nothing
+    synchronizes to host here; callers fetch any_hits and the runs (a
+    few bytes) when they decide on pruning and on the membership path.
 
     Raises ValueError for needles longer than MAX_NEEDLE_BYTES — callers
     fall back to the exact host scan for that query."""
     T = len(needles)
     if T == 0:
-        raise ValueError("probe_value_hits needs at least one needle")
+        raise ValueError("probe_values needs at least one needle")
     lmax = max(len(n) for n in needles)
     if lmax > MAX_NEEDLE_BYTES:
         raise ValueError(f"needle exceeds {MAX_NEEDLE_BYTES} bytes")
@@ -375,7 +442,8 @@ def probe_value_hits(ddev: DeviceDict, needles: list[bytes]):
                 with rec.stage(stage):
                     out = dist_probe_kernel(
                         ddev.mesh, d["buf"], d["pos"], d["off"],
-                        d["n_real"], *needle_args, n_needle_max=Lp)
+                        d["n_real"], *needle_args, n_needle_max=Lp,
+                        r_max=R_MAX)
             # fence after releasing the collective lock (lock-order
             # suite: no blocking wait under dispatch_lock); the stage
             # timer accumulates so kernel time books to the same stage
@@ -385,7 +453,8 @@ def probe_value_hits(ddev: DeviceDict, needles: list[bytes]):
         with rec.stage(stage):
             out = probe_kernel(d["buf"], d["pos"], d["off"], d["n_real"],
                                jnp.asarray(arr), jnp.asarray(lens),
-                               jnp.asarray(empties), n_needle_max=Lp)
+                               jnp.asarray(empties), n_needle_max=Lp,
+                               r_max=R_MAX)
             rec.fence(out)
         return out
 

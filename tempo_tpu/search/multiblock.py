@@ -28,6 +28,7 @@ import numpy as np
 from tempo_tpu import tempopb
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import profile
+from tempo_tpu.observability import tracing
 
 from .columnar import ColumnarPages
 from .dict_probe import _pow2
@@ -52,6 +53,14 @@ from .pipeline import (
 
 import copy
 import functools
+
+
+# Ranges a term up to which the scan compares them in one pass, and past
+# which a member fuses apart from narrower ones (batcher.submit): on a
+# v5e the compares cost 1.9 ms for 4,096 pages at one range, 8.8 at 64
+# and 0.117 more for each range past that, 64 at a time
+# (scripts/membership_bench.py, PR 33).
+WIDE_RANGES = 64
 
 
 @dataclass
@@ -510,13 +519,19 @@ class MultiQuery:
     win_end: int
     limit: int
     n_terms: int
-    # device-probe product (search/dict_probe.py): bool [G, T, Vmax]
-    # per-dictionary-GROUP value hit masks on device, and the int32 [B]
-    # block -> group row map (-1 = this block compiled through the host
-    # range path; its val_ranges row applies). The probe output feeds
-    # the kernel directly — no id-set ever crossed the host boundary.
+    # device-probe product (search/dict_probe.py) where some dictionary
+    # answered with a hit mask (a term of more than dict_probe.R_MAX
+    # runs): bool [G, T, Vmax] per-dictionary-GROUP value hit masks on
+    # device, and the int32 [B] block -> group row map (-1 = this block
+    # compiled to ranges, on the host or from the device's runs; its
+    # val_ranges row applies). None where every block compiled to
+    # ranges: the launch then takes no mask and gathers nothing.
     val_hits: object = None
     block_group: np.ndarray | None = None
+    # (start, end, [(path, product)]) of the compile over the group's
+    # distinct dictionaries: the batcher's `dict_probe.probe` span
+    # (pipeline.probe_summary makes its attributes, in a traced search)
+    probes: tuple | None = None
     # compiled structural predicate (structural.CompiledStructural):
     # static plan + dynamic tables ANDed into the entry mask by the
     # kernels; None = the legacy pytree and executables exactly
@@ -579,6 +594,8 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
     # scan runs on device and yields a hit mask instead of host ranges
     staged_dicts = getattr(cache_on, "staged_dicts", None) or {}
     compiled: dict[bytes, CompiledQuery | None] = {}
+    probed: list = []
+    t_probe = tracing.now_ns()
     for fp, i in rep_idx.items():
         b = blocks[i]
         compiled[fp] = compile_query(
@@ -589,8 +606,9 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
             cache_on=b,  # blocks are immutable: repeated tag-sets skip
                          # the O(dict) probe (VERDICT r2 #1 host cost)
             staged_dict=None if host_only else staged_dicts.get(fp),
-            host_only=host_only,
+            host_only=host_only, probed=probed,
         )
+    probes = (t_probe, tracing.now_ns(), probed)
     per_block: list[CompiledQuery | None] = [
         None if (skip is not None and skip[i]) else compiled[fp_of[i]]
         for i in range(len(blocks))
@@ -670,7 +688,7 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
         dur_lo=any_cq.dur_lo, dur_hi=any_cq.dur_hi,
         win_start=any_cq.win_start, win_end=any_cq.win_end,
         limit=any_cq.limit, n_terms=T,
-        val_hits=val_hits, block_group=block_group,
+        val_hits=val_hits, block_group=block_group, probes=probes,
     )
 
 
@@ -855,6 +873,10 @@ def multi_entry_mask(kv_key, kv_val, entry_start, entry_end, entry_dur,
             probe_page = (bg_page >= 0)[:, None, None]     # [P,1,1]
             safe_g = jnp.maximum(bg_page, 0)
 
+        def in_ranges(v, lo, hi):
+            return ((v >= lo[:, None, None, :]) &
+                    (v <= hi[:, None, None, :])).any(-1)   # [P,E,C]
+
         def term_body(t, acc):
             kk = unpack_ids(kv_key, kw)                    # fused widen
             vv = unpack_ids(kv_val, vw)
@@ -863,8 +885,23 @@ def multi_entry_mask(kv_key, kv_val, entry_start, entry_end, entry_dur,
             lo = val_ranges[safe_block, t, :, 0]           # [P,R]
             hi = val_ranges[safe_block, t, :, 1]
             v = vv[..., None]                              # [P,E,C,1]
-            valm = ((v >= lo[:, None, None, :]) &
-                    (v <= hi[:, None, None, :])).any(-1)   # [P,E,C]
+            if lo.shape[1] <= WIDE_RANGES:
+                valm = in_ranges(v, lo, hi)
+            else:
+                # WIDE_RANGES at a time (R is a power of two): taken
+                # whole, the compiler lays a wide range axis across the
+                # lanes and the reduce over it cost 145 ms for 4,096
+                # pages at 256 ranges and 160 at 1,024; 64 at a time
+                # they cost 31 and 120
+                def some(i, m):
+                    at = i * WIDE_RANGES
+                    return m | in_ranges(
+                        v, jax.lax.dynamic_slice_in_dim(lo, at, WIDE_RANGES, 1),
+                        jax.lax.dynamic_slice_in_dim(hi, at, WIDE_RANGES, 1))
+
+                valm = jax.lax.fori_loop(
+                    0, lo.shape[1] // WIDE_RANGES, some,
+                    jnp.zeros(vv.shape, dtype=bool))
             if val_hits is not None:
                 safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
                 mh = (mask_select_grouped(val_hits, safe_g[:, None, None],
@@ -1106,6 +1143,18 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
       struct_mask, span_cols, s_tables, entry_agg)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "n_terms", "top_k", "widths",
+                                    "plan", "span_sharded", "shard_tail",
+                                    "agg"))
+def mask_scan_kernel(*tables, **statics):
+    """batch_scan_kernel for a launch that takes a hit mask (`val_hits`):
+    the same body under a program name of its own, so that a device
+    trace tells the launches that gather (one lookup for every slot of
+    every entry and term) from those that only compare."""
+    return batch_scan_kernel.__wrapped__(*tables, **statics)
+
+
 class MultiBlockEngine:
     """Batched scan over many blocks in one kernel dispatch; with a mesh,
     the batch shards across devices (the serving-path union of the
@@ -1333,14 +1382,19 @@ class MultiBlockEngine:
                      tuple(sorted((n, tuple(a.shape))
                                   for n, a in span_cols.items()))))
                 stage = "compile" if miss else "execute"
+                membership = "range" if vh is None else "mask"
+                obs.scan_membership.inc(attrs.get("queries", 1),
+                                        path=membership)
                 rec.set(**attrs, scan_bytes=batch.device_nbytes,
-                        shards=self.n_shards,
+                        shards=self.n_shards, membership=membership,
                         pages_per_shard=self.pages_per_shard(batch))
                 book_topk(rec, d["entry_valid"].size // self.n_shards,
                           top_k)
 
                 def call():
-                    return batch_scan_kernel(
+                    kernel = (batch_scan_kernel if vh is None
+                              else mask_scan_kernel)
+                    return kernel(
                         d["kv_key"], d["kv_val"], d["entry_start"],
                         d["entry_end"], d["entry_dur"], d["entry_valid"],
                         d["page_block"], *tables, d.get("entry_dur_res"),

@@ -21,6 +21,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from tempo_tpu import tempopb
+from tempo_tpu.observability import metrics as obs
 
 INT32_SENTINEL = np.int32(2**31 - 1)
 UINT32_MAX = 0xFFFFFFFF
@@ -50,11 +51,13 @@ class CompiledQuery:
     win_start: int
     win_end: int
     limit: int
-    # device-probe product (search/dict_probe.py): bool [T, v_pad] value
-    # hit mask, resident on device. When set, val_ranges is the
-    # never-match padding and the scan kernels test membership with a
-    # mask lookup instead of range compares — the probe result never
-    # crosses the host boundary.
+    # device-probe product (search/dict_probe.py) of a term whose hits
+    # are more than dict_probe.R_MAX runs of the sorted dictionary: bool
+    # [T, v_pad] value hit mask, resident on device. When set,
+    # val_ranges is the never-match padding and the scan kernels test
+    # membership with a mask lookup instead of range compares. With
+    # fewer runs the device probe fills val_ranges as the host does and
+    # this stays None.
     val_hits: object = None
 
     @property
@@ -147,6 +150,45 @@ _COMPILE_CACHE: OrderedDict = OrderedDict()
 _compile_cache_lock = threading.Lock()
 
 
+class _MaskBytes:
+    """HBM pinned by device hit masks, by who holds them: `probe_cache`,
+    the [T, v_pad] products in _COMPILE_CACHE (at most _PROBE_CACHE_MAX a
+    dictionary; no batch owns them, so no budget is charged), and
+    `memo`, the [G, T, Vmax] stacks the batcher's prepare memo keeps (a
+    copy, charged to its staged batch under search_batch_cache_bytes).
+    Range products are a few ints on the host and count nowhere."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._held = {"probe_cache": 0, "memo": 0}
+        self._peak = 0
+        for holder in self._held:       # a 0 from the start, not a gap
+            obs.probe_mask_bytes.set(0, held_by=holder)
+        obs.probe_mask_peak_bytes.set(0)
+
+    def add(self, holder: str, nbytes: int) -> None:
+        if not nbytes:
+            return
+        with self._lock:
+            self._held[holder] += nbytes
+            obs.probe_mask_bytes.set(self._held[holder], held_by=holder)
+            total = sum(self._held.values())
+            if total > self._peak:
+                self._peak = total
+                obs.probe_mask_peak_bytes.set(total)
+
+
+MASK_BYTES = _MaskBytes()
+
+
+def _mask_nbytes(product) -> int:
+    """Device bytes of a cached probe product's hit mask (0: pruned, or
+    a range product)."""
+    if isinstance(product, str) or product is None or product[3] is None:
+        return 0
+    return int(product[3].nbytes)
+
+
 def _dict_fingerprint(cache_on, key_dict: list, val_dict: list) -> bytes:
     """Content digest of the dictionaries, computed once per container
     OUTSIDE the cache lock (a 1M-value dictionary hashes for ~100ms — it
@@ -200,7 +242,8 @@ def compile_query(key_dict: list, val_dict: list,
                   req: tempopb.SearchRequest,
                   packed_vals: tuple | None = None,
                   cache_on=None, staged_dict=None,
-                  host_only: bool = False) -> CompiledQuery | None:
+                  host_only: bool = False,
+                  probed: list | None = None) -> CompiledQuery | None:
     """Returns None when the block provably cannot match (key absent from
     the key dictionary, or no dictionary value satisfies a term). Under the
     exhaustive debug flag blocks are never pruned: an unsatisfiable term
@@ -218,12 +261,17 @@ def compile_query(key_dict: list, val_dict: list,
     `staged_dict`: a dict_probe.DeviceDict for this value dictionary —
     when present the substring probe runs ON DEVICE (staging-time
     routing already applied the `search_device_probe_min_vals`
-    threshold) and the compiled query carries the [T, v_pad] hit mask
-    instead of host-folded ranges. The cache key is unchanged, so
-    repeated tag-sets skip all probe work on either path; a cached
-    host-path product is served to a device-capable caller (and vice
-    versa) — both are exact, only the kernel's membership test
-    differs.
+    threshold) and the compiled query carries what the device found:
+    ranges like the host's where every term's hits are at most
+    dict_probe.R_MAX runs of the sorted dictionary, else the [T, v_pad]
+    hit mask. The cache key is unchanged, so repeated tag-sets skip all
+    probe work on either path; a cached host-path product is served to
+    a device-capable caller (and vice versa) — both are exact, only
+    the kernel's membership test differs.
+
+    `probed`: where given, gets one (path, product) for this
+    dictionary, path `cached`, `device` or `host`, product None where
+    the block was pruned (compile_multi's `dict_probe.probe` span).
 
     `host_only`: the breaker's host-fallback path — the probe must not
     touch the device AT ALL: staged dictionaries are ignored, and a
@@ -266,33 +314,74 @@ def compile_query(key_dict: list, val_dict: list,
         if hit is not None:
             # _PRUNED can only come from a non-exhaustive probe (the
             # exhaustive flag is part of the signature)
-            return None if isinstance(hit, str) else _from_probe(hit, req)
+            pruned = isinstance(hit, str)
+            _book_probe("cached", None if pruned else hit, probed)
+            return None if pruned else _from_probe(hit, req)
 
-    out = _probe_tags(key_dict, val_dict, req, packed_vals,
-                      staged_dict=staged_dict, fp=fp)
+    path, out = _probe_tags(key_dict, val_dict, req, packed_vals,
+                            staged_dict=staged_dict, fp=fp)
+    _book_probe(path, out, probed)
     if sig is not None:
         from . import packing
 
         with _compile_cache_lock:
             cache = _COMPILE_CACHE.get(fp)
             if cache is not None:
+                freed = _mask_nbytes(cache.get(sig))
                 cache[sig] = _PRUNED if out is None else out
                 while len(cache) > _COMPILE_CACHE_MAX:
-                    cache.popitem(last=False)
-                # device hit masks pin HBM: keep only the newest few.
-                # Bit-packed masks are 8x smaller, so they get an 8x
-                # deeper bound at the same HBM charge.
-                probed = [s for s, o in cache.items()
-                          if not isinstance(o, str) and o[3] is not None
-                          and not packing.is_packed_mask(o[3])]
-                while len(probed) > _PROBE_CACHE_MAX:
-                    cache.pop(probed.pop(0), None)
-                packed = [s for s, o in cache.items()
-                          if not isinstance(o, str) and o[3] is not None
-                          and packing.is_packed_mask(o[3])]
-                while len(packed) > _PROBE_CACHE_MAX_PACKED:
-                    cache.pop(packed.pop(0), None)
+                    freed += _mask_nbytes(cache.popitem(last=False)[1])
+                # device hit masks pin HBM: keep only the newest few
+                # (range products, the device's too, are a few ints and
+                # share the host entries' bound above; only a mask coming
+                # in can push the masks past theirs). Bit-packed masks
+                # are 8x smaller, so they get an 8x deeper bound at the
+                # same HBM charge.
+                if _mask_nbytes(out):
+                    masks = [s for s, o in cache.items()
+                             if _mask_nbytes(o)
+                             and not packing.is_packed_mask(o[3])]
+                    while len(masks) > _PROBE_CACHE_MAX:
+                        freed += _mask_nbytes(cache.pop(masks.pop(0), None))
+                    packed = [s for s, o in cache.items()
+                              if _mask_nbytes(o)
+                              and packing.is_packed_mask(o[3])]
+                    while len(packed) > _PROBE_CACHE_MAX_PACKED:
+                        freed += _mask_nbytes(
+                            cache.pop(packed.pop(0), None))
+                MASK_BYTES.add("probe_cache", _mask_nbytes(out) - freed)
     return None if out is None else _from_probe(out, req)
+
+
+def _book_probe(path: str, product, probed: list | None) -> None:
+    obs.dict_probes.inc(path=path)
+    if probed is not None:
+        probed.append((path, product))
+
+
+def probe_summary(probed: list) -> dict:
+    """What one compile over a group's distinct dictionaries did, as the
+    attributes of its `dict_probe.probe` span: `path` is the dearest
+    taken (device, then host, then cached), `membership` is `mask` once
+    one product is a hit mask, `runs_max` the most runs a term has in
+    a range product (a mask's are past dict_probe.R_MAX by definition)."""
+    by = {"device": 0, "host": 0, "cached": 0}
+    terms = runs_max = 0
+    membership = "range"
+    for path, product in probed:
+        by[path] += 1
+        if product is None:
+            continue
+        terms = max(terms, int(product[0].shape[0]))
+        if product[3] is not None:
+            membership = "mask"
+        elif product[2].size:
+            vr = product[2]
+            runs_max = max(runs_max,
+                           int((vr[..., 0] <= vr[..., 1]).sum(axis=1).max()))
+    path = next((p for p in ("device", "host") if by[p]), "cached")
+    return dict(by, path=path, dicts=len(probed), terms=terms,
+                runs_max=runs_max, membership=membership)
 
 
 def _from_probe(probe, req) -> CompiledQuery:
@@ -312,10 +401,15 @@ def _from_probe(probe, req) -> CompiledQuery:
 
 def _device_probe_tags(terms, key_dict, staged_dict, exhaustive):
     """Device-path value probe: ONE vmapped kernel call for all terms;
-    the only host sync is the [T]-bool any_hits fetch that prune
-    decisions need. Returns the probe product or None (pruned).
-    Raises ValueError when a needle exceeds the kernel's unroll bound —
-    the caller falls back to the exact host scan."""
+    the only host sync fetches, per term, any_hits (prune decisions)
+    and the runs of its hits over the sorted ids (a few ints). Where
+    every term has at most dict_probe.R_MAX runs the product carries
+    them as val_ranges and no mask, exactly what the host path makes,
+    and the scan tests membership by compares; otherwise the mask stays
+    on the device and the scan gathers from it. Returns the probe
+    product or None (pruned). Raises ValueError when a needle exceeds
+    the kernel's unroll bound — the caller falls back to the exact
+    host scan."""
     from . import dict_probe
 
     term_key_ids = []
@@ -331,15 +425,26 @@ def _device_probe_tags(terms, key_dict, staged_dict, exhaustive):
         if len(nb) > dict_probe.MAX_NEEDLE_BYTES:
             raise ValueError("needle too long for device probe")
         needles.append(nb)
-    hits, any_hits = dict_probe.probe_value_hits(staged_dict, needles)
+    import jax
+
+    hits, *small = dict_probe.probe_values(staged_dict, needles)
+    any_host, n_runs, bounds = jax.device_get(small)
     if not exhaustive:
-        any_host = np.asarray(any_hits)
         for t, ki in enumerate(term_key_ids):
             if ki >= 0 and not any_host[t]:
                 return None  # no dictionary value satisfies this term
+    T = len(term_key_ids)
+    term_keys = np.asarray(term_key_ids, dtype=np.int32)
+    term_vals = np.full((T, 1), INT32_SENTINEL, dtype=np.int32)
     # missing keys (exhaustive only) must contribute an all-false row
     # regardless of what the probe said for their needle
-    key_ok = np.asarray(term_key_ids, dtype=np.int32) >= 0
+    key_ok = term_keys >= 0
+    runs_max = int(np.where(key_ok, n_runs, 0).max())
+    if runs_max <= dict_probe.R_MAX:
+        R = dict_probe._pow2(max(1, runs_max))
+        val_ranges = np.tile(np.array([1, 0], dtype=np.int32), (T, R, 1))
+        val_ranges[key_ok, :bounds.shape[1]] = bounds[key_ok, :R]
+        return term_keys, term_vals, val_ranges, None
     if not key_ok.all():
         import jax.numpy as jnp
 
@@ -352,9 +457,6 @@ def _device_probe_tags(terms, key_dict, staged_dict, exhaustive):
         # bools — 8x fewer HBM bytes pinned per cached tag-set; the
         # scan kernels select the bit in-register (packing.mask_select)
         hits = packing.PACKING.pack_hits(hits)
-    T = len(term_key_ids)
-    term_keys = np.asarray(term_key_ids, dtype=np.int32)
-    term_vals = np.full((T, 1), INT32_SENTINEL, dtype=np.int32)
     val_ranges = np.tile(np.array([1, 0], dtype=np.int32), (T, 1, 1))
     return term_keys, term_vals, val_ranges, hits
 
@@ -404,9 +506,9 @@ def _probe_tags(key_dict: list, val_dict: list, req,
     """The expensive, tags-only part of compilation: binary-search keys,
     then either the host substring scan folded to range sets, or the
     device probe (staged_dict present, and — when the offload planner is
-    enabled — the cost model picks device) yielding a device hit mask.
-    Returns (term_keys, term_vals, val_ranges, val_hits) or None
-    (pruned)."""
+    enabled — the cost model picks device) yielding ranges or a device
+    hit mask. Returns (path, product): `device` or `host`, and
+    (term_keys, term_vals, val_ranges, val_hits) or None (pruned)."""
     from .analytics import AGG_QUERY_TAG
     from .structural import STRUCTURAL_QUERY_TAG
 
@@ -423,7 +525,7 @@ def _probe_tags(key_dict: list, val_dict: list, req,
             # watchdog-bounded like every other device dispatch: a probe
             # kernel that hangs or errors books a breaker fault and the
             # EXACT host scan below answers instead (byte-identical)
-            return GUARD.run(
+            return "device", GUARD.run(
                 "dict_probe",
                 lambda: _device_probe_tags(terms, key_dict, staged_dict,
                                            exhaustive))
@@ -451,8 +553,8 @@ def _probe_tags(key_dict: list, val_dict: list, req,
         nb = len(terms) * planner.dict_bytes_est(val_dict)
         t0 = _time.perf_counter()
         try:
-            return _host_probe_tags(terms, key_dict, val_dict,
-                                    packed_vals, exhaustive)
+            return "host", _host_probe_tags(terms, key_dict, val_dict,
+                                            packed_vals, exhaustive)
         finally:
             dt = _time.perf_counter() - t0
             profile.observe_stage("build", "host_probe", dt, nbytes=nb)
@@ -465,8 +567,8 @@ def _probe_tags(key_dict: list, val_dict: list, req,
                 # — the per-query bytes-by-placement split counts it
                 qs.add_host_probe(dt, nb)
                 qs.add_inspected(nbytes=nb, placement="host")
-    return _host_probe_tags(terms, key_dict, val_dict, packed_vals,
-                            exhaustive)
+    return "host", _host_probe_tags(terms, key_dict, val_dict, packed_vals,
+                                    exhaustive)
 
 
 def _host_probe_tags(terms, key_dict, val_dict, packed_vals, exhaustive):

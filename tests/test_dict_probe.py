@@ -48,6 +48,17 @@ def _fresh_compile_cache():
     pipeline._COMPILE_CACHE.clear()
 
 
+@pytest.fixture(params=["mask", "range"])
+def membership(request, monkeypatch):
+    """How the device probe's product leaves it: `range` is the shipped
+    rule (hits of at most dict_probe.R_MAX runs leave as [lo, hi] id
+    ranges, as these corpora's all do), `mask` holds R_MAX at 0 so that
+    every hit leaves as the [T, v_pad] mask."""
+    if request.param == "mask":
+        monkeypatch.setattr(dict_probe, "R_MAX", 0)
+    return request.param
+
+
 def _mk_req(tags=None, **kw):
     req = tempopb.SearchRequest()
     for k, v in (tags or {}).items():
@@ -237,7 +248,7 @@ def _blocks(n=3, entries=150, small_tail=True):
 # one-block batches
 
 
-def test_one_block_batch_device_probe_byte_identical():
+def test_one_block_batch_device_probe_byte_identical(membership):
     pages = ColumnarPages.build(_corpus(300, seed=1), PageGeometry(64, 8))
     req = _mk_req({"session.id": "session-00"}, limit=1000)
 
@@ -248,7 +259,7 @@ def test_one_block_batch_device_probe_byte_identical():
     pipeline._COMPILE_CACHE.clear()
     dev = scan_batch([pages], req, top_k=1024, probe_min_vals=1)
     assert dev.batch.staged_dicts
-    assert dev.mq.val_hits is not None
+    assert (dev.mq.val_hits is not None) == (membership == "mask")
 
     assert host.out[:2] == dev.out[:2]
     r_h = [(m.trace_id, m.start_time_unix_nano) for m in host.metas]
@@ -287,7 +298,7 @@ def test_exhaustive_flag_with_device_probe():
     assert got.count == 0 and got.inspected == 100
 
 
-def test_compile_cache_skips_device_probe_work():
+def test_compile_cache_skips_device_probe_work(membership):
     """Repeated tag-sets must hit the compile cache without re-running
     the probe kernel (same contract as the host path's cache)."""
     from unittest import mock
@@ -295,8 +306,8 @@ def test_compile_cache_skips_device_probe_work():
     pages = ColumnarPages.build(_corpus(150, seed=4), PageGeometry(32, 8))
     sd = staged_dict(pages)
     req = _mk_req({"session.id": "session-01"}, limit=20)
-    with mock.patch.object(dict_probe, "probe_value_hits",
-                           wraps=dict_probe.probe_value_hits) as probe:
+    with mock.patch.object(dict_probe, "probe_values",
+                           wraps=dict_probe.probe_values) as probe:
         cq1 = compile_query(pages.key_dict, pages.val_dict, req,
                             cache_on=pages, staged_dict=sd)
         assert cq1 is not None and probe.call_count == 1
@@ -304,13 +315,15 @@ def test_compile_cache_skips_device_probe_work():
                             cache_on=pages, staged_dict=sd)
         assert probe.call_count == 1  # cache hit: no second dispatch
         assert cq2.val_hits is cq1.val_hits
+        assert cq2.val_ranges is cq1.val_ranges
+        assert (cq1.val_hits is not None) == (membership == "mask")
 
 
 # ---------------------------------------------------------------------------
 # multi-block / coalesced / mesh dispatch paths
 
 
-def test_multiblock_mixed_device_and_host_blocks():
+def test_multiblock_mixed_device_and_host_blocks(membership):
     """High-cardinality blocks probe on device while the small block
     keeps host ranges, in ONE batch — results byte-identical to the
     all-host compile."""
@@ -326,9 +339,12 @@ def test_multiblock_mixed_device_and_host_blocks():
     batch_dev = stack_blocks(blocks, pad_to=32, probe_min_vals=50)
     assert len(batch_dev.staged_dicts) == 3  # the small block stays host
     mq_dev = compile_multi(blocks, req, cache_on=batch_dev)
-    assert mq_dev.val_hits is not None
-    assert (mq_dev.block_group >= 0).sum() == 3
-    assert mq_dev.block_group[3] == -1
+    if membership == "mask":
+        assert (mq_dev.block_group >= 0).sum() == 3
+        assert mq_dev.block_group[3] == -1
+    else:           # the device's runs are ranges like the host's
+        assert mq_dev.val_hits is None and mq_dev.block_group is None
+        assert np.array_equal(mq_dev.val_ranges, mq_host.val_ranges)
     out_d = eng.scan(batch_dev, mq_dev)
 
     assert out_h[0] == out_d[0] and out_h[1] == out_d[1]
@@ -339,7 +355,7 @@ def test_multiblock_mixed_device_and_host_blocks():
     assert r_h == r_d
 
 
-def test_multiblock_header_skip_masks_device_probed_block():
+def test_multiblock_header_skip_masks_device_probed_block(membership):
     from tempo_tpu.search.data import search_data_matches
 
     blocks = _blocks(n=2, small_tail=False)
@@ -347,7 +363,8 @@ def test_multiblock_header_skip_masks_device_probed_block():
     batch = stack_blocks(blocks, probe_min_vals=10)
     mq = compile_multi(blocks, req, skip=[True, False], cache_on=batch)
     assert mq is not None
-    assert mq.block_group[0] == -1          # skipped row: range path,
+    if membership == "mask":
+        assert mq.block_group[0] == -1      # skipped row: range path,
     assert (mq.term_keys[0] == -1).all()    # unmatchable sentinel
     eng = MultiBlockEngine(top_k=1024)
     count, _, scores, idx = eng.scan(batch, mq)
@@ -360,7 +377,7 @@ def test_multiblock_header_skip_masks_device_probed_block():
     assert got == expected
 
 
-def test_coalesced_dispatch_with_device_probe_queries():
+def test_coalesced_dispatch_with_device_probe_queries(membership):
     """Fused multi-query dispatch where some members carry device hit
     masks and others compiled through the host path — every member's
     fused result equals its solo dispatch."""
@@ -376,11 +393,12 @@ def test_coalesced_dispatch_with_device_probe_queries():
                                              limit=1000),
                              cache_on=batch))
     mqs = [m for m in mqs if m is not None]
-    assert any(m.val_hits is not None for m in mqs)
+    masked = membership == "mask"
+    assert any(m.val_hits is not None for m in mqs) == masked
     assert any(m.val_hits is None for m in mqs)
 
     cq = stack_queries(mqs)
-    assert cq.val_hits is not None
+    assert (cq.val_hits is not None) == masked
     counts, inspected, scores, idx = eng.coalesced_scan_async(
         batch, cq, 1024)
     counts, scores, idx = (np.asarray(counts), np.asarray(scores),
@@ -391,7 +409,7 @@ def test_coalesced_dispatch_with_device_probe_queries():
         assert np.array_equal(scores[qi][:s_scores.shape[0]], s_scores)
 
 
-def test_mesh_sharded_dispatch_with_device_probe():
+def test_mesh_sharded_dispatch_with_device_probe(membership):
     """The dictionary shards along the value axis over the mesh, the
     hit masks all_gather, and the sharded scan consumes them — results
     identical to the unsharded host-path scan."""
@@ -406,7 +424,7 @@ def test_mesh_sharded_dispatch_with_device_probe():
     assert len(batch.staged_dicts) == 2
     assert all(dd.mesh is not None for dd in batch.staged_dicts.values())
     mq = compile_multi(blocks, req, cache_on=batch)
-    assert mq.val_hits is not None
+    assert (mq.val_hits is not None) == (membership == "mask")
     out_mesh = eng.scan(batch, mq)
 
     pipeline._COMPILE_CACHE.clear()

@@ -761,6 +761,10 @@ def test_chaos_wedged_owner_breaker_to_host_route(tmp_path,
     base = _canon(db.search("t", req).response())
     ownership.configure(enabled=True, members="m0,m1", self_id="m0",
                         groups=32)
+    # block ids are uuid4: be the member that owns a staged group's
+    # anchor, or the wedge has no owner to hit (a run in some dozens)
+    anchor = str(next(iter(db.batcher._cache))[0][0])
+    ownership.configure(self_id=ownership.OWNERSHIP.owner_of(anchor))
     robustness.BREAKER.reset()
     robustness.GUARD.timeout_s = 0.3
     with robustness.FAULTS.armed("device_dispatch_hang", delay_s=5.0,

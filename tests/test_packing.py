@@ -350,7 +350,17 @@ def test_parity_mesh_one_block_batch():
         assert on == off, req
 
 
-def test_parity_dict_probe_mask_path():
+@pytest.fixture
+def mask_products(monkeypatch):
+    """Every device probe product as a hit mask (R_MAX 0): the shipped
+    rule turns these corpora's one-run needles into ranges, and the
+    packed-mask format would go untested."""
+    from tempo_tpu.search import dict_probe
+
+    monkeypatch.setattr(dict_probe, "R_MAX", 0)
+
+
+def test_parity_dict_probe_mask_path(mask_products):
     """The mask-lookup membership path with bit-packed hit masks must
     agree with the unpacked masks AND the pure host range path, over a
     batch mixing device-probed and host-compiled blocks."""
@@ -408,7 +418,7 @@ def test_host_scan_parity_over_packed_host_batch():
         assert _canon(hb) == dev, req
 
 
-def test_compile_cache_mask_format_flip_is_a_miss():
+def test_compile_cache_mask_format_flip_is_a_miss(mask_products):
     """A cached probe product minted under the other gate state must
     recompile, not leak the wrong mask format into an assembled batch."""
     from tempo_tpu.search.multiblock import stack_blocks

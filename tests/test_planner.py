@@ -41,9 +41,13 @@ from conftest import scan_batch, staged_dict
 
 
 @pytest.fixture(autouse=True)
-def _fresh_state():
+def _fresh_state(monkeypatch):
     """Cold planner + compile cache per test; planner disabled on exit
-    (it is process-wide, like the profiler)."""
+    (it is process-wide, like the profiler). These tests tell a device
+    verdict from a host one by the product's hit mask, so every device
+    product is held to the mask form (R_MAX 0): with the shipped rule
+    their one-run needles would leave the probe as the host's ranges."""
+    monkeypatch.setattr(dict_probe, "R_MAX", 0)
     pipeline._COMPILE_CACHE.clear()
     planner.configure(enabled=False, seed=False, reset=True)
     yield
