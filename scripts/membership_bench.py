@@ -8,11 +8,16 @@ One staged group as a high-cardinality tenant's is (1,024 entries a
 page, 16 kv slots, int8 keys, int32 value ids under 57,000 a block, 64
 blocks), made on the device from a fixed seed. For T = 1 and 2 terms it
 times a solo launch with ranges at R = 1 .. 1,024 and with the mask,
-and a fused launch of four members at a few of them; every launch is
+and a fused launch of four members at the same R; every launch is
 fenced and the median of `--calls` is printed, one JSON line each and a
 table at the end. The mask starts to win where its row is the faster;
-`R_MAX` is the last R before that. On the CPU it runs at `--pages 8`
-and proves only that the shapes trace.
+`R_MAX` is the last R before that. It is also where
+`multiblock.ENTRY_RANGES` comes from, the R from which the compares run
+once an entry and not once a slot: `--entry-from 1` takes every R that
+way and `--entry-from 4096` none, and `--values-per-key` puts the
+term's key in that many adjacent slots of every entry, a pass of the
+entry form for each. On the CPU it runs at `--pages 8` and proves only
+that the shapes trace.
 """
 
 from __future__ import annotations
@@ -33,26 +38,47 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--pages", type=int, default=4096)
     ap.add_argument("--calls", type=int, default=5)
-    ap.add_argument("--ranges", default="1,16,64,256,512,1024")
-    ap.add_argument("--fused", default="1,16,256")
+    ap.add_argument("--ranges", default="1,2,4,8,16,32,64,128,256,512,1024")
+    ap.add_argument("--fused", default=None,
+                    help="R of the fused launches (default: --ranges)")
+    ap.add_argument("--fused-terms", default="1",
+                    help="T of the fused launches")
+    ap.add_argument("--values-per-key", type=int, default=1,
+                    help="slots of every entry that hold the term's key")
+    ap.add_argument("--entry-from", type=int, default=None,
+                    help="multiblock.ENTRY_RANGES for this run "
+                         "(default: the kernel's own)")
+    ap.add_argument("--no-mask", action="store_true",
+                    help="skip the mask rows (40 s of a call)")
     args = ap.parse_args()
+    if not 1 <= args.values_per_key < C:
+        ap.error(f"--values-per-key: 1 .. {C - 1}")
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from tempo_tpu.search import multiblock
     from tempo_tpu.search.engine import DEFAULT_TOP_K, resolve_top_k
     from tempo_tpu.search.multiblock import (batch_scan_kernel,
                                              mask_scan_kernel)
     from tempo_tpu.utils.jaxenv import enable_compile_cache
 
     enable_compile_cache()
+    if args.entry_from is not None:
+        # read when a shape is first traced: set before any is
+        multiblock.ENTRY_RANGES = args.entry_from
     dev = jax.devices()[0]
     tag = f"[platform={dev.platform} kind={dev.device_kind}]"
     P = args.pages
     key = jax.random.PRNGKey(33)
     k1, k2, k3 = jax.random.split(key, 3)
-    kv_key = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int8), (P, E, C))
+    # slot j holds key j, but the term's key (1) fills slots 1 .. k: in
+    # sorted-key order, as ColumnarPages.build lays a k-valued key
+    slot = np.arange(C)
+    kv_key = jnp.broadcast_to(jnp.asarray(np.where(
+        (slot >= 1) & (slot <= args.values_per_key), 1, slot).astype(np.int8)),
+        (P, E, C))
     kv_val = jax.random.randint(k1, (P, E, C), 0, VALS, dtype=jnp.int32)
     start = jax.random.randint(k2, (P, E), 1_700_000_000, 1_700_086_400,
                                dtype=jnp.int32).astype(jnp.uint32)
@@ -87,7 +113,9 @@ def main() -> int:
             jax.block_until_ready(fn())
             ms.append((time.perf_counter() - t) * 1e3)
         row = dict(facts, label=label, pages=P, launch_ms=statistics.median(ms),
-                   min_ms=min(ms), max_ms=max(ms), first_s=first)
+                   min_ms=min(ms), max_ms=max(ms), first_s=first,
+                   values_per_key=args.values_per_key,
+                   entry_from=multiblock.ENTRY_RANGES)
         print(tag, json.dumps(row), flush=True)
         return row
 
@@ -123,14 +151,17 @@ def main() -> int:
         for R in (int(r) for r in args.ranges.split(",")):
             rows.append(timed(f"solo T={T} R={R}", solo(T, vr=ranges(T, R)),
                               T=T, R=R, Q=1, membership="range"))
-        rows.append(timed(f"solo T={T} mask", solo(T, vh=mask(T)),
-                          T=T, R=None, Q=1, membership="mask"))
-    for R in (int(r) for r in args.fused.split(",")):
-        rows.append(timed(f"fused Q=4 T=1 R={R}",
-                          fused(4, 1, vr=ranges(1, R)),
-                          T=1, R=R, Q=4, membership="range"))
-    rows.append(timed("fused Q=4 T=1 mask", fused(4, 1, vh=mask(1)),
-                      T=1, R=None, Q=4, membership="mask"))
+        if not args.no_mask:
+            rows.append(timed(f"solo T={T} mask", solo(T, vh=mask(T)),
+                              T=T, R=None, Q=1, membership="mask"))
+    for T in (int(t) for t in args.fused_terms.split(",")):
+        for R in (int(r) for r in (args.fused or args.ranges).split(",")):
+            rows.append(timed(f"fused Q=4 T={T} R={R}",
+                              fused(4, T, vr=ranges(T, R)),
+                              T=T, R=R, Q=4, membership="range"))
+    if not args.no_mask:
+        rows.append(timed("fused Q=4 T=1 mask", fused(4, 1, vh=mask(1)),
+                          T=1, R=None, Q=4, membership="mask"))
     print(tag, "membership  Q  T      R  launch_ms")
     for r in rows:
         print(tag, f"{r['membership']:>10} {r['Q']:>2} {r['T']:>2} "

@@ -41,7 +41,10 @@ line and writes it to
                `host`, `cached`), the `dict_probe` dispatches' `execute`
                stage since the start, launch members by membership
                (`tempo_search_scan_membership_total{path}`: `range`,
-               `mask`), the `dispatch.execute` spans by `membership`,
+               `mask`) and by what their range compares test
+               (`tempo_search_scan_range_compare_total{by}`: `slot`,
+               `entry`), the `dispatch.execute` spans by `membership`
+               and by `compare`,
                the `dict_probe.probe` spans by `path` and `membership`
                with their longest `runs_max`, the HBM that dictionaries
                and hit masks hold, and from the profiler's trace the
@@ -348,9 +351,13 @@ def probe_facts(view: dict) -> dict:
             "layers", "probe_ms.highcard").compute(view),
         "launch_members_in_window": {k: delta(view, members, path=k)
                                      for k in ("range", "mask")},
-        "execute_spans_by_membership": dict(collections.Counter(
-            s["attributes"].get("membership", "absent")
-            for s in view["spans"] if s["name"] == "dispatch.execute")),
+        "launch_members_by_compare_in_window": {
+            k: delta(view, "tempo_search_scan_range_compare_total", by=k)
+            for k in ("slot", "entry")},
+        **{f"execute_spans_by_{key}": dict(collections.Counter(
+            s["attributes"].get(key, "absent")
+            for s in view["spans"] if s["name"] == "dispatch.execute"))
+           for key in ("membership", "compare")},
         "probe_spans": {
             "by_path": dict(collections.Counter(
                 a.get("path") for a in spans)),

@@ -476,6 +476,14 @@ scan_membership = Counter(
     "membership: path=range (compares against [lo, hi] id ranges, no "
     "gather) or path=mask (a gather from the device probe's hit mask "
     "for every slot of every entry); one for each launch and member")
+scan_range_compare = Counter(
+    "tempo_search_scan_range_compare_total",
+    "members of scan launches that have tag terms by what the launch's "
+    "range compares test, read from its ranges a term: by=slot (under multiblock."
+    "ENTRY_RANGES: every kv slot of every entry against every range, "
+    "one pass) or by=entry (from there on: the entry's value for the "
+    "term's key, a pass for each value an entry has for it); one for "
+    "each launch and member, beside tempo_search_scan_membership_total")
 hbm_logical_bytes = Gauge("tempo_search_hbm_logical_bytes",
                           "unpacked-layout equivalent of the staged-batch "
                           "HBM occupancy — equals tempo_search_hbm_cache_"

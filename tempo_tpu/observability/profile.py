@@ -462,7 +462,7 @@ class DispatchProfiler:
             if stage in ("compile", "execute") and rec.jit is not None:
                 span.set_attribute("jit_cache", rec.jit)
                 for key in ("topk", "shards", "pages_per_shard", "params",
-                            "membership"):
+                            "membership", "compare"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
             span.end(end_ns)

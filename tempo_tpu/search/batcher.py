@@ -437,8 +437,9 @@ class QueryCoalescer:
         elif mq.val_ranges.shape[2] > WIDE_RANGES:
             # and so do members of many ranges a term: a fused launch
             # pads every member to its widest, and at 512 ranges the
-            # compares cost 61 ms a member and term where up to 64 they
-            # cost under 9 (PERF.md section 6, PR 33)
+            # compares cost 3.6 ms a member and term where at 64 they
+            # cost 0.7 (61 and under 9 when PR 33 set this key; since
+            # PR 36 they run once an entry: PERF.md section 6)
             key = key + ("wide",)
         flush_now = None
         with self._lock:

@@ -55,12 +55,38 @@ import copy
 import functools
 
 
-# Ranges a term up to which the scan compares them in one pass, and past
-# which a member fuses apart from narrower ones (batcher.submit): on a
-# v5e the compares cost 1.9 ms for 4,096 pages at one range, 8.8 at 64
-# and 0.117 more for each range past that, 64 at a time
-# (scripts/membership_bench.py, PR 33).
+# Ranges a term past which a member fuses apart from narrower ones
+# (batcher.submit): a fused launch pads every member to its widest, and
+# a fused launch of four costs 6.1 ms for 4,096 pages at 64 ranges, 17.8
+# at 512 (scripts/membership_bench.py on a v5e, PR 36).
 WIDE_RANGES = 64
+
+# Ranges a term from which the scan tests an ENTRY's value for the
+# term's key against them, and under which it tests every slot of the
+# entry against all of them in one pass (multi_entry_mask): the lowest
+# power of two at which the entry form is a tenth faster solo at one and
+# two terms and fused. The same bench, ms a launch by slot -> by entry at
+# 1 term | 2 terms | fused x 4: 4 ranges 1.90 | 2.58 | 4.65 -> 2.20 |
+# 3.05 | 4.92; 8 ranges 3.21 | 5.32 | 11.04 -> 2.12 | 3.07 | 4.89; 16 4.75
+# | 8.27 | 15.76 -> 2.13 | 2.95 | 4.75; 512 60.5 | 119.8 | 246.8 -> 5.44 |
+# 9.47 | 17.75 (by slot the compares ran 64 at a time past 64); one range
+# 1.89 | 2.46 | 3.18, by slot as ever. Past 128, 0.0065 ms a range and
+# term where by slot it was 0.117.
+ENTRY_RANGES = 8
+# Ranges the entry form compares at a time, and the least it does: a
+# narrower table is padded with sentinels. Under 32 the TPU compiler
+# took the kv columns through a two-term loop with their slots on the
+# lanes, a copy of both for every launch (17.0 ms at 2 .. 16 ranges and
+# two terms, 2.9 at 32: tests/test_scan_kernel_v5e.py holds it). 128 at
+# a time read 5.36 ms at 512 ranges, 64 at a time 6.05.
+_RANGE_BLOCK = 128
+_RANGE_BLOCK_MIN = 32
+
+
+def compares_by(n_ranges: int) -> str:
+    """Which of multi_entry_mask's two range forms a launch of that
+    many ranges a term traces: `slot` or `entry`."""
+    return "entry" if n_ranges >= ENTRY_RANGES else "slot"
 
 
 @dataclass
@@ -877,6 +903,64 @@ def multi_entry_mask(kv_key, kv_val, entry_start, entry_end, entry_dur,
             return ((v >= lo[:, None, None, :]) &
                     (v <= hi[:, None, None, :])).any(-1)   # [P,E,C]
 
+        def entry_in_ranges(keym, vv, lo, hi):
+            """Select, then compare: [P,E], does a value of the term's
+            key lie in a range. A term names one key and an entry has a
+            slot for each value of it, so a pass takes the largest value
+            not yet taken from the slots where `keym` holds (-1 where
+            none is left: ids are >= 0, pads -1, no range holds -1) and
+            tests that one value against the page's ranges, a block at
+            a time. Passes repeat while some entry of the launch has a
+            second value left: one where a key has one value an entry,
+            k for a k-valued key, never more than C. The count is read
+            on the device from the slots; under a fused launch's vmap
+            the loop runs until every member is done, on a mesh each
+            shard loops over its own pages."""
+            vv = vv.astype(jnp.int32)
+            if lo.shape[1] < _RANGE_BLOCK_MIN:
+                # sentinels ([1, 0] holds nothing) up to the least block
+                pad = ((0, 0), (0, _RANGE_BLOCK_MIN - lo.shape[1]))
+                lo = jnp.pad(lo, pad, constant_values=1)
+                hi = jnp.pad(hi, pad, constant_values=0)
+            step = min(lo.shape[1], _RANGE_BLOCK)
+
+            def holds(v):                                  # [P,E]
+                # [P, step, E]: the entries stay on the lanes, as the kv
+                # columns have them, and the ranges are reduced across
+                # vregs; with the ranges on the lanes the same compares
+                # took 12.0 ms where these take 5.4 (R = 512)
+                def some(i, m):
+                    l, h = (jax.lax.dynamic_slice_in_dim(b, i * step, step, 1)
+                            [:, :, None] for b in (lo, hi))
+                    return m | ((v[:, None, :] >= l) &
+                                (v[:, None, :] <= h)).any(1)
+
+                return jax.lax.fori_loop(0, lo.shape[1] // step, some,
+                                         jnp.zeros(v.shape, dtype=bool))
+
+            def take(state):
+                below, hit, _ = state
+                left = jnp.where(keym & (vv < below[..., None]), vv, -1)
+                # the largest value left and how many are, in one pass
+                # over the slots
+                v, n = jax.lax.reduce(
+                    (left, (left >= 0).astype(jnp.int32)),
+                    (jnp.int32(-1), jnp.int32(0)),
+                    lambda a, b: (jnp.maximum(a[0], b[0]), a[1] + b[1]),
+                    (2,))
+                return v, hit | holds(v), (n > 1).any()
+
+            return jax.lax.while_loop(
+                lambda state: state[2], take,
+                (jnp.full(vv.shape[:2], jnp.iinfo(jnp.int32).max),
+                 jnp.zeros(vv.shape[:2], dtype=bool), jnp.bool_(True)))[1]
+
+        def mask_hits(vv, t):
+            safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
+            return (mask_select_grouped(val_hits, safe_g[:, None, None],
+                                        t, safe_v)
+                    & (vv >= 0))                           # [P,E,C]
+
         def term_body(t, acc):
             kk = unpack_ids(kv_key, kw)                    # fused widen
             vv = unpack_ids(kv_val, vw)
@@ -884,31 +968,17 @@ def multi_entry_mask(kv_key, kv_val, entry_start, entry_end, entry_dur,
             keym = kk == k_per_page[:, None, None]         # [P,E,C]
             lo = val_ranges[safe_block, t, :, 0]           # [P,R]
             hi = val_ranges[safe_block, t, :, 1]
-            v = vv[..., None]                              # [P,E,C,1]
-            if lo.shape[1] <= WIDE_RANGES:
-                valm = in_ranges(v, lo, hi)
+            if compares_by(lo.shape[1]) == "slot":
+                valm = in_ranges(vv[..., None], lo, hi)
+                if val_hits is not None:
+                    valm = jnp.where(probe_page, mask_hits(vv, t), valm)
+                hit = jnp.any(keym & valm, axis=-1)        # [P,E]
             else:
-                # WIDE_RANGES at a time (R is a power of two): taken
-                # whole, the compiler lays a wide range axis across the
-                # lanes and the reduce over it cost 145 ms for 4,096
-                # pages at 256 ranges and 160 at 1,024; 64 at a time
-                # they cost 31 and 120
-                def some(i, m):
-                    at = i * WIDE_RANGES
-                    return m | in_ranges(
-                        v, jax.lax.dynamic_slice_in_dim(lo, at, WIDE_RANGES, 1),
-                        jax.lax.dynamic_slice_in_dim(hi, at, WIDE_RANGES, 1))
-
-                valm = jax.lax.fori_loop(
-                    0, lo.shape[1] // WIDE_RANGES, some,
-                    jnp.zeros(vv.shape, dtype=bool))
-            if val_hits is not None:
-                safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
-                mh = (mask_select_grouped(val_hits, safe_g[:, None, None],
-                                          t, safe_v)
-                      & (vv >= 0))                         # [P,E,C]
-                valm = jnp.where(probe_page, mh, valm)
-            hit = jnp.any(keym & valm, axis=-1)            # [P,E]
+                hit = entry_in_ranges(keym, vv, lo, hi)
+                if val_hits is not None:
+                    hit = jnp.where(
+                        probe_page[..., 0],
+                        jnp.any(keym & mask_hits(vv, t), axis=-1), hit)
             if term_active is not None:
                 hit = hit | ~term_active[t]
             return acc & hit
@@ -1388,6 +1458,12 @@ class MultiBlockEngine:
                 rec.set(**attrs, scan_bytes=batch.device_nbytes,
                         shards=self.n_shards, membership=membership,
                         pages_per_shard=self.pages_per_shard(batch))
+                if q.n_terms:
+                    # a launch without tag terms compares no range
+                    compare = compares_by(q.val_ranges.shape[-2])
+                    obs.scan_range_compare.inc(attrs.get("queries", 1),
+                                               by=compare)
+                    rec.set(compare=compare)
                 book_topk(rec, d["entry_valid"].size // self.n_shards,
                           top_k)
 

@@ -360,3 +360,328 @@ def test_counters_gauges_and_span_attributes_say_what_happened():
     with b._lock:
         b._drop_hbm_locked(next(iter(b._cache)))
     assert obs.probe_mask_bytes.value(held_by="memo") == memo_before
+
+
+# ---- PR 36: from multiblock.ENTRY_RANGES ranges a term on, the compares
+# test an entry's value for the term's key, not every slot of the entry ----
+
+C_SLOTS = 8
+WIDTHS = (1, 8, 16, 64, 128, 512, 1024)
+MULTIPLICITIES = (0, 1, 2, 5, C_SLOTS)   # values an entry has for the key
+
+
+def _slot_formula(kk, vv, valid, page_block, term_keys, val_ranges,
+                  term_active=None):
+    """The predicate as it was before the entry form, in numpy: every
+    slot against every range, then the slot's key. The reference the
+    kernel's two range forms are held to."""
+    safe = np.maximum(page_block, 0)
+    mask = valid & (page_block >= 0)[:, None]
+    for t in range(term_keys.shape[1]):
+        if term_active is not None and not term_active[t]:
+            continue
+        keym = kk == term_keys[safe, t][:, None, None]
+        lo = val_ranges[safe, t, :, 0][:, None, None, :]
+        hi = val_ranges[safe, t, :, 1][:, None, None, :]
+        v = vv.astype(np.int64)[..., None]
+        mask = mask & (keym & ((v >= lo) & (v <= hi)).any(-1)).any(-1)
+    return mask
+
+
+def _set_oracle(kk, vv, valid, page_block, term_keys, val_ranges):
+    """The same answer without arrays: an entry's set of values for the
+    term's key against the block's ranges, one entry at a time."""
+    out = np.zeros(valid.shape, dtype=bool)
+    for p, e in zip(*np.nonzero(valid)):
+        b = page_block[p]
+        if b < 0:
+            continue
+        out[p, e] = all(
+            any(lo <= v <= hi for v in
+                {int(v) for k, v in zip(kk[p, e], vv[p, e])
+                 if k == term_keys[b, t]}
+                for lo, hi in val_ranges[b, t])
+            for t in range(term_keys.shape[1]))
+    return out
+
+
+def _synthetic(mult: int, R: int, T: int, seed: int):
+    """Six pages of three blocks whose every valid entry has `mult`
+    values for key 3 (slots 1 .. mult, as ColumnarPages.build lays a
+    multi-valued key: adjacent, in sorted-key order) and, while a slot
+    is left, one for key 9; trailing slots are pads (-1, -1). Block 0's
+    ranges hold value id 0, block 1's are sentinels past their first
+    half, block 2 has the -1 key sentinel for term 0."""
+    rng = np.random.default_rng(seed)
+    P, E, B, V = 6, 16, 3, 4000
+    kk = np.full((P, E, C_SLOTS), -1, dtype=np.int8)
+    vv = np.full((P, E, C_SLOTS), -1, dtype=np.int16)
+    kk[..., 0] = 1
+    kk[..., 1:1 + mult] = 3
+    used = min(C_SLOTS, 1 + mult)
+    if used < C_SLOTS:
+        kk[..., used] = 9
+        used += 1
+    more = rng.integers(used, C_SLOTS + 1, (P, E))      # filled slots
+    for c in range(used, C_SLOTS):
+        kk[..., c] = np.where(more > c, 20 + c, -1)
+    vv[kk >= 0] = rng.integers(0, V, int((kk >= 0).sum()))
+    vv[0, :4, 1:1 + mult] = 0                            # value id 0
+    valid = rng.random((P, E)) < 0.9
+    kk[~valid] = -1
+    vv[~valid] = -1
+    page_block = np.repeat(np.arange(B, dtype=np.int32), P // B)
+    term_keys = np.tile(np.array([3, 9][:T], dtype=np.int32), (B, 1))
+    term_keys[2, 0] = -1
+    lo = np.sort(rng.integers(0, V, (B, T, R)), axis=-1)
+    lo[0, :, 0] = 0
+    val_ranges = np.stack(
+        [lo, lo + rng.integers(0, 3, lo.shape)], -1).astype(np.int32)
+    val_ranges[1, :, (R + 1) // 2:] = [1, 0]
+    return kk, vv, valid, page_block, term_keys, val_ranges
+
+
+@pytest.mark.parametrize("T", [1, 2])
+@pytest.mark.parametrize("R", WIDTHS)
+@pytest.mark.parametrize("mult", MULTIPLICITIES)
+def test_both_range_forms_equal_the_slot_formula_and_the_oracle(mult, R, T):
+    """multi_entry_mask at every width on both sides of ENTRY_RANGES,
+    for entries with no, one, several and C values of the term's key."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from tempo_tpu.search.multiblock import multi_entry_mask
+
+    kk, vv, valid, page_block, term_keys, val_ranges = _synthetic(
+        mult, R, T, seed=R + mult)
+    want = _slot_formula(kk, vv, valid, page_block, term_keys, val_ranges)
+    assert np.array_equal(
+        want, _set_oracle(kk, vv, valid, page_block, term_keys, val_ranges))
+    if mult and T == 1:
+        assert want[:2].any() and not want[4:].any()  # id 0; the -1 key
+    ones = jnp.ones(valid.shape, dtype=jnp.uint32)
+    got = jax.jit(functools.partial(multi_entry_mask, n_terms=T))(
+        jnp.asarray(kk), jnp.asarray(vv), ones, ones, ones,
+        jnp.asarray(valid), jnp.asarray(page_block), jnp.asarray(term_keys),
+        jnp.asarray(val_ranges), jnp.uint32(0), jnp.uint32(0xFFFFFFFF),
+        jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
+    assert np.array_equal(np.asarray(got), want)
+
+
+MARKS = {1: 1, 8: 6, 16: 12, 64: 40, 128: 100, 512: 300, 1024: 600}
+LAUNCHES = ("solo", "fused2", "fused4", "mesh_solo", "mesh_fused")
+
+
+def _marked(n: int) -> str:
+    """Value n of a pool that sorts by n. An even n under 2r carries the
+    marker `-m<r>.`, the odd ones between them none: the needle `-m<r>.`
+    hits r values, each a run of its own in the sorted dictionary, so
+    its ranges pad to the width MARKS names it for; n = 0 has them all."""
+    return f"id{n:04d}" + "".join(
+        f"-m{r}." for r in MARKS.values() if n % 2 == 0 and n // 2 < r)
+
+
+def _multivalued_block(seed: int, pool: int, with_key: bool = True):
+    """(entries, pages): entries take the pool's values in order, 0, 1,
+    2, 5, C - 1 and C at a time for `customer.id` (the C-valued ones
+    have no slot left for another key), until every value is on some
+    entry, so the dictionary is the pool."""
+    rng = random.Random(seed)
+    entries, n = [], 0
+    while n < pool or len(entries) % 6:
+        take = (0, 1, 2, 5, C_SLOTS - 1, C_SLOTS)[len(entries) % 6]
+        tid = (seed.to_bytes(2, "big") + len(entries).to_bytes(4, "big")
+               ).rjust(16, b"\x00")
+        sd = SearchData(trace_id=tid)
+        sd.start_s = 1_600_000_000 + seed * 100_000 + len(entries)
+        sd.end_s = sd.start_s + 3
+        sd.dur_ms = rng.randint(1, 20_000)
+        sd.kvs = {}
+        if with_key and take:
+            sd.kvs["customer.id"] = {_marked((n + i) % pool)
+                                     for i in range(take)}
+            n += take
+        if take < C_SLOTS:
+            sd.kvs["svc"] = {rng.choice(["svc-a", "svc-b"])}
+        entries.append(sd)
+        if not with_key and len(entries) >= 60:
+            break
+    return entries, ColumnarPages.build(entries, PageGeometry(32, C_SLOTS))
+
+
+@pytest.fixture(scope="module")
+def multivalued():
+    """Two blocks whose dictionaries hold the whole pool (1,300 values,
+    value id 0 a hit of every needle), and one without the key at all:
+    its term key is the -1 sentinel."""
+    built = [_multivalued_block(1, 1300), _multivalued_block(2, 1300),
+             _multivalued_block(3, 0, with_key=False)]
+    return ([sd for entries, _ in built for sd in entries],
+            [pages for _, pages in built])
+
+
+def _width_reqs(R: int) -> list:
+    needle = f"-m{MARKS[R]}."
+    reqs = [_req({"customer.id": needle}),
+            _req({"customer.id": needle, "svc": "svc-a"}),
+            _req({"customer.id": needle, "svc": "svc-b"},
+                 min_duration_ms=4_000),
+            _req({"customer.id": needle}, max_duration_ms=15_000)]
+    for r in reqs:
+        r.limit = 1000
+    return reqs
+
+
+def _launch(eng, batch, mqs, fused: bool, top_k: int) -> list:
+    """[(count, inspected, scores, idx)] a member: each alone, or all
+    in one fused launch."""
+    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.multiblock import stack_queries
+
+    if not fused:
+        return [eng.scan(batch, mq) for mq in mqs]
+    cq = stack_queries(mqs)
+    if len({mq.n_terms for mq in mqs}) > 1:
+        # the one-term member rides with its second term inactive
+        assert cq.n_terms == 2 and not cq.term_active[0, 1]
+    counts, inspected, scores, idx = fetch_coalesced_out(
+        eng.coalesced_scan_async(batch, cq, top_k))
+    return [(int(counts[i]), inspected, scores[i], idx[i])
+            for i in range(len(mqs))]
+
+
+def _check_launches(launch, R, entries, blocks, packed=False):
+    """Every request of width R through one launch kind: count and
+    matches equal the per-entry oracle's and the slot formula's over the
+    staged columns."""
+    from tempo_tpu.parallel import make_mesh
+    from tempo_tpu.search import packing
+    from tempo_tpu.search.data import search_data_matches
+
+    top_k = 1024
+    eng = MultiBlockEngine(
+        top_k=top_k, mesh=make_mesh(8) if launch.startswith("mesh") else None)
+    batch = eng.stage(blocks)
+    assert (batch.widths is not None) == packed
+    reqs = _width_reqs(R)
+    mqs = [compile_multi(blocks, r, cache_on=batch) for r in reqs]
+    assert all(mq.val_hits is None and mq.val_ranges.shape[2] == R
+               for mq in mqs)
+    assert (mqs[0].term_keys[2] == -1).all()
+    n = 4 if launch in ("fused4", "mesh_fused") else 2
+    mqs, reqs = mqs[:n], reqs[:n]
+    outs = _launch(eng, batch, mqs, "fused" in launch, top_k)
+    d = {k: np.asarray(v) for k, v in batch.device.items()}
+    kw, vw, _ = batch.widths or (None, None, None)
+    kk = np.asarray(packing.unpack_ids(batch.device["kv_key"], kw))
+    vv = np.asarray(packing.unpack_ids(batch.device["kv_val"], vw))
+    for req, mq, (count, _inspected, scores, idx) in zip(reqs, mqs, outs):
+        expected = {sd.trace_id for sd in entries
+                    if search_data_matches(sd, req)}
+        assert len(expected) > 1 or R == 1
+        assert count == len(expected)
+        ids = {bytes.fromhex(m.trace_id)
+               for m in eng.results(batch, mq, scores, idx)}
+        assert ids == expected
+        by_slot = _slot_formula(kk, vv, d["entry_valid"], d["page_block"],
+                                mq.term_keys, mq.val_ranges)
+        dur = d["entry_dur"].astype(np.int64)
+        by_slot &= (dur >= mq.dur_lo) & (dur <= mq.dur_hi)
+        assert set(np.flatnonzero(by_slot).tolist()) == set(
+            idx[scores >= 0].tolist())
+
+
+@pytest.mark.parametrize("R", WIDTHS)
+@pytest.mark.parametrize("launch", LAUNCHES)
+def test_launch_kinds_answer_as_the_oracle_at_every_width(
+        launch, R, multivalued):
+    """Solo, fused (two and four members, one with an inactive padded
+    term), and both on a mesh of the host's eight devices: entries with
+    0, 1, 2, 5, C - 1 and C values of the term's key, value id 0, pads,
+    a block whose term key is the -1 sentinel, ranges that are
+    sentinels past a block's own runs."""
+    _check_launches(launch, R, *multivalued)
+
+
+@pytest.mark.parametrize("R", [8, 16, 512])
+@pytest.mark.parametrize("launch", ["solo", "fused4", "mesh_fused"])
+def test_packed_widths_answer_alike(launch, R, multivalued):
+    """The same under packed residency: the unpack runs inside the term
+    body, before either range form."""
+    from tempo_tpu.search import packing
+
+    packing.configure(enabled=True)
+    try:
+        _check_launches(launch, R, *multivalued, packed=True)
+    finally:
+        packing.configure(enabled=False)
+
+
+@pytest.mark.parametrize("R", [16, 64])
+@pytest.mark.parametrize("launch", ["solo", "fused2"])
+def test_a_mask_group_beside_wide_host_ranges(launch, R, multivalued):
+    """One launch, both arms of the kernel's `where(probe_page, ...)`
+    in the entry form: a block over the floor whose needle passes R_MAX
+    leaves the probe as a hit mask, a block under it is compiled on the
+    host to R ranges (multi-valued key and all)."""
+    from tempo_tpu.search.data import search_data_matches
+    from tempo_tpu.search.multiblock import ENTRY_RANGES
+
+    assert R >= ENTRY_RANGES
+    big_entries = [sd for sd in multivalued[0]
+                   if sd.trace_id[10:12] == (1).to_bytes(2, "big")]
+    small_entries, small = _multivalued_block(7, 2 * MARKS[R])
+    entries, blocks = big_entries + small_entries, [multivalued[1][0], small]
+    eng = MultiBlockEngine(top_k=1024, device_probe_min_vals=500)
+    batch = eng.stage(blocks)
+    assert len(batch.staged_dicts) == 1
+    reqs = _width_reqs(R)[:2]
+    mqs = [compile_multi(blocks, r, cache_on=batch) for r in reqs]
+    for mq in mqs:
+        assert mq.val_hits is not None and mq.val_ranges.shape[2] == R
+        assert mq.block_group.tolist() == [0, -1]
+    outs = _launch(eng, batch, mqs, launch != "solo", 1024)
+    for req, mq, (count, _inspected, scores, idx) in zip(reqs, mqs, outs):
+        expected = {sd.trace_id for sd in entries
+                    if search_data_matches(sd, req)}
+        in_small = {sd.trace_id for sd in small_entries} & expected
+        assert in_small and expected - in_small
+        assert count == len(expected)
+        assert {bytes.fromhex(m.trace_id) for m in eng.results(
+            batch, mq, scores, idx)} == expected
+
+
+def test_counter_and_span_say_which_range_form_a_launch_traced(multivalued):
+    """`tempo_search_scan_range_compare_total{by}` and `compare` on the
+    launch's span follow the launch's R alone; the membership counter
+    counts as it did."""
+    from tempo_tpu.search.multiblock import ENTRY_RANGES
+
+    blocks = multivalued[1]
+    jobs = _jobs(blocks)
+    under, at = max(r for r in WIDTHS if r < ENTRY_RANGES), ENTRY_RANGES
+    collector = tracing.CollectExporter()
+    tracing.set_tracer(tracing.Tracer(tracing.SyncProcessor(collector)))
+    try:
+        b = BlockBatcher(coalesce_max_queries=1, device_probe_min_vals=0)
+        before = {k: obs.scan_range_compare.value(by=k)
+                  for k in ("slot", "entry")}
+        members = {p: obs.scan_membership.value(path=p)
+                   for p in ("range", "mask")}
+        with tracing.start_span("test.root"):
+            for R in (1, under, at, 512):
+                b.search(jobs, _width_reqs(R)[0])
+    finally:
+        tracing.set_tracer(None)
+    assert {k: obs.scan_range_compare.value(by=k) - before[k]
+            for k in before} == {"slot": 2, "entry": 2}
+    assert {p: obs.scan_membership.value(path=p) - members[p]
+            for p in members} == {"range": 4, "mask": 0}
+    launched = [dict(s.attributes) for s in collector.spans
+                if s.name in ("dispatch.execute", "dispatch.compile")
+                and dict(s.attributes).get("mode") == "batched"]
+    assert [a["compare"] for a in launched] == [
+        "slot", "slot", "entry", "entry"]
+    assert {a["membership"] for a in launched} == {"range"}

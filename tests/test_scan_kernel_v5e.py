@@ -1,0 +1,77 @@
+"""The scan program compiled for a v5e that is described, not attached
+(`jax.experimental.topologies`): what the TPU compiler makes of the
+entry form of the range compares (search/multiblock.py
+`multi_entry_mask`, PR 36) at a share16 group's shapes. Nothing runs,
+so nothing here is a time.
+
+Held: the kv columns go through the term loop as they are staged, the
+entries on the lanes. With a range table under 32 wide beside them the
+compiler took them through a two-term loop slot-minor instead: a copy
+of both columns for every launch, 3.2 GB of scratch, 17.0 ms a launch
+where 2.9 is due (my chip run, PR 36). `_RANGE_BLOCK_MIN` pads the
+table; this file says if a compiler or a change undoes that.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+P, E, C, B = 4096, 1024, 16, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it from being described
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("Q,T,R,val_dtype", [
+    (None, 2, 8, jnp.int32),      # highcard.substring: int32 ids
+    (None, 2, 16, jnp.int16),     # share16.triage: the role infix
+    (4, 2, 16, jnp.int16),        # and its fused launch
+], ids=["solo-2x8-int32", "solo-2x16-int16", "fused-4x2x16-int16"])
+def test_entry_form_keeps_the_kv_columns_entry_minor(
+        Q, T, R, val_dtype, one_chip, no_compile_cache):
+    from tempo_tpu.search.engine import DEFAULT_TOP_K, resolve_top_k
+    from tempo_tpu.search.multiblock import ENTRY_RANGES, batch_scan_kernel
+
+    assert R >= ENTRY_RANGES
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cols = (S((P, E, C), jnp.int8), S((P, E, C), val_dtype),
+            S((P, E), jnp.uint32), S((P, E), jnp.uint32),
+            S((P, E), jnp.uint32), S((P, E), jnp.bool_), S((P,), jnp.int32))
+    q = () if Q is None else (Q,)
+    tables = (S((*q, B, T), jnp.int32), S((*q, B, T, R, 2), jnp.int32),
+              None if Q is None else S((Q, T), jnp.bool_),
+              *[S(q, jnp.uint32)] * 4)
+    compiled = batch_scan_kernel.lower(
+        *cols, *tables, n_terms=T,
+        top_k=resolve_top_k(DEFAULT_TOP_K, 20)).compile()
+    text = compiled.as_text()
+    assert "copy(%kv_key" not in text and "copy(%kv_val" not in text
+    # the fused launch's [Q, P, E, C] key matches are 0.27 GB of it
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
