@@ -61,6 +61,23 @@ line and writes it to
                mesh, and the `jax.device_put` that places them alone.
                In the window the same call is the `execute` stage of
                `mesh.stages`, one thread among many, GIL waits included
+  host         what the host's cores did (`chipbench/layers/hostcpu.py`
+               holds the arithmetic): `host_cores_busy`, the delta of
+               `process_cpu_seconds_total` (and its user and system
+               parts) over the window, which `--trace 0` reads too; by
+               span name over the window `n` and, per search, ms of
+               `wall_self`, `cpu_self` and `off_self` (a span's wall
+               and `thread.cpu_ns` less those of the spans of ITS
+               THREAD directly inside it; off = wall - cpu of the
+               name's SUMS: the thread off a core), with `named_wait`
+               on the spans whose code blocks by design; the four span
+               metrics; `spanned_cores`, the CPU the spans' threads
+               burned together over the time from the first search's
+               start to the last one's end (1.0 is one interpreter
+               lock's worth); and the put's
+               `cpu_over_wall` from `batcher.place` (a put that burns
+               its wall on a core holds the host, one that sleeps
+               does not)
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -386,6 +403,67 @@ def probe_facts(view: dict) -> dict:
     return out
 
 
+def host_facts(view: dict) -> dict:
+    """The `host` block. On a tree without the counter or the CPU
+    stamps the numbers are None and `by_span` is empty."""
+    from chipbench.layers import hostcpu
+
+    counted = hostcpu.PROCESS_CPU in view["counters"]["after"]
+    rows = hostcpu.self_times(view["spans"])
+    traces = sp.searches(view["spans"])
+    n = len(traces) or None
+    by_span: dict = {}
+    for s, wall, cpu, away in rows:
+        r = by_span.setdefault(s["name"], [0, 0, 0, 0])
+        r[0] += 1
+        r[1] += wall
+        r[2] += cpu
+        # off a core in its own code, by name (never span by span:
+        # where CPU is accounted by the tick only sums mean anything)
+        r[3] += wall - cpu - away
+    places = [s for s in hostcpu.stamped(view["spans"])
+              if s["name"] == "batcher.place"]
+    place_wall = sum(s["end_ns"] - s["start_ns"] for s in places)
+    place_cpu = sum(s["attributes"][hostcpu.CPU] for s in places)
+    # from the first search's start to the last one's end: the load,
+    # without what the benchmark does after it (in a traced run the
+    # read of the profiler's trace, which `window_wall_s` holds) and
+    # without a poll that comes later still
+    edges = [(s["start_ns"], s["end_ns"]) for ss in traces.values()
+             for s in ss]
+    extent = (max(b for _a, b in edges) - min(a for a, _b in edges)
+              if edges else 0)
+    spanned = sum(cpu for _s, _w, cpu, _a in rows)
+    return {
+        "host_cores_busy": hostcpu.cores_busy(view),
+        "process_cpu_s": {
+            part or "total": delta(view, "process_cpu_%sseconds_total"
+                                   % (part and part + "_"))
+            for part in ("", "user", "system")} if counted else None,
+        "window_wall_s": view["window_wall_s"],
+        "search_cpu_ms": hostcpu.search_cpu_ms(view),
+        "launch_cpu_ms": hostcpu.launch_cpu_ms(view),
+        "unnamed_offcore_share": hostcpu.unnamed_offcore_share(view),
+        "spanned_cpu_share": hostcpu.spanned_cpu_share(view),
+        # cores the spans' threads kept busy together: 1.0 is one
+        # interpreter lock's worth
+        "spanned_cpu_s": spanned / 1e9, "span_extent_s": extent / 1e9,
+        "spanned_cores": spanned / extent if extent else None,
+        "threads": len({s["attributes"][hostcpu.TID] for s, *_ in rows}),
+        "searches": n,
+        "by_span": {name: {
+            "n": r[0], "named_wait": name in hostcpu.NAMED_WAITS,
+            "wall_self_ms": r[1] / n / 1e6, "cpu_self_ms": r[2] / n / 1e6,
+            "off_self_ms": max(0, r[3]) / n / 1e6}
+            for name, r in sorted(by_span.items(),
+                                  key=lambda kv: -kv[1][1])} if n else {},
+        "place": {"n": len(places), "wall_s": place_wall / 1e9,
+                  "cpu_s": place_cpu / 1e9,
+                  "cpu_over_wall": (place_cpu / place_wall
+                                    if place_wall else None)},
+    }
+
+
 def report(view: dict, e2e_names: list) -> dict:
     out: dict = {"workload": view["workload"], "end_to_end": {}}
     for name in e2e_names:
@@ -407,6 +485,7 @@ def report(view: dict, e2e_names: list) -> dict:
     out["mesh"] = mesh_facts(view)
     out["staging"] = staging_facts(view)
     out["probe"] = probe_facts(view)
+    out["host"] = host_facts(view)
     traces = sp.searches(spans)
     if not traces:
         return out

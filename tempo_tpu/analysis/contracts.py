@@ -276,6 +276,15 @@ GUARDED_CALLS = (
                 "get_tracer", "self_tracing"),
     GuardedCall("DEVICE_TIMELINE", ("watch",), (), "recording",
                 "get_tracer", "self_tracing"),
+    # the thread's CPU clock is read only where a span will be written:
+    # a stamp site is `c0 = tracing.cpu_ns() if span.recording else None`,
+    # one test of a flag it already holds; the profiler's stage timers
+    # hold theirs as `rec.intervals is not None` (kept only while a
+    # tracer is installed)
+    GuardedCall("tracing", ("cpu_ns",), (), "recording",
+                "get_tracer", "self_tracing"),
+    GuardedCall("tracing", ("cpu_ns",), (), "intervals",
+                "intervals", "self_tracing"),
     # flight-recorder triggers (breaker trip, watchdog, slow query)
     # live on failure paths of otherwise-hot code: each site reads
     # RECORDER.enabled before snapshotting state into a bundle
@@ -328,6 +337,20 @@ def _mention_polarities(test: ast.AST, rule: GuardedCall) -> set:
 
 def _test_mentions_negated(test: ast.AST, rule: GuardedCall) -> bool:
     return "negated" in _mention_polarities(test, rule)
+
+
+def _branch_guards(test: ast.AST, rules, guards: frozenset) -> tuple:
+    """(guards of the branch taken when `test` is truthy, guards of the
+    other branch): polarity-aware, for an `if` statement and for a
+    conditional expression alike."""
+    body_g, else_g = guards, guards
+    for rule in rules:
+        pol = _mention_polarities(test, rule)
+        if "positive" in pol:
+            body_g = body_g | {rule.knob}
+        if "negated" in pol:
+            else_g = else_g | {rule.knob}
+    return body_g, else_g
 
 
 def _receiver_name(fn: ast.Attribute) -> str | None:
@@ -461,13 +484,8 @@ class NoopContractChecker(Checker):
                     # truthy-with-gate-OFF — `if X.active: ... else:
                     # X.hit()` runs the record protocol exactly on the
                     # disabled path and must NOT get guard credit
-                    body_g, else_g = g, g
-                    for rule in self.guarded:
-                        pol = _mention_polarities(stmt.test, rule)
-                        if "positive" in pol:
-                            body_g = body_g | {rule.knob}
-                        if "negated" in pol:
-                            else_g = else_g | {rule.knob}
+                    body_g, else_g = _branch_guards(stmt.test,
+                                                    self.guarded, g)
                     walk(stmt.body, body_g)
                     walk(stmt.orelse, else_g)
                 elif isinstance(stmt, (ast.For, ast.While, ast.With,
@@ -497,20 +515,29 @@ class NoopContractChecker(Checker):
                      getattr(stmt, "iter", None)]
             exprs += [item.context_expr
                       for item in getattr(stmt, "items", [])]
-            nodes = [n for e in exprs if e is not None
-                     for n in ast.walk(e)]
+            roots = [e for e in exprs if e is not None]
         else:
-            nodes = list(ast.walk(stmt))
-        for node in nodes:
+            roots = [stmt]
+        # (node, the guards that dominate it): a conditional expression
+        # `X if <guard> else Y` guards X as an `if` guards its body and
+        # Y as its else branch
+        todo = [(r, guards) for r in roots]
+        while todo:
+            node, g = todo.pop()
+            if isinstance(node, ast.IfExp):
+                body_g, else_g = _branch_guards(node.test, self.guarded, g)
+                todo += [(node.test, g), (node.body, body_g),
+                         (node.orelse, else_g)]
+                continue
+            todo += [(c, g) for c in ast.iter_child_nodes(node)]
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)):
                 continue
             for rule in self.guarded:
                 if not _rule_matches(rule, node.func):
                     continue
-                if rule.knob in guards:
+                if rule.knob in g:
                     continue
-                # conditional-expression guard: X if <guard> else Y
                 findings.append(Finding(
                     checker=self.id, path=mod.rel, line=node.lineno,
                     message=(f"{qual}() calls {rule.receiver}."

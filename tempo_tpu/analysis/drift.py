@@ -24,7 +24,7 @@ from .core import Checker, Finding, Package
 
 # metric-name prefixes the observability catalog covers (matches the
 # legacy grep in tests/test_observability.py)
-_METRIC_PREFIXES = ("tempo", "tempodb", "traces")
+_METRIC_PREFIXES = ("tempo", "tempodb", "traces", "process")
 
 
 @dataclass(frozen=True)

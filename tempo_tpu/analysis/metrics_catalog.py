@@ -36,7 +36,7 @@ import re
 from .core import Checker, Finding, Package
 
 # prefixes the observability catalog covers (mirrors drift.metric_names)
-_METRIC_PREFIXES = ("tempo", "tempodb", "traces")
+_METRIC_PREFIXES = ("tempo", "tempodb", "traces", "process")
 _CTORS = ("Counter", "Gauge", "Histogram")
 
 # every metric method whose **kwargs are label names

@@ -17,6 +17,7 @@ classic Prometheus text format (0.0.4) is byte-identical to before.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from bisect import bisect_left
@@ -91,6 +92,29 @@ class _Metric:
 class Counter(_Metric):
     kind = "counter"
 
+    def __init__(self, name: str, help_: str = "",
+                 registry: "Registry | None" = None, read=None):
+        """`read`: for a counter the operating system keeps for the
+        process (its CPU clocks): the unlabelled series is `read()`,
+        taken whenever the counter is rendered, sampled or asked for;
+        nothing writes it, so it costs no request anything."""
+        super().__init__(name, help_, registry)
+        self._read = read
+
+    def _refresh(self) -> None:
+        if self._read is not None:
+            v = self._read()
+            with self._lock:
+                self._series[()] = v
+
+    def expose(self, openmetrics: bool = False) -> str:
+        self._refresh()
+        return super().expose(openmetrics)
+
+    def samples(self) -> list[Sample]:
+        self._refresh()
+        return super().samples()
+
     def inc(self, n: float = 1, **labels: LabelValue) -> None:
         k = self._key(labels)
         with self._lock:
@@ -108,6 +132,7 @@ class Counter(_Metric):
         the right count for a reader that names fewer labels than the
         writers set (`scan_dispatches.value(mode="batched")` over every
         `shards`)."""
+        self._refresh()
         want = set(labels.items())
         # locked like every writer: a bare dict read races resize-in-
         # progress under free-threading and misses published updates
@@ -763,6 +788,25 @@ selftrace_export_failures = Counter(
     "flush loop; this counter is the only visible signal)")
 
 # ---- build identity ----
+# the process's CPU clocks under upstream's names (its Prometheus
+# client's process collector): read when /metrics is rendered, never on
+# a request path. rate(process_cpu_seconds_total[1m]) near 1.0 on a busy
+# querier says one interpreter lock is the limit, not the chip
+process_cpu_seconds = Counter(
+    "process_cpu_seconds_total",
+    "total user and system CPU time spent by the process in seconds "
+    "(every thread: the interpreter's, jax's, the profiler's)",
+    read=time.process_time)
+process_cpu_user_seconds = Counter(
+    "process_cpu_user_seconds_total",
+    "user CPU time spent by the process in seconds",
+    read=lambda: os.times().user)
+process_cpu_system_seconds = Counter(
+    "process_cpu_system_seconds_total",
+    "system CPU time spent by the process in seconds (the kernel's "
+    "work for it: page faults of a large put, socket IO)",
+    read=lambda: os.times().system)
+
 build_info = Gauge(
     "tempo_build_info",
     "constant 1; the process's build/runtime identity rides the labels "

@@ -121,6 +121,7 @@ class _NoopDispatch:
 
     __slots__ = ()
     enabled = False
+    intervals = None
 
     def stage(self, name):
         return _NOOP_STAGE
@@ -128,7 +129,8 @@ class _NoopDispatch:
     def add_stage(self, name, seconds):
         return self
 
-    def add_interval(self, name, start_ns, end_ns):
+    def add_interval(self, name, start_ns, end_ns, cpu_start_ns=None,
+                     cpu_end_ns=None):
         return self
 
     def add_bytes(self, h2d=0, d2h=0):
@@ -158,7 +160,7 @@ NOOP_DISPATCH = _NoopDispatch()
 
 
 class _StageTimer:
-    __slots__ = ("_rec", "_name", "_t0")
+    __slots__ = ("_rec", "_name", "_t0", "_c0")
 
     def __init__(self, rec, name):
         self._rec = rec
@@ -166,10 +168,16 @@ class _StageTimer:
 
     def __enter__(self):
         self._t0 = tracing.now_ns()
+        # the thread's CPU clock beside it, only where the interval
+        # will become a span
+        self._c0 = (tracing.cpu_ns() if self._rec.intervals is not None
+                    else None)
         return self
 
     def __exit__(self, *a):
-        self._rec.add_interval(self._name, self._t0, tracing.now_ns())
+        t1 = tracing.now_ns()
+        c1 = tracing.cpu_ns() if self._rec.intervals is not None else None
+        self._rec.add_interval(self._name, self._t0, t1, self._c0, c1)
         return False
 
 
@@ -184,9 +192,10 @@ class Dispatch:
     def __init__(self, prof, mode: str):
         self.mode = mode
         self.stages: dict[str, float] = {}
-        # (stage, start_ns, end_ns) as the stage timers read them, kept
-        # only while a tracer is installed: _finish writes them as
-        # `dispatch.<stage>` spans
+        # (stage, start_ns, end_ns, cpu_start_ns, cpu_end_ns) as the
+        # stage timers read them (the CPU stamps None where the site
+        # took none), kept only while a tracer is installed: _finish writes
+        # them as `dispatch.<stage>` spans
         self.intervals = [] if tracing.get_tracer() is not None else None
         self.h2d_bytes = 0
         self.d2h_bytes = 0
@@ -204,13 +213,17 @@ class Dispatch:
         self.stages[name] = self.stages.get(name, 0.0) + seconds
         return self
 
-    def add_interval(self, name: str, start_ns: int,
-                     end_ns: int) -> "Dispatch":
+    def add_interval(self, name: str, start_ns: int, end_ns: int,
+                     cpu_start_ns: int | None = None,
+                     cpu_end_ns: int | None = None) -> "Dispatch":
         """A stage from two `tracing.now_ns()` stamps: its seconds and
-        its span are the same two clock reads."""
+        its span are the same two clock reads. The two `cpu_ns()`
+        stamps, taken beside them while `intervals` is kept, go to the
+        span alone."""
         self.add_stage(name, (end_ns - start_ns) / 1e9)
         if self.intervals is not None:
-            self.intervals.append((name, start_ns, end_ns))
+            self.intervals.append((name, start_ns, end_ns, cpu_start_ns,
+                                   cpu_end_ns))
         return self
 
     def add_bytes(self, h2d: int = 0, d2h: int = 0) -> "Dispatch":
@@ -451,10 +464,10 @@ class DispatchProfiler:
         observed. A stage that was only given a duration (add_stage)
         has no span: a start and end nobody read from the clock would
         be worse than none on a timeline shared with the device's."""
-        for stage, start_ns, end_ns in rec.intervals:
+        for stage, start_ns, end_ns, cpu0, cpu1 in rec.intervals:
             span = tracing.start_span(f"dispatch.{stage}", parent=parent,
-                                      start_ns=start_ns, stage=stage,
-                                      mode=rec.mode)
+                                      start_ns=start_ns, cpu_start_ns=cpu0,
+                                      stage=stage, mode=rec.mode)
             if stage == "h2d" and rec.h2d_bytes:
                 span.set_attribute("bytes", rec.h2d_bytes)
             elif stage == "d2h" and rec.d2h_bytes:
@@ -465,7 +478,7 @@ class DispatchProfiler:
                             "membership", "compare"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
-            span.end(end_ns)
+            span.end(end_ns, cpu1)
 
     # ---- operator surface ----
 

@@ -62,6 +62,21 @@ def now_ns() -> int:
     return _CLOCK_OFFSET_NS + time.perf_counter_ns()
 
 
+def cpu_ns() -> int:
+    """CPU the calling thread has burned so far (user and system,
+    `CLOCK_THREAD_CPUTIME_ID`): the one function every CPU stamp of a
+    span goes through. Two reads at the two edges a span already stamps
+    give the time its thread was on a core between them; the wall time
+    less that is the time it was off it (asleep, or runnable behind the
+    interpreter lock: the clock cannot tell which). Read only where a
+    span will be written: with no tracer installed no site calls it.
+    Its step is the kernel's: nanoseconds where the scheduler's clock is
+    fine, one tick (10 ms) where CPU is accounted by the tick, as on the
+    v5e hosts this was measured on; there one span's difference is 0 or
+    a whole tick and only sums over many spans mean anything."""
+    return time.thread_time_ns()
+
+
 def _reanchor_clock() -> None:
     """Take the anchor anew once the wall clock has moved a millisecond
     off it: called when a tracer is installed, the one moment no span
@@ -86,16 +101,22 @@ class Span:
 
     __slots__ = ("name", "context", "parent_span_id", "kind", "start_ns",
                  "end_ns", "attributes", "events", "status_code",
-                 "status_message", "_tracer", "_token")
+                 "status_message", "_tracer", "_token", "_cpu_start_ns",
+                 "_thread_id")
 
     def __init__(self, tracer, name: str, context: SpanContext,
                  parent_span_id: bytes | None, kind: int,
-                 start_ns: int | None = None):
+                 start_ns: int | None = None,
+                 cpu_start_ns: int | None = None):
         self.name = name
         self.context = context
         self.parent_span_id = parent_span_id
         self.kind = kind
         self.start_ns = start_ns or now_ns()
+        # `cpu_ns()` of the thread that starts the span, taken beside
+        # the start stamp, and that thread (`start_span` below)
+        self._cpu_start_ns = cpu_start_ns
+        self._thread_id = threading.get_ident()
         self.end_ns = 0
         self.attributes: dict = {}
         self.events: list = []
@@ -131,13 +152,34 @@ class Span:
                           "exception.message": str(exc)})
         return self.set_status(STATUS_ERROR, str(exc))
 
-    def end(self, end_ns: int | None = None) -> None:
+    def end(self, end_ns: int | None = None,
+            cpu_end_ns: int | None = None) -> None:
         """end_ns: a `now_ns()` stamp the caller already took at the
         edge this span ends on (a wait that ended on another thread, a
-        stage whose histogram sample reads the same stamp)."""
+        stage whose histogram sample reads the same stamp);
+        cpu_end_ns: the `cpu_ns()` stamp taken beside it.
+
+        A span that has a CPU stamp at both edges, taken on one thread,
+        says so: `thread.id`, and `thread.cpu_ns`, the CPU that thread
+        burned between them, children included. One ended on another
+        thread than started it, or written from wall stamps alone, is a
+        wait that crossed threads and carries neither."""
         if self.end_ns:
             return
-        self.end_ns = end_ns or now_ns()
+        # `is not None`: a young thread's CPU clock reads 0 where the
+        # kernel accounts CPU by the tick
+        on_its_thread = (self._cpu_start_ns is not None
+                         and threading.get_ident() == self._thread_id)
+        if end_ns:
+            self.end_ns = end_ns
+        else:
+            self.end_ns = now_ns()
+            if on_its_thread:
+                cpu_end_ns = cpu_ns()
+        if on_its_thread and cpu_end_ns is not None:
+            self.attributes["thread.id"] = self._thread_id
+            self.attributes["thread.cpu_ns"] = (cpu_end_ns
+                                                - self._cpu_start_ns)
         if self.context.sampled:
             self._tracer._on_end(self)
 
@@ -177,7 +219,7 @@ class _NoopSpan:
     def record_exception(self, exc):
         return self
 
-    def end(self, end_ns=None):
+    def end(self, end_ns=None, cpu_end_ns=None):
         pass
 
     def __enter__(self):
@@ -217,7 +259,7 @@ class NonRecordingSpan:
     def record_exception(self, exc):
         return self
 
-    def end(self, end_ns=None):
+    def end(self, end_ns=None, cpu_end_ns=None):
         pass
 
     def __enter__(self):
@@ -245,11 +287,14 @@ class Tracer:
 
     def start_span(self, name: str, kind: int = KIND_INTERNAL,
                    parent: SpanContext | None = None,
-                   start_ns: int | None = None, **attributes):
+                   start_ns: int | None = None,
+                   cpu_start_ns: int | None = None, **attributes):
         """`start_ns`: a `now_ns()` stamp taken where the spanned work
         began — with `parent=` a context captured there and
         `end(end_ns=)`, a wait that crossed threads is written after
-        the fact from its two stamps (`record_span`)."""
+        the fact from its two stamps (`record_span`). `cpu_start_ns`:
+        the `cpu_ns()` stamp taken beside `start_ns`, by the thread
+        that writes the span."""
         if _suppressed.get():
             return NOOP_SPAN
         cur = _current_span.get()
@@ -268,7 +313,8 @@ class Tracer:
                                                 or b"\x00" * 8, False))
         ctx = SpanContext(trace_id,
                           self._rng.getrandbits(64).to_bytes(8, "big"), True)
-        span = Span(self, name, ctx, parent_id, kind, start_ns)
+        span = Span(self, name, ctx, parent_id, kind, start_ns,
+                    cpu_start_ns)
         if attributes:
             span.attributes.update(attributes)
         return span
@@ -554,22 +600,33 @@ def get_tracer() -> Tracer | None:
 
 def start_span(name: str, kind: int = KIND_INTERNAL,
                parent: SpanContext | None = None,
-               start_ns: int | None = None, **attributes):
-    """Module-level convenience: noop when no tracer is installed."""
+               start_ns: int | None = None,
+               cpu_start_ns: int | None = None, **attributes):
+    """The one entry the program's span sites use: noop when no tracer
+    is installed. A span that takes its own start stamp (`start_ns` not
+    given) takes its thread's CPU stamp here too; one written from a
+    stamp taken earlier has a CPU stamp only if the call site took one
+    there, on this thread, and hands it over."""
     t = _tracer
     if t is None:
         return NOOP_SPAN
+    if not start_ns:
+        cpu_start_ns = cpu_ns()
     return t.start_span(name, kind=kind, parent=parent, start_ns=start_ns,
-                        **attributes)
+                        cpu_start_ns=cpu_start_ns, **attributes)
 
 
 def record_span(name: str, start_ns: int, end_ns: int,
-                parent: SpanContext | None = None, **attributes) -> None:
-    """Write a finished span from two `now_ns()` stamps. Call sites on
-    hot paths guard on `get_tracer() is not None` (or a recording
-    span) BEFORE building the attributes."""
+                parent: SpanContext | None = None,
+                cpu_start_ns: int | None = None,
+                cpu_end_ns: int | None = None, **attributes) -> None:
+    """Write a finished span from two `now_ns()` stamps and, where the
+    calling thread took them beside those, two `cpu_ns()` stamps. Call
+    sites on hot paths guard on `get_tracer() is not None` (or a
+    recording span) BEFORE building the attributes."""
     start_span(name, parent=parent, start_ns=start_ns,
-               **attributes).end(end_ns)
+               cpu_start_ns=cpu_start_ns, **attributes).end(end_ns,
+                                                            cpu_end_ns)
 
 
 def current_span():

@@ -51,6 +51,10 @@ def locked_collective(rec=None):
 
     timeout = GUARD.lock_timeout_s
     t0 = tracing.now_ns()
+    # the wait as a `dispatch.lock_wait` span: its thread's CPU clock
+    # beside the stamps, only where the record keeps intervals
+    c0 = (tracing.cpu_ns()
+          if rec is not None and rec.intervals is not None else None)
     if timeout and timeout > 0:
         ok = dispatch_lock.acquire(timeout=timeout)
     else:
@@ -64,7 +68,9 @@ def locked_collective(rec=None):
         raise DispatchLockTimeout(msg)
     try:
         if rec is not None:
-            rec.add_interval("lock_wait", t0, tracing.now_ns())
+            rec.add_interval(
+                "lock_wait", t0, tracing.now_ns(), c0,
+                tracing.cpu_ns() if rec.intervals is not None else None)
         if FAULTS.active:
             # simulates a dispatch wedged INSIDE the collective section
             # (holding the lock): later submitters hit the bounded wait

@@ -558,9 +558,10 @@ class QueryCoalescer:
                                      fused_q=len(items))
 
     def _trace_launch(self, lspan, items, batch, out, recs,
-                      launched: int) -> None:
+                      launched: int, cpu_launched: int | None) -> None:
         """Close one launch's spans at `launched`, the stamp taken when
-        the kernel call returned: `coalescer.launch` (open since the
+        the kernel call returned (`cpu_launched` the flushing thread's
+        CPU clock beside it): `coalescer.launch` (open since the
         flush began) ends there, the device timeline takes the outputs
         over, and every traced member gets its `coalescer.wait`, from
         its own submit to this launch, under its own `batcher.Search`.
@@ -580,8 +581,11 @@ class QueryCoalescer:
             kernel=kernel, shards=self.engine.n_shards,
             pages_per_shard=self.engine.pages_per_shard(batch),
             jit_cache=(recs[0].get("jit_cache", "") if recs else ""))
-        lspan.end(launched)
-        for _mq, _k, _fut, t_submit, _qs, parent in items:
+        lspan.end(launched, cpu_launched)
+        for _mq, _k, fut, t_submit, _qs, parent in items:
+            # the member's drain names the launch it slept on
+            # (`batcher.await_launch`)
+            fut.launch = launch
             if parent is not None:
                 tracing.record_span(
                     "coalescer.wait", t_submit, launched, parent=parent,
@@ -600,9 +604,10 @@ class QueryCoalescer:
             first = next((p for *_r, p in items
                           if p is not None and p.sampled), None)
             lspan = tracing.NOOP_SPAN
-            if first is not None:
-                lspan = tracing.start_span("coalescer.launch",
-                                           parent=first, start_ns=now)
+            if first is not None and tracing.get_tracer() is not None:
+                lspan = tracing.start_span(
+                    "coalescer.launch", parent=first, start_ns=now,
+                    cpu_start_ns=tracing.cpu_ns())
             structural = bool(
                 items and getattr(items[0][0], "structural", None)
                 is not None)
@@ -644,8 +649,9 @@ class QueryCoalescer:
                     with profile.collect_records() as recs:
                         out = self.engine.scan_async(grp.batch, mq)
                     launched = tracing.now_ns()
-                    self._trace_launch(lspan, items, grp.batch, out, recs,
-                                       launched)
+                    self._trace_launch(
+                        lspan, items, grp.batch, out, recs, launched,
+                        tracing.cpu_ns() if lspan.recording else None)
                 self._attribute(items, recs, (launched - t0d) / 1e9)
                 start_fetch(out)
                 obs.scan_dispatches.inc(mode="batched",
@@ -674,8 +680,9 @@ class QueryCoalescer:
                 with profile.collect_records() as recs:
                     out = self.engine.coalesced_scan_async(grp.batch, cq, k)
                 launched = tracing.now_ns()
-                self._trace_launch(lspan, items, grp.batch, out, recs,
-                                   launched)
+                self._trace_launch(
+                    lspan, items, grp.batch, out, recs, launched,
+                    tracing.cpu_ns() if lspan.recording else None)
             self._attribute(items, recs, (launched - t0d) / 1e9)
             obs.scan_dispatches.inc(mode="coalesced",
                                     shards=self.engine.n_shards)
@@ -1114,16 +1121,25 @@ class BlockBatcher:
             # into a device that stopped answering raises DeviceFault
             # (breaker fault booked) and the caller answers through the
             # host route
-            t0 = tracing.now_ns()
-            batch = robustness.GUARD.run(
-                "h2d", lambda: self.engine.place(host))
-            if tracing.get_tracer() is not None:
-                # the put alone, fenced (place_batch waits for the
-                # arrays): H2D apart from `_load_host`'s IO and stacking
+            def put():
+                """The put alone, fenced (place_batch waits for the
+                arrays): H2D apart from `_load_host`'s IO and stacking.
+                `batcher.place` is stamped and written by the thread
+                that does the put, which under the dispatch watchdog is
+                one of its workers: the span's `thread.cpu_ns` is then
+                the put's own, not that of the caller asleep on it."""
+                if tracing.get_tracer() is None:
+                    return self.engine.place(host)
+                t0, c0 = tracing.now_ns(), tracing.cpu_ns()
+                batch = self.engine.place(host)
                 tracing.record_span(
                     "batcher.place", t0, tracing.now_ns(),
                     parent=parent or tracing.current_span().context,
+                    cpu_start_ns=c0, cpu_end_ns=tracing.cpu_ns(),
                     bytes=int(batch.device_nbytes), blocks=len(group))
+                return batch
+
+            batch = robustness.GUARD.run("h2d", put)
             # batch.nbytes covers the stacked page arrays AND any staged
             # probe dictionaries — both live in HBM under this budget
             # (physical/packed bytes; the logical twin feeds the gauges)
@@ -1421,20 +1437,24 @@ class BlockBatcher:
                   "dispatch": 0.0, "drain": 0.0, "host_fallback": 0.0}
         t_search0 = tracing.now_ns()
 
-        def book(stage, t0, gi, **attrs):
+        def book(stage, t0, c0, gi, **attrs):
             """One stage interval ends now. Its seconds go to the stage
             sums; in a traced search the same two stamps make the
             `batcher.<stage>` child of `batcher.Search` (`span`, bound
-            below, before any stage runs). `gi` is the group's index in
-            the plan, whenever the walk took it."""
+            below, before any stage runs), and `c0`, this thread's
+            `cpu_ns()` beside `t0` (None in an untraced search), says how
+            much of the interval it was on a core. `gi` is the group's
+            index in the plan, whenever the walk took it."""
             t1 = tracing.now_ns()
             stages[stage] += (t1 - t0) / 1e9
             probes = attrs.pop("probes", None)
             if span.recording:
+                c1 = tracing.cpu_ns()
                 child = tracing.start_span(
                     "batcher.stage" if stage == "staging"
                     else "batcher." + stage, parent=span.context,
-                    start_ns=t0, group=gi, blocks=len(groups[gi]), **attrs)
+                    start_ns=t0, cpu_start_ns=c0, group=gi,
+                    blocks=len(groups[gi]), **attrs)
                 if probes is not None:
                     # the compile over the group's distinct dictionaries,
                     # inside `batcher.prepare` (compile_multi's stamps)
@@ -1442,7 +1462,7 @@ class BlockBatcher:
                     tracing.record_span("dict_probe.probe", p0, p1,
                                         parent=child.context,
                                         **probe_summary(probed))
-                child.end(t1)
+                child.end(t1, c1)
 
         def release(cached):
             """This search is done with `cached`: its pin goes, and with
@@ -1458,22 +1478,37 @@ class BlockBatcher:
             if span.recording:
                 dspan = tracing.start_span(
                     "batcher.drain", parent=span.context, start_ns=t0,
-                    group=item[0], blocks=len(item[2].jobs))
+                    cpu_start_ns=tracing.cpu_ns(), group=item[0],
+                    blocks=len(item[2].jobs))
             try:
                 drain(dspan, *item)
             finally:
                 release(item[2])
                 t1 = tracing.now_ns()
                 stages["drain"] += (t1 - t0) / 1e9
-                dspan.end(t1)
+                dspan.end(t1, tracing.cpu_ns() if dspan.recording else None)
 
         def drain(dspan, gi, gkey, cached, mq, pre, fut):
             try:
                 if hasattr(fut, "result"):  # coalescer Future vs tuple
                     # NOT timed as d2h: a coalescer Future's wait
                     # includes the coalescing window + the group's
-                    # stacking/dispatch
-                    fut = fut.result()
+                    # stacking/dispatch. Where a traced search has to
+                    # sleep on it (a launch this thread flushed itself
+                    # is done by now) the sleep is a span of its own,
+                    # `batcher.await_launch`: the drain waiting for
+                    # another thread's flush
+                    if dspan.recording and not fut.done():
+                        with tracing.start_span(
+                                "batcher.await_launch",
+                                parent=dspan.context, group=gi) as wspan:
+                            out = fut.result()
+                            launch = getattr(fut, "launch", None)
+                            if launch is not None:
+                                wspan.set_attribute("launch", launch)
+                        fut = out
+                    else:
+                        fut = fut.result()
                 # the ACTUAL device→host sync: fused-slice demux happens
                 # at unpack, the direct path syncs at the scalar/array
                 # fetches — time exactly these so stage=d2h means
@@ -1481,6 +1516,7 @@ class BlockBatcher:
                 # device can hang the SYNC even when the enqueue
                 # returned, and that hang must become a fault too.
                 t0d = tracing.now_ns()
+                c0d = tracing.cpu_ns() if dspan.recording else None
 
                 def _sync(fut=fut):
                     count, inspected, scores, idx, *ext = fut
@@ -1512,10 +1548,11 @@ class BlockBatcher:
                 return
             t1d = tracing.now_ns()
             d2h_s = (t1d - t0d) / 1e9
-            if span.recording:
+            if dspan.recording:
                 # what the `d2h` stage times: the one blocking sync
                 tracing.record_span("batcher.sync", t0d, t1d,
-                                    parent=dspan.context, group=gi)
+                                    parent=dspan.context, cpu_start_ns=c0d,
+                                    cpu_end_ns=tracing.cpu_ns(), group=gi)
             profile.observe_stage(
                 "d2h", "batched", d2h_s,
                 nbytes=scores.nbytes + idx.nbytes + 8, spanned=True)
@@ -1676,6 +1713,7 @@ class BlockBatcher:
             re-booking would inflate skipped_blocks and break the
             wedged-vs-healthy identity whenever a block dict-prunes."""
             t0 = tracing.now_ns()
+            c0 = tracing.cpu_ns() if span.recording else None
             group, gkey, hdr_reasons = groups[gi], gkeys[gi], reasons[gi]
             try:
                 host = self._host_batch(group)
@@ -1757,7 +1795,7 @@ class BlockBatcher:
                 if agg_counts:
                     results.add_agg(mq.agg_stage.decode(agg_counts[0]))
             finally:
-                book("host_fallback", t0, gi)
+                book("host_fallback", t0, c0, gi)
 
         # what the header prune decided, by plan index, once it is known
         # to this search: the per-job skip REASON list (None = scan the
@@ -1786,6 +1824,7 @@ class BlockBatcher:
             answer for every later search with this predicate. Only
             this, a miss, writes a `batcher.header_prune` span."""
             t0 = tracing.now_ns()
+            c0 = tracing.cpu_ns() if span.recording else None
             why = [block_header_skip_reason(j.header, req)
                    for j in groups[gi]]
             reasons[gi], live[gi] = why, not all(why)
@@ -1793,7 +1832,7 @@ class BlockBatcher:
                 self._prune_cache[(gkeys[gi], sig)] = why
                 while len(self._prune_cache) > _PRUNE_CACHE_MAX:
                     self._prune_cache.popitem(last=False)
-            book("header_prune", t0, gi)
+            book("header_prune", t0, c0, gi)
 
         def owned(gi):
             """Is group `gi` this member's to hold in HBM: one owned
@@ -1994,6 +2033,7 @@ class BlockBatcher:
                 # evicted, on its host-tier entry (`HostBatch.query_memo`):
                 # it dies with the last of the two
                 t0 = tracing.now_ns()
+                c0 = tracing.cpu_ns() if span.recording else None
                 if cached is None:
                     try:
                         # pinned from here (a look-ahead took its pin
@@ -2005,11 +2045,11 @@ class BlockBatcher:
                         # the staging H2D hit the wedged device (fault
                         # booked): host tier already holds the stacked
                         # arrays, answer from there
-                        book("staging", t0, gi)
+                        book("staging", t0, c0, gi)
                         host_route(gi)
                         continue
                     pinned.append(cached)
-                book("staging", t0, gi, cache=_event, pick=pick)
+                book("staging", t0, c0, gi, cache=_event, pick=pick)
                 obs.group_picks.inc(pick=pick)
                 if qs is not None:
                     qs.add_cache(_event)
@@ -2025,6 +2065,7 @@ class BlockBatcher:
                     result="miss" if pre is None else "hit")
                 if pre is None:
                     t0 = tracing.now_ns()
+                    c0 = tracing.cpu_ns() if span.recording else None
                     # attributed: query compilation can fire the device
                     # dictionary probe (mode=dict_probe) — that dispatch
                     # belongs to this query's bill (no wall fallback:
@@ -2034,7 +2075,7 @@ class BlockBatcher:
                         pre = prepare(group, cached.batch,
                                       [r is not None for r in hdr_reasons],
                                       hdr_reasons)
-                    book("prepare", t0, gi, terms=pre.get("n_terms", 0),
+                    book("prepare", t0, c0, gi, terms=pre.get("n_terms", 0),
                          probes=pre.pop("probes", None))
                     # a hit mask the memo keeps is HBM like a predicate's
                     # uploaded tables: charged to the batch, so the
@@ -2101,6 +2142,7 @@ class BlockBatcher:
                     mq._device_params = dp
                 results.metrics.skipped_blocks += pre["skipped"]
                 t0 = tracing.now_ns()
+                c0 = tracing.cpu_ns() if span.recording else None
                 if self.coalescer is not None:
                     # concurrent peers hitting this batch within the
                     # window share ONE fused kernel launch; a dispatch
@@ -2132,11 +2174,11 @@ class BlockBatcher:
                         # skips were already counted above, so the
                         # resubmit must not re-book them. Interest for
                         # this gkey is released by the outer finally.
-                        book("dispatch", t0, gi)
+                        book("dispatch", t0, c0, gi)
                         release(cached)
                         host_route(gi, book_skips=False)
                         continue
-                book("dispatch", t0, gi)
+                book("dispatch", t0, c0, gi)
                 dispatches += 1
                 inflight.append((gi, gkey, cached, mq, pre, fut))
                 # this search never returns to this batch: release its

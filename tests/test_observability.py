@@ -358,7 +358,12 @@ def test_profiler_stage_events_annotate_span(sync_tracer, profiler_reset):
     assert ex.parent_span_id == span.context.span_id
     assert span.start_ns <= ex.start_ns < ex.end_ns <= span.end_ns
     assert (ex.end_ns - ex.start_ns) / 1e9 == rec.stages["execute"]
+    # the stage timer stamped its thread's CPU clock beside the two
+    # edges: the span says which thread, and how long it was on a core
+    cpu = {k: ex.attributes.pop(k) for k in ("thread.id", "thread.cpu_ns")}
     assert ex.attributes == {"stage": "execute", "mode": "single"}
+    assert cpu["thread.id"] == threading.get_ident()
+    assert cpu["thread.cpu_ns"] >= 0
 
 
 def test_profiler_ring_resize_and_bound(profiler_reset):
