@@ -74,10 +74,20 @@ line and writes it to
                metrics; `spanned_cores`, the CPU the spans' threads
                burned together over the time from the first search's
                start to the last one's end (1.0 is one interpreter
-               lock's worth); and the put's
+               lock's worth); the put's
                `cpu_over_wall` from `batcher.place` (a put that burns
                its wall on a core holds the host, one that sleeps
-               does not)
+               does not); and the launch's stages apart by the launch's
+               `mode` (`batched` solo, `coalesced` fused, `mesh` either
+               on a mesh): per `dispatch.<stage>` span and mode `n`, ms
+               of `wall_self` and `cpu_self` a span and `cpu_self` a
+               search (`dispatch_by_mode`), and the host arrays a launch
+               put on the device for its query tables
+               (`param_puts_per_launch`:
+               `tempo_search_launch_param_puts_total{mode}` over the
+               `build` stages counted, which `--trace 0` reads too; a
+               fused launch puts 1, a solo launch whose predicate is
+               resident 0)
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -413,14 +423,19 @@ def host_facts(view: dict) -> dict:
     traces = sp.searches(view["spans"])
     n = len(traces) or None
     by_span: dict = {}
+    by_mode: dict = {}
     for s, wall, cpu, away in rows:
-        r = by_span.setdefault(s["name"], [0, 0, 0, 0])
-        r[0] += 1
-        r[1] += wall
-        r[2] += cpu
-        # off a core in its own code, by name (never span by span:
-        # where CPU is accounted by the tick only sums mean anything)
-        r[3] += wall - cpu - away
+        sums = [by_span.setdefault(s["name"], [0, 0, 0, 0])]
+        if s["name"].startswith("dispatch."):
+            sums.append(by_mode.setdefault(s["name"], {}).setdefault(
+                s["attributes"].get("mode", "absent"), [0, 0, 0, 0]))
+        for r in sums:
+            r[0] += 1
+            r[1] += wall
+            r[2] += cpu
+            # off a core in its own code, by name (never span by span:
+            # where CPU is accounted by the tick only sums mean anything)
+            r[3] += wall - cpu - away
     places = [s for s in hostcpu.stamped(view["spans"])
               if s["name"] == "batcher.place"]
     place_wall = sum(s["end_ns"] - s["start_ns"] for s in places)
@@ -434,6 +449,17 @@ def host_facts(view: dict) -> dict:
     extent = (max(b for _a, b in edges) - min(a for a, _b in edges)
               if edges else 0)
     spanned = sum(cpu for _s, _w, cpu, _a in rows)
+    stage = "tempo_search_dispatch_stage_seconds_count"
+    puts = {}
+    for mode in ("batched", "coalesced", "mesh"):
+        launches = delta(view, stage, mode=mode, stage="build")
+        if launches:
+            # absent on a tree that has no such counter: reads 0
+            puts[mode] = {
+                "launches": launches,
+                "puts_per_launch": delta(
+                    view, "tempo_search_launch_param_puts_total",
+                    mode=mode) / launches}
     return {
         "host_cores_busy": hostcpu.cores_busy(view),
         "process_cpu_s": {
@@ -457,6 +483,13 @@ def host_facts(view: dict) -> dict:
             "off_self_ms": max(0, r[3]) / n / 1e6}
             for name, r in sorted(by_span.items(),
                                   key=lambda kv: -kv[1][1])} if n else {},
+        "dispatch_by_mode": {name: {mode: {
+            "n": r[0], "wall_self_ms": r[1] / r[0] / 1e6,
+            "cpu_self_ms": r[2] / r[0] / 1e6,
+            "cpu_self_ms_per_search": r[2] / n / 1e6 if n else None}
+            for mode, r in sorted(modes.items())}
+            for name, modes in sorted(by_mode.items())},
+        "param_puts_per_launch": puts,
         "place": {"n": len(places), "wall_s": place_wall / 1e9,
                   "cpu_s": place_cpu / 1e9,
                   "cpu_over_wall": (place_cpu / place_wall

@@ -401,6 +401,15 @@ mesh_param_placements = Counter(
     "predicate, or result=placed, put there by this launch (a first "
     "launch, a predicate the batcher no longer memoises, every fused "
     "launch's stacked tables); never moves off a mesh")
+launch_param_puts = Counter(
+    "tempo_search_launch_param_puts_total",
+    "host arrays a scan launch transferred to the device(s) for its "
+    "query tables, counted in its build stage by mode=batched (a solo "
+    "launch: 0 when the predicate's parameters are resident, the two "
+    "tables and the bounds not memoised by value on its first), "
+    "coalesced (a fused launch: its one packed buffer, and the block "
+    "-> group rows where a member brings a hit mask) or mesh (either, "
+    "on a mesh: arrays, not device copies)")
 batch_cache_events = Counter("tempo_search_batch_cache_events_total",
                              "staged-batch HBM cache hits/misses/evictions")
 group_picks = Counter(

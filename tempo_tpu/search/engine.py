@@ -204,13 +204,18 @@ def device_scalar(v: int, mesh=None):
     put on every device of it (parallel.mesh.put_replicated) and
     memoized under (value, mesh): a device-0 scalar handed to a mesh
     launch would be re-placed inside the locked call."""
+    return _device_scalar(v, mesh)[0]
+
+
+def _device_scalar(v: int, mesh) -> tuple:
+    """device_scalar's (array, did this call transfer it)."""
     v = int(v)
     key = v if mesh is None else (v, mesh)
     with _scalar_lock:
         hit = _SCALAR_CACHE.get(key)
         if hit is not None:
             _SCALAR_CACHE.move_to_end(key)
-            return hit
+            return hit, False
     if mesh is None:
         arr = jnp.uint32(v)
     else:
@@ -221,7 +226,7 @@ def device_scalar(v: int, mesh=None):
         _SCALAR_CACHE[key] = arr
         while len(_SCALAR_CACHE) > _SCALAR_CACHE_MAX:
             _SCALAR_CACHE.popitem(last=False)
-    return arr
+    return arr, True
 
 
 def query_device_params(mq, mesh=None):
@@ -241,7 +246,11 @@ def query_device_params(mq, mesh=None):
     by every launch, inside the collective lock. The cache holds the
     arrays of the last placement asked for, and their own sharding says
     which that was: a query that moves between an engine with a mesh
-    and one without gets the right arrays from each."""
+    and one without gets the right arrays from each. The host arrays
+    that upload transferred (the two tables and the bounds the by-value
+    memo did not hold) are counted beside it, `_device_params_puts`: the
+    launch that made it books them
+    (tempo_search_launch_param_puts_total)."""
     from tempo_tpu.parallel.mesh import placed_for, put_replicated
 
     cached = getattr(mq, "_device_params", None)
@@ -254,6 +263,9 @@ def query_device_params(mq, mesh=None):
     else:
         tables = put_replicated(
             mesh, (np.asarray(mq.term_keys), np.asarray(mq.val_ranges)))
-    cached = tables + tuple(device_scalar(v, mesh) for v in bounds)
+    scalars = [_device_scalar(v, mesh) for v in bounds]
+    cached = tables + tuple(arr for arr, _put in scalars)
+    object.__setattr__(mq, "_device_params_puts",
+                       len(tables) + sum(put for _arr, put in scalars))
     object.__setattr__(mq, "_device_params", cached)
     return cached

@@ -53,6 +53,8 @@ from .pipeline import (
 
 import copy
 import functools
+import itertools
+import math
 
 
 # Ranges a term past which a member fuses apart from narrower ones
@@ -718,12 +720,52 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
     )
 
 
+def _packed_slots(dims: tuple):
+    """(start, stop, shape) of a fused launch's seven per-query tables
+    in its one int32 buffer, in the order the scan takes them:
+    term_keys, val_ranges, term_active, dur_lo, dur_hi, win_start,
+    win_end. A pure function of the static (Q, B, T, R) the launch is
+    keyed on: the host lays the tables out by it (stack_queries), the
+    device takes them apart by it (unpack_queries)."""
+    Q, B, T, R = dims
+    shapes = ((Q, B, T), (Q, B, T, R, 2), (Q, T), (Q,), (Q,), (Q,), (Q,))
+    ends = list(itertools.accumulate(math.prod(s) for s in shapes))
+    return [(end - math.prod(s), end, s) for s, end in zip(shapes, ends)]
+
+
+def _packed_views(dims: tuple):
+    """The buffer and the seven tables as views of it: the tag tables
+    int32, term_active int32 (0 | 1), the four bounds uint32 over the
+    same bits, never value-cast (a dur_hi of 0xFFFFFFFF comes back as
+    it went)."""
+    slots = _packed_slots(dims)
+    buf = np.empty(slots[-1][1], dtype=np.int32)
+    views = [buf[a:b].reshape(shape) for a, b, shape in slots]
+    return buf, views[:3] + [v.view(np.uint32) for v in views[3:]]
+
+
+def unpack_queries(packed, dims: tuple) -> tuple:
+    """A fused launch's seven tables from its one buffer, on the
+    device: static slices, reshapes, a compare for term_active and a
+    bit-cast for the bounds, traced in front of the scan they feed."""
+    views = [packed[a:b].reshape(shape)
+             for a, b, shape in _packed_slots(dims)]
+    return (views[0], views[1], views[2] != 0,
+            *(jax.lax.bitcast_convert_type(v, jnp.uint32)
+              for v in views[3:]))
+
+
 @dataclass
 class CoalescedQuery:
     """Several requests' MultiQueries stacked along a new QUERY axis for
     one fused dispatch over a shared staged batch — the continuous-
     batching shape: predicate tables become [Q, B, ...] and the kernel
-    computes per-query masks + per-query top-k in a single launch."""
+    computes per-query masks + per-query top-k in a single launch.
+
+    The seven tables are views of `packed`, the ONE host buffer the
+    launch puts on the device (_packed_slots is the layout): every
+    small host-to-device transfer pays a fixed per-call cost, and seven
+    of them were the largest piece of a fused launch's `build`."""
     term_keys: np.ndarray    # int32 [Q, B, T]
     val_ranges: np.ndarray   # int32 [Q, B, T, R, 2]
     term_active: np.ndarray  # bool [Q, T] — False = padding term (no-op)
@@ -733,6 +775,7 @@ class CoalescedQuery:
     win_end: np.ndarray      # uint32 [Q]
     n_terms: int             # padded (static) term count
     n_queries: int           # REAL queries; padding rows match nothing
+    packed: np.ndarray       # int32 [N]: the seven tables, as laid out
     # device-probe product stacked along the query axis: bool
     # [Q, G, T, Vmax] hit masks + int32 [Q, B] block->group rows (a
     # member query that compiled through the host path gets an all -1
@@ -746,6 +789,11 @@ class CoalescedQuery:
     # batch-scoped ?agg= stage (analytics.AggStage), shared across the
     # query axis — set when any member requested aggregation
     agg_stage: object = None
+
+    @property
+    def dims(self) -> tuple:
+        """The static (Q, B, T, R) `packed` is laid out by."""
+        return (*self.term_keys.shape, self.val_ranges.shape[3])
 
 
 def stack_queries(mqs: list[MultiQuery]) -> CoalescedQuery:
@@ -799,13 +847,15 @@ def stack_queries(mqs: list[MultiQuery]) -> CoalescedQuery:
     Q = _pow2(Qn)
     T = _pow2(max(1, max(mq.n_terms for mq in mqs)))
     R = _pow2(max(mq.val_ranges.shape[2] for mq in mqs))
-    term_keys = np.full((Q, B, T), -1, dtype=np.int32)
-    val_ranges = np.tile(np.array([1, 0], dtype=np.int32), (Q, B, T, R, 1))
-    term_active = np.zeros((Q, T), dtype=bool)
-    dur_lo = np.ones(Q, dtype=np.uint32)      # pad: empty dur range
-    dur_hi = np.zeros(Q, dtype=np.uint32)
-    win_start = np.zeros(Q, dtype=np.uint32)
-    win_end = np.zeros(Q, dtype=np.uint32)
+    packed, (term_keys, val_ranges, active, dur_lo, dur_hi, win_start,
+             win_end) = _packed_views((Q, B, T, R))
+    term_keys[...] = -1
+    val_ranges[...] = (1, 0)
+    active[...] = 0
+    dur_lo[...] = 1                           # pad: empty dur range
+    dur_hi[...] = 0
+    win_start[...] = 0
+    win_end[...] = 0
     for qi, mq in enumerate(mqs):
         if mq.term_keys.shape[0] != B:
             raise ValueError("coalesced queries must share one batch")
@@ -813,7 +863,7 @@ def stack_queries(mqs: list[MultiQuery]) -> CoalescedQuery:
         r_n = mq.val_ranges.shape[2]
         term_keys[qi, :, :t_n] = mq.term_keys
         val_ranges[qi, :, :t_n, :r_n] = mq.val_ranges
-        term_active[qi, :mq.n_terms] = True
+        active[qi, :mq.n_terms] = 1
         dur_lo[qi] = mq.dur_lo
         dur_hi[qi] = min(mq.dur_hi, 0xFFFFFFFF)
         win_start[qi] = mq.win_start
@@ -849,10 +899,10 @@ def stack_queries(mqs: list[MultiQuery]) -> CoalescedQuery:
         val_hits = jnp.stack(rows)                  # [Q, Gm, T, Vm]
     aggs = [mq for mq in mqs if getattr(mq, "agg_stage", None) is not None]
     return CoalescedQuery(
-        term_keys=term_keys, val_ranges=val_ranges, term_active=term_active,
+        term_keys=term_keys, val_ranges=val_ranges, term_active=active != 0,
         dur_lo=dur_lo, dur_hi=dur_hi, win_start=win_start, win_end=win_end,
-        n_terms=T, n_queries=Qn, val_hits=val_hits, block_group=block_group,
-        structural=stacked_st,
+        n_terms=T, n_queries=Qn, packed=packed, val_hits=val_hits,
+        block_group=block_group, structural=stacked_st,
         # members share one batch, so their AggStage is the same
         # memoized object — any requester turns the stage on
         agg_stage=aggs[0].agg_stage if aggs else None)
@@ -1113,7 +1163,7 @@ def _merge_shards(count, inspected, scores, idx, agg_counts, *,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg"))
+                                    "agg", "packed"))
 def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       entry_valid, page_block, term_keys, val_ranges,
                       term_active, dur_lo, dur_hi, win_start, win_end,
@@ -1121,7 +1171,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       span_cols=None, s_tables=None, entry_agg=None,
                       *, mesh=None, n_terms: int, top_k: int, widths=None,
                       plan=None, span_sharded=False, shard_tail: int = 0,
-                      agg=None):
+                      agg=None, packed=None):
     """THE scan program: every block batch, on one device or a mesh,
     for one query or a fused group. Returns (count, inspected, scores
     [k], flat idx [k][, agg [K]]); flat idx = page * E + entry over the
@@ -1153,7 +1203,18 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
         `child` gather and `desc` pointer-doubling evaluate INSIDE the
         shard over the local chunk — parent joins scale with the mesh,
         per-shard span HBM ~1/P, and only the per-trace verdict feeds
-        the collectives."""
+        the collectives.
+
+    `packed` (STATIC, the (Q, B, T, R) of a fused launch): the seven
+    per-query tables come as ONE int32 buffer in `term_keys`' place
+    (CoalescedQuery.packed; the other six are None) and are taken apart
+    here, in front of the scan and outside the shard_map: one operand
+    to put on the device, or to replicate over the mesh, where there
+    were seven. None: the tables come one by one, as a solo launch's
+    resident parameters do."""
+    if packed is not None:
+        (term_keys, val_ranges, term_active, dur_lo, dur_hi, win_start,
+         win_end) = unpack_queries(term_keys, packed)
     scan = functools.partial(_scan_pages, n_terms=n_terms, top_k=top_k,
                              widths=widths, plan=plan, agg=agg)
     if mesh is None:
@@ -1216,7 +1277,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg"))
+                                    "agg", "packed"))
 def mask_scan_kernel(*tables, **statics):
     """batch_scan_kernel for a launch that takes a hit mask (`val_hits`):
     the same body under a program name of its own, so that a device
@@ -1320,7 +1381,11 @@ class MultiBlockEngine:
         parallel.mesh.put_replicated: placed in the `build` stage,
         before the collective lock is taken."""
         if self.mesh is None:
-            return tuple(None if t is None else jnp.asarray(t)
+            # device_put and not jnp.asarray: the same 0.27 ms of a
+            # thread for a small table, but it does not wait for a large
+            # one (1 MB of wide ranges: 0.28 ms against 1.16,
+            # scripts/fused_params_bench.py on a v5e, PR 39)
+            return tuple(None if t is None else jax.device_put(t)
                          for t in tables)
         from tempo_tpu.parallel.mesh import put_replicated
 
@@ -1350,10 +1415,12 @@ class MultiBlockEngine:
             resident = getattr(mq, "_device_params", None)
             params = query_device_params(mq, self.mesh)
             tk, vr, *bounds = params
+            puts = 0 if params is resident else mq._device_params_puts
             vh, bg = mq.val_hits, None
             if vh is not None:
                 vh, bg = self._place_params((vh, mq.block_group))
-            return (tk, vr, None, *bounds, vh, bg), params is resident
+                puts += 1
+            return (tk, vr, None, *bounds, vh, bg), params is resident, puts
 
         return self._launch(
             "batched", batch, mq, place,
@@ -1373,32 +1440,36 @@ class MultiBlockEngine:
         member requesting ?agg= turns the stage on for the dispatch;
         non-requesters ignore their row of the [Q, K] output."""
         def place():
-            # the stacked tables of THIS fused launch, uploaded in one
-            # put to where the launch reads them
+            # the stacked tables of THIS fused launch, one host buffer:
+            # one transfer to where the launch reads them. A member's
+            # hit mask is on the device already; its block -> group
+            # rows are a second, small host array
             vh = cq.val_hits
-            return self._place_params((
-                cq.term_keys, cq.val_ranges, cq.term_active, cq.dur_lo,
-                cq.dur_hi, cq.win_start, cq.win_end, vh,
-                None if vh is None else cq.block_group)), False
+            buf, vh, bg = self._place_params((
+                cq.packed, vh, None if vh is None else cq.block_group))
+            return ((buf, *[None] * 6, vh, bg), False,
+                    1 if vh is None else 2)
 
         st = cq.structural
         return self._launch(
-            "coalesced", batch, cq, place, top_k=top_k,
+            "coalesced", batch, cq, place, top_k=top_k, packed=cq.dims,
             tables_key=(cq.term_keys.shape, cq.val_ranges.shape),
-            h2d=cq.term_keys.nbytes + cq.val_ranges.nbytes
-            + cq.term_active.nbytes + 16 * len(cq.dur_lo)
+            h2d=cq.packed.nbytes
             + (0 if st is None else sum(
                 int(getattr(t, "nbytes", 0)) for t in st.tables
                 if t is not None)),
             kernel="coalesced", queries=cq.n_queries)
 
     def _launch(self, mode: str, batch: BlockBatch, q, place, *, top_k: int,
-                tables_key: tuple, h2d: int = 0, **attrs):
+                tables_key: tuple, h2d: int = 0, packed: tuple | None = None,
+                **attrs):
         """THE launch of batch_scan_kernel on the device(s): `q` is a
         MultiQuery or a CoalescedQuery, `place()` puts its nine
-        per-query tables where the launch reads them and says whether
-        they were resident already. `mode` names the launch off a mesh;
-        on one every launch is a `mesh` launch.
+        per-query tables where the launch reads them (a fused launch's
+        first seven as one buffer, laid out by `packed`) and says
+        whether they were resident already and how many host arrays it
+        transferred. `mode` names the launch off a mesh; on one every
+        launch is a `mesh` launch.
 
         Watchdog-bounded (robustness.GUARD): a hung or erroring
         dispatch surfaces as DeviceFault (breaker fault booked) instead
@@ -1414,7 +1485,9 @@ class MultiBlockEngine:
             with profile.dispatch(mode) as rec:
                 d = batch.device
                 with rec.stage("build"):
-                    tables, resident = place()
+                    tables, resident, puts = place()
+                    if puts:
+                        obs.launch_param_puts.inc(puts, mode=mode)
                     vh = tables[7]
                     # structural plan (search/structural.py): static
                     # plan in the jit key, dynamic tables uploaded once
@@ -1477,7 +1550,7 @@ class MultiBlockEngine:
                         span_cols, s_tables, entry_agg, mesh=self.mesh,
                         n_terms=q.n_terms, top_k=top_k, widths=widths,
                         plan=plan, span_sharded=span_sharded,
-                        shard_tail=shard_tail, agg=agg)
+                        shard_tail=shard_tail, agg=agg, packed=packed)
 
                 if self.mesh is None:
                     with rec.stage(stage):

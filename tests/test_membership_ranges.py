@@ -223,7 +223,7 @@ def test_group_on_both_sides_of_the_floor_and_mixed_launches(monkeypatch):
     real = co.engine._launch
 
     def spy(mode, batch, q, place, **kw):
-        tables, _resident = place()
+        tables, *_ = place()
         launches.append((mode, kw.get("queries", 1), tables[7] is not None,
                          q.val_hits is not None, q.val_ranges.shape[-2]))
         return real(mode, batch, q, place, **kw)

@@ -106,6 +106,37 @@ def test_a_mesh_launch_transfers_nothing_under_the_lock(kind, guarded_lock,
         launch()
 
 
+def test_a_fused_mesh_launch_puts_one_array(guarded_lock, monkeypatch):
+    """A fused launch's seven tables reach the mesh as ONE replicated
+    array, put in `build`, before the lock (the parent put seven: 28
+    transfers on four devices); the counter counts arrays, not device
+    copies; the locked call moves nothing."""
+    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.multiblock import MultiBlockEngine, stack_queries
+
+    blocks = _tied_blocks(5, 100)
+    mesh = make_mesh(4)
+    eng = MultiBlockEngine(top_k=64, mesh=mesh)
+    batch = eng.stage(blocks)
+    cq = stack_queries(_tied_queries(blocks))
+    put, real = [], mesh_mod.put_replicated
+
+    def counting(m, tree):
+        assert not guarded_lock, "put under the collective lock"
+        put.extend(t for t in jax.tree_util.tree_leaves(tree)
+                   if isinstance(t, np.ndarray))
+        return real(m, tree)
+
+    monkeypatch.setattr(mesh_mod, "put_replicated", counting)
+    before = obs.launch_param_puts.value(mode="mesh")
+    counts = fetch_coalesced_out(
+        eng.coalesced_scan_async(batch, cq, 64))[0]
+    assert [int(c) for c in counts] == [250, 250]
+    assert len(put) == 1 and put[0] is cq.packed
+    assert obs.launch_param_puts.value(mode="mesh") - before == 1
+    assert len(guarded_lock) == 1
+
+
 def test_served_mesh_searches_under_the_guard_equal_the_reference(
         corpus, tmp_path, guarded_lock):  # noqa: F811
     """The served path on a mesh of four, every locked call under the

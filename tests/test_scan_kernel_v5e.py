@@ -9,7 +9,10 @@ entries on the lanes. With a range table under 32 wide beside them the
 compiler took them through a two-term loop slot-minor instead: a copy
 of both columns for every launch, 3.2 GB of scratch, 17.0 ms a launch
 where 2.9 is due (my chip run, PR 36). `_RANGE_BLOCK_MIN` pads the
-table; this file says if a compiler or a change undoes that.
+table; this file says if a compiler or a change undoes that. And the
+fused launch given its seven tables as one packed operand (PR 39) is
+the program it was given them one by one: the prelude's slices in front
+of the term loop move nothing the loop reads.
 """
 
 import jax
@@ -46,6 +49,18 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def _group(one_chip, val_dtype):
+    """(S, cols): a shape on the described chip, and a share16 group's
+    seven page arrays."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return S, (S((P, E, C), jnp.int8), S((P, E, C), val_dtype),
+               S((P, E), jnp.uint32), S((P, E), jnp.uint32),
+               S((P, E), jnp.uint32), S((P, E), jnp.bool_),
+               S((P,), jnp.int32))
+
+
 @pytest.mark.parametrize("Q,T,R,val_dtype", [
     (None, 2, 8, jnp.int32),      # highcard.substring: int32 ids
     (None, 2, 16, jnp.int16),     # share16.triage: the role infix
@@ -57,13 +72,7 @@ def test_entry_form_keeps_the_kv_columns_entry_minor(
     from tempo_tpu.search.multiblock import ENTRY_RANGES, batch_scan_kernel
 
     assert R >= ENTRY_RANGES
-
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    cols = (S((P, E, C), jnp.int8), S((P, E, C), val_dtype),
-            S((P, E), jnp.uint32), S((P, E), jnp.uint32),
-            S((P, E), jnp.uint32), S((P, E), jnp.bool_), S((P,), jnp.int32))
+    S, cols = _group(one_chip, val_dtype)
     q = () if Q is None else (Q,)
     tables = (S((*q, B, T), jnp.int32), S((*q, B, T, R, 2), jnp.int32),
               None if Q is None else S((Q, T), jnp.bool_),
@@ -75,3 +84,37 @@ def test_entry_form_keeps_the_kv_columns_entry_minor(
     assert "copy(%kv_key" not in text and "copy(%kv_val" not in text
     # the fused launch's [Q, P, E, C] key matches are 0.27 GB of it
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("Q,T,R,val_dtype", [
+    (2, 2, 1, jnp.int16),         # share16.scan: nearly every fused launch
+    (2, 1, 512, jnp.int32),       # highcard.substring: 1 MB of ranges
+], ids=["fused-2x2x1-int16", "fused-2x1x512-int32"])
+def test_the_packed_operand_leaves_the_fused_program_as_it_was(
+        Q, T, R, val_dtype, one_chip, no_compile_cache):
+    """One operand where there were seven, taken apart by static
+    slices and bit-casts: the same loops, no copy of a kv column, the
+    scratch within a hundredth."""
+    import re
+
+    from tempo_tpu.search.engine import DEFAULT_TOP_K, resolve_top_k
+    from tempo_tpu.search.multiblock import _packed_slots, batch_scan_kernel
+
+    S, cols = _group(one_chip, val_dtype)
+    dims = (Q, B, T, R)
+    forms = {
+        "seven": ((S((Q, B, T), jnp.int32), S((Q, B, T, R, 2), jnp.int32),
+                   S((Q, T), jnp.bool_), *[S((Q,), jnp.uint32)] * 4), None),
+        "packed": ((S((_packed_slots(dims)[-1][1],), jnp.int32),
+                    *[None] * 6), dims)}
+    loops, temp = {}, {}
+    for form, (tables, packed) in forms.items():
+        compiled = batch_scan_kernel.lower(
+            *cols, *tables, n_terms=T, packed=packed,
+            top_k=resolve_top_k(DEFAULT_TOP_K, 20)).compile()
+        text = compiled.as_text()
+        assert "copy(%kv_key" not in text and "copy(%kv_val" not in text
+        loops[form] = len(re.findall(r" while\(", text))
+        temp[form] = compiled.memory_analysis().temp_size_in_bytes
+    assert loops["packed"] == loops["seven"] > 0
+    assert abs(temp["packed"] - temp["seven"]) < temp["seven"] / 100
