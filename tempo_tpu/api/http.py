@@ -796,6 +796,15 @@ def serve_http(api: HTTPApi, host: str = "0.0.0.0", port: int = 3200):
         accept thread to the handler thread."""
 
     class Server(ThreadingHTTPServer):
+        # connections the kernel holds while the accept thread waits for
+        # its turn at the interpreter lock. The stdlib's 5 overflows at
+        # ~150 requests/s from sixteen callers that each open a
+        # connection a request: the kernel drops the SYN and the client
+        # sends it again after 1 s, then 3, 7, 15 (p99 1.07 s and one
+        # request of 7-16 s a window on a v5e host, PERF.md section 6,
+        # PR 40)
+        request_queue_size = 128
+
         def get_request(self):
             request, addr = super().get_request()
             if tracing.get_tracer() is not None:

@@ -1003,7 +1003,8 @@ class TempoDB:
             # len(jobs) in the plan key: fallback promotion grows the job
             # list within an epoch and the memoized plan must not drop it
             self.batcher.search(jobs, req, results,
-                                plan_key=(tenant, epoch, len(jobs)))
+                                plan_key=(tenant, epoch, len(jobs)),
+                                tenant=tenant)
             if fallback and not results.complete:
                 # container-less blocks have no header rollup to prune on
                 # — apply the meta time filter here
@@ -1247,7 +1248,8 @@ class TempoDB:
             groups = self._plan(jobs)
             self._breq_jobs_cache.put(
                 sig, (epoch, jobs, fallback, missing, groups))
-        self.batcher.search(jobs, breq.search_req, results, groups=groups)
+        self.batcher.search(jobs, breq.search_req, results, groups=groups,
+                            tenant=breq.tenant_id)
         # container-less blocks have no header rollup: apply the meta
         # window carried in the job before paying a whole-block proto
         # decode (same gate as search(); the frontend no longer

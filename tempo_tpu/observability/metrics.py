@@ -462,6 +462,18 @@ jit_cache_events = Counter(
     "tempo_search_jit_cache_events_total",
     "dispatch-shape compile-cache outcomes (result=hit|miss); a miss "
     "means that dispatch paid XLA trace+compile")
+scan_jit_keys = Gauge(
+    "tempo_search_scan_jit_keys",
+    "distinct jit keys (compile_check shape signatures) the scan "
+    "program's launches have shown since the process started: page "
+    "bucket x block-axis bucket x (Q, T, R) x top-k; a blocklist that "
+    "changes adds keys only when a group crosses a power of two")
+launch_table_rows = Counter(
+    "tempo_search_launch_table_rows_total",
+    "rows of the per-block query tables that scan launches carried, "
+    "once for each launch and member: kind=real (the group's blocks) "
+    "or kind=pad (rows that fill the block count's power-of-two "
+    "bucket: key id -1, no page names one)")
 h2d_bytes = Counter("tempo_search_h2d_bytes_total",
                     "bytes staged host->device (pages, dictionaries, "
                     "query tables)")

@@ -475,7 +475,8 @@ class DispatchProfiler:
             if stage in ("compile", "execute") and rec.jit is not None:
                 span.set_attribute("jit_cache", rec.jit)
                 for key in ("topk", "shards", "pages_per_shard", "params",
-                            "membership", "compare"):
+                            "membership", "compare", "blocks",
+                            "blocks_bucket"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
             span.end(end_ns, cpu1)

@@ -66,7 +66,8 @@ from .engine import DEFAULT_TOP_K, fetch_coalesced_out, resolve_top_k, \
     start_fetch
 from .ownership import OWNERSHIP
 from .multiblock import (
-    WIDE_RANGES, MultiBlockEngine, compile_multi, stack_queries,
+    WIDE_RANGES, MultiBlockEngine, block_bucket, compile_multi,
+    stack_queries,
 )
 from .pipeline import MASK_BYTES, block_header_skip_reason, probe_summary
 from .results import SearchResults
@@ -576,8 +577,13 @@ class QueryCoalescer:
         mode = "coalesced" if fused else "batched"
         launch = profile.DEVICE_TIMELINE.watch(
             out, lspan.context, len(items), len(batch.blocks), kernel)
+        blocks = len(batch.blocks)
+        bucket = block_bucket(blocks)
+        if bucket > blocks:
+            # as on `dispatch.execute`: only where there are pad rows
+            lspan.set_attribute("blocks_bucket", bucket)
         lspan.set_attributes(
-            launch=launch, queries=len(items), blocks=len(batch.blocks),
+            launch=launch, queries=len(items), blocks=blocks,
             kernel=kernel, shards=self.engine.n_shards,
             pages_per_shard=self.engine.pages_per_shard(batch),
             jit_cache=(recs[0].get("jit_cache", "") if recs else ""))
@@ -1317,12 +1323,12 @@ class BlockBatcher:
                      str(cached.batch.device["kv_key"].dtype),
                      str(cached.batch.device["kv_val"].dtype),
                      cached.batch.widths,
-                     len(cached.batch.blocks))
+                     block_bucket(len(cached.batch.blocks)))
         with self._lock:
             if shape_sig in self._warmed_shapes:
                 return
             self._warmed_shapes.add(shape_sig)
-        B = len(cached.batch.blocks)
+        B = block_bucket(len(cached.batch.blocks))
         for n_terms in (0, 2):
             mq = MultiQuery(
                 term_keys=np.full((B, max(1, n_terms)), -1, dtype=np.int32),
@@ -1338,13 +1344,15 @@ class BlockBatcher:
 
     def search(self, jobs: list[ScanJob], req,
                results: SearchResults | None = None,
-               plan_key=None, groups: list | None = None) -> SearchResults:
+               plan_key=None, groups: list | None = None,
+               tenant: str | None = None) -> SearchResults:
         """Run the request over all jobs: group → stage → compile →
         dispatch (pipelined, early-quitting) → merge. `plan_key` (e.g.
         (tenant, blocklist-epoch)) memoizes the grouping — the plan is a
         pure function of the job list, and re-sorting 10K jobs per query
         is measurable host overhead. Callers that already hold the plan
         (tempodb's protocol-path job cache) pass `groups` directly.
+        `tenant` is whose jobs these are, for the `batcher.Search` span.
 
         Concurrent calls coalesce: dispatches landing on the same staged
         batch within the coalescing window fuse into one multi-query
@@ -1360,7 +1368,8 @@ class BlockBatcher:
         planned = [False]
         try:
             return self._search_impl(jobs, req, results, plan_key, groups,
-                                     pinned, prefetched, interest, planned)
+                                     pinned, prefetched, interest, planned,
+                                     tenant)
         finally:
             # an early quit or an exception leaves a look-ahead pending:
             # cancel it so a not-yet-started stage doesn't burn
@@ -1387,7 +1396,8 @@ class BlockBatcher:
                      results: SearchResults | None,
                      plan_key, groups: list | None,
                      pinned: list, prefetched: dict, interest: list,
-                     planned: list) -> SearchResults:
+                     planned: list,
+                     tenant: str | None = None) -> SearchResults:
         from .pipeline import is_exhaustive
 
         results = results or SearchResults.for_request(req)
@@ -1659,7 +1669,9 @@ class BlockBatcher:
             # as skipped; under the exhaustive flag nothing is skipped —
             # every page is scanned by definition
             if not exhaustive and mq.n_terms:
-                dict_pruned = (mq.term_keys == -1).all(axis=1)
+                # the tables' block axis is padded to its bucket
+                # (multiblock.block_bucket): the group's rows come first
+                dict_pruned = (mq.term_keys[:len(group)] == -1).all(axis=1)
                 skip = [s or bool(dict_pruned[i])
                         for i, s in enumerate(skip)]
             pre = {
@@ -2204,6 +2216,8 @@ class BlockBatcher:
                     inflight.clear()   # their pins: search()'s finally
                     break
                 drain_one()
+            if tenant is not None:
+                span.set_attribute("tenant", tenant)
             span.set_attributes(groups=len(groups), scan_dispatches=dispatches,
                                 inspected_blocks=results.metrics.inspected_blocks,
                                 skipped_blocks=results.metrics.skipped_blocks)

@@ -84,11 +84,26 @@ ENTRY_RANGES = 8
 _RANGE_BLOCK = 128
 _RANGE_BLOCK_MIN = 32
 
+# every key the scan program's launches gave `compile_check` since the
+# process started (the gauge tempo_search_scan_jit_keys is its size)
+_SCAN_JIT_KEYS: set = set()
+
 
 def compares_by(n_ranges: int) -> str:
     """Which of multi_entry_mask's two range forms a launch of that
     many ranges a term traces: `slot` or `entry`."""
     return "entry" if n_ranges >= ENTRY_RANGES else "slot"
+
+
+def block_bucket(n_blocks: int) -> int:
+    """Rows of a launch's per-block tables for a group of `n_blocks`:
+    the next power of two, as the page axis beside it is padded
+    (MultiBlockEngine.stage_host). The block count of a group follows
+    the blocklist; the rows are a jit shape of the scan program, so
+    they come in log2 sizes. Rows past the group's blocks are pad rows:
+    key id -1, the sentinel of a pruned block, and no page's
+    `page_block` names one."""
+    return _pow2(max(1, int(n_blocks)))
 
 
 @dataclass
@@ -539,6 +554,8 @@ def stack_blocks(blocks: list[ColumnarPages], pad_to: int | None = None,
 @dataclass
 class MultiQuery:
     """Per-block compiled query folded into block-indexed tables."""
+    # B is the group's block count in its bucket (block_bucket): rows
+    # past the blocks are pad rows, key id -1 like a pruned block's
     term_keys: np.ndarray    # int32 [B, T] key id per (block, term); -1 = prune
     val_ranges: np.ndarray   # int32 [B, T, R, 2]
     dur_lo: int
@@ -647,7 +664,9 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
     # exhaustive debug tag is not itself a predicate, so raw-tag counting
     # would leave an unmatchable extra -1 key per block
     T = max((cq.n_terms for cq in per_block if cq is not None), default=0)
-    B = len(blocks)
+    # the block axis in its bucket: rows past len(blocks) keep the -1
+    # key and the empty range they are filled with here
+    B = block_bucket(len(blocks))
     rmax = 1
     for cq in per_block:
         if cq is not None and cq.n_terms:
@@ -704,7 +723,7 @@ def compile_multi(blocks: list[ColumnarPages], req: tempopb.SearchRequest,
     if skip is not None and any(skip):
         # header-pruned rows back to the unmatchable sentinel (their
         # dict group was assembled wholesale above)
-        sk = np.asarray(skip, dtype=bool)
+        sk = np.flatnonzero(np.asarray(skip, dtype=bool))
         term_keys[sk] = -1
         val_ranges[sk] = np.array([1, 0], dtype=np.int32)
         if block_group is not None:
@@ -1425,8 +1444,7 @@ class MultiBlockEngine:
         return self._launch(
             "batched", batch, mq, place,
             top_k=resolve_top_k(self.top_k, mq.limit),
-            tables_key=(mq.val_ranges.shape,),
-            kernel="multi", blocks=len(batch.blocks))
+            tables_key=(mq.val_ranges.shape,), kernel="multi")
 
     def scan(self, batch: BlockBatch, mq: MultiQuery):
         return fetch_scan_out(self.scan_async(batch, mq))
@@ -1512,30 +1530,46 @@ class MultiBlockEngine:
                 widths = batch.widths
                 span_sharded = bool(st is not None and batch.span_sharded)
                 shard_tail = self._shard_tail(batch, d)
-                miss = rec.compile_check(
-                    (attrs["kernel"], self.mesh is not None,
-                     d["kv_key"].shape, str(d["kv_key"].dtype),
-                     str(d["kv_val"].dtype), *tables_key,
-                     None if vh is None else (tuple(vh.shape),
-                                              str(vh.dtype)),
-                     widths, q.n_terms, top_k,
-                     None if st is None else st.shape_sig(), span_sharded,
-                     shard_tail, agg,
-                     None if span_cols is None else
-                     tuple(sorted((n, tuple(a.shape))
-                                  for n, a in span_cols.items()))))
+                jit_key = (
+                    attrs["kernel"], self.mesh is not None,
+                    d["kv_key"].shape, str(d["kv_key"].dtype),
+                    str(d["kv_val"].dtype), *tables_key,
+                    None if vh is None else (tuple(vh.shape),
+                                             str(vh.dtype)),
+                    widths, q.n_terms, top_k,
+                    None if st is None else st.shape_sig(), span_sharded,
+                    shard_tail, agg,
+                    None if span_cols is None else
+                    tuple(sorted((n, tuple(a.shape))
+                                 for n, a in span_cols.items())))
+                miss = rec.compile_check(jit_key)
+                if miss:
+                    _SCAN_JIT_KEYS.add(jit_key)
+                    obs.scan_jit_keys.set(len(_SCAN_JIT_KEYS))
                 stage = "compile" if miss else "execute"
                 membership = "range" if vh is None else "mask"
-                obs.scan_membership.inc(attrs.get("queries", 1),
-                                        path=membership)
+                members = attrs.get("queries", 1)
+                obs.scan_membership.inc(members, path=membership)
+                # the block axis of the tables: the group's blocks and
+                # the pad rows that fill their bucket, once a member
+                blocks = len(batch.blocks)
+                bucket = int(q.term_keys.shape[-2])
+                obs.launch_table_rows.inc(members * blocks, kind="real")
                 rec.set(**attrs, scan_bytes=batch.device_nbytes,
                         shards=self.n_shards, membership=membership,
-                        pages_per_shard=self.pages_per_shard(batch))
+                        pages_per_shard=self.pages_per_shard(batch),
+                        blocks=blocks)
+                if bucket > blocks:
+                    # said only where there are pad rows: a served
+                    # search's self-trace keeps 64 pairs in key order
+                    # and every key before `service.name` costs it one
+                    obs.launch_table_rows.inc(members * (bucket - blocks),
+                                              kind="pad")
+                    rec.set(blocks_bucket=bucket)
                 if q.n_terms:
                     # a launch without tag terms compares no range
                     compare = compares_by(q.val_ranges.shape[-2])
-                    obs.scan_range_compare.inc(attrs.get("queries", 1),
-                                               by=compare)
+                    obs.scan_range_compare.inc(members, by=compare)
                     rec.set(compare=compare)
                 book_topk(rec, d["entry_valid"].size // self.n_shards,
                           top_k)

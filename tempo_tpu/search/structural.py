@@ -1002,7 +1002,7 @@ def _assemble_terms(terms: list, blocks: list, cache_on=None,
     device packed-probe kernel, bit-packed masks, breaker fallback and
     watchdog bounds are all the SAME code the legacy terms run."""
     from . import packing
-    from .multiblock import _dict_groups
+    from .multiblock import _dict_groups, block_bucket
     from .pipeline import _host_probe_tags
 
     import jax.numpy as jnp
@@ -1017,7 +1017,9 @@ def _assemble_terms(terms: list, blocks: list, cache_on=None,
             b, terms, None if host_only else staged_dicts.get(fp),
             host_only=host_only)
 
-    B = len(blocks)
+    # the block axis in its bucket, as compile_multi's tables: rows
+    # past the blocks stay key id -1 and no page or span names one
+    B = block_bucket(len(blocks))
     rmax = 1
     for tk, tv, vr, vh in compiled.values():
         if vr is not None:
