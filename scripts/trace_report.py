@@ -87,7 +87,12 @@ line and writes it to
                `tempo_search_launch_param_puts_total{mode}` over the
                `build` stages counted, which `--trace 0` reads too; a
                fused launch puts 1, a solo launch whose predicate is
-               resident 0)
+               resident 0), and the host arrays its drain fetched back
+               (`out_fetches_per_launch`:
+               `tempo_search_launch_out_fetches_total{mode}` over the
+               same launches: 1, the one packed output; under 1 by the
+               launches no drain waited for; 0 on a tree without the
+               counter, which fetched four)
   coverage     per search: how much of `http.request` (accept -> last
                byte written) its child spans cover, the wait before the
                handler (its `accept_wait_ms`) counted with them
@@ -450,7 +455,7 @@ def host_facts(view: dict) -> dict:
               if edges else 0)
     spanned = sum(cpu for _s, _w, cpu, _a in rows)
     stage = "tempo_search_dispatch_stage_seconds_count"
-    puts = {}
+    puts, fetches = {}, {}
     for mode in ("batched", "coalesced", "mesh"):
         launches = delta(view, stage, mode=mode, stage="build")
         if launches:
@@ -459,6 +464,11 @@ def host_facts(view: dict) -> dict:
                 "launches": launches,
                 "puts_per_launch": delta(
                     view, "tempo_search_launch_param_puts_total",
+                    mode=mode) / launches}
+            fetches[mode] = {
+                "launches": launches,
+                "fetches_per_launch": delta(
+                    view, "tempo_search_launch_out_fetches_total",
                     mode=mode) / launches}
     return {
         "host_cores_busy": hostcpu.cores_busy(view),
@@ -490,6 +500,7 @@ def host_facts(view: dict) -> dict:
             for mode, r in sorted(modes.items())}
             for name, modes in sorted(by_mode.items())},
         "param_puts_per_launch": puts,
+        "out_fetches_per_launch": fetches,
         "place": {"n": len(places), "wall_s": place_wall / 1e9,
                   "cpu_s": place_cpu / 1e9,
                   "cpu_over_wall": (place_cpu / place_wall

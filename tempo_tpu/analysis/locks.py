@@ -48,6 +48,7 @@ _BLOCKING_ATTRS = {
     "join": "join() waits for another thread to finish",
     "block_until_ready": "device sync: waits for the kernel/transfer",
     "fence": "profiler fence = block_until_ready on the kernel outputs",
+    "fetch": "d2h sync: the one blocking fetch of a launch's output",
     "item": "device scalar sync: .item() waits for the device value",
     "wait": "unbounded wait() parks this thread",
     "acquire": "unbounded acquire() can park this thread forever",
@@ -56,8 +57,7 @@ _BLOCKING_ATTRS = {
 # module-level helper functions that synchronize with the device (d2h)
 _BLOCKING_NAMES = {
     "host_scan": "runs the full host-path kernel + d2h sync",
-    "fetch_scan_out": "d2h sync of a dispatch's outputs",
-    "fetch_coalesced_out": "d2h sync of a fused dispatch's outputs",
+    "fetch_scan_out": "d2h sync of a dispatch's output",
     "fence_arrays": "block_until_ready over kernel outputs",
 }
 

@@ -410,6 +410,14 @@ launch_param_puts = Counter(
     "coalesced (a fused launch: its one packed buffer, and the block "
     "-> group rows where a member brings a hit mask) or mesh (either, "
     "on a mesh: arrays, not device copies)")
+launch_out_fetches = Counter(
+    "tempo_search_launch_out_fetches_total",
+    "host arrays the drain of a scan launch fetched from the device(s), "
+    "by the launch's mode=batched|coalesced|mesh: one a launch, its "
+    "packed output (count, inspected, scores, idx and the ?agg= counts "
+    "in one int32 array; a fused launch's is fetched once, by the first "
+    "member to drain); under scan_dispatches of the same modes by the "
+    "launches no drain waited for (a search that filled its limit)")
 batch_cache_events = Counter("tempo_search_batch_cache_events_total",
                              "staged-batch HBM cache hits/misses/evictions")
 group_picks = Counter(

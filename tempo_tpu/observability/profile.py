@@ -770,9 +770,12 @@ def _device_memory(d) -> dict:
 
 
 def fence_arrays(arrays) -> None:
-    """block_until_ready every device array in `arrays` (tuples from the
-    scan kernels) — the execute-stage fence. Tolerates host scalars and
-    None leaves so call sites can pass kernel outputs verbatim."""
+    """block_until_ready a kernel's output — the execute-stage fence:
+    the scan program's one array, or every device array of another
+    kernel's tuple. Tolerates host scalars and None leaves so call
+    sites can pass kernel outputs verbatim."""
+    if hasattr(arrays, "block_until_ready"):
+        arrays = (arrays,)
     for a in arrays:
         wait = getattr(a, "block_until_ready", None)
         if wait is not None:

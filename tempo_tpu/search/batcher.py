@@ -62,8 +62,7 @@ from tempo_tpu.observability import tracing
 from . import query_stats
 from . import structural as _structural
 from .analytics import ANALYTICS, agg_requested
-from .engine import DEFAULT_TOP_K, fetch_coalesced_out, resolve_top_k, \
-    start_fetch
+from .engine import DEFAULT_TOP_K, fetch_scan_out, resolve_top_k, start_fetch
 from .ownership import OWNERSHIP
 from .multiblock import (
     WIDE_RANGES, MultiBlockEngine, block_bucket, compile_multi,
@@ -147,11 +146,7 @@ def host_scan(host, mq, top_k: int):
             # packs before the tiers fork), so the fallback kernel
             # unpacks with the batch's own width descriptor
             widths=getattr(host, "widths", None), plan=plan, agg=agg)
-        count, inspected, scores, idx, *ext = out
-        res = (int(count), int(inspected), np.asarray(scores),
-               np.asarray(idx))
-        if ext:
-            res += (np.asarray(ext[0]),)
+        res = fetch_scan_out(out, agg or 0)
     profile.observe_stage("execute", "host_fallback",
                           time.perf_counter() - t0)
     return res
@@ -255,21 +250,30 @@ class _FusedOut:
     for the group instead of one per member (each member's drain then
     resubmits its own query on the host path, as before)."""
 
-    __slots__ = ("_out", "_host", "_exc", "_claimed", "_done")
+    __slots__ = ("_out", "_engine", "_cq", "_host", "_exc", "_claimed",
+                 "_done")
 
-    def __init__(self, out):
+    def __init__(self, out, engine, cq):
+        # the launch's one device array, and who fetches it: the engine
+        # that launched `cq` (one np.asarray, taken apart by row)
         self._out = out
+        self._engine = engine
+        self._cq = cq
         self._host = None
         self._exc = None
         self._claimed = threading.Lock()
         self._done = threading.Event()
 
-    def host(self):
+    def host(self) -> tuple:
+        """(the group's host values, how many host arrays THIS caller
+        fetched for them: 1 for the claimer, 0 for who found it done)."""
+        fetched = 0
         if not self._done.is_set() and self._claimed.acquire(blocking=False):
             # first waiter: the one real d2h sync, not under any lock
+            fetched = 1
             try:
-                self._host = fetch_coalesced_out(self._out)
-                self._out = None
+                self._host = self._engine.fetch(self._out, self._cq)
+                self._out = self._cq = None
             except Exception as e:  # noqa: BLE001 — published to waiters
                 self._exc = e
             finally:
@@ -288,13 +292,12 @@ class _FusedOut:
             # exception mid-fetch): RuntimeError is device-fault-shaped,
             # so each member's drain resubmits on the host path
             raise RuntimeError("fused d2h fetch aborted before publishing")
-        return self._host
+        return self._host, fetched
 
 
 class _FusedSlice:
-    """One member query's view of a _FusedOut; unpacks like the direct
-    path's (count, inspected, scores, idx) tuple so drain code cannot
-    tell a fused dispatch from a solo one."""
+    """One member query's view of a _FusedOut: its row of the group's
+    one output array, in the solo fetch's form."""
 
     __slots__ = ("_shared", "_qi")
 
@@ -302,15 +305,14 @@ class _FusedSlice:
         self._shared = shared
         self._qi = qi
 
-    def __iter__(self):
-        counts, inspected, scores, idx, *ext = self._shared.host()
+    def fetch(self) -> tuple:
+        """((count, inspected, scores, idx[, agg]), host arrays this
+        call fetched): the member's row of every per-query part (the
+        ?agg= counts demux like scores), views of the group's array."""
+        (counts, inspected, *rows), fetched = self._shared.host()
         qi = self._qi
-        res = (int(counts[qi]), inspected, scores[qi], idx[qi])
-        if ext:
-            # fused ?agg= counts demux like scores: row qi of the [Q, K]
-            # dense-count matrix belongs to this member
-            res += (ext[0][qi],)
-        return iter(res)
+        return (int(counts[qi]), inspected,
+                *(r[qi] for r in rows)), fetched
 
 
 class QueryCoalescer:
@@ -378,8 +380,9 @@ class QueryCoalescer:
 
     def submit(self, batch, mq, top_k: int, peers: int | None = None):
         """Queue one compiled query against `batch`; returns a Future
-        resolving to the engine's (count, inspected, scores, idx) — the
-        same host types drain code gets from a direct dispatch. `peers`
+        resolving to what the drain fetches: a solo flush's one output
+        array, as a direct dispatch hands it over, or the member's
+        _FusedSlice of a fused launch's. `peers`
         is the caller's count of in-flight searches that could target
         THIS batch (self included); <=1 flushes immediately.
 
@@ -698,7 +701,7 @@ class QueryCoalescer:
             # size-triggered flush runs on the last submitter's thread,
             # which still has its own dispatch loop to overlap
             start_fetch(out)
-            shared = _FusedOut(out)
+            shared = _FusedOut(out, self.engine, cq)
             for qi, it in enumerate(items):
                 it[2].set_result(_FusedSlice(shared, qi))
         except BaseException as e:  # noqa: BLE001 — delivered via futures
@@ -1519,26 +1522,27 @@ class BlockBatcher:
                         fut = out
                     else:
                         fut = fut.result()
-                # the ACTUAL device→host sync: fused-slice demux happens
-                # at unpack, the direct path syncs at the scalar/array
-                # fetches — time exactly these so stage=d2h means
-                # transfer, not queue. Watchdog-bounded: a wedged
-                # device can hang the SYNC even when the enqueue
-                # returned, and that hang must become a fault too.
+                # the ACTUAL device→host sync: the one fetch of the
+                # launch's output array (a fused member's slice makes
+                # or awaits its group's) — time exactly this so
+                # stage=d2h means transfer, not queue. Watchdog-bounded:
+                # a wedged device can hang the SYNC even when the
+                # enqueue returned, and that hang must become a fault
+                # too.
                 t0d = tracing.now_ns()
                 c0d = tracing.cpu_ns() if dspan.recording else None
 
                 def _sync(fut=fut):
-                    count, inspected, scores, idx, *ext = fut
-                    out = (int(count), int(inspected),
-                           np.asarray(scores), np.asarray(idx))
-                    if ext:
-                        # dense ?agg= counts ride the same sync
-                        out += (np.asarray(ext[0]),)
-                    return out
+                    # a fused member's slice fetches the group's array
+                    # once a group; a solo launch's is fetched here.
+                    # Either way ONE host array, the dense ?agg= counts
+                    # behind the rest of it
+                    if isinstance(fut, _FusedSlice):
+                        return fut.fetch()
+                    return self.engine.fetch(fut, mq), 1
 
-                count, inspected, scores, idx, *agg_counts = \
-                    robustness.GUARD.run("d2h", _sync)
+                (count, inspected, scores, idx, *agg_counts), out_fetches \
+                    = robustness.GUARD.run("d2h", _sync)
             except robustness.DeadlineExceeded:
                 # the request's budget ran out mid-drain: the answer
                 # goes out PARTIAL — this group's results are dropped,
@@ -1559,10 +1563,18 @@ class BlockBatcher:
             t1d = tracing.now_ns()
             d2h_s = (t1d - t0d) / 1e9
             if dspan.recording:
-                # what the `d2h` stage times: the one blocking sync
+                # what the `d2h` stage times: the one blocking sync.
+                # `out_fetches` is said only where it is not the 1 of a
+                # sync that fetched its launch's array (a fused member
+                # that found the group's fetched): a served search's
+                # self-trace keeps 64 pairs in key order and every key
+                # before `service.name` costs it one
+                attrs = {} if out_fetches == 1 else {
+                    "out_fetches": out_fetches}
                 tracing.record_span("batcher.sync", t0d, t1d,
                                     parent=dspan.context, cpu_start_ns=c0d,
-                                    cpu_end_ns=tracing.cpu_ns(), group=gi)
+                                    cpu_end_ns=tracing.cpu_ns(), group=gi,
+                                    **attrs)
             profile.observe_stage(
                 "d2h", "batched", d2h_s,
                 nbytes=scores.nbytes + idx.nbytes + 8, spanned=True)

@@ -61,30 +61,69 @@ def cpu_pinned():
             else contextlib.nullcontext())
 
 
-def start_fetch(arrays) -> None:
-    """Kick off device→host copies without blocking: issuing the async
-    copies at dispatch time collapses N blocking fetches into one wait
-    and overlaps the transfer with later kernel work."""
-    for a in arrays:
-        copy = getattr(a, "copy_to_host_async", None)
-        if copy is not None:
-            try:
-                copy()
-            except Exception:  # noqa: BLE001 — fetch still works, just sync
-                pass
+def pack_out(count, inspected, scores, idx, *agg):
+    """A launch's results as ONE int32 array, on the device, the last
+    thing the scan program traces: a row [count, inspected, scores [k],
+    idx [k]] of 2 + 2k words, and behind them the ?agg= counts [K] of a
+    launch that reduces. A fused launch's results carry a leading [Q]
+    (`inspected` alone does not: it is a property of the pages) and
+    make one row a member, [Q, 2 + 2k (+ K)], `inspected` repeated.
+    Every part is int32 as the scan makes it (masked_topk's clamped
+    start seconds and flat indices, integer sums), so nothing is cast
+    and nothing narrows. unpack_out is the same layout read on the
+    host; these two functions are the only place it is written.
+
+    The barrier keeps the scan in front of it the program it was when
+    these parts were its outputs: without it the TPU compiler, seeing
+    count and scores meet in one consumer, fuses the count into the
+    mask's last pass, makes the scores in a pass of their own over a
+    copy of entry_start, and a launch moves 1.6x the bytes behind its
+    term loop (compiled for a v5e, PR 41; tests/test_scan_kernel_v5e.py
+    holds the ops equal). With it the concatenate is one more fusion
+    over 2 + 2k words."""
+    count, inspected, scores, idx, *agg = jax.lax.optimization_barrier(
+        (count, inspected, scores, idx, *agg))
+    return jnp.concatenate(
+        (count[..., None],
+         jnp.broadcast_to(inspected, count.shape)[..., None],
+         scores, idx, *agg), axis=-1)
 
 
-def fetch_scan_out(out):
-    """(count, inspected, scores, idx[, agg]) device arrays → host
-    values with a single synchronization point. The optional trailing
-    aggregate histogram (?agg= dispatches) rides the same sync."""
-    start_fetch(out)
-    count, inspected, scores, idx, *ext = out
-    fetched = (int(count), int(inspected), np.asarray(scores),
-               np.asarray(idx))
-    if ext:
-        return fetched + (np.asarray(ext[0]),)
-    return fetched
+def unpack_out(host: np.ndarray, n_agg: int = 0) -> tuple:
+    """pack_out's array on the host, taken apart into views of it (no
+    copy): (count, inspected, scores [k], idx [k]) of a solo launch's
+    row, (counts [Q], inspected, scores [Q, k], idx [Q, k]) of a fused
+    launch's rows; `inspected`, and a solo launch's count, as Python
+    ints. `n_agg` is the K of a launch that reduced (?agg=), whose
+    counts [K] ([Q, K]) come fifth; k is what the row's width leaves
+    (masked_topk keeps min(top_k, entries))."""
+    k = (host.shape[-1] - 2 - n_agg) // 2
+    if host.ndim == 1:
+        count, inspected = int(host[0]), int(host[1])
+    else:
+        count, inspected = host[:, 0], int(host[0, 1])
+    out = (count, inspected, host[..., 2:2 + k], host[..., 2 + k:2 + 2 * k])
+    if n_agg:
+        out += (host[..., 2 + 2 * k:],)
+    return out
+
+
+def start_fetch(out) -> None:
+    """Kick off the device→host copy of a launch's one output array
+    without blocking: issued at dispatch time, the transfer overlaps
+    later kernel work and the drain's fetch finds the bytes there."""
+    try:
+        out.copy_to_host_async()
+    except Exception:  # noqa: BLE001 — fetch still works, just sync
+        pass
+
+
+def fetch_scan_out(out, n_agg: int = 0) -> tuple:
+    """A launch's output, solo or fused, as host values (unpack_out's
+    tuple): ONE blocking fetch of its one array, the launch's single
+    synchronization point. A fused group's demux slices the host array,
+    one D2H wait for the whole group, not Q."""
+    return unpack_out(np.asarray(out), n_agg)
 
 
 def resolve_top_k(base: int, limit: int) -> int:
@@ -96,20 +135,6 @@ def resolve_top_k(base: int, limit: int) -> int:
     while k < limit:
         k *= 2
     return k
-
-
-def fetch_coalesced_out(out):
-    """Query-axis variant of fetch_scan_out: (counts [Q], inspected,
-    scores [Q,k], idx [Q,k][, agg [Q,K]]) device arrays → host values
-    with a single synchronization point. The per-query demux slices the
-    host arrays — one D2H wait for the whole coalesced group, not Q."""
-    start_fetch(out)
-    counts, inspected, scores, idx, *ext = out
-    fetched = (np.asarray(counts), int(inspected),
-               np.asarray(scores), np.asarray(idx))
-    if ext:
-        return fetched + (np.asarray(ext[0]),)
-    return fetched
 
 
 def topk_row_width(n: int, k: int) -> int:

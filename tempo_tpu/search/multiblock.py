@@ -38,6 +38,7 @@ from .engine import (
     fetch_scan_out,
     latest_k,
     masked_topk,
+    pack_out,
     query_device_params,
     resolve_top_k,
 )
@@ -1192,20 +1193,24 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       plan=None, span_sharded=False, shard_tail: int = 0,
                       agg=None, packed=None):
     """THE scan program: every block batch, on one device or a mesh,
-    for one query or a fused group. Returns (count, inspected, scores
-    [k], flat idx [k][, agg [K]]); flat idx = page * E + entry over the
-    whole stacked page axis. What it is given decides what it traces,
-    and each of the four is its own program:
+    for one query or a fused group. Returns ONE int32 array, count,
+    inspected, scores [k], flat idx [k] and the ?agg= counts [K] where
+    `agg` is set, as engine.pack_out lays them out (unpack_out reads
+    them on the host); flat idx = page * E + entry over the whole
+    stacked page axis. What it is given decides what it traces, and
+    each of the four is its own program:
 
-      - `term_active` None: one query, no query axis anywhere; else the
-        per-query tables are [Q, ...]-stacked and count, scores, idx and
-        agg come back with a leading [Q] (_scan_pages). The page arrays
-        are read once per term loop regardless of Q.
+      - `term_active` None: one query, no query axis anywhere, the
+        output one row; else the per-query tables are [Q, ...]-stacked,
+        count, scores, idx and agg carry a leading [Q] (_scan_pages)
+        and the output is one row a member. The page arrays are read
+        once per term loop regardless of Q.
       - `mesh` None: the whole page axis on the default device, no
         shard_map; else the stacked page axis (blocks x pages — the
         corpus 'sequence' axis, SURVEY.md §5) splits across the mesh's
         scan axis, the query tables replicate, each shard scans its
-        slice and _merge_shards reduces — one jit call.
+        slice and _merge_shards reduces, packed inside the shard_map
+        so that one replicated array leaves it — one jit call.
 
     On a mesh the structural predicate (plan + span_cols/s_tables) has
     two placements, selected by the STATIC `span_sharded` flag (part of
@@ -1237,11 +1242,11 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
     scan = functools.partial(_scan_pages, n_terms=n_terms, top_k=top_k,
                              widths=widths, plan=plan, agg=agg)
     if mesh is None:
-        return scan(kv_key, kv_val, entry_start, entry_end, entry_dur,
-                    entry_valid, page_block, term_keys, val_ranges,
-                    term_active, dur_lo, dur_hi, win_start, win_end,
-                    val_hits, block_group, entry_dur_res, None, span_cols,
-                    s_tables, entry_agg)
+        return pack_out(*scan(
+            kv_key, kv_val, entry_start, entry_end, entry_dur,
+            entry_valid, page_block, term_keys, val_ranges, term_active,
+            dur_lo, dur_hi, win_start, win_end, val_hits, block_group,
+            entry_dur_res, None, span_cols, s_tables, entry_agg))
 
     from jax.sharding import PartitionSpec as P
     from tempo_tpu.parallel.mesh import SCAN_AXIS, shard_map_compat
@@ -1269,8 +1274,9 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
         count, inspected, scores, idx, *agg_counts = scan(
             kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
             page_block, *rest)
-        return _merge_shards(count, inspected, scores, idx, agg_counts,
-                             local_flat=local_flat, top_k=top_k)
+        return pack_out(*_merge_shards(
+            count, inspected, scores, idx, agg_counts,
+            local_flat=local_flat, top_k=top_k))
 
     return shard_map_compat(
         shard_fn, mesh=mesh,
@@ -1282,10 +1288,10 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
         in_specs=(P(SCAN_AXIS),) * 7 + (P(),) * 9
         + (P(SCAN_AXIS), P(None, SCAN_AXIS) if fused else P(SCAN_AXIS),
            P(SCAN_AXIS), P(), P(SCAN_AXIS)),
-        out_specs=(P(), P(), P(), P())
-        + ((P(),) if agg is not None else ()),
-        # all_gather + latest_k yields identical values on every shard,
-        # but the replication checker can't infer it through the gather
+        # the one packed array, the same on every shard: all_gather +
+        # latest_k yields identical values on each, but the replication
+        # checker can't infer it through the gather
+        out_specs=P(),
         check=False,
     )(kv_key, kv_val, entry_start, entry_end, entry_dur, entry_valid,
       page_block, term_keys, val_ranges, term_active, dur_lo, dur_hi,
@@ -1426,8 +1432,8 @@ class MultiBlockEngine:
         return self.place(self.stage_host(blocks))
 
     def scan_async(self, batch: BlockBatch, mq: MultiQuery):
-        """Dispatch one query without device→host sync; returns device
-        arrays (count, inspected, scores [k], idx [k][, agg])."""
+        """Dispatch one query without device→host sync; returns the
+        launch's one device array (engine.pack_out: one row)."""
         def place():
             # uploaded once per MultiQuery, to where this engine's
             # launches read them, and resident from then on
@@ -1447,16 +1453,35 @@ class MultiBlockEngine:
             tables_key=(mq.val_ranges.shape,), kernel="multi")
 
     def scan(self, batch: BlockBatch, mq: MultiQuery):
-        return fetch_scan_out(self.scan_async(batch, mq))
+        return self.fetch(self.scan_async(batch, mq), mq)
+
+    def fetch(self, out, q) -> tuple:
+        """The drain's end of a launch of `q` (a MultiQuery or a
+        CoalescedQuery): the ONE blocking fetch of its output array,
+        taken apart on the host (engine.unpack_out's tuple) and counted
+        under the launch's mode (tempo_search_launch_out_fetches_total,
+        the way out's mirror of launch_param_puts)."""
+        agg_stage = q.agg_stage
+        fetched = fetch_scan_out(
+            out, 0 if agg_stage is None else agg_stage.n_keys)
+        obs.launch_out_fetches.inc(mode=self._mode(
+            "batched" if isinstance(q, MultiQuery) else "coalesced"))
+        return fetched
+
+    def _mode(self, mode: str) -> str:
+        """What a launch is booked as: on a mesh every launch is a
+        `mesh` launch."""
+        return mode if self.mesh is None else "mesh"
 
     def coalesced_scan_async(self, batch: BlockBatch, cq: CoalescedQuery,
                              top_k: int):
         """Fused multi-query dispatch without device→host sync; returns
-        device arrays (counts [Q], inspected, scores [Q,k], idx [Q,k][,
-        agg [Q,K]]). `top_k` is the GROUP k — max over the coalesced
-        requests' resolved k, so every member's limit is covered. Any
-        member requesting ?agg= turns the stage on for the dispatch;
-        non-requesters ignore their row of the [Q, K] output."""
+        the launch's one device array, a row a member (engine.pack_out:
+        [Q, 2 + 2k (+ K)]). `top_k` is the GROUP k — max over the
+        coalesced requests' resolved k, so every member's limit is
+        covered. ?agg= members fuse apart from plain ones
+        (QueryCoalescer's group key), so every row of a group carries
+        the counts or none does."""
         def place():
             # the stacked tables of THIS fused launch, one host buffer:
             # one transfer to where the launch reads them. A member's
@@ -1496,8 +1521,7 @@ class MultiBlockEngine:
         byte-identical host path. Guard inactive = direct call."""
         from tempo_tpu.robustness import GUARD
 
-        if self.mesh is not None:
-            mode = "mesh"
+        mode = self._mode(mode)
 
         def run():
             with profile.dispatch(mode) as rec:

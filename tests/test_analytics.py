@@ -43,7 +43,6 @@ from tempo_tpu.search.analytics import (
 from tempo_tpu.search.batcher import host_scan
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import SearchData, encode_search_data
-from tempo_tpu.search.engine import fetch_coalesced_out
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     compile_multi,
@@ -445,8 +444,8 @@ def test_agg_engine_paths_byte_identical(tags):
         mqs.append(m)
     cq = stack_queries(mqs)
     assert cq.agg_stage is mq.agg_stage
-    _cs, _i3, _s3, _x3, *ext_c = fetch_coalesced_out(
-        eng.coalesced_scan_async(batch, cq, 512))
+    _cs, _i3, _s3, _x3, *ext_c = eng.fetch(
+        eng.coalesced_scan_async(batch, cq, 512), cq)
     assert ext_c
     for qi, other in enumerate(({"env": "prod"}, tags, {"env": "dev"})):
         assert mq.agg_stage.decode(ext_c[0][qi]) == \

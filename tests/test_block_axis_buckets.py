@@ -23,7 +23,7 @@ from tempo_tpu.search import multiblock
 from tempo_tpu.search.batcher import host_scan
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import SearchData
-from tempo_tpu.search.engine import fetch_coalesced_out
+from tempo_tpu.search.engine import fetch_scan_out
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     batch_scan_kernel,
@@ -162,7 +162,7 @@ def test_a_group_of_any_size_answers_as_the_walk_and_the_host_route(
     if Q == 1:
         got = [eng.scan(batch, mq) for mq in mqs]
     else:
-        counts, inspected, scores, idx = fetch_coalesced_out(
+        counts, inspected, scores, idx = fetch_scan_out(
             eng.coalesced_scan_async(batch, stack_queries(mqs), TOP_K))
         got = [(int(counts[i]), inspected, scores[i], idx[i])
                for i in range(Q)]
@@ -193,7 +193,7 @@ def test_pad_rows_match_nothing_even_under_exhaustive(n, groups):
     assert (count, int(inspected)) == (len(want), g.entries)
     assert _ids(batch, scores, idx) == want
     # three members: the query axis pads to four, the block axis to B
-    counts, inspected, scores, idx = fetch_coalesced_out(
+    counts, inspected, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, stack_queries([mq] * 3), TOP_K))
     assert counts.tolist() == [len(want)] * 3 + [0]
     assert int(inspected) == g.entries

@@ -115,7 +115,7 @@ def _check_bucketed(entries, exprs, packed: bool, mesh=None):
     """Mixed-plan differential: the bucket-fused dispatch answers
     bit-for-bit identically to solo dispatches and the host reference
     evaluator, per member lane."""
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
 
     packing_mod.PACKING.enabled = packed
     half = len(entries) // 2
@@ -155,7 +155,7 @@ def _check_bucketed(entries, exprs, packed: bool, mesh=None):
         assert isinstance(cq.structural, BucketedStructural)
         assert cq.structural.plan == bk
         assert cq.structural.active_nodes <= cq.structural.slot_nodes
-        counts, _ins, scores, idx = fetch_coalesced_out(
+        counts, _ins, scores, idx = fetch_scan_out(
             eng.coalesced_scan_async(batch, cq, 512))
         for qi, mq in enumerate(group):
             got = set()

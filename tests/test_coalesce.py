@@ -25,7 +25,7 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.search import ColumnarPages, PageGeometry, SearchResults
 from tempo_tpu.search.batcher import BlockBatcher, QueryCoalescer, ScanJob
 from tempo_tpu.search.data import SearchData
-from tempo_tpu.search.engine import fetch_coalesced_out, resolve_top_k
+from tempo_tpu.search.engine import fetch_scan_out, resolve_top_k
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     compile_multi,
@@ -112,7 +112,7 @@ def test_coalesced_kernel_matches_serial_dispatches():
     serial = [eng.scan(batch, mq) for mq in mqs]
     cq = stack_queries(mqs)
     k = max(resolve_top_k(eng.top_k, mq.limit) for mq in mqs)
-    counts, inspected, scores, idx = fetch_coalesced_out(
+    counts, inspected, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, cq, k))
     for qi, (c, ins, s, i) in enumerate(serial):
         assert int(counts[qi]) == c
@@ -165,10 +165,11 @@ def test_window_timeout_flushes_without_peers():
     out = fut.result(timeout=10)
     waited = time.perf_counter() - t0
     assert waited >= 0.10, f"flushed after {waited * 1e3:.1f}ms, window 150ms"
-    count, inspected, scores, idx = out
-    assert (int(count), int(inspected)) == (want[0], want[1])
-    np.testing.assert_array_equal(np.asarray(scores), want[2])
-    np.testing.assert_array_equal(np.asarray(idx), want[3])
+    # a lone member's flush is a solo launch: its one output array
+    count, inspected, scores, idx = fetch_scan_out(out)
+    assert (count, inspected) == (want[0], want[1])
+    np.testing.assert_array_equal(scores, want[2])
+    np.testing.assert_array_equal(idx, want[3])
 
 
 def test_max_queries_triggers_immediate_fused_flush():
@@ -187,12 +188,20 @@ def test_max_queries_triggers_immediate_fused_flush():
     out1 = f1.result(timeout=30)
     out2 = f2.result(timeout=30)
     assert co.fused == 1 and co.queries == 2
+    at = obs.launch_out_fetches.value(mode="coalesced")
+    fetches = []
     for out, want in ((out1, want1), (out2, want2)):
-        count, inspected, scores, idx = out
-        assert (int(count), int(inspected)) == (want[0], want[1])
+        # each member takes its own row of the group's one array
+        (count, inspected, scores, idx), fetched = out.fetch()
+        fetches.append(fetched)
+        assert (count, inspected) == (want[0], want[1])
         kq = want[2].shape[0]
-        np.testing.assert_array_equal(np.asarray(scores)[:kq], want[2])
-        np.testing.assert_array_equal(np.asarray(idx)[:kq], want[3])
+        np.testing.assert_array_equal(scores[:kq], want[2])
+        np.testing.assert_array_equal(idx[:kq], want[3])
+        assert scores.base is idx.base is not None, "rows are views"
+    # the first member to drain fetched the array, the second found it
+    assert fetches == [1, 0]
+    assert obs.launch_out_fetches.value(mode="coalesced") - at == 1
 
 
 def test_solo_search_skips_window_entirely():

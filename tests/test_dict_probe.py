@@ -399,10 +399,8 @@ def test_coalesced_dispatch_with_device_probe_queries(membership):
 
     cq = stack_queries(mqs)
     assert (cq.val_hits is not None) == masked
-    counts, inspected, scores, idx = eng.coalesced_scan_async(
-        batch, cq, 1024)
-    counts, scores, idx = (np.asarray(counts), np.asarray(scores),
-                           np.asarray(idx))
+    counts, inspected, scores, idx = eng.fetch(
+        eng.coalesced_scan_async(batch, cq, 1024), cq)
     for qi, mq in enumerate(mqs):
         s_count, _, s_scores, s_idx = eng.scan(batch, mq)
         assert counts[qi] == s_count
@@ -447,7 +445,7 @@ def test_mesh_sharded_dispatch_with_device_probe(membership):
            for v in ("session-001", "session-01")]
     mqs = [m for m in mqs if m is not None]
     cq = stack_queries(mqs)
-    counts = np.asarray(eng.coalesced_scan_async(batch, cq, 1024)[0])
+    counts = eng.fetch(eng.coalesced_scan_async(batch, cq, 1024), cq)[0]
     for qi, m in enumerate(mqs):
         assert counts[qi] == eng.scan(batch, m)[0]
 

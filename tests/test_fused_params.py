@@ -17,7 +17,7 @@ import pytest
 
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.search import dict_probe, multiblock, pipeline
-from tempo_tpu.search.engine import fetch_coalesced_out, resolve_top_k
+from tempo_tpu.search.engine import fetch_scan_out, resolve_top_k
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     MultiQuery,
@@ -124,13 +124,13 @@ def _reqs():
 @pytest.fixture
 def kernel_calls(monkeypatch):
     """Every launch's kernel call under `jax.transfer_guard("disallow")`
-    (an operand the call would have to move first raises), and the
-    operands each was given."""
+    (an operand the call would have to move first raises): the operands
+    and statics each was given, and the program's name."""
     calls = []
 
-    def guarded(real):
+    def guarded(name, real):
         def call(*tables, **statics):
-            calls.append((tables, statics))
+            calls.append((tables, statics, name))
             with jax.transfer_guard("disallow"):
                 return real(*tables, **statics)
         # mask_scan_kernel traces batch_scan_kernel's body by this
@@ -139,7 +139,7 @@ def kernel_calls(monkeypatch):
 
     for name in ("batch_scan_kernel", "mask_scan_kernel"):
         monkeypatch.setattr(multiblock, name,
-                            guarded(getattr(multiblock, name)))
+                            guarded(name, getattr(multiblock, name)))
     return calls
 
 
@@ -167,7 +167,7 @@ def test_a_fused_launch_answers_as_its_members_solo(member, request,
     del kernel_calls[:]
     cq = stack_queries(mqs)
     k = max(resolve_top_k(eng.top_k, mq.limit) for mq in mqs)
-    counts, inspected, scores, idx = fetch_coalesced_out(
+    counts, inspected, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, cq, k))
     assert counts.shape == (4,) and counts[3] == 0
     for qi, (c, ins, s, i) in enumerate(serial):
@@ -176,7 +176,7 @@ def test_a_fused_launch_answers_as_its_members_solo(member, request,
         np.testing.assert_array_equal(scores[qi][:kq], s)
         np.testing.assert_array_equal(idx[qi][:kq], i)
     # one kernel call, given the buffer where the seven tables were
-    (tables, statics), = kernel_calls
+    (tables, statics, _name), = kernel_calls
     assert statics["packed"] == cq.dims
     buf, *rest = tables[7:14]
     assert rest == [None] * 6 and buf.shape == cq.packed.shape
@@ -209,7 +209,7 @@ def test_a_fused_launch_transfers_one_array_in_build(member, request,
     before = {m: obs.launch_param_puts.value(mode=m)
               for m in ("batched", "coalesced", "mesh")}
     cq = stack_queries(mqs)
-    fetch_coalesced_out(eng.coalesced_scan_async(batch, cq, 128))
+    fetch_scan_out(eng.coalesced_scan_async(batch, cq, 128))
     moved = {m: obs.launch_param_puts.value(mode=m) - before[m]
              for m in before}
     want = 1 if member == "ranges" else 2

@@ -537,7 +537,7 @@ def _width_reqs(R: int) -> list:
 def _launch(eng, batch, mqs, fused: bool, top_k: int) -> list:
     """[(count, inspected, scores, idx)] a member: each alone, or all
     in one fused launch."""
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
     from tempo_tpu.search.multiblock import stack_queries
 
     if not fused:
@@ -546,7 +546,7 @@ def _launch(eng, batch, mqs, fused: bool, top_k: int) -> list:
     if len({mq.n_terms for mq in mqs}) > 1:
         # the one-term member rides with its second term inactive
         assert cq.n_terms == 2 and not cq.term_active[0, 1]
-    counts, inspected, scores, idx = fetch_coalesced_out(
+    counts, inspected, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, cq, top_k))
     return [(int(counts[i]), inspected, scores[i], idx[i])
             for i in range(len(mqs))]

@@ -72,7 +72,7 @@ def test_a_mesh_launch_transfers_nothing_under_the_lock(kind, guarded_lock,
     the call, and the guard raises `Disallowed device-to-device
     transfer`. The second half puts that placement back and expects the
     raise."""
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
     from tempo_tpu.search.multiblock import MultiBlockEngine, stack_queries
 
     blocks = _tied_blocks(5, 100)
@@ -85,7 +85,7 @@ def test_a_mesh_launch_transfers_nothing_under_the_lock(kind, guarded_lock,
             # the first launch places, the second finds them resident
             eng.scan(batch, mqs[0])
             return [int(eng.scan(batch, mqs[0])[0])]
-        counts = fetch_coalesced_out(
+        counts = fetch_scan_out(
             eng.coalesced_scan_async(batch, stack_queries(mqs), 64))[0]
         return [int(c) for c in counts]
 
@@ -111,7 +111,7 @@ def test_a_fused_mesh_launch_puts_one_array(guarded_lock, monkeypatch):
     array, put in `build`, before the lock (the parent put seven: 28
     transfers on four devices); the counter counts arrays, not device
     copies; the locked call moves nothing."""
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
     from tempo_tpu.search.multiblock import MultiBlockEngine, stack_queries
 
     blocks = _tied_blocks(5, 100)
@@ -129,7 +129,7 @@ def test_a_fused_mesh_launch_puts_one_array(guarded_lock, monkeypatch):
 
     monkeypatch.setattr(mesh_mod, "put_replicated", counting)
     before = obs.launch_param_puts.value(mode="mesh")
-    counts = fetch_coalesced_out(
+    counts = fetch_scan_out(
         eng.coalesced_scan_async(batch, cq, 64))[0]
     assert [int(c) for c in counts] == [250, 250]
     assert len(put) == 1 and put[0] is cq.packed

@@ -235,7 +235,7 @@ def test_ties_across_shards_merge_in_full_sort_order(shards, queries):
     mesh and however many queries share the launch."""
     from tempo_tpu import tempopb
     from tempo_tpu.parallel import make_mesh
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
     from tempo_tpu.search.multiblock import (
         MultiBlockEngine, compile_multi, stack_queries,
     )
@@ -264,7 +264,7 @@ def test_ties_across_shards_merge_in_full_sort_order(shards, queries):
         count, _, scores, idx = eng.scan(batch, mqs[0])
         got = [(int(count), scores, idx)]
     else:
-        counts, _, scores, idx = fetch_coalesced_out(
+        counts, _, scores, idx = fetch_scan_out(
             eng.coalesced_scan_async(batch, stack_queries(mqs), k))
         got = [(int(counts[q]), scores[q], idx[q]) for q in range(queries)]
     for (count, scores, idx), w in zip(got, want):

@@ -421,9 +421,9 @@ def test_all_dispatch_modes_populate_profiler(profiler_reset):
                                         limit=20))
     ccq = stack_queries([mq, mq2])
     out = mbe.coalesced_scan_async(batch, ccq, 64)
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
 
-    fetch_coalesced_out(out)
+    fetch_scan_out(out)
     assert "coalesced" in _modes_seen()
 
     # mesh (8 virtual CPU devices, conftest)

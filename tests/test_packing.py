@@ -295,7 +295,7 @@ def test_parity_one_block_batches():
 
 
 def test_parity_coalesced_engine():
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
 
     eng = MultiBlockEngine(top_k=32)
     blocks = _parity_blocks()
@@ -306,7 +306,7 @@ def test_parity_coalesced_engine():
         batch = eng.place(host)
         mqs = [compile_multi(blocks, r, cache_on=batch) for r in reqs]
         cq = stack_queries(mqs)
-        out = fetch_coalesced_out(
+        out = fetch_scan_out(
             eng.coalesced_scan_async(batch, cq, top_k=32))
         return (out[0].tolist(), int(out[1]),
                 out[2].tolist(), out[3].tolist())

@@ -249,7 +249,7 @@ def test_multiblock_and_coalesced_byte_identical_both_verdicts():
             mqs.append(mq)
         # coalesced fused dispatch over the same batch, same verdicts
         cq = stack_queries(mqs)
-        counts = np.asarray(eng.coalesced_scan_async(batch, cq, 1024)[0])
+        counts = eng.fetch(eng.coalesced_scan_async(batch, cq, 1024), cq)[0]
         for qi in range(len(mqs)):
             assert counts[qi] == base[qi][0], (verdict, qi)
 

@@ -7,6 +7,7 @@ its launch needs and no more — no shard_map off a mesh, no query axis
 on a solo launch. Plus the offline harness (`cli/blocks.py search`),
 which answers through the batcher like the server."""
 
+import functools
 import json
 
 import jax
@@ -14,16 +15,19 @@ import numpy as np
 import pytest
 
 from tempo_tpu.parallel import make_mesh
+from tempo_tpu.search import dict_probe, multiblock, pipeline
 from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 from tempo_tpu.search.data import search_data_matches
-from tempo_tpu.search.engine import fetch_coalesced_out
+from tempo_tpu.search.engine import fetch_scan_out
 from tempo_tpu.search.multiblock import (
     MultiBlockEngine,
     batch_scan_kernel,
     compile_multi,
     stack_queries,
+    unpack_queries,
 )
 
+from tests.test_fused_params import kernel_calls  # noqa: F401 (fixture)
 from tests.test_search import _corpus, _mk_req
 
 KINDS = ("solo", "fused", "mesh_solo", "mesh_fused")
@@ -59,7 +63,7 @@ def _launch(kind, blocks, reqs):
     mqs = [compile_multi(blocks, r, cache_on=batch) for r in reqs]
     if kind.endswith("solo"):
         return eng, batch, mqs, [eng.scan(batch, mq) for mq in mqs]
-    counts, inspected, scores, idx = fetch_coalesced_out(
+    counts, inspected, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, stack_queries(mqs), TOP_K))
     # a fused launch pads its query axis to a power of two: dead lanes
     assert counts.shape[0] == 4 and counts[3] == 0
@@ -86,6 +90,66 @@ def test_launch_kinds_answer_alike_and_as_the_oracle(kind):
         ids = {bytes.fromhex(m.trace_id)
                for m in eng.results(batch, mq, g[2], g[3])}
         assert ids == expected
+
+
+def _parent_form(tables, statics):
+    """What the scan program returned until PR 41, for the operands a
+    launch of today was given: the (count, inspected, scores, idx)
+    TUPLE of `_scan_pages` over the whole page axis on one device,
+    jitted as the parent's entry jitted it, fetched part by part."""
+    host = [None if t is None else np.asarray(t) for t in tables]
+    queries = host[7:14]
+    if statics["packed"] is not None:
+        queries = unpack_queries(host[7], statics["packed"])
+    scan = jax.jit(functools.partial(
+        multiblock._scan_pages, n_terms=statics["n_terms"],
+        top_k=statics["top_k"], widths=statics["widths"], plan=None,
+        agg=None))
+    count, inspected, scores, idx = scan(
+        *host[:7], *queries, host[14], host[15], host[16], None, None,
+        None, None)
+    return (np.asarray(count), int(inspected), np.asarray(scores),
+            np.asarray(idx))
+
+
+@pytest.mark.parametrize("kind", KINDS + ("mask_solo", "mask_fused"))
+def test_the_one_array_holds_what_the_parent_tuple_held(
+        kind, kernel_calls, monkeypatch):  # noqa: F811
+    """Every trace of the program, and `mask_scan_kernel` (the same
+    body given hit masks), solo and fused: the one int32 array it
+    returns, read by `unpack_out`, equals bit for bit the four arrays
+    the parent's program returned for the same operands."""
+    _, blocks = _blocks()
+    reqs = _reqs()
+    mask = kind.startswith("mask")
+    if mask:
+        # every device probe's product leaves as a hit mask
+        monkeypatch.setattr(dict_probe, "R_MAX", 0)
+        pipeline._COMPILE_CACHE.clear()
+    eng = MultiBlockEngine(
+        top_k=TOP_K, mesh=make_mesh(4) if kind.startswith("mesh") else None,
+        device_probe_min_vals=1 if mask else None)
+    batch = eng.stage(blocks)
+    mqs = [compile_multi(blocks, r, cache_on=batch) for r in reqs]
+    if mask:
+        pipeline._COMPILE_CACHE.clear()
+    assert all((mq.val_hits is not None) == mask for mq in mqs)
+    if kind.endswith("solo"):
+        got = [eng.scan(batch, mq) for mq in mqs]
+    else:
+        counts, inspected, scores, idx = fetch_scan_out(
+            eng.coalesced_scan_async(batch, stack_queries(mqs), TOP_K))
+        got = [(counts, inspected, scores, idx)]
+    assert len(kernel_calls) == len(got)
+    assert any(np.any(g[0]) for g in got)
+    for (tables, statics, name), g in zip(kernel_calls, got):
+        assert name == ("mask_scan_kernel" if mask else "batch_scan_kernel")
+        want = _parent_form(tables, statics)
+        assert g[1] == want[1] and type(g[1]) is int
+        for a, b in zip((g[0], g[2], g[3]), (want[0], want[2], want[3])):
+            # (a solo launch's count comes back a Python int)
+            assert b.dtype == getattr(a, "dtype", np.int32) == np.int32
+            np.testing.assert_array_equal(a, b)
 
 
 def _avals(jaxpr):
@@ -136,7 +200,6 @@ def test_each_kind_traces_what_its_launch_needs_and_no_more(kind):
         d["entry_dur"], d["entry_valid"], d["page_block"], *tables)
     prims = {name for name, _ in _avals(closed.jaxpr)}
     shapes = {shape for _, shape in _avals(closed.jaxpr)}
-    out = [tuple(v.aval.shape) for v in closed.jaxpr.outvars]
 
     assert ("shard_map" in prims) == (mesh is not None)
     local = P // 4 if mesh is not None else P
@@ -147,12 +210,16 @@ def test_each_kind_traces_what_its_launch_needs_and_no_more(kind):
         assert {"psum", "all_gather"} <= prims
     else:
         assert not {"psum", "all_gather", "axis_index"} & prims
+    # ONE int32 output, packed inside the shard_map on a mesh: a row
+    # of count, inspected, scores [k], idx [k], and a row a member
+    (out,) = closed.jaxpr.outvars
+    assert str(out.aval.dtype) == "int32"
     if Q is None:
-        assert out == [(), (), (TOP_K,), (TOP_K,)]
+        assert out.aval.shape == (2 + 2 * TOP_K,)
         assert not any(s[:1] == (1,) and s[1:3] == (local, E)
                        for s in shapes)
     else:
-        assert out == [(Q,), (), (Q, TOP_K), (Q, TOP_K)]
+        assert out.aval.shape == (Q, 2 + 2 * TOP_K)
         assert (Q, local, E) in shapes
 
 

@@ -12,8 +12,12 @@ where 2.9 is due (my chip run, PR 36). `_RANGE_BLOCK_MIN` pads the
 table; this file says if a compiler or a change undoes that. And the
 fused launch given its seven tables as one packed operand (PR 39) is
 the program it was given them one by one: the prelude's slices in front
-of the term loop move nothing the loop reads.
+of the term loop move nothing the loop reads. And the one packed output
+(PR 41) leaves the scan in front of it op for op what it was when it
+returned four arrays: one more small fusion, no other pass.
 """
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +122,51 @@ def test_the_packed_operand_leaves_the_fused_program_as_it_was(
         temp[form] = compiled.memory_analysis().temp_size_in_bytes
     assert loops["packed"] == loops["seven"] > 0
     assert abs(temp["packed"] - temp["seven"]) < temp["seven"] / 100
+
+
+def _ops(text):
+    """(opcode, shape) of every instruction of a compiled module,
+    layouts and names dropped: what the program does, countable."""
+    import collections
+    import re
+
+    return collections.Counter(
+        (m.group(2), re.sub(r"\{[^}]*\}", "", m.group(1)))
+        for m in re.finditer(r"= (\S+) (\w[\w\-]*)\(", text))
+
+
+@pytest.mark.parametrize("Q", [None, 2], ids=["solo", "fused"])
+def test_the_packed_output_leaves_the_scan_in_front_of_it_as_it_was(
+        Q, one_chip, no_compile_cache):
+    """`_scan_pages` jitted alone returns the four arrays the program
+    returned until PR 41; `batch_scan_kernel` packs them. Compiled for
+    the v5e at a share16.scan launch's shapes, every op of the first is
+    in the second, shape for shape, and what the second adds works on
+    the packed row alone. (Without `pack_out`'s barrier the compiler
+    re-fuses the count and the scores: a pass over a copy of
+    entry_start that the parent did not make.)"""
+    import functools
+
+    from tempo_tpu.search import multiblock
+
+    T, R, K = 2, 1, 128
+    S, cols = _group(one_chip, jnp.int16)
+    q = () if Q is None else (Q,)
+    tables = (S((*q, B, T), jnp.int32), S((*q, B, T, R, 2), jnp.int32),
+              None if Q is None else S((Q, T), jnp.bool_),
+              *[S(q, jnp.uint32)] * 4)
+    four = jax.jit(functools.partial(
+        multiblock._scan_pages, n_terms=T, top_k=K, widths=None, plan=None,
+        agg=None)).lower(*cols, *tables, *[None] * 7).compile().as_text()
+    one = multiblock.batch_scan_kernel.lower(
+        *cols, *tables, n_terms=T, top_k=K).compile().as_text()
+    was, now = _ops(four), _ops(one)
+    # the parent's ROOT tuple is the one op the packed program lacks
+    gone = {op for op in was - now if op[0] != "tuple"}
+    assert not gone, gone
+    row = (Q or 1) * (2 + 2 * K)
+    for (op, shape), n in (now - was).items():
+        dims = [int(d) for d in shape.split("[")[-1].rstrip("]").split(",")
+                if d]
+        assert math.prod(dims) <= row, (op, shape, n)
+    assert now[("fusion", f"s32[{'2,' if Q else ''}{2 + 2 * K}]")] == 1

@@ -449,7 +449,7 @@ def _check_stacked(entries, template, rng, packed: bool, mesh=None,
     """Plan-shape stacking differential: a random same-shape query
     group answers bit-for-bit identically fused (stack_queries + one
     launch), solo (scan_async) and on the host reference evaluator. Returns the group size actually stacked."""
-    from tempo_tpu.search.engine import fetch_coalesced_out
+    from tempo_tpu.search.engine import fetch_scan_out
     from tempo_tpu.search.multiblock import stack_queries
 
     packing_mod.PACKING.enabled = packed
@@ -481,7 +481,7 @@ def _check_stacked(entries, template, rng, packed: bool, mesh=None,
     assert len(group) >= 2, "reparam produced no same-plan peer"
     cq = stack_queries(group)
     assert cq.structural is not None and cq.structural.plan == base
-    counts, _ins, scores, idx = fetch_coalesced_out(
+    counts, _ins, scores, idx = fetch_scan_out(
         eng.coalesced_scan_async(batch, cq, 512))
     all_entries = entries + spanless
     E = E_GEO.entries_per_page
