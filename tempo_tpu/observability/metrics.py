@@ -559,6 +559,27 @@ structural_stack_events = Counter(
     "the plan shape within the window), solo_disabled "
     "(search_structural_stack_enabled off) — unstackable plan shapes "
     "are visible here instead of silently flushing solo")
+structural_launches = Counter(
+    "tempo_search_structural_launches_total",
+    "scan launches that evaluated a structural plan over staged span "
+    "columns, by how the plan joins spans: rel=none (span-scope "
+    "predicates and aggregates only), child (one gather through the "
+    "parent column), desc (pointer doubling over the span axis)")
+structural_join_trips = Counter(
+    "tempo_search_structural_join_trips_total",
+    "pointer-doubling trips the `desc` joins of structural launches "
+    "ran (two gathers over the span axis a trip): log2 of the group's "
+    "longest trace a join, not of the padded span axis")
+structural_span_rows = Counter(
+    "tempo_search_structural_span_rows_total",
+    "span rows put on the device with staged groups: kind=live (spans "
+    "of the group's traces) or kind=pad (rows that fill the span axis "
+    "to its power of two: every pass of a launch reads them too)")
+structural_span_bytes = Gauge(
+    "tempo_search_structural_span_bytes",
+    "HBM held by the span columns of the groups resident in the "
+    "staged-batch cache now (pad rows included); part of "
+    "tempo_search_hbm_cache_bytes")
 
 # ---- hot-tier live search (search/live_tier.py) ----
 live_tier_entries = Gauge(

@@ -476,7 +476,8 @@ class DispatchProfiler:
                 span.set_attribute("jit_cache", rec.jit)
                 for key in ("topk", "shards", "pages_per_shard", "params",
                             "membership", "compare", "blocks",
-                            "blocks_bucket"):
+                            "blocks_bucket", "rel", "join_trips",
+                            "span_rows"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
             span.end(end_ns, cpu1)

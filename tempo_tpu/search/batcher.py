@@ -145,7 +145,8 @@ def host_scan(host, mq, top_k: int):
             # the host tier stages the SAME packed layout (stack_host
             # packs before the tiers fork), so the fallback kernel
             # unpacks with the batch's own width descriptor
-            widths=getattr(host, "widths", None), plan=plan, agg=agg)
+            widths=getattr(host, "widths", None), plan=plan, agg=agg,
+            span_max=host.span_max if span_dev is not None else None)
         res = fetch_scan_out(out, agg or 0)
     profile.observe_stage("execute", "host_fallback",
                           time.perf_counter() - t0)
@@ -783,6 +784,7 @@ class BlockBatcher:
         self._cache_total = 0
         self._cache_peak = 0        # high water of _cache_total, as published
         self._probe_dict_total = 0  # staged-dict bytes across _cache
+        self._span_total = 0        # span-column bytes across _cache
         # logical (unpacked-layout) bytes across both tiers — the other
         # half of the packed-residency accounting split: budgets charge
         # PHYSICAL bytes (that is why packing fits more blocks), the
@@ -906,6 +908,12 @@ class BlockBatcher:
         return sum(int(d.nbytes)
                    for d in getattr(batch, "staged_dicts", {}).values())
 
+    @staticmethod
+    def _span_bytes(batch) -> int:
+        """HBM held by a batch's structural span columns."""
+        return _structural.span_device_bytes(
+            getattr(batch, "span_device", None))
+
     def _publish_gauges_locked(self) -> None:
         """Occupancy gauges for /metrics (caller holds self._lock): HBM
         + host tier bytes, and the HBM share held by staged device-probe
@@ -920,6 +928,7 @@ class BlockBatcher:
             obs.hbm_cache_peak_bytes.set(self._cache_peak)
         obs.host_cache_bytes.set(self._host_total)
         obs.probe_dict_bytes.set(self._probe_dict_total)
+        obs.structural_span_bytes.set(self._span_total)
         obs.hbm_logical_bytes.set(self._cache_logical)
         obs.host_logical_bytes.set(self._host_logical)
 
@@ -967,6 +976,7 @@ class BlockBatcher:
         self._cache_total -= old.nbytes
         self._cache_logical -= old.logical
         self._probe_dict_total -= self._dict_bytes(old.batch)
+        self._span_total -= self._span_bytes(old.batch)
         MASK_BYTES.add("memo", -old.mask_bytes)
         obs.batch_cache_events.inc(result="evict")
         obs.hbm_evicted_bytes.inc(old.nbytes)
@@ -1149,6 +1159,14 @@ class BlockBatcher:
                 return batch
 
             batch = robustness.GUARD.run("h2d", put)
+            if batch.span_device is not None:
+                # span rows staged, live and pad: counters alone, so a
+                # flat search that stages a span-bearing group writes
+                # nothing new into its trace (PERF.md section 7 h11)
+                rows = int(batch.span_device["span_trace"].shape[0])
+                live = sum(b.n_spans for b in batch.blocks)
+                obs.structural_span_rows.inc(live, kind="live")
+                obs.structural_span_rows.inc(rows - live, kind="pad")
             # batch.nbytes covers the stacked page arrays AND any staged
             # probe dictionaries — both live in HBM under this budget
             # (physical/packed bytes; the logical twin feeds the gauges)
@@ -1167,11 +1185,13 @@ class BlockBatcher:
                     self._cache_total -= prev.nbytes
                     self._cache_logical -= prev.logical
                     self._probe_dict_total -= self._dict_bytes(prev.batch)
+                    self._span_total -= self._span_bytes(prev.batch)
                     MASK_BYTES.add("memo", -prev.mask_bytes)
                 self._cache[key] = entry
                 self._cache_total += nbytes
                 self._cache_logical += entry.logical
                 self._probe_dict_total += self._dict_bytes(batch)
+                self._span_total += self._span_bytes(batch)
                 self._evict_hbm_locked()
             return entry
         finally:
@@ -1249,6 +1269,7 @@ class BlockBatcher:
                 self._cache_total -= old.nbytes
                 self._cache_logical -= old.logical
                 self._probe_dict_total -= self._dict_bytes(old.batch)
+                self._span_total -= self._span_bytes(old.batch)
                 MASK_BYTES.add("memo", -old.mask_bytes)
                 # a pending rebalance deferral for a dead block's batch
                 # is satisfied by this removal — keeping the marker
@@ -1671,12 +1692,20 @@ class BlockBatcher:
             expr = _structural.structural_query(req)
             if expr is not None:
                 blocks = list(holder.blocks)
-                st = _structural.compile_structural(
-                    expr, blocks, cache_on=holder,
-                    staged_dicts=(None if host_only else
-                                  getattr(holder, "staged_dicts", None)),
-                    host_only=host_only,
-                    entry_kv_slots=blocks[0].geometry.kv_per_entry)
+                # spanned on structural searches alone (a flat search
+                # writes no new span name: PERF.md section 7 h11)
+                with tracing.start_span("structural.compile") as cspan:
+                    st = _structural.compile_structural(
+                        expr, blocks, cache_on=holder,
+                        staged_dicts=(None if host_only else
+                                      getattr(holder, "staged_dicts",
+                                              None)),
+                        host_only=host_only,
+                        entry_kv_slots=blocks[0].geometry.kv_per_entry)
+                    cspan.set_attributes(
+                        nodes=len(st.node_info), blocks=len(blocks),
+                        terms=(0 if st.term_keys is None
+                               else int(st.term_keys.shape[1])))
             # dictionary-pruned jobs (term key -1 across all terms) count
             # as skipped; under the exhaustive flag nothing is skipped —
             # every page is scanned by definition
@@ -2074,6 +2103,20 @@ class BlockBatcher:
                         continue
                     pinned.append(cached)
                 book("staging", t0, c0, gi, cache=_event, pick=pick)
+                if (span.recording and pick == "staged"
+                        and cached.batch.span_put_ns
+                        and _structural.STRUCTURAL_QUERY_TAG in req.tags):
+                    # the put of the group's span columns, from its own
+                    # stamps: only a structural search that paid for it
+                    # writes the span (a flat search's trace gets no new
+                    # span name: PERF.md section 7 h11)
+                    b = cached.batch
+                    rows = int(b.span_device["span_trace"].shape[0])
+                    tracing.record_span(
+                        "batcher.stage_spans", *b.span_put_ns,
+                        parent=span.context, span_rows=rows,
+                        pad_rows=rows - sum(x.n_spans for x in b.blocks),
+                        bytes=self._span_bytes(b), span_max=b.span_max)
                 obs.group_picks.inc(pick=pick)
                 if qs is not None:
                     qs.add_cache(_event)

@@ -138,6 +138,13 @@ class BlockBatch:
     # structural mask inside shard_map. Static at every consuming call
     # site — part of the jit shape key like `widths`
     span_sharded: bool = False
+    # the longest trace's span count as a power of two
+    # (StructuralGate.span_max; 0 = no span columns): the static that
+    # bounds a structural launch's `desc` joins, in either span layout
+    span_max: int = 0
+    # (start, end) on the span clock of the span columns' own put,
+    # fenced (place_batch): what `batcher.stage_spans` is written from
+    span_put_ns: tuple = ()
 
     @property
     def n_pages(self) -> int:
@@ -205,6 +212,7 @@ class HostBatch:
     # structural span columns, host tier (see BlockBatch.span_device):
     # the host-fallback scan runs the same structural kernel over these
     span_cat: dict | None = None
+    span_max: int = 0       # see BlockBatch.span_max
     # bytes of `blocks`' columns that are views of `cat`: counted once
     aliased_nbytes: int = 0
     # the prepare memo of the group's last staged batch, kept across an
@@ -440,6 +448,7 @@ def stack_host(blocks: list[ColumnarPages],
         # gate off is one attribute read and the identical layout
         span_cat = STRUCTURAL.stack_spans(blocks, E,
                                           int(page_block.shape[0]))
+    span_max = 0 if span_cat is None else STRUCTURAL.span_max(span_cat)
     entries_padded = int(page_block.shape[0]) * E
     packed_dicts = _pack_batch_dicts(blocks, probe_min_vals,
                                      n_shards=n_shards)
@@ -450,7 +459,7 @@ def stack_host(blocks: list[ColumnarPages],
         blocks, aliased = _blocks_over(cat, blocks, page_offset)
     return HostBatch(cat=cat, page_block=page_block, blocks=blocks,
                      page_offset=page_offset, packed_dicts=packed_dicts,
-                     widths=widths, span_cat=span_cat,
+                     widths=widths, span_cat=span_cat, span_max=span_max,
                      aliased_nbytes=aliased,
                      cat_logical_nbytes=(
                          packing.logical_nbytes(entries_padded, C0,
@@ -493,9 +502,11 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
                           nbytes=sum(int(v.nbytes) for v in cat.values()))
     span_dev = None
     span_sharded = False
+    span_put_ns = ()
     if host.span_cat is not None:
         from .structural import STRUCTURAL
 
+        t_span = tracing.now_ns()
         span_host = host.span_cat
         if sharding is not None and STRUCTURAL.shard_spans:
             # segment-aligned span sharding: each trace's contiguous
@@ -528,6 +539,10 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
         else:
             span_dev = {k: jnp.asarray(v)
                         for k, v in span_host.items()}
+        # fenced like the page arrays above: nothing scans the batch
+        # before its span columns are there, and the stamp is the put's
+        jax.block_until_ready(span_dev)
+        span_put_ns = (t_span, tracing.now_ns())
     staged = {}
     for fp, pd in host.packed_dicts.items():
         dict_mesh = (mesh if mesh is not None and pd.n_shards > 1
@@ -537,7 +552,8 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
                       blocks=host.blocks, page_offset=host.page_offset,
                       staged_dicts=staged, widths=host.widths,
                       logical_device_nbytes=host.cat_logical_nbytes,
-                      span_device=span_dev, span_sharded=span_sharded)
+                      span_device=span_dev, span_sharded=span_sharded,
+                      span_max=host.span_max, span_put_ns=span_put_ns)
 
 
 def stack_blocks(blocks: list[ColumnarPages], pad_to: int | None = None,
@@ -1080,7 +1096,8 @@ def _scan_pages(kv_key, kv_val, entry_start, entry_end, entry_dur,
                 entry_valid, page_block, term_keys, val_ranges, term_active,
                 dur_lo, dur_hi, win_start, win_end, val_hits, block_group,
                 entry_dur_res, struct_mask, span_cols, s_tables, entry_agg,
-                *, n_terms: int, top_k: int, widths, plan, agg):
+                *, n_terms: int, top_k: int, widths, plan, agg,
+                span_max=None):
     """The scan of the pages one device holds: per query the verdict
     mask, its count, the top-k of its matches and, where `agg` (static,
     the dense key-space size K) is set, the ?agg= counts the same mask
@@ -1113,7 +1130,8 @@ def _scan_pages(kv_key, kv_val, entry_start, entry_end, entry_dur,
             # the same for every query
             mask = mask & structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
+                entry_dur_res, span_cols, st_t, plan=plan, widths=widths,
+                span_max=span_max)
         count = jnp.sum(mask, dtype=jnp.int32)
         # traced HERE though it reads no query table: between count and
         # top-k the TPU compiler fuses this reduce with the mask's first
@@ -1183,7 +1201,7 @@ def _merge_shards(count, inspected, scores, idx, agg_counts, *,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg", "packed"))
+                                    "agg", "packed", "span_max"))
 def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       entry_valid, page_block, term_keys, val_ranges,
                       term_active, dur_lo, dur_hi, win_start, win_end,
@@ -1191,7 +1209,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       span_cols=None, s_tables=None, entry_agg=None,
                       *, mesh=None, n_terms: int, top_k: int, widths=None,
                       plan=None, span_sharded=False, shard_tail: int = 0,
-                      agg=None, packed=None):
+                      agg=None, packed=None, span_max=None):
     """THE scan program: every block batch, on one device or a mesh,
     for one query or a fused group. Returns ONE int32 array, count,
     inspected, scores [k], flat idx [k] and the ?agg= counts [K] where
@@ -1235,12 +1253,18 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
     here, in front of the scan and outside the shard_map: one operand
     to put on the device, or to replicate over the mesh, where there
     were seven. None: the tables come one by one, as a solo launch's
-    resident parameters do."""
+    resident parameters do.
+
+    `span_max` (STATIC, a structural launch's: BlockBatch.span_max, the
+    longest trace's span count as a power of two) bounds the trips of
+    the plan's `desc` joins (structural.join_trips) in either span
+    layout; None on a launch with no structural plan."""
     if packed is not None:
         (term_keys, val_ranges, term_active, dur_lo, dur_hi, win_start,
          win_end) = unpack_queries(term_keys, packed)
     scan = functools.partial(_scan_pages, n_terms=n_terms, top_k=top_k,
-                             widths=widths, plan=plan, agg=agg)
+                             widths=widths, plan=plan, agg=agg,
+                             span_max=span_max)
     if mesh is None:
         return pack_out(*scan(
             kv_key, kv_val, entry_start, entry_end, entry_dur,
@@ -1259,7 +1283,8 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
         def verdicts(st_t):
             return structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
+                entry_dur_res, span_cols, st_t, plan=plan, widths=widths,
+                span_max=span_max)
 
         struct_mask = (jax.vmap(verdicts) if fused else verdicts)(s_tables)
         span_cols = s_tables = None
@@ -1302,7 +1327,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg", "packed"))
+                                    "agg", "packed", "span_max"))
 def mask_scan_kernel(*tables, **statics):
     """batch_scan_kernel for a launch that takes a hit mask (`val_hits`):
     the same body under a program name of its own, so that a device
@@ -1553,6 +1578,9 @@ class MultiBlockEngine:
                 rec.add_bytes(h2d=h2d)
                 widths = batch.widths
                 span_sharded = bool(st is not None and batch.span_sharded)
+                # the bound of the plan's ancestor joins: the longest
+                # trace of the group, not the padded span axis
+                span_max = batch.span_max if span_cols is not None else None
                 shard_tail = self._shard_tail(batch, d)
                 jit_key = (
                     attrs["kernel"], self.mesh is not None,
@@ -1565,7 +1593,8 @@ class MultiBlockEngine:
                     shard_tail, agg,
                     None if span_cols is None else
                     tuple(sorted((n, tuple(a.shape))
-                                 for n, a in span_cols.items())))
+                                 for n, a in span_cols.items())),
+                    span_max)
                 miss = rec.compile_check(jit_key)
                 if miss:
                     _SCAN_JIT_KEYS.add(jit_key)
@@ -1590,6 +1619,17 @@ class MultiBlockEngine:
                     obs.launch_table_rows.inc(members * (bucket - blocks),
                                               kind="pad")
                     rec.set(blocks_bucket=bucket)
+                if span_cols is not None:
+                    # a structural launch alone says how it joins and
+                    # over how many span rows (pad rows included): no
+                    # flat search carries these (PERF.md section 7 h11)
+                    from .structural import plan_joins
+
+                    rows = int(span_cols["span_parent"].shape[0])
+                    rel, trips = plan_joins(plan, span_max)
+                    obs.structural_launches.inc(rel=rel)
+                    obs.structural_join_trips.inc(trips)
+                    rec.set(rel=rel, join_trips=trips, span_rows=rows)
                 if q.n_terms:
                     # a launch without tag terms compares no range
                     compare = compares_by(q.val_ranges.shape[-2])
@@ -1608,7 +1648,8 @@ class MultiBlockEngine:
                         span_cols, s_tables, entry_agg, mesh=self.mesh,
                         n_terms=q.n_terms, top_k=top_k, widths=widths,
                         plan=plan, span_sharded=span_sharded,
-                        shard_tail=shard_tail, agg=agg, packed=packed)
+                        shard_tail=shard_tail, agg=agg, packed=packed,
+                        span_max=span_max)
 
                 if self.mesh is None:
                     with rec.stage(stage):

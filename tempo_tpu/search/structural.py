@@ -156,6 +156,19 @@ class StructuralGate:
             page_off += P
         return cols
 
+    @staticmethod
+    def span_max(cols: dict) -> int:
+        """The longest trace's span count in a stacked segment
+        (stack_spans' product), rounded up to a power of two: the static
+        a launch carries for its `desc` joins (join_trips). A span has
+        fewer proper ancestors than its trace has spans, so that many
+        rows bound every ancestor walk whatever the padded axis holds;
+        ingest caps a trace at `max_spans` (512 shipped), so a tenant
+        shows at most ten values and one where any trace reaches the
+        cap. The sharded layout keeps a trace whole, so it reads the
+        same static."""
+        return _pow2(max(1, int(cols["entry_span_count"].max())))
+
     def stack_group_key(self, batch, st) -> tuple | None:
         """THE plan-shape stacking gate: the coalescer's pending-group
         key for a structural query, or None — one attribute read when
@@ -1114,7 +1127,7 @@ def _probe_leaf_terms(block, terms: list, staged_dict, host_only: bool):
 
 def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
                           page_block, entry_dur_res, span_cols, tables,
-                          *, plan, widths):
+                          *, plan, widths, span_max):
     """[P, E] bool trace verdicts for a compiled structural plan.
     Recursion over the STATIC plan runs at trace time and emits one
     fused computation — compiled, never interpreted per row. Span-level
@@ -1123,7 +1136,9 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
     columns; trace-level leaves evaluate on the entry columns with the
     same unpack/membership code paths the legacy kernel uses. ``plan``
     (like the packed-residency ``widths``) is a static descriptor at
-    every call site — the jit-purity lint's descriptor rule pins it."""
+    every call site — the jit-purity lint's descriptor rule pins it.
+    ``span_max`` (static too: StructuralGate.span_max of the staged
+    segment) bounds the `desc` joins' trips (join_trips)."""
     import jax.numpy as jnp
 
     safe_pb = jnp.maximum(page_block, 0)
@@ -1149,13 +1164,75 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
                 span_cols["span_kv_val"],
                 span_cols["entry_span_begin"],
                 span_cols["entry_span_count"],
-                bg_span)
+                bg_span,
+                join_trips(span_max))
     ectx = (kv_key, kv_val, entry_dur, entry_dur_res, valid, safe_pb,
             bg_page)
     if bucketed:
         return _bucket_trace_mask(ectx, sctx, tables, widths,
                                   bucket=plan) & valid
     return _trace_mask(plan, ectx, sctx, tables, widths) & valid
+
+
+def join_trips(span_max: int) -> int:
+    """Trips of one `desc` join's pointer doubling: after k trips a
+    span has seen its first 2^k proper ancestors, and it has fewer of
+    them than its trace has spans, so log2 of `span_max` (the longest
+    trace's span count as a power of two: StructuralGate.span_max)
+    reaches them all: 9 for a trace capped at 512, where the padded
+    span axis of a group of 64 blocks would give 26."""
+    return max(1, (int(span_max) - 1).bit_length())
+
+
+def plan_joins(plan, span_max: int) -> tuple:
+    """(rel, trips) of a launch of `plan`: `rel` is "desc" where the
+    plan joins by ancestor (a bucket plan with relations runs that arm
+    for every slot), else "child" where it joins by parent, else
+    "none"; `trips` the doubling trips its `desc` joins run between
+    them."""
+    if not plan:
+        return "none", 0
+    if plan[0] == "bucket":
+        n_desc = plan[1] if plan[3] else 0
+        rel = "desc" if n_desc else "none"
+    else:
+        ops = list(_plan_ops(plan))
+        n_desc = ops.count("desc")
+        rel = "desc" if n_desc else "child" if "child" in ops else "none"
+    return rel, n_desc * join_trips(span_max)
+
+
+def _plan_ops(plan):
+    """The ops of a plan's nodes: a node is a tuple that starts with its
+    op, its children further tuples (and/or keep theirs in one)."""
+    if isinstance(plan, tuple):
+        if plan and isinstance(plan[0], str):
+            yield plan[0]
+        for sub in plan:
+            yield from _plan_ops(sub)
+
+
+def _descends(am, sm, s_par, trips: int):
+    """[S]: the spans of `sm` with a proper ancestor in `am`, by
+    pointer doubling through the parent column: after k trips a span
+    has seen its first 2^k proper ancestors, two lookups over the span
+    axis a trip, `trips` (static: join_trips) of them in a fori_loop (a
+    Python unroll sends XLA's CPU fusion passes into minutes of
+    optimisation on batch-sized span axes, measured)."""
+    import jax
+    import jax.numpy as jnp
+
+    def _dbl(_i, carry):
+        acc, jump = carry
+        safe_j = jnp.maximum(jump, 0)
+        acc2 = acc | ((jump >= 0) & acc[safe_j])
+        jump2 = jnp.where(jump >= 0, jump[safe_j], -1)
+        return acc2, jump2
+
+    safe_par = jnp.maximum(s_par, 0)
+    acc, _ = jax.lax.fori_loop(
+        0, trips, _dbl, ((s_par >= 0) & am[safe_par], s_par))
+    return sm & acc
 
 
 def _seg_count(m, seg_b, seg_n):
@@ -1194,7 +1271,7 @@ def _span_mask(plan, sctx, tables, widths):
     if plan is None:
         raise StructuralCompileError("span plan must not be None")
     (s_valid, s_block, s_par, s_dur, s_kind, s_kk, s_vv,
-     _seg_b, _seg_n, bg_span) = sctx
+     _seg_b, _seg_n, bg_span, trips) = sctx
     (term_keys, val_ranges, val_hits, _bg, dur_params, kind_params,
      _agg) = tables
     op = plan[0]
@@ -1240,29 +1317,9 @@ def _span_mask(plan, sctx, tables, widths):
         safe_par = jnp.maximum(s_par, 0)
         return cm & (s_par >= 0) & pm[safe_par]
     if op == "desc":
-        import jax
-
         am = _span_mask(plan[2], sctx, tables, widths)
         sm = _span_mask(plan[3], sctx, tables, widths)
-        safe_par = jnp.maximum(s_par, 0)
-        # pointer doubling: after k steps acc covers the first 2^k
-        # proper ancestors; the trip count is log2 of the PADDED span
-        # axis — static, so the jit key stays shape-only. fori_loop, not
-        # a Python unroll: the unrolled gather chain sends XLA's CPU
-        # fusion passes into minutes-long optimization on batch-sized
-        # span axes (measured), while the rolled loop compiles once.
-        def _dbl(_i, carry):
-            acc, jump = carry
-            safe_j = jnp.maximum(jump, 0)
-            acc2 = acc | ((jump >= 0) & acc[safe_j])
-            jump2 = jnp.where(jump >= 0, jump[safe_j], -1)
-            return acc2, jump2
-
-        S = int(s_par.shape[0])
-        acc, _ = jax.lax.fori_loop(
-            0, max(1, (S - 1).bit_length()), _dbl,
-            ((s_par >= 0) & am[safe_par], s_par))
-        return sm & acc
+        return _descends(am, sm, s_par, trips)
     raise StructuralCompileError(f"bad span plan op {op!r}")
 
 
@@ -1382,13 +1439,12 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
     selects by the traced opcode — the slot-machine dual of
     _span_mask's static descriptor dispatch. Pad slots (opcode 0)
     evaluate to false and are unreachable from any real slot."""
-    import jax
     import jax.numpy as jnp
 
     from .packing import mask_select_grouped
 
     (s_valid, s_block, s_par, s_dur, s_kind, s_kk, s_vv,
-     _seg_b, _seg_n, bg_span) = sctx
+     _seg_b, _seg_n, bg_span, trips) = sctx
     (term_keys, val_ranges, val_hits, _bg, dur_params, kind_params,
      _agg) = core
     S = int(s_valid.shape[0])
@@ -1433,18 +1489,8 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
             val = jnp.where(opc == 7,
                             rb & (s_par >= 0) & ra[safe_par], val)
 
-            # the same rolled pointer doubling as _span_mask's desc
-            def _dbl(_i, carry):
-                acc, jump = carry
-                safe_j = jnp.maximum(jump, 0)
-                acc2 = acc | ((jump >= 0) & acc[safe_j])
-                jump2 = jnp.where(jump >= 0, jump[safe_j], -1)
-                return acc2, jump2
-
-            acc, _ = jax.lax.fori_loop(
-                0, max(1, (S - 1).bit_length()), _dbl,
-                ((s_par >= 0) & ra[safe_par], s_par))
-            val = jnp.where(opc == 8, rb & acc, val)
+            val = jnp.where(opc == 8,
+                            _descends(ra, rb, s_par, trips), val)
         regs.append(val)
     return regs
 
