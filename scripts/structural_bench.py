@@ -12,8 +12,14 @@ parameters, 65,536 traces a block), stages them as ONE group on a
 each of the traffic mix's five templates against it and launches it
 `--calls` times, fenced. One JSON line a (size, plan): the median
 launch, the first (compile + launch), the span axis, the trips of its
-joins by ancestor. Times are the host's clock around a fenced launch; on
+joins by ancestor; and one `stage` line a size: the span axis, its tile
+(`structural.SPAN_TILE`) and the pad rows the tile's alignment and the
+power of two cost. Times are the host's clock around a fenced launch; on
 anything but a TPU the lines say so in `platform` and mean nothing.
+
+`--span-tiles 128,256,512,1024` stages and times every size once a
+tile (it sets the module's constant for the run: how the shipped one
+was chosen, PERF.md section 6, PR 45).
 """
 
 from __future__ import annotations
@@ -36,6 +42,9 @@ def main() -> int:
     ap.add_argument("--calls", type=int, default=3)
     ap.add_argument("--seed", type=int, default=2147483777)
     ap.add_argument("--out", default="chiprun_out/structural_bench.jsonl")
+    ap.add_argument("--span-tiles", default="",
+                    help="tiles of the span axis to try, each in turn "
+                         "(default: the shipped structural.SPAN_TILE)")
     args = ap.parse_args()
 
     import jax
@@ -70,6 +79,54 @@ def main() -> int:
         print(line, flush=True)
         out.write(line + "\n")
         out.flush()
+
+    def bench_group(blocks: list, requests: list, n_blocks: int) -> None:
+        """Stage `blocks` as one group, then compile and launch each
+        request's plan against it."""
+        eng = MultiBlockEngine()
+        t = time.perf_counter()
+        host = eng.stage_host(blocks)
+        stack_s = time.perf_counter() - t
+        t = time.perf_counter()
+        batch = eng.place(host)
+        live = sum(b.n_spans for b in blocks)
+        rows = int(batch.span_device["span_trace"].shape[0])
+        tile = rows // int(batch.span_device["span_tile_block"].shape[0])
+        say({"blocks": n_blocks, "label": "stage", "stack_s": stack_s,
+             "put_s": time.perf_counter() - t, "span_rows": rows,
+             "live_rows": live, "span_tile": tile,
+             # pad rows: up to each block's next tile, then the axis'
+             # power of two
+             "align_pad_rows": sum(-b.n_spans % tile for b in blocks),
+             "span_max": batch.span_max,
+             "span_bytes": structural.span_device_bytes(batch.span_device),
+             "device_bytes": batch.device_nbytes})
+        req = tempopb.SearchRequest()
+        req.tags[EXHAUSTIVE_SEARCH_TAG] = "1"
+        req.limit = 20
+        for r in requests:
+            expr = ir.parse(json.dumps(r["ref"]["q"]))
+            mq = compile_multi(blocks, req, cache_on=batch)
+            mq.structural = structural.compile_structural(
+                expr, blocks, cache_on=batch,
+                staged_dicts=batch.staged_dicts,
+                entry_kv_slots=blocks[0].geometry.kv_per_entry)
+            rel, trips = structural.plan_joins(mq.structural.plan,
+                                               batch.span_max)
+            t = time.perf_counter()
+            res = eng.scan(batch, mq)
+            first = time.perf_counter() - t
+            ms = []
+            for _ in range(args.calls):
+                t = time.perf_counter()
+                res = eng.scan(batch, mq)
+                ms.append((time.perf_counter() - t) * 1e3)
+            say({"blocks": n_blocks, "label": r["name"], "rel": rel,
+                 "span_rows": rows, "span_tile": tile, "trips": trips,
+                 "launch_ms": statistics.median(ms),
+                 "min_ms": min(ms), "max_ms": max(ms),
+                 "first_s": first, "matches": int(res[0]),
+                 "inspected": int(res[1])})
 
     pages, spans_all, vals_all, dur_all = [], [], [], []
     for n_blocks in [int(x) for x in args.blocks.split(",")]:
@@ -107,46 +164,10 @@ def main() -> int:
                 params, float(q)),
         }
         requests, _ = build_requests(traffic, manifest, args.seed)
-        eng = MultiBlockEngine()
-        t = time.perf_counter()
-        host = eng.stage_host(blocks)
-        stack_s = time.perf_counter() - t
-        t = time.perf_counter()
-        batch = eng.place(host)
-        live = sum(b.n_spans for b in blocks)
-        rows = int(batch.span_device["span_trace"].shape[0])
-        say({"blocks": n_blocks, "label": "stage", "stack_s": stack_s,
-             "put_s": time.perf_counter() - t, "span_rows": rows,
-             "live_rows": live, "span_max": batch.span_max,
-             "span_bytes": structural.span_device_bytes(batch.span_device),
-             "device_bytes": batch.device_nbytes})
-        req = tempopb.SearchRequest()
-        req.tags[EXHAUSTIVE_SEARCH_TAG] = "1"
-        req.limit = 20
-        for r in requests:
-            expr = ir.parse(json.dumps(r["ref"]["q"]))
-            mq = compile_multi(blocks, req, cache_on=batch)
-            mq.structural = structural.compile_structural(
-                expr, blocks, cache_on=batch,
-                staged_dicts=batch.staged_dicts,
-                entry_kv_slots=blocks[0].geometry.kv_per_entry)
-            rel, trips = structural.plan_joins(mq.structural.plan,
-                                               batch.span_max)
-            t = time.perf_counter()
-            res = eng.scan(batch, mq)
-            first = time.perf_counter() - t
-            ms = []
-            for _ in range(args.calls):
-                t = time.perf_counter()
-                res = eng.scan(batch, mq)
-                ms.append((time.perf_counter() - t) * 1e3)
-            say({"blocks": n_blocks, "label": r["name"], "rel": rel,
-                 "span_rows": rows, "trips": trips,
-                 "launch_ms": statistics.median(ms),
-                 "min_ms": min(ms), "max_ms": max(ms),
-                 "first_s": first, "matches": int(res[0]),
-                 "inspected": int(res[1])})
-        del batch, host
+        for tile in [int(t) for t in args.span_tiles.split(",") if t] \
+                or [structural.SPAN_TILE]:
+            structural.SPAN_TILE = tile
+            bench_group(blocks, requests, n_blocks)
     return 0 if dev.platform == "tpu" else 3
 
 

@@ -570,11 +570,20 @@ structural_join_trips = Counter(
     "pointer-doubling trips the `desc` joins of structural launches "
     "ran (two gathers over the span axis a trip): log2 of the group's "
     "longest trace a join, not of the padded span axis")
+structural_leaf_lookup_rows = Counter(
+    "tempo_search_structural_leaf_lookup_rows_total",
+    "rows the tag leaves of structural launches indexed their "
+    "per-block tables by (key, range lows, range highs a leaf; the "
+    "dictionary groups where a hit mask rides): one a TILE of the "
+    "staged span axis a lookup and member, so 1 / SPAN_TILE of the "
+    "launch's span rows a lookup (reckoned from the plan and the "
+    "staged shape, not read from the compiled program)")
 structural_span_rows = Counter(
     "tempo_search_structural_span_rows_total",
     "span rows put on the device with staged groups: kind=live (spans "
-    "of the group's traces) or kind=pad (rows that fill the span axis "
-    "to its power of two: every pass of a launch reads them too)")
+    "of the group's traces) or kind=pad (rows that fill a block's last "
+    "tile of the span axis, and the axis to its power of two: every "
+    "pass of a launch reads them too)")
 structural_span_bytes = Gauge(
     "tempo_search_structural_span_bytes",
     "HBM held by the span columns of the groups resident in the "

@@ -1623,13 +1623,21 @@ class MultiBlockEngine:
                     # a structural launch alone says how it joins and
                     # over how many span rows (pad rows included): no
                     # flat search carries these (PERF.md section 7 h11)
-                    from .structural import plan_joins
+                    from .structural import leaf_lookup_rows, plan_joins
 
                     rows = int(span_cols["span_parent"].shape[0])
+                    tiles = int(span_cols["span_tile_block"].shape[0])
                     rel, trips = plan_joins(plan, span_max)
+                    # what the tag leaves index their tables by: a tile
+                    # of the span axis a lookup, once a member
+                    looked = members * leaf_lookup_rows(plan, s_tables,
+                                                        span_cols)
                     obs.structural_launches.inc(rel=rel)
                     obs.structural_join_trips.inc(trips)
-                    rec.set(rel=rel, join_trips=trips, span_rows=rows)
+                    obs.structural_leaf_lookup_rows.inc(looked)
+                    rec.set(rel=rel, join_trips=trips, span_rows=rows,
+                            span_tile=rows // tiles,
+                            leaf_lookup_rows=looked)
                 if q.n_terms:
                     # a launch without tag terms compares no range
                     compare = compares_by(q.val_ranges.shape[-2])

@@ -59,6 +59,16 @@ STRUCTURAL_QUERY_TAG = "x-structural-q"
 
 _PARSE_CACHE_MAX = 256
 
+# THE tile of the span axis (a power of two, a multiple of 128): staging
+# starts every block's spans on a multiple of it (stack_spans,
+# shard_span_segment), so a tag leaf looks its block's tables up once a
+# tile, S / SPAN_TILE indices, and broadcasts over the tile's rows
+# (_tile_leaf) where a lookup a row was three gathers with S indices a
+# leaf. The price is the alignment's pad, under SPAN_TILE rows a block:
+# nothing for a block of 774,000 spans, ~13 % for a group of blocks of
+# 2,000, inside what the power-of-two bucket gives away anyway.
+SPAN_TILE = 512
+
 
 class StructuralGate:
     """Process-wide gate + knobs (the PACKING/OWNERSHIP singleton
@@ -109,27 +119,26 @@ class StructuralGate:
     def stack_spans(self, blocks: list, E: int, pad_pages: int) -> dict | None:
         """Stack the blocks' span segments for a batched staging:
         flat span arrays concatenate with per-block index remaps (trace
-        index += page offset * E, parent/begin += span base), the span
-        axis pads to a power of two (shape-only jit keys), and a
-        per-span block-row column is precomputed so leaf tables gather
-        per block exactly like the page kernels do. Returns the host
-        numpy dict, or None when no block carries spans."""
+        index += page offset * E, parent/begin += span base). THE
+        LAYOUT RULE: every block's spans start on a multiple of
+        SPAN_TILE and the axis pads to a power of two of at least one
+        tile (shape-only jit keys), so each aligned tile of SPAN_TILE
+        rows holds live rows of ONE block; ``span_tile_block`` names
+        it, a row a tile, and the tag leaves look their block's tables
+        up by the tile (_tile_leaf). The rows between a block's end and
+        the next tile are pad rows like those at the axis' end
+        (``span_trace`` -1). Returns the host numpy dict, or None when
+        no block carries spans."""
         if not any(getattr(b, "has_spans", False) for b in blocks):
             return None
-        total = sum(b.n_spans for b in blocks)
-        S = _pow2(max(1, total))
+        tile = SPAN_TILE
+        total = sum(_tile_up(b.n_spans, tile) for b in blocks
+                    if getattr(b, "has_spans", False))
+        S = _pow2(max(tile, total))
         Cs = max(b.span_kv_key.shape[1] for b in blocks if b.has_spans)
-        cols = {
-            "span_trace": np.full(S, -1, dtype=np.int32),
-            "span_parent": np.full(S, -1, dtype=np.int32),
-            "span_block": np.zeros(S, dtype=np.int32),
-            "span_dur": np.zeros(S, dtype=np.uint32),
-            "span_kind": np.zeros(S, dtype=np.int8),
-            "span_kv_key": np.full((S, Cs), -1, dtype=np.int32),
-            "span_kv_val": np.full((S, Cs), -1, dtype=np.int32),
-            "entry_span_begin": np.zeros((pad_pages, E), dtype=np.int32),
-            "entry_span_count": np.zeros((pad_pages, E), dtype=np.int32),
-        }
+        cols = _empty_span_cols(S, Cs, tile)
+        cols["entry_span_begin"] = np.zeros((pad_pages, E), dtype=np.int32)
+        cols["entry_span_count"] = np.zeros((pad_pages, E), dtype=np.int32)
         base = 0
         page_off = 0
         for bi, b in enumerate(blocks):
@@ -141,7 +150,6 @@ class StructuralGate:
                 par = b.span_parent.astype(np.int32, copy=True)
                 par[par >= 0] += base
                 cols["span_parent"][base:base + n] = par
-                cols["span_block"][base:base + n] = bi
                 cols["span_dur"][base:base + n] = b.span_dur
                 cols["span_kind"][base:base + n] = b.span_kind
                 cols["span_kv_key"][base:base + n, :b.span_kv_key.shape[1]] \
@@ -152,7 +160,9 @@ class StructuralGate:
                 cols["entry_span_begin"][page_off:page_off + P] = \
                     np.where(cnt > 0, b.entry_span_begin + base, 0)
                 cols["entry_span_count"][page_off:page_off + P] = cnt
-                base += n
+                end = base + _tile_up(n, tile)
+                cols["span_tile_block"][base // tile:end // tile] = bi
+                base = end
             page_off += P
         return cols
 
@@ -231,7 +241,11 @@ class StructuralGate:
         of one uniform pow2 ``per_shard`` length, chunk ``s`` holding
         exactly the spans of traces whose page lands on shard ``s``
         (per-trace runs are contiguous and a trace lives on one page,
-        so segments never straddle chunks). Coordinates REBASE to the
+        so segments never straddle chunks). Inside a chunk stack_spans'
+        layout rule holds: the chunk's rows lie block after block, each
+        block on a multiple of SPAN_TILE, ``per_shard`` is at least one
+        tile, and ``span_tile_block`` (split over the mesh with the
+        rest) names each tile's block. Coordinates REBASE to the
         shard-local frame shard_map hands each device: ``span_trace``
         to the local entry flat index, ``span_parent`` and
         ``entry_span_begin`` to chunk-local span positions — the
@@ -242,35 +256,39 @@ class StructuralGate:
             return None
         if n_shards <= 1 or pad_pages % n_shards:
             return None
+        tile = SPAN_TILE
         S_old = int(span_cat["span_trace"].shape[0])
         pp = pad_pages // n_shards          # pages per shard
         trace = span_cat["span_trace"]
-        live = trace >= 0
-        shard_of = np.where(live, trace // (pp * E), -1)
-        per_shard = _pow2(max(
-            1, int(np.bincount(shard_of[live], minlength=n_shards).max()
-                   if live.any() else 1)))
-        S_new = n_shards * per_shard
-        Cs = span_cat["span_kv_key"].shape[1]
-        out = {
-            "span_trace": np.full(S_new, -1, dtype=np.int32),
-            "span_parent": np.full(S_new, -1, dtype=np.int32),
-            "span_block": np.zeros(S_new, dtype=np.int32),
-            "span_dur": np.zeros(S_new, dtype=np.uint32),
-            "span_kind": np.zeros(S_new, dtype=np.int8),
-            "span_kv_key": np.full((S_new, Cs), -1, dtype=np.int32),
-            "span_kv_val": np.full((S_new, Cs), -1, dtype=np.int32),
-        }
+        shard_of = np.where(trace >= 0, trace // (pp * E), -1)
+        tiles_old = span_cat["span_tile_block"]
+        block_of = np.repeat(tiles_old, S_old // tiles_old.shape[0])
         # old global span index -> chunk-LOCAL position (for the parent
         # and entry_span_begin rebase); -1 = dropped padding row
         local_of = np.full(S_old, -1, dtype=np.int64)
+        chunks = []     # (shard, its old rows, the blocks' tiles)
+        need = tile
         for s in range(n_shards):
             idx = np.flatnonzero(shard_of == s)
-            n = len(idx)
-            if not n:
+            if not idx.size:
                 continue
-            local_of[idx] = np.arange(n)
-            dst = slice(s * per_shard, s * per_shard + n)
+            # the chunk's rows come block after block (stack_spans laid
+            # them down so): a run a block, each run on a tile boundary
+            blk, first, counts = np.unique(
+                block_of[idx], return_index=True, return_counts=True)
+            room = _tile_up(counts, tile)
+            starts = np.cumsum(room) - room
+            local_of[idx] = (np.repeat(starts - first, counts)
+                             + np.arange(idx.size))
+            chunks.append((s, idx, np.repeat(blk, room // tile)))
+            need = max(need, int(room.sum()))
+        per_shard = _pow2(need)
+        Cs = span_cat["span_kv_key"].shape[1]
+        out = _empty_span_cols(n_shards * per_shard, Cs, tile)
+        for s, idx, tiles in chunks:
+            dst = s * per_shard + local_of[idx]
+            t0 = s * per_shard // tile
+            out["span_tile_block"][t0:t0 + tiles.size] = tiles
             out["span_trace"][dst] = trace[idx] - s * pp * E
             par = span_cat["span_parent"][idx]
             safe = np.clip(par, 0, S_old - 1)
@@ -278,16 +296,14 @@ class StructuralGate:
             # resolves within one trace), hence the same shard; a
             # malformed cross-shard pointer maps to -1 (no parent) —
             # the explicit shard check matters because local_of is one
-            # global map, so an already-processed OTHER shard's local
-            # index would otherwise rebase to a wrong in-chunk row
+            # global map, so an OTHER shard's local index would
+            # otherwise rebase to a wrong in-chunk row
             out["span_parent"][dst] = np.where(
-                (par >= 0) & (shard_of[safe] == s)
-                & (local_of[safe] >= 0),
+                (par >= 0) & (shard_of[safe] == s),
                 local_of[safe], -1).astype(np.int32)
-            for name in ("span_block", "span_dur", "span_kind"):
+            for name in ("span_dur", "span_kind", "span_kv_key",
+                         "span_kv_val"):
                 out[name][dst] = span_cat[name][idx]
-            out["span_kv_key"][dst] = span_cat["span_kv_key"][idx]
-            out["span_kv_val"][dst] = span_cat["span_kv_val"][idx]
         begin = span_cat["entry_span_begin"]
         count = span_cat["entry_span_count"]
         safe_b = np.clip(begin, 0, S_old - 1)
@@ -380,6 +396,25 @@ def _pow2(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _tile_up(n, tile: int):
+    """`n` (a count, or an array of them) rounded up to whole tiles."""
+    return -(-n // tile) * tile
+
+
+def _empty_span_cols(S: int, Cs: int, tile: int) -> dict:
+    """The span-axis columns of a staged segment, all pad rows: `S`
+    rows of `Cs` kv slots and a block a tile of `tile` rows."""
+    return {
+        "span_trace": np.full(S, -1, dtype=np.int32),
+        "span_parent": np.full(S, -1, dtype=np.int32),
+        "span_tile_block": np.zeros(S // tile, dtype=np.int32),
+        "span_dur": np.zeros(S, dtype=np.uint32),
+        "span_kind": np.zeros(S, dtype=np.int8),
+        "span_kv_key": np.full((S, Cs), -1, dtype=np.int32),
+        "span_kv_val": np.full((S, Cs), -1, dtype=np.int32),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -1144,34 +1179,45 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
     safe_pb = jnp.maximum(page_block, 0)
     valid = entry_valid & (page_block >= 0)[:, None]
     bucketed = plan[0] == "bucket"
-    (term_keys, val_ranges, val_hits, block_group,
-     dur_params, kind_params, agg_params) = tables[:7]
+    val_hits, block_group = tables[2], tables[3]
     bg_page = None
     if val_hits is not None and block_group is not None:
         bg_page = block_group[safe_pb]                   # [P]
     sctx = None
     if span_cols is not None:
-        s_block = jnp.maximum(span_cols["span_block"], 0)
-        bg_span = None
-        if val_hits is not None and block_group is not None:
-            bg_span = block_group[s_block]               # [S]
-        sctx = (span_cols["span_trace"] >= 0,            # s_valid
-                s_block,
-                span_cols["span_parent"],
-                span_cols["span_dur"],
-                span_cols["span_kind"],
-                span_cols["span_kv_key"],
-                span_cols["span_kv_val"],
-                span_cols["entry_span_begin"],
-                span_cols["entry_span_count"],
-                bg_span,
-                join_trips(span_max))
+        sctx = _span_ctx(span_cols, val_hits, block_group, span_max)
     ectx = (kv_key, kv_val, entry_dur, entry_dur_res, valid, safe_pb,
             bg_page)
     if bucketed:
         return _bucket_trace_mask(ectx, sctx, tables, widths,
                                   bucket=plan) & valid
     return _trace_mask(plan, ectx, sctx, tables, widths) & valid
+
+
+def _span_ctx(span_cols, val_hits, block_group, span_max) -> tuple:
+    """What the span-level evaluators read of a staged segment
+    (_span_mask, _tile_leaf, _bucket_span_regs unpack it). Which
+    block's tables a span row reads is said by the TILE, not by the
+    row: staging starts every block's spans on a tile of the span axis
+    (SPAN_TILE), so `tile_block` is S / SPAN_TILE indices, and where a
+    hit mask rides so is the tiles' dictionary group."""
+    import jax.numpy as jnp
+
+    tile_block = jnp.maximum(span_cols["span_tile_block"], 0)
+    bg_tile = None
+    if val_hits is not None and block_group is not None:
+        bg_tile = block_group[tile_block]                # [S / tile]
+    return (span_cols["span_trace"] >= 0,                # s_valid
+            tile_block,
+            span_cols["span_parent"],
+            span_cols["span_dur"],
+            span_cols["span_kind"],
+            span_cols["span_kv_key"],
+            span_cols["span_kv_val"],
+            span_cols["entry_span_begin"],
+            span_cols["entry_span_count"],
+            bg_tile,
+            join_trips(span_max))
 
 
 def join_trips(span_max: int) -> int:
@@ -1259,6 +1305,80 @@ def _cmp_dev(a, b, op):
     return a != b
 
 
+def _table_match(kk, vv, row_block, row_group, tables, i):
+    """[N, M] bool: the entries with a kv slot that holds term `i`'s
+    key and a value the term matches. `kk` / `vv` are [N, M, C] key and
+    value ids, `row_block` [N] the block whose table row the N-th row
+    of entries reads (a page of entries, a tile of spans): ONE lookup
+    a row into `term_keys [B, T]` and `val_ranges [B, T, R, 2]`,
+    broadcast over the row's M entries. Where a hit mask rides
+    (`row_group` [N], the row's dictionary group or -1) membership is
+    the mask's bit instead of the ranges'. `i` is static in a plan and
+    traced in a slot program."""
+    import jax.numpy as jnp
+
+    from .packing import mask_select_grouped
+
+    term_keys, val_ranges, val_hits = tables[:3]
+    keym = kk == term_keys[row_block, i][:, None, None]
+    lo = val_ranges[row_block, i, :, 0]                  # [N,R]
+    hi = val_ranges[row_block, i, :, 1]
+    v = vv[..., None]
+    valm = ((v >= lo[:, None, None, :]) &
+            (v <= hi[:, None, None, :])).any(-1)         # [N,M,C]
+    if row_group is not None:
+        safe_g = jnp.maximum(row_group, 0)
+        safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
+        mh = (mask_select_grouped(val_hits, safe_g[:, None, None], i,
+                                  safe_v)
+              & (vv >= 0))
+        valm = jnp.where((row_group >= 0)[:, None, None], mh, valm)
+    return jnp.any(keym & valm, axis=-1)
+
+
+def _tile_leaf(sctx, tables, i):
+    """[S] bool: the spans tag term `i` matches, THE tag leaf over
+    spans of a static plan (_span_mask) and of a slot program
+    (_bucket_span_regs). The span axis folds to [S / tile, tile] (a
+    reshape: every aligned tile is one block's, stack_spans' layout
+    rule) and the tables are looked up by `span_tile_block`, S / tile
+    indices a lookup at any B and R, where a lookup a row cost a v5e
+    ~90 ms each at 8.4M rows."""
+    s_valid, tile_block, s_kk, s_vv, bg_tile = (
+        sctx[0], sctx[1], sctx[5], sctx[6], sctx[9])
+    S, Cs = s_kk.shape
+    n_tiles = tile_block.shape[0]
+    m = _table_match(s_kk.reshape(n_tiles, -1, Cs),
+                     s_vv.reshape(n_tiles, -1, Cs),
+                     tile_block, bg_tile, tables, i)
+    return m.reshape(S) & s_valid
+
+
+def leaf_lookup_rows(plan, tables, span_cols) -> int:
+    """Rows ONE query of a launch of `plan` indexes its per-block
+    tables by for its tag leaves over spans: three lookups a leaf (key,
+    range lows, range highs; every span slot of a bucket program with
+    tag terms is a leaf) and one more where a hit mask rides (the
+    tiles' dictionary groups), each of one index a TILE of the staged
+    span axis. Reckoned on the host from the static plan and the
+    tables' and the staged columns' shapes, as join_trips is: over the
+    launch's span rows it reads 1 / SPAN_TILE a lookup. It says what
+    the plan asks of the layout, not what the compiled program does:
+    the jaxpr test (tests/test_structural_tiles.py) holds the kernel to
+    it."""
+    if not plan or tables[0] is None:
+        return 0
+    if plan[0] == "bucket":
+        leaves = plan[1]
+    else:
+        leaves = sum(op == "tag" for op in _plan_ops(plan))
+    if not leaves:
+        return 0
+    hit_mask = tables[2] is not None and tables[3] is not None
+    return (3 * leaves + hit_mask) * int(
+        span_cols["span_tile_block"].shape[0])
+
+
 def _span_mask(plan, sctx, tables, widths):
     """[S] bool mask for a span-level plan node. This is a DESCRIPTOR
     DISPATCHER over `plan` (branch structure decided at trace time):
@@ -1266,32 +1386,14 @@ def _span_mask(plan, sctx, tables, widths):
     jit-purity lint's descriptor rule pins that contract."""
     import jax.numpy as jnp
 
-    from .packing import mask_select_grouped
-
     if plan is None:
         raise StructuralCompileError("span plan must not be None")
-    (s_valid, s_block, s_par, s_dur, s_kind, s_kk, s_vv,
-     _seg_b, _seg_n, bg_span, trips) = sctx
-    (term_keys, val_ranges, val_hits, _bg, dur_params, kind_params,
-     _agg) = tables
+    (s_valid, _tile_block, s_par, s_dur, s_kind, _s_kk, _s_vv,
+     _seg_b, _seg_n, _bg_tile, trips) = sctx
+    dur_params, kind_params = tables[4], tables[5]
     op = plan[0]
     if op == "tag":
-        i = plan[2]
-        k_per = term_keys[s_block, i]                    # [S]
-        keym = s_kk == k_per[:, None]                    # [S,Cs]
-        lo = val_ranges[s_block, i, :, 0]                # [S,R]
-        hi = val_ranges[s_block, i, :, 1]
-        v = s_vv[..., None]                              # [S,Cs,1]
-        valm = ((v >= lo[:, None, :]) &
-                (v <= hi[:, None, :])).any(-1)           # [S,Cs]
-        if bg_span is not None:
-            safe_g = jnp.maximum(bg_span, 0)
-            safe_v = jnp.maximum(s_vv, 0).astype(jnp.int32)
-            mh = (mask_select_grouped(val_hits, safe_g[:, None], i,
-                                      safe_v)
-                  & (s_vv >= 0))
-            valm = jnp.where((bg_span >= 0)[:, None], mh, valm)
-        return jnp.any(keym & valm, axis=-1) & s_valid
+        return _tile_leaf(sctx, tables, plan[2])
     if op == "dur":
         i = plan[2]
         return ((s_dur >= dur_params[i, 0]) &
@@ -1329,35 +1431,20 @@ def _trace_mask(plan, ectx, sctx, tables, widths):
     A descriptor dispatcher over `plan`, like _span_mask."""
     import jax.numpy as jnp
 
-    from .packing import duration_ok, mask_select_grouped, unpack_ids
+    from .packing import duration_ok, unpack_ids
 
     if plan is None:
         raise StructuralCompileError("trace plan must not be None")
     (kv_key, kv_val, entry_dur, entry_dur_res, valid, safe_pb,
      bg_page) = ectx
-    (term_keys, val_ranges, val_hits, _bg, dur_params, _kind,
-     agg_params) = tables
+    dur_params, agg_params = tables[4], tables[6]
     kw, vw, dw = widths if widths is not None else (None, None, None)
     op = plan[0]
     if op == "ttag":
         i = plan[2]
         kk = unpack_ids(kv_key, kw)
         vv = unpack_ids(kv_val, vw)
-        k_per_page = term_keys[safe_pb, i]               # [P]
-        keym = kk == k_per_page[:, None, None]           # [P,E,C]
-        lo = val_ranges[safe_pb, i, :, 0]                # [P,R]
-        hi = val_ranges[safe_pb, i, :, 1]
-        v = vv[..., None]
-        valm = ((v >= lo[:, None, None, :]) &
-                (v <= hi[:, None, None, :])).any(-1)
-        if bg_page is not None:
-            safe_g = jnp.maximum(bg_page, 0)
-            safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
-            mh = (mask_select_grouped(
-                val_hits, safe_g[:, None, None], i, safe_v)
-                & (vv >= 0))
-            valm = jnp.where((bg_page >= 0)[:, None, None], mh, valm)
-        return jnp.any(keym & valm, axis=-1) & valid
+        return _table_match(kk, vv, safe_pb, bg_page, tables, i) & valid
     if op == "tdur":
         i = plan[2]
         return duration_ok(entry_dur, entry_dur_res,
@@ -1441,12 +1528,9 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
     evaluate to false and are unreachable from any real slot."""
     import jax.numpy as jnp
 
-    from .packing import mask_select_grouped
-
-    (s_valid, s_block, s_par, s_dur, s_kind, s_kk, s_vv,
-     _seg_b, _seg_n, bg_span, trips) = sctx
-    (term_keys, val_ranges, val_hits, _bg, dur_params, kind_params,
-     _agg) = core
+    (s_valid, _tile_block, s_par, s_dur, s_kind, _s_kk, _s_vv,
+     _seg_b, _seg_n, _bg_tile, trips) = sctx
+    term_keys, dur_params, kind_params = core[0], core[4], core[5]
     S = int(s_valid.shape[0])
     false = jnp.zeros(S, dtype=bool)
     safe_par = jnp.maximum(s_par, 0)
@@ -1458,22 +1542,7 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
         rb = prev[jnp.clip(b, 0, i)]
         val = false
         if term_keys is not None:
-            k_per = term_keys[s_block, a]            # [S]
-            keym = s_kk == k_per[:, None]            # [S,Cs]
-            lo = val_ranges[s_block, a, :, 0]        # [S,R]
-            hi = val_ranges[s_block, a, :, 1]
-            v = s_vv[..., None]                      # [S,Cs,1]
-            valm = ((v >= lo[:, None, :]) &
-                    (v <= hi[:, None, :])).any(-1)   # [S,Cs]
-            if bg_span is not None:
-                safe_g = jnp.maximum(bg_span, 0)
-                safe_v = jnp.maximum(s_vv, 0).astype(jnp.int32)
-                mh = (mask_select_grouped(val_hits, safe_g[:, None], a,
-                                          safe_v)
-                      & (s_vv >= 0))
-                valm = jnp.where((bg_span >= 0)[:, None], mh, valm)
-            tag_m = jnp.any(keym & valm, axis=-1) & s_valid
-            val = jnp.where(opc == 1, tag_m, val)
+            val = jnp.where(opc == 1, _tile_leaf(sctx, core, a), val)
         if dur_params is not None:
             dur_m = ((s_dur >= dur_params[a, 0]) &
                      (s_dur <= dur_params[a, 1]) & s_valid)
@@ -1505,14 +1574,13 @@ def _bucket_trace_mask(ectx, sctx, tables, widths, *, bucket):
     is needed."""
     import jax.numpy as jnp
 
-    from .packing import duration_ok, mask_select_grouped, unpack_ids
+    from .packing import duration_ok, unpack_ids
 
     core = tables[:7]
     span_prog, trace_prog = tables[7], tables[8]
     (kv_key, kv_val, entry_dur, entry_dur_res, valid, safe_pb,
      bg_page) = ectx
-    (term_keys, val_ranges, val_hits, _bg, dur_params, _kind,
-     agg_params) = core
+    term_keys, dur_params, agg_params = core[0], core[4], core[6]
     kw, vw, dw = widths if widths is not None else (None, None, None)
     NS, NT = bucket[1], bucket[2]
     sprev = seg_b = seg_n = s_dur = None
@@ -1536,22 +1604,8 @@ def _bucket_trace_mask(ectx, sctx, tables, widths, *, bucket):
         rb = prev[jnp.clip(b, 0, i)]
         val = false
         if term_keys is not None:
-            k_per_page = term_keys[safe_pb, a]       # [P]
-            keym = kk == k_per_page[:, None, None]   # [P,E,C]
-            lo = val_ranges[safe_pb, a, :, 0]        # [P,R]
-            hi = val_ranges[safe_pb, a, :, 1]
-            v = vv[..., None]
-            valm = ((v >= lo[:, None, None, :]) &
-                    (v <= hi[:, None, None, :])).any(-1)
-            if bg_page is not None:
-                safe_g = jnp.maximum(bg_page, 0)
-                safe_v = jnp.maximum(vv, 0).astype(jnp.int32)
-                mh = (mask_select_grouped(
-                    val_hits, safe_g[:, None, None], a, safe_v)
-                    & (vv >= 0))
-                valm = jnp.where((bg_page >= 0)[:, None, None], mh,
-                                 valm)
-            ttag_m = jnp.any(keym & valm, axis=-1) & valid
+            ttag_m = _table_match(kk, vv, safe_pb, bg_page, core,
+                                  a) & valid
             val = jnp.where(opc == 1, ttag_m, val)
         if dur_params is not None:
             tdur_m = duration_ok(entry_dur, entry_dur_res,

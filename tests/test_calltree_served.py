@@ -166,15 +166,18 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
         launches = {r: obs.structural_launches.value(rel=r)
                     for r in ("none", "child", "desc")}
         trips = obs.structural_join_trips.value()
+        looked = obs.structural_leaf_lookup_rows.value()
         for request in served["flat"]["service-exhaustive"]:
             assert _ask(api, request)[0] == 200
         names = {s.name for s in collector.spans}
         assert "dispatch.execute" in names
         assert not names & {"structural.compile", "batcher.stage_spans"}
-        assert not any("rel" in s.attributes or "join_trips" in s.attributes
+        assert not any({"rel", "join_trips", "span_tile",
+                        "leaf_lookup_rows"} & set(s.attributes)
                        for s in collector.spans)
         assert launches == {r: obs.structural_launches.value(rel=r)
                             for r in launches}
+        assert obs.structural_leaf_lookup_rows.value() == looked
         # evicted, the group is staged again by the structural search
         with batcher._lock:
             for key in list(batcher._cache):
@@ -210,6 +213,13 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     # one join by ancestor, its trips from the longest trace (512)
     assert execute["rel"] == "desc" and execute["join_trips"] == 9
     assert execute["span_rows"] == staged["span_rows"]
+    # its two tag leaves look their tables up by the tile: key, lows
+    # and highs a leaf, one index a tile of the span axis
+    assert execute["span_tile"] == structural.SPAN_TILE
+    assert execute["leaf_lookup_rows"] * structural.SPAN_TILE \
+        == 2 * 3 * execute["span_rows"]
+    assert obs.structural_leaf_lookup_rows.value() \
+        == looked + execute["leaf_lookup_rows"]
     assert obs.structural_launches.value(rel="desc") \
         == launches["desc"] + 1
     assert obs.structural_join_trips.value() == trips + 9
@@ -506,6 +516,8 @@ def test_desc_answers_hold_at_the_trip_counts_boundary(case, trips):
                            structural=ir.parse(json.dumps(DESC[0])))
         batch, eng = first.batch, first.engine
         rows = int(batch.span_device["span_parent"].shape[0])
+        assert batch.span_device["span_tile_block"].shape \
+            == (rows // structural.SPAN_TILE,)
         if case == "longest-9-of-2^15-rows":
             assert rows == 1 << 15 and batch.span_max == 16
         else:
