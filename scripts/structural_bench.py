@@ -3,7 +3,7 @@ the table `chipbench/configs/tempo-search-calltree16.json` was sized
 from.
 
     chiprun --timeout 1500 -- python3 scripts/structural_bench.py \
-        --blocks 8,16,32,64 --calls 3
+        --blocks 8,64 --calls 3
 
 For each size it builds that many of the cell's blocks
 (`chipbench/generators/otel_calltree.py`, the configuration's corpus
@@ -11,11 +11,14 @@ parameters, 65,536 traces a block), stages them as ONE group on a
 `MultiBlockEngine` (no server, no batcher: the launch alone), compiles
 each of the traffic mix's five templates against it and launches it
 `--calls` times, fenced. One JSON line a (size, plan): the median
-launch, the first (compile + launch), the span axis, the trips of its
-joins by ancestor; and one `stage` line a size: the span axis, its tile
-(`structural.SPAN_TILE`) and the pad rows the tile's alignment and the
-power of two cost. Times are the host's clock around a fenced launch; on
-anything but a TPU the lines say so in `platform` and mean nothing.
+launch, the first (compile + launch), the span axis, the running-max
+passes of its joins by ancestor; and one `stage` line a size: the host's
+stacking of the group (`stack_s`) and, of it, the laying out of every
+trace's spans depth first (`order_s`, with the rows it moved), the span
+axis, its tile (`structural.SPAN_TILE`) and the pad rows the tile's
+alignment and the power of two cost. Times are the host's clock around a
+fenced launch; on anything but a TPU the lines say so in `platform` and
+mean nothing.
 
 `--span-tiles 128,256,512,1024` stages and times every size once a
 tile (it sets the module's constant for the run: how the shipped one
@@ -37,7 +40,7 @@ sys.path.insert(0, ROOT)
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--blocks", default="8,16,32,64")
+    ap.add_argument("--blocks", default="8,64")
     ap.add_argument("--entries", type=int, default=65536)
     ap.add_argument("--calls", type=int, default=3)
     ap.add_argument("--seed", type=int, default=2147483777)
@@ -53,6 +56,7 @@ def main() -> int:
     from chipbench.generators import otel_calltree as oc
     from chipbench.run import build_requests
     from tempo_tpu import tempopb
+    from tempo_tpu.observability import metrics as obs
     from tempo_tpu.search import ir, structural
     from tempo_tpu.search.multiblock import MultiBlockEngine, compile_multi
     from tempo_tpu.search.pipeline import EXHAUSTIVE_SEARCH_TAG
@@ -84,21 +88,25 @@ def main() -> int:
         """Stage `blocks` as one group, then compile and launch each
         request's plan against it."""
         eng = MultiBlockEngine()
+        moved = obs.structural_span_reorder_rows.value(moved="yes")
+        order_s = obs.structural_span_order_seconds.value()
         t = time.perf_counter()
         host = eng.stage_host(blocks)
         stack_s = time.perf_counter() - t
+        moved = obs.structural_span_reorder_rows.value(moved="yes") - moved
+        order_s = obs.structural_span_order_seconds.value() - order_s
         t = time.perf_counter()
         batch = eng.place(host)
         live = sum(b.n_spans for b in blocks)
         rows = int(batch.span_device["span_trace"].shape[0])
         tile = rows // int(batch.span_device["span_tile_block"].shape[0])
         say({"blocks": n_blocks, "label": "stage", "stack_s": stack_s,
+             "order_s": order_s, "moved_rows": int(moved),
              "put_s": time.perf_counter() - t, "span_rows": rows,
              "live_rows": live, "span_tile": tile,
              # pad rows: up to each block's next tile, then the axis'
              # power of two
              "align_pad_rows": sum(-b.n_spans % tile for b in blocks),
-             "span_max": batch.span_max,
              "span_bytes": structural.span_device_bytes(batch.span_device),
              "device_bytes": batch.device_nbytes})
         req = tempopb.SearchRequest()
@@ -111,8 +119,7 @@ def main() -> int:
                 expr, blocks, cache_on=batch,
                 staged_dicts=batch.staged_dicts,
                 entry_kv_slots=blocks[0].geometry.kv_per_entry)
-            rel, trips = structural.plan_joins(mq.structural.plan,
-                                               batch.span_max)
+            rel, scans = structural.plan_joins(mq.structural.plan)
             t = time.perf_counter()
             res = eng.scan(batch, mq)
             first = time.perf_counter() - t
@@ -122,7 +129,7 @@ def main() -> int:
                 res = eng.scan(batch, mq)
                 ms.append((time.perf_counter() - t) * 1e3)
             say({"blocks": n_blocks, "label": r["name"], "rel": rel,
-                 "span_rows": rows, "span_tile": tile, "trips": trips,
+                 "span_rows": rows, "span_tile": tile, "join_scans": scans,
                  "launch_ms": statistics.median(ms),
                  "min_ms": min(ms), "max_ms": max(ms),
                  "first_s": first, "matches": int(res[0]),

@@ -74,13 +74,10 @@ _NP_HOST_FNS = {"asarray", "array", "frombuffer", "copy"}
 # descriptor and `agg`/`n_keys` the ?agg= dense key-space sizes
 # (search/analytics.py): all three select the aggregate-reduction arm
 # and size its key range at trace time, so they belong to the static
-# jit key for exactly the `widths`/`plan` reason. `span_max` is the
-# longest trace's span count of a structural launch's group
-# (structural.join_trips): it sets the trip count of the `desc` joins'
-# loop at trace time, a static like `shard_tail`.
+# jit key for exactly the `widths`/`plan` reason.
 _DESCRIPTOR_PARAMS = {"w", "dw", "widths", "plan", "span_sharded",
                       "bucket", "shard_tail", "tier", "buckets", "agg",
-                      "n_keys", "span_max"}
+                      "n_keys"}
 
 
 def _branches_on_param(helper: ast.AST, param: str) -> bool:

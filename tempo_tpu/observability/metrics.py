@@ -564,12 +564,20 @@ structural_launches = Counter(
     "scan launches that evaluated a structural plan over staged span "
     "columns, by how the plan joins spans: rel=none (span-scope "
     "predicates and aggregates only), child (one gather through the "
-    "parent column), desc (pointer doubling over the span axis)")
-structural_join_trips = Counter(
-    "tempo_search_structural_join_trips_total",
-    "pointer-doubling trips the `desc` joins of structural launches "
-    "ran (two gathers over the span axis a trip): log2 of the group's "
-    "longest trace a join, not of the padded span axis")
+    "parent column), desc (one running max over the span axis, laid "
+    "out depth first inside every trace)")
+structural_span_reorder_rows = Counter(
+    "tempo_search_structural_span_reorder_rows_total",
+    "span rows of the blocks staging stacked, by whether laying a "
+    "trace's spans out depth first (every span directly before its "
+    "subtree: what the `desc` join's running max reads) had to move "
+    "the row: moved=yes, or moved=no for a row stored where the layout "
+    "wants it. How far a tenant's stored order is from the layout")
+structural_span_order_seconds = Counter(
+    "tempo_search_structural_span_order_seconds_total",
+    "host seconds staging spent laying blocks' spans out depth first, "
+    "the sort and the permuted copies of the span columns: paid once "
+    "a block when a group's host columns are stacked")
 structural_leaf_lookup_rows = Counter(
     "tempo_search_structural_leaf_lookup_rows_total",
     "rows the tag leaves of structural launches indexed their "

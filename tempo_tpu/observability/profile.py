@@ -476,7 +476,7 @@ class DispatchProfiler:
                 span.set_attribute("jit_cache", rec.jit)
                 for key in ("topk", "shards", "pages_per_shard", "params",
                             "membership", "compare", "blocks",
-                            "blocks_bucket", "rel", "join_trips",
+                            "blocks_bucket", "rel", "join_scans",
                             "span_rows", "span_tile",
                             "leaf_lookup_rows"):
                     if key in rec.attrs:

@@ -145,8 +145,7 @@ def host_scan(host, mq, top_k: int):
             # the host tier stages the SAME packed layout (stack_host
             # packs before the tiers fork), so the fallback kernel
             # unpacks with the batch's own width descriptor
-            widths=getattr(host, "widths", None), plan=plan, agg=agg,
-            span_max=host.span_max if span_dev is not None else None)
+            widths=getattr(host, "widths", None), plan=plan, agg=agg)
         res = fetch_scan_out(out, agg or 0)
     profile.observe_stage("execute", "host_fallback",
                           time.perf_counter() - t0)
@@ -2116,7 +2115,7 @@ class BlockBatcher:
                         "batcher.stage_spans", *b.span_put_ns,
                         parent=span.context, span_rows=rows,
                         pad_rows=rows - sum(x.n_spans for x in b.blocks),
-                        bytes=self._span_bytes(b), span_max=b.span_max)
+                        bytes=self._span_bytes(b))
                 obs.group_picks.inc(pick=pick)
                 if qs is not None:
                     qs.add_cache(_event)

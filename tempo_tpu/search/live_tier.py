@@ -79,11 +79,10 @@ def _tier_valid(entry_valid, n_pages, tier):
 
 
 @functools.partial(jax.jit, static_argnames=("n_terms", "top_k", "widths",
-                                             "plan", "tier", "span_max"))
+                                             "plan", "tier"))
 def hot_scan_kernel(cat, n_pages, term_keys, val_ranges, dur_lo, dur_hi,
                     win_start, win_end, span_cols=None, s_tables=None,
-                    *, n_terms, top_k, widths=None, plan=None, tier=None,
-                    span_max=None):
+                    *, n_terms, top_k, widths=None, plan=None, tier=None):
     """The hot-tier dispatch: batch_scan_kernel over a capacity-padded
     rolling stage, a one-block batch (`cat`: stack_host's arrays).
     Delegation keeps it byte-identical to the backend-block scan — same
@@ -96,7 +95,7 @@ def hot_scan_kernel(cat, n_pages, term_keys, val_ranges, dur_lo, dur_hi,
         cat["page_block"], term_keys, val_ranges, None, dur_lo, dur_hi,
         win_start, win_end, None, None, cat.get("entry_dur_res"),
         span_cols, s_tables, n_terms=n_terms, top_k=top_k, widths=widths,
-        plan=plan, span_max=span_max)
+        plan=plan)
 
 
 class _HotStage:
@@ -166,8 +165,7 @@ def scan_search_data(entries: list[SearchData], req, results,
             jnp.uint32(min(mq.win_end, 0xFFFFFFFF)),
             span_dev, s_tables,
             n_terms=mq.n_terms, top_k=resolve_top_k(DEFAULT_TOP_K, mq.limit),
-            widths=host.widths, plan=plan, tier=stage.tier,
-            span_max=host.span_max if span_dev is not None else None)
+            widths=host.widths, plan=plan, tier=stage.tier)
         _, inspected, scores, idx = fetch_scan_out(out)
     results.metrics.inspected_traces += inspected
     for m in MultiBlockEngine.results(host, mq, scores, idx):

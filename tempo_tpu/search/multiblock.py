@@ -138,10 +138,6 @@ class BlockBatch:
     # structural mask inside shard_map. Static at every consuming call
     # site — part of the jit shape key like `widths`
     span_sharded: bool = False
-    # the longest trace's span count as a power of two
-    # (StructuralGate.span_max; 0 = no span columns): the static that
-    # bounds a structural launch's `desc` joins, in either span layout
-    span_max: int = 0
     # (start, end) on the span clock of the span columns' own put,
     # fenced (place_batch): what `batcher.stage_spans` is written from
     span_put_ns: tuple = ()
@@ -212,7 +208,6 @@ class HostBatch:
     # structural span columns, host tier (see BlockBatch.span_device):
     # the host-fallback scan runs the same structural kernel over these
     span_cat: dict | None = None
-    span_max: int = 0       # see BlockBatch.span_max
     # bytes of `blocks`' columns that are views of `cat`: counted once
     aliased_nbytes: int = 0
     # the prepare memo of the group's last staged batch, kept across an
@@ -448,7 +443,6 @@ def stack_host(blocks: list[ColumnarPages],
         # gate off is one attribute read and the identical layout
         span_cat = STRUCTURAL.stack_spans(blocks, E,
                                           int(page_block.shape[0]))
-    span_max = 0 if span_cat is None else STRUCTURAL.span_max(span_cat)
     entries_padded = int(page_block.shape[0]) * E
     packed_dicts = _pack_batch_dicts(blocks, probe_min_vals,
                                      n_shards=n_shards)
@@ -459,7 +453,7 @@ def stack_host(blocks: list[ColumnarPages],
         blocks, aliased = _blocks_over(cat, blocks, page_offset)
     return HostBatch(cat=cat, page_block=page_block, blocks=blocks,
                      page_offset=page_offset, packed_dicts=packed_dicts,
-                     widths=widths, span_cat=span_cat, span_max=span_max,
+                     widths=widths, span_cat=span_cat,
                      aliased_nbytes=aliased,
                      cat_logical_nbytes=(
                          packing.logical_nbytes(entries_padded, C0,
@@ -553,7 +547,7 @@ def place_batch(host: HostBatch, sharding=None, mesh=None) -> BlockBatch:
                       staged_dicts=staged, widths=host.widths,
                       logical_device_nbytes=host.cat_logical_nbytes,
                       span_device=span_dev, span_sharded=span_sharded,
-                      span_max=host.span_max, span_put_ns=span_put_ns)
+                      span_put_ns=span_put_ns)
 
 
 def stack_blocks(blocks: list[ColumnarPages], pad_to: int | None = None,
@@ -1096,8 +1090,7 @@ def _scan_pages(kv_key, kv_val, entry_start, entry_end, entry_dur,
                 entry_valid, page_block, term_keys, val_ranges, term_active,
                 dur_lo, dur_hi, win_start, win_end, val_hits, block_group,
                 entry_dur_res, struct_mask, span_cols, s_tables, entry_agg,
-                *, n_terms: int, top_k: int, widths, plan, agg,
-                span_max=None):
+                *, n_terms: int, top_k: int, widths, plan, agg):
     """The scan of the pages one device holds: per query the verdict
     mask, its count, the top-k of its matches and, where `agg` (static,
     the dense key-space size K) is set, the ?agg= counts the same mask
@@ -1130,8 +1123,7 @@ def _scan_pages(kv_key, kv_val, entry_start, entry_end, entry_dur,
             # the same for every query
             mask = mask & structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, span_cols, st_t, plan=plan, widths=widths,
-                span_max=span_max)
+                entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
         count = jnp.sum(mask, dtype=jnp.int32)
         # traced HERE though it reads no query table: between count and
         # top-k the TPU compiler fuses this reduce with the mask's first
@@ -1201,7 +1193,7 @@ def _merge_shards(count, inspected, scores, idx, agg_counts, *,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg", "packed", "span_max"))
+                                    "agg", "packed"))
 def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       entry_valid, page_block, term_keys, val_ranges,
                       term_active, dur_lo, dur_hi, win_start, win_end,
@@ -1209,7 +1201,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
                       span_cols=None, s_tables=None, entry_agg=None,
                       *, mesh=None, n_terms: int, top_k: int, widths=None,
                       plan=None, span_sharded=False, shard_tail: int = 0,
-                      agg=None, packed=None, span_max=None):
+                      agg=None, packed=None):
     """THE scan program: every block batch, on one device or a mesh,
     for one query or a fused group. Returns ONE int32 array, count,
     inspected, scores [k], flat idx [k] and the ?agg= counts [K] where
@@ -1242,7 +1234,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
       - segment-aligned sharded span columns
         (search_structural_shard_spans): each trace's span run lives
         whole on its page's shard in shard-local coordinates, so the
-        `child` gather and `desc` pointer-doubling evaluate INSIDE the
+        `child` gather and `desc` running max evaluate INSIDE the
         shard over the local chunk — parent joins scale with the mesh,
         per-shard span HBM ~1/P, and only the per-trace verdict feeds
         the collectives.
@@ -1253,18 +1245,12 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
     here, in front of the scan and outside the shard_map: one operand
     to put on the device, or to replicate over the mesh, where there
     were seven. None: the tables come one by one, as a solo launch's
-    resident parameters do.
-
-    `span_max` (STATIC, a structural launch's: BlockBatch.span_max, the
-    longest trace's span count as a power of two) bounds the trips of
-    the plan's `desc` joins (structural.join_trips) in either span
-    layout; None on a launch with no structural plan."""
+    resident parameters do."""
     if packed is not None:
         (term_keys, val_ranges, term_active, dur_lo, dur_hi, win_start,
          win_end) = unpack_queries(term_keys, packed)
     scan = functools.partial(_scan_pages, n_terms=n_terms, top_k=top_k,
-                             widths=widths, plan=plan, agg=agg,
-                             span_max=span_max)
+                             widths=widths, plan=plan, agg=agg)
     if mesh is None:
         return pack_out(*scan(
             kv_key, kv_val, entry_start, entry_end, entry_dur,
@@ -1283,8 +1269,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
         def verdicts(st_t):
             return structural_entry_mask(
                 kv_key, kv_val, entry_dur, entry_valid, page_block,
-                entry_dur_res, span_cols, st_t, plan=plan, widths=widths,
-                span_max=span_max)
+                entry_dur_res, span_cols, st_t, plan=plan, widths=widths)
 
         struct_mask = (jax.vmap(verdicts) if fused else verdicts)(s_tables)
         span_cols = s_tables = None
@@ -1327,7 +1312,7 @@ def batch_scan_kernel(kv_key, kv_val, entry_start, entry_end, entry_dur,
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "n_terms", "top_k", "widths",
                                     "plan", "span_sharded", "shard_tail",
-                                    "agg", "packed", "span_max"))
+                                    "agg", "packed"))
 def mask_scan_kernel(*tables, **statics):
     """batch_scan_kernel for a launch that takes a hit mask (`val_hits`):
     the same body under a program name of its own, so that a device
@@ -1578,9 +1563,6 @@ class MultiBlockEngine:
                 rec.add_bytes(h2d=h2d)
                 widths = batch.widths
                 span_sharded = bool(st is not None and batch.span_sharded)
-                # the bound of the plan's ancestor joins: the longest
-                # trace of the group, not the padded span axis
-                span_max = batch.span_max if span_cols is not None else None
                 shard_tail = self._shard_tail(batch, d)
                 jit_key = (
                     attrs["kernel"], self.mesh is not None,
@@ -1593,8 +1575,7 @@ class MultiBlockEngine:
                     shard_tail, agg,
                     None if span_cols is None else
                     tuple(sorted((n, tuple(a.shape))
-                                 for n, a in span_cols.items())),
-                    span_max)
+                                 for n, a in span_cols.items())))
                 miss = rec.compile_check(jit_key)
                 if miss:
                     _SCAN_JIT_KEYS.add(jit_key)
@@ -1627,15 +1608,14 @@ class MultiBlockEngine:
 
                     rows = int(span_cols["span_parent"].shape[0])
                     tiles = int(span_cols["span_tile_block"].shape[0])
-                    rel, trips = plan_joins(plan, span_max)
+                    rel, scans = plan_joins(plan)
                     # what the tag leaves index their tables by: a tile
                     # of the span axis a lookup, once a member
                     looked = members * leaf_lookup_rows(plan, s_tables,
                                                         span_cols)
                     obs.structural_launches.inc(rel=rel)
-                    obs.structural_join_trips.inc(trips)
                     obs.structural_leaf_lookup_rows.inc(looked)
-                    rec.set(rel=rel, join_trips=trips, span_rows=rows,
+                    rec.set(rel=rel, join_scans=scans, span_rows=rows,
                             span_tile=rows // tiles,
                             leaf_lookup_rows=looked)
                 if q.n_terms:
@@ -1656,8 +1636,7 @@ class MultiBlockEngine:
                         span_cols, s_tables, entry_agg, mesh=self.mesh,
                         n_terms=q.n_terms, top_k=top_k, widths=widths,
                         plan=plan, span_sharded=span_sharded,
-                        shard_tail=shard_tail, agg=agg, packed=packed,
-                        span_max=span_max)
+                        shard_tail=shard_tail, agg=agg, packed=packed)
 
                 if self.mesh is None:
                     with rec.stage(stage):

@@ -620,8 +620,8 @@ _listener_registered = False
 
 def structural_node_seconds(node_bytes: dict) -> dict:
     """Structural plan nodes registered with the cost model: each node's
-    byte estimate (structural.plan_node_bytes — leaf scans, pointer
-    joins with their doubling log-factor, segment reductions) through
+    byte estimate (structural.plan_node_bytes — leaf scans, the joins,
+    segment reductions) through
     the live per-byte scan rate, the SAME EWMA the fused scan kernels
     calibrate via the dispatch-profiler feed. Consumed by the explain
     tree's est_ms column and the per-node device-seconds apportionment

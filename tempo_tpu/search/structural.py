@@ -16,11 +16,12 @@ already lives (the Taurus near-data argument, arxiv 2506.20010):
     test is the same range-compare / ``mask_select_grouped`` lookup —
     bit-packed masks and packed-width entry columns (``unpack_ids`` /
     ``duration_ok``) included;
-  - **structural relations** lower to vectorized parent-pointer joins
-    over the per-trace span segments: ``child`` is one gather through
-    the parent-pointer column; ``desc`` is pointer-doubling (a log-many
-    static unroll over the padded span axis — jit cache keys stay
-    shape-only);
+  - **structural relations** lower to vectorized joins over the
+    per-trace span segments: ``child`` is one gather through the
+    parent-pointer column; ``desc`` is one running max over the span
+    axis, which staging lays out depth first inside every trace with
+    each span's last descendant beside it (``stack_spans``,
+    ``_descends`` — jit cache keys stay shape-only);
   - **aggregates** lower to segment reductions (one cumsum + two
     gathers per count, via the per-entry span-range columns) whose
     [P, E] verdicts AND into the legacy entry mask feeding the existing
@@ -116,21 +117,33 @@ class StructuralGate:
     # ---- staging (called behind `if STRUCTURAL.enabled` guards — the
     # noop-contract GuardedCall rule pins the call-site shape) ----
 
-    def stack_spans(self, blocks: list, E: int, pad_pages: int) -> dict | None:
+    def stack_spans(self, blocks: list, E: int, pad_pages: int) -> tuple:
         """Stack the blocks' span segments for a batched staging:
         flat span arrays concatenate with per-block index remaps (trace
-        index += page offset * E, parent/begin += span base). THE
-        LAYOUT RULE: every block's spans start on a multiple of
-        SPAN_TILE and the axis pads to a power of two of at least one
-        tile (shape-only jit keys), so each aligned tile of SPAN_TILE
-        rows holds live rows of ONE block; ``span_tile_block`` names
-        it, a row a tile, and the tag leaves look their block's tables
-        up by the tile (_tile_leaf). The rows between a block's end and
-        the next tile are pad rows like those at the axis' end
-        (``span_trace`` -1). Returns the host numpy dict, or None when
-        no block carries spans."""
+        index += page offset * E, parent/last/begin += span base). THE
+        LAYOUT RULE has two halves. By the tile: every block's spans
+        start on a multiple of SPAN_TILE and the axis pads to a power
+        of two of at least one tile (shape-only jit keys), so each
+        aligned tile of SPAN_TILE rows holds live rows of ONE block;
+        ``span_tile_block`` names it, a row a tile, and the tag leaves
+        look their block's tables up by the tile (_tile_leaf). The rows
+        between a block's end and the next tile are pad rows like those
+        at the axis' end (``span_trace`` -1). Inside a trace: its run
+        of rows stays where the block has it and is laid out depth
+        first, every span directly before its subtree (span_preorder:
+        whatever order the block stores), with ``span_last`` the row of
+        each span's last descendant, so a span's subtree is the run
+        ``[row, span_last[row]]`` and `desc` is one running max
+        (_descends). Returns the host numpy dict, or None when no
+        block carries spans. What the depth-first layout cost the host
+        is booked here, where it is paid: the rows that moved and the
+        seconds of the sort with the permuted copies."""
+        import time
+
         if not any(getattr(b, "has_spans", False) for b in blocks):
             return None
+        from tempo_tpu.observability import metrics as obs
+
         tile = SPAN_TILE
         total = sum(_tile_up(b.n_spans, tile) for b in blocks
                     if getattr(b, "has_spans", False))
@@ -145,17 +158,29 @@ class StructuralGate:
             P = b.n_pages
             if getattr(b, "has_spans", False):
                 n = b.n_spans
+                t0 = time.perf_counter()
+                perm, par, last, moved = span_preorder(
+                    b.span_parent,
+                    b.entry_span_begin.reshape(-1)[b.span_trace])
+                moved_cols = {
+                    name: getattr(b, name)[perm] if moved
+                    else getattr(b, name)
+                    for name in ("span_dur", "span_kind", "span_kv_key",
+                                 "span_kv_val")}
+                obs.structural_span_order_seconds.inc(
+                    time.perf_counter() - t0)
+                obs.structural_span_reorder_rows.inc(moved, moved="yes")
+                obs.structural_span_reorder_rows.inc(n - moved, moved="no")
+                # a trace's run keeps its place, so its rows' trace does
                 cols["span_trace"][base:base + n] = \
                     b.span_trace + page_off * E
-                par = b.span_parent.astype(np.int32, copy=True)
-                par[par >= 0] += base
-                cols["span_parent"][base:base + n] = par
-                cols["span_dur"][base:base + n] = b.span_dur
-                cols["span_kind"][base:base + n] = b.span_kind
-                cols["span_kv_key"][base:base + n, :b.span_kv_key.shape[1]] \
-                    = b.span_kv_key
-                cols["span_kv_val"][base:base + n, :b.span_kv_val.shape[1]] \
-                    = b.span_kv_val
+                cols["span_parent"][base:base + n] = \
+                    np.where(par >= 0, par + base, -1)
+                cols["span_last"][base:base + n] = last + base
+                for name, col in moved_cols.items():
+                    # [n] or, the kv columns, [n, the block's Cs]
+                    cols[name][(slice(base, base + n),
+                                *map(slice, col.shape[1:]))] = col
                 cnt = b.entry_span_count
                 cols["entry_span_begin"][page_off:page_off + P] = \
                     np.where(cnt > 0, b.entry_span_begin + base, 0)
@@ -165,19 +190,6 @@ class StructuralGate:
                 base = end
             page_off += P
         return cols
-
-    @staticmethod
-    def span_max(cols: dict) -> int:
-        """The longest trace's span count in a stacked segment
-        (stack_spans' product), rounded up to a power of two: the static
-        a launch carries for its `desc` joins (join_trips). A span has
-        fewer proper ancestors than its trace has spans, so that many
-        rows bound every ancestor walk whatever the padded axis holds;
-        ingest caps a trace at `max_spans` (512 shipped), so a tenant
-        shows at most ten values and one where any trace reaches the
-        cap. The sharded layout keeps a trace whole, so it reads the
-        same static."""
-        return _pow2(max(1, int(cols["entry_span_count"].max())))
 
     def stack_group_key(self, batch, st) -> tuple | None:
         """THE plan-shape stacking gate: the coalescer's pending-group
@@ -248,10 +260,12 @@ class StructuralGate:
         rest) names each tile's block. Coordinates REBASE to the
         shard-local frame shard_map hands each device: ``span_trace``
         to the local entry flat index, ``span_parent`` and
-        ``entry_span_begin`` to chunk-local span positions — the
-        ``child`` gather and ``desc`` pointer-doubling then read only
-        local rows, and per-shard span HBM is ~1/P of the replicated
-        layout."""
+        ``entry_span_begin`` and ``span_last`` to chunk-local span
+        positions — the ``child`` gather and ``desc``'s running max
+        then read only local rows, and per-shard span HBM is ~1/P of
+        the replicated layout. A trace's rows move together and keep
+        their order, so the depth-first layout inside a trace
+        (stack_spans) survives the reshard."""
         if not self.shard_spans:
             return None
         if n_shards <= 1 or pad_pages % n_shards:
@@ -301,6 +315,8 @@ class StructuralGate:
             out["span_parent"][dst] = np.where(
                 (par >= 0) & (shard_of[safe] == s),
                 local_of[safe], -1).astype(np.int32)
+            # a span's last descendant is a row of its own trace
+            out["span_last"][dst] = local_of[span_cat["span_last"][idx]]
             for name in ("span_dur", "span_kind", "span_kv_key",
                          "span_kv_val"):
                 out[name][dst] = span_cat[name][idx]
@@ -409,12 +425,139 @@ def _empty_span_cols(S: int, Cs: int, tile: int) -> dict:
     return {
         "span_trace": np.full(S, -1, dtype=np.int32),
         "span_parent": np.full(S, -1, dtype=np.int32),
+        "span_last": np.full(S, -1, dtype=np.int32),
         "span_tile_block": np.zeros(S // tile, dtype=np.int32),
         "span_dur": np.zeros(S, dtype=np.uint32),
         "span_kind": np.zeros(S, dtype=np.int8),
         "span_kv_key": np.full((S, Cs), -1, dtype=np.int32),
         "span_kv_val": np.full((S, Cs), -1, dtype=np.int32),
     }
+
+
+def _ancestor_counts(parent: np.ndarray) -> tuple:
+    """(depth, stuck, jump) of a parent column (row indices, -1 none):
+    `depth` each row's count of proper ancestors, by pointer doubling
+    over the rows that still climb (log2 of the deepest chain trips,
+    each over fewer rows than the last); `stuck` the rows whose chain
+    never ends, those on a loop of parents and those hanging off one,
+    and `jump[stuck]` rows ON the loops, every one of them."""
+    n = int(parent.shape[0])
+    depth = (parent >= 0).astype(np.int32)
+    jump = parent.astype(np.int32, copy=True)
+    live = np.flatnonzero(jump >= 0)
+    # 2^trips > n bounds every chain that ends
+    for _ in range(n.bit_length()):
+        if not live.size:
+            break
+        j = jump[live]
+        step, nxt = depth[j], jump[j]
+        depth[live] += step
+        jump[live] = nxt
+        live = live[nxt >= 0]
+    return depth, live, jump
+
+
+def cut_parent_loops(parent: np.ndarray) -> tuple:
+    """THE rule for a malformed parent column, applied wherever the
+    column is READ (staging before it sorts, eval_host before it
+    walks), never to the stored bytes: a loop of parents of any length
+    is cut at its first span in stored order, which then has no parent
+    (collect_span_rows' rule for a span that names itself, extended);
+    the spans hanging off the loop keep theirs. Returns (the column,
+    the same array where it holds no loop; each row's count of proper
+    ancestors)."""
+    depth, stuck, jump = _ancestor_counts(parent)
+    if not stuck.size:
+        return parent, depth
+    parent = parent.copy()
+    seen: set = set()
+    # ascending, so the first row met of a loop is its first stored
+    for r in np.unique(jump[stuck]).tolist():
+        if r in seen:
+            continue
+        p, parent[r] = int(parent[r]), -1
+        while p != r:
+            seen.add(p)
+            p = int(parent[p])
+    return parent, _ancestor_counts(parent)[0]
+
+
+def _has_parent_loop(parent: list) -> bool:
+    """Whether a walk up `parent` (one trace's, -1 none) ever comes
+    back to a span it passed: every span is visited once."""
+    walk = [0] * len(parent)    # the walk that first met the span
+    for i in range(len(parent)):
+        p = i
+        while p >= 0 and not walk[p]:
+            walk[p] = i + 1
+            p = parent[p]
+        if p >= 0 and walk[p] == i + 1:
+            return True
+    return False
+
+
+def span_preorder(parent: np.ndarray, run_begin: np.ndarray) -> tuple:
+    """The depth-first layout of one block's spans: `parent` [n] the
+    block's parent column (rows of the block, -1 none), `run_begin` [n]
+    the first row of each span's trace (a trace's rows are one run).
+    Returns (perm, parent, last, moved): `perm[new row]` the stored row
+    that moves there, `parent` and `last` the re-pointed parent and the
+    last descendant of each NEW row (its own row for a leaf), `moved`
+    how many rows are not where they were stored. Every trace's run
+    stays where it is; inside it every span lies directly before its
+    subtree, the parentless spans (roots, and spans whose parent the
+    ingest cap cut) in stored order, siblings in stored order. Loops
+    are cut first (cut_parent_loops) and a parent in another trace is
+    no parent. Vectorised by level, not walked by trace: depths, then
+    subtree sizes bottom-up, then positions top-down as the parent's
+    position + 1 + the sizes of the earlier siblings."""
+    n = int(parent.shape[0])
+    rows = np.arange(n, dtype=np.int32)
+    if not n:
+        return rows, rows, rows, 0
+    safe = np.clip(parent, 0, n - 1)
+    parent = np.where((parent >= 0) & (run_begin[safe] == run_begin),
+                      safe, -1).astype(np.int32)
+    parent, depth = cut_parent_loops(parent)
+    # rows by (depth, parent, stored row): siblings side by side in
+    # stored order, level after level
+    by_parent = np.argsort(parent, kind="stable")
+    deepest = int(depth.max())
+    d = depth[by_parent]
+    order = by_parent[np.argsort(
+        d.astype(np.uint16) if deepest < 1 << 16 else d, kind="stable")]
+    lo = np.concatenate([[0], np.cumsum(np.bincount(depth))])
+    par_o = parent[order]
+    first = np.ones(n, dtype=bool)      # of its siblings
+    first[1:] = par_o[1:] != par_o[:-1]
+    size = np.ones(n, dtype=np.int32)
+    for lv in range(deepest, 0, -1):
+        a, b = lo[lv], lo[lv + 1]
+        starts = np.flatnonzero(first[a:b])
+        size[par_o[a:b][starts]] += np.add.reduceat(size[order[a:b]],
+                                                    starts)
+    pos = np.empty(n, dtype=np.int32)
+    for lv in range(deepest + 1):
+        a, b = lo[lv], lo[lv + 1]
+        level = order[a:b]
+        if lv:
+            head, start = first[a:b], pos[par_o[a:b]] + 1
+        else:
+            # the parentless spans of a trace, from its run's first row
+            start = run_begin[level]
+            head = np.ones(b - a, dtype=bool)
+            head[1:] = start[1:] != start[:-1]
+        before = np.cumsum(size[level]) - size[level]
+        pos[level] = start + before - np.maximum.accumulate(
+            np.where(head, before, 0))
+    perm = np.empty(n, dtype=np.int32)
+    perm[pos] = rows
+    new_parent = np.full(n, -1, dtype=np.int32)
+    has = parent >= 0
+    new_parent[pos[has]] = pos[parent[has]]
+    last = np.empty(n, dtype=np.int32)
+    last[pos] = pos + size - 1
+    return perm, new_parent, last, int((pos != rows).sum())
 
 
 # ---------------------------------------------------------------------------
@@ -695,7 +838,7 @@ def canonical_bucket(plan: tuple, max_nodes: int) -> tuple | None:
     the flattened span/trace programs (NT includes the root-copy
     slot), has_rel marks the child/desc machinery (relation plans
     bucket separately from relation-free ones — fusing them would make
-    every member pay the pointer-doubling arms). Returns None when the
+    every member pay the join arms). Returns None when the
     flattened slot count exceeds ``max_nodes``: the plan "still goes
     solo", i.e. falls back to exact-plan grouping."""
     try:
@@ -998,7 +1141,7 @@ class _LeafCollector:
             return ("child", nid, self.lower_span(e.parent),
                     self.lower_span(e.child))
         if isinstance(e, ir.DescOf):
-            nid = self._nid("desc", "pointer-doubling ancestor join")
+            nid = self._nid("desc", "running-max ancestor join")
             return ("desc", nid, self.lower_span(e.anc),
                     self.lower_span(e.span))
         raise StructuralCompileError(
@@ -1162,7 +1305,7 @@ def _probe_leaf_terms(block, terms: list, staged_dict, host_only: bool):
 
 def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
                           page_block, entry_dur_res, span_cols, tables,
-                          *, plan, widths, span_max):
+                          *, plan, widths):
     """[P, E] bool trace verdicts for a compiled structural plan.
     Recursion over the STATIC plan runs at trace time and emits one
     fused computation — compiled, never interpreted per row. Span-level
@@ -1171,9 +1314,7 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
     columns; trace-level leaves evaluate on the entry columns with the
     same unpack/membership code paths the legacy kernel uses. ``plan``
     (like the packed-residency ``widths``) is a static descriptor at
-    every call site — the jit-purity lint's descriptor rule pins it.
-    ``span_max`` (static too: StructuralGate.span_max of the staged
-    segment) bounds the `desc` joins' trips (join_trips)."""
+    every call site — the jit-purity lint's descriptor rule pins it."""
     import jax.numpy as jnp
 
     safe_pb = jnp.maximum(page_block, 0)
@@ -1185,7 +1326,7 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
         bg_page = block_group[safe_pb]                   # [P]
     sctx = None
     if span_cols is not None:
-        sctx = _span_ctx(span_cols, val_hits, block_group, span_max)
+        sctx = _span_ctx(span_cols, val_hits, block_group)
     ectx = (kv_key, kv_val, entry_dur, entry_dur_res, valid, safe_pb,
             bg_page)
     if bucketed:
@@ -1194,7 +1335,7 @@ def structural_entry_mask(kv_key, kv_val, entry_dur, entry_valid,
     return _trace_mask(plan, ectx, sctx, tables, widths) & valid
 
 
-def _span_ctx(span_cols, val_hits, block_group, span_max) -> tuple:
+def _span_ctx(span_cols, val_hits, block_group) -> tuple:
     """What the span-level evaluators read of a staged segment
     (_span_mask, _tile_leaf, _bucket_span_regs unpack it). Which
     block's tables a span row reads is said by the TILE, not by the
@@ -1217,25 +1358,15 @@ def _span_ctx(span_cols, val_hits, block_group, span_max) -> tuple:
             span_cols["entry_span_begin"],
             span_cols["entry_span_count"],
             bg_tile,
-            join_trips(span_max))
+            span_cols["span_last"])
 
 
-def join_trips(span_max: int) -> int:
-    """Trips of one `desc` join's pointer doubling: after k trips a
-    span has seen its first 2^k proper ancestors, and it has fewer of
-    them than its trace has spans, so log2 of `span_max` (the longest
-    trace's span count as a power of two: StructuralGate.span_max)
-    reaches them all: 9 for a trace capped at 512, where the padded
-    span axis of a group of 64 blocks would give 26."""
-    return max(1, (int(span_max) - 1).bit_length())
-
-
-def plan_joins(plan, span_max: int) -> tuple:
-    """(rel, trips) of a launch of `plan`: `rel` is "desc" where the
+def plan_joins(plan) -> tuple:
+    """(rel, scans) of a launch of `plan`: `rel` is "desc" where the
     plan joins by ancestor (a bucket plan with relations runs that arm
     for every slot), else "child" where it joins by parent, else
-    "none"; `trips` the doubling trips its `desc` joins run between
-    them."""
+    "none"; `scans` the running-max passes over the span axis its
+    `desc` joins make, one a node (_descends)."""
     if not plan:
         return "none", 0
     if plan[0] == "bucket":
@@ -1245,7 +1376,7 @@ def plan_joins(plan, span_max: int) -> tuple:
         ops = list(_plan_ops(plan))
         n_desc = ops.count("desc")
         rel = "desc" if n_desc else "child" if "child" in ops else "none"
-    return rel, n_desc * join_trips(span_max)
+    return rel, n_desc
 
 
 def _plan_ops(plan):
@@ -1258,27 +1389,20 @@ def _plan_ops(plan):
             yield from _plan_ops(sub)
 
 
-def _descends(am, sm, s_par, trips: int):
-    """[S]: the spans of `sm` with a proper ancestor in `am`, by
-    pointer doubling through the parent column: after k trips a span
-    has seen its first 2^k proper ancestors, two lookups over the span
-    axis a trip, `trips` (static: join_trips) of them in a fori_loop (a
-    Python unroll sends XLA's CPU fusion passes into minutes of
-    optimisation on batch-sized span axes, measured)."""
+def _descends(am, sm, s_last):
+    """[S]: the spans of `sm` with a proper ancestor in `am`, as ONE
+    running max over the span axis. Staging lays every trace out depth
+    first and keeps each span's last descendant (stack_spans), so row
+    `c` lies under row `a` iff a < c <= s_last[a]: `c` has an ancestor
+    in `am` iff the largest `s_last` of the `am` rows BEFORE it reaches
+    it. Rows of earlier traces (and blocks) end before `c`, so nothing
+    leaks across them; no lookup has the span axis in its indices."""
     import jax
     import jax.numpy as jnp
 
-    def _dbl(_i, carry):
-        acc, jump = carry
-        safe_j = jnp.maximum(jump, 0)
-        acc2 = acc | ((jump >= 0) & acc[safe_j])
-        jump2 = jnp.where(jump >= 0, jump[safe_j], -1)
-        return acc2, jump2
-
-    safe_par = jnp.maximum(s_par, 0)
-    acc, _ = jax.lax.fori_loop(
-        0, trips, _dbl, ((s_par >= 0) & am[safe_par], s_par))
-    return sm & acc
+    reach = jax.lax.cummax(jnp.where(am, s_last, -1))
+    before = jnp.concatenate([jnp.full(1, -1, reach.dtype), reach[:-1]])
+    return sm & (before >= jnp.arange(reach.shape[0], dtype=reach.dtype))
 
 
 def _seg_count(m, seg_b, seg_n):
@@ -1361,8 +1485,8 @@ def leaf_lookup_rows(plan, tables, span_cols) -> int:
     tag terms is a leaf) and one more where a hit mask rides (the
     tiles' dictionary groups), each of one index a TILE of the staged
     span axis. Reckoned on the host from the static plan and the
-    tables' and the staged columns' shapes, as join_trips is: over the
-    launch's span rows it reads 1 / SPAN_TILE a lookup. It says what
+    tables' and the staged columns' shapes: over the launch's span
+    rows it reads 1 / SPAN_TILE a lookup. It says what
     the plan asks of the layout, not what the compiled program does:
     the jaxpr test (tests/test_structural_tiles.py) holds the kernel to
     it."""
@@ -1389,7 +1513,7 @@ def _span_mask(plan, sctx, tables, widths):
     if plan is None:
         raise StructuralCompileError("span plan must not be None")
     (s_valid, _tile_block, s_par, s_dur, s_kind, _s_kk, _s_vv,
-     _seg_b, _seg_n, _bg_tile, trips) = sctx
+     _seg_b, _seg_n, _bg_tile, s_last) = sctx
     dur_params, kind_params = tables[4], tables[5]
     op = plan[0]
     if op == "tag":
@@ -1421,7 +1545,7 @@ def _span_mask(plan, sctx, tables, widths):
     if op == "desc":
         am = _span_mask(plan[2], sctx, tables, widths)
         sm = _span_mask(plan[3], sctx, tables, widths)
-        return _descends(am, sm, s_par, trips)
+        return _descends(am, sm, s_last)
     raise StructuralCompileError(f"bad span plan op {op!r}")
 
 
@@ -1529,7 +1653,7 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
     import jax.numpy as jnp
 
     (s_valid, _tile_block, s_par, s_dur, s_kind, _s_kk, _s_vv,
-     _seg_b, _seg_n, _bg_tile, trips) = sctx
+     _seg_b, _seg_n, _bg_tile, s_last) = sctx
     term_keys, dur_params, kind_params = core[0], core[4], core[5]
     S = int(s_valid.shape[0])
     false = jnp.zeros(S, dtype=bool)
@@ -1557,9 +1681,7 @@ def _bucket_span_regs(sctx, core, n_slots, prog, has_rel) -> list:
         if has_rel:
             val = jnp.where(opc == 7,
                             rb & (s_par >= 0) & ra[safe_par], val)
-
-            val = jnp.where(opc == 8,
-                            _descends(ra, rb, s_par, trips), val)
+            val = jnp.where(opc == 8, _descends(ra, rb, s_last), val)
         regs.append(val)
     return regs
 
@@ -1671,9 +1793,26 @@ def eval_host(expr: "ir.TraceExpr", sd) -> bool:
     """Reference semantics over a SearchData (with its span rows):
     byte-for-byte what the compiled kernels answer — substring tag
     terms, inclusive ranges, pointer joins, and the SAME integer
-    rank-count quantile formula (never a sort, never a float)."""
+    rank-count quantile formula (never a sort, never a float). A
+    malformed loop of parents is cut as staging cuts it
+    (cut_parent_loops), so host and device agree on every input."""
     spans = list(getattr(sd, "spans", ()) or ())
     n_spans = len(spans)
+    parents: list = []
+
+    def parent_of() -> list:
+        """Each span's parent in the trace or -1, loops cut; read by
+        the joins alone, once a trace. A trace is tens of spans: a
+        plain walk finds out whether there is a loop to cut at all,
+        and only then is the rule's one helper asked."""
+        if not parents and n_spans:
+            raw = [sp.parent if 0 <= sp.parent < n_spans else -1
+                   for sp in spans]
+            if _has_parent_loop(raw):
+                raw = cut_parent_loops(
+                    np.array(raw, dtype=np.int32))[0].tolist()
+            parents.extend(raw)
+        return parents
 
     def sev(e) -> list:
         if isinstance(e, ir.SpanTag):
@@ -1696,26 +1835,18 @@ def eval_host(expr: "ir.TraceExpr", sd) -> bool:
         if isinstance(e, ir.SpanNot):
             return [not v for v in sev(e.arg)]
         if isinstance(e, ir.ChildOf):
-            pm, cm = sev(e.parent), sev(e.child)
-            return [cm[i] and 0 <= spans[i].parent < n_spans
-                    and pm[spans[i].parent] for i in range(n_spans)]
+            pm, cm, par = sev(e.parent), sev(e.child), parent_of()
+            return [cm[i] and par[i] >= 0 and pm[par[i]]
+                    for i in range(n_spans)]
         if isinstance(e, ir.DescOf):
-            am, sm = sev(e.anc), sev(e.span)
+            am, sm, par = sev(e.anc), sev(e.span), parent_of()
             out = []
             for i in range(n_spans):
                 ok = False
                 if sm[i]:
-                    p = spans[i].parent
-                    # bounded walk: malformed parent cycles terminate
-                    # after n_spans hops (the device doubling covers the
-                    # same reachable set)
-                    for _ in range(n_spans):
-                        if not 0 <= p < n_spans:
-                            break
-                        if am[p]:
-                            ok = True
-                            break
-                        p = spans[p].parent
+                    p = par[i]      # no loops: the walk ends at a root
+                    while p >= 0 and not ok:
+                        ok, p = am[p], par[p]
                 out.append(ok)
             return out
         raise StructuralCompileError(
@@ -1776,8 +1907,7 @@ def plan_node_bytes(plan: tuple, n_spans: int, n_entries: int,
     """Per-node device-byte estimates — the unit the planner's
     calibrated scan rate (seconds/byte) turns into predicted seconds,
     and the conserved weights measured kernel time apportions over for
-    the explain tree. Deliberately simple: bytes touched per op,
-    including the log-factor of the doubling join."""
+    the explain tree. Deliberately simple: bytes touched per op."""
     S = max(1, n_spans)
     PE = max(1, n_entries)
     out: dict[int, int] = {}
@@ -1797,12 +1927,8 @@ def plan_node_bytes(plan: tuple, n_spans: int, n_entries: int,
         elif op == "not":
             out[nid] = S
             w_span(p[2])
-        elif op == "child":
+        elif op in ("child", "desc"):
             out[nid] = S * 12
-            w_span(p[2])
-            w_span(p[3])
-        elif op == "desc":
-            out[nid] = S * 12 * max(1, (S - 1).bit_length())
             w_span(p[2])
             w_span(p[3])
 

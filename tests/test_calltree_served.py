@@ -10,8 +10,9 @@ the same corpus answers flat requests as it does with the gate on, and a
 Beside it: the generator's stated targets, that a group's span total
 sits inside 0.70-0.90 of its power of two whatever the seed, the plain
 reference against the program's own host evaluator (`eval_host`) on
-random small trees, the `desc` join's trip count at its boundary, and
-that the new spans and counters are written by structural searches only.
+random small trees, the `desc` join at the ingest cap's boundary (one
+running max whatever the longest trace), and that the new spans and
+counters are written by structural searches only.
 """
 
 import base64
@@ -165,14 +166,13 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     try:
         launches = {r: obs.structural_launches.value(rel=r)
                     for r in ("none", "child", "desc")}
-        trips = obs.structural_join_trips.value()
         looked = obs.structural_leaf_lookup_rows.value()
         for request in served["flat"]["service-exhaustive"]:
             assert _ask(api, request)[0] == 200
         names = {s.name for s in collector.spans}
         assert "dispatch.execute" in names
         assert not names & {"structural.compile", "batcher.stage_spans"}
-        assert not any({"rel", "join_trips", "span_tile",
+        assert not any({"rel", "join_scans", "span_tile",
                         "leaf_lookup_rows"} & set(s.attributes)
                        for s in collector.spans)
         assert launches == {r: obs.structural_launches.value(rel=r)
@@ -184,6 +184,8 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
                 batcher._drop_hbm_locked(key)
         rows = {k: obs.structural_span_rows.value(kind=k)
                 for k in ("live", "pad")}
+        reordered = obs.structural_span_reorder_rows.value()
+        order_s = obs.structural_span_order_seconds.value()
         request = op.build(
             dict(TEMPLATES["errors-below"], variants=1,
                  draw={"A": {"service": "strata"}}), m,
@@ -200,7 +202,11 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     assert compiled["nodes"] == 4 and compiled["terms"] == 2
     staged = by_name["batcher.stage_spans"][0].attributes
     assert staged["span_rows"] - staged["pad_rows"] == m["spans"]
-    assert staged["span_max"] == 512
+    # put again from the host tier: the layout was paid when the
+    # group's host columns were stacked, not by this search
+    assert "order_ms" not in staged
+    assert obs.structural_span_order_seconds.value() == order_s
+    assert obs.structural_span_reorder_rows.value() == reordered
     assert obs.structural_span_rows.value(kind="live") - rows["live"] \
         == m["spans"]
     assert obs.structural_span_rows.value(kind="pad") - rows["pad"] \
@@ -210,8 +216,9 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     execute = [s.attributes for s in collector.spans
                if s.name in ("dispatch.execute", "dispatch.compile")
                and "rel" in s.attributes][-1]
-    # one join by ancestor, its trips from the longest trace (512)
-    assert execute["rel"] == "desc" and execute["join_trips"] == 9
+    # one join by ancestor: one running max over the span axis
+    assert execute["rel"] == "desc" and execute["join_scans"] == 1
+    assert "join_trips" not in execute
     assert execute["span_rows"] == staged["span_rows"]
     # its two tag leaves look their tables up by the tile: key, lows
     # and highs a leaf, one index a tile of the span axis
@@ -222,7 +229,17 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
         == looked + execute["leaf_lookup_rows"]
     assert obs.structural_launches.value(rel="desc") \
         == launches["desc"] + 1
-    assert obs.structural_join_trips.value() == trips + 9
+    assert not hasattr(obs, "structural_join_trips")
+    # the corpus is stored as an ingester stores it, a child mostly
+    # before its parent: the layout moves most of its rows
+    with batcher._lock:
+        stored = [b for h in batcher._host_cache.values()
+                  for b in h.blocks if b.has_spans]
+    assert sum(b.n_spans for b in stored) == m["spans"]
+    moved = sum(structural.span_preorder(
+        b.span_parent, b.entry_span_begin.reshape(-1)[b.span_trace])[3]
+        for b in stored)
+    assert 0.5 * m["spans"] < moved <= m["spans"]
 
 
 # ---------------------------------------------------------------------------
@@ -485,10 +502,9 @@ DESC = [
 ]
 
 
-@pytest.mark.parametrize("case,trips", [("chain-511", 9),
-                                        ("chain-600-cut-at-512", 9),
-                                        ("longest-9-of-2^15-rows", 4)])
-def test_desc_answers_hold_at_the_trip_counts_boundary(case, trips):
+@pytest.mark.parametrize("case", ["chain-511", "chain-600-cut-at-512",
+                                  "longest-9-of-2^15-rows"])
+def test_desc_answers_hold_at_the_ingest_caps_boundary(case):
     was = structural.STRUCTURAL.enabled
     structural.STRUCTURAL.enabled = True
     try:
@@ -518,11 +534,15 @@ def test_desc_answers_hold_at_the_trip_counts_boundary(case, trips):
         rows = int(batch.span_device["span_parent"].shape[0])
         assert batch.span_device["span_tile_block"].shape \
             == (rows // structural.SPAN_TILE,)
-        if case == "longest-9-of-2^15-rows":
-            assert rows == 1 << 15 and batch.span_max == 16
-        else:
-            assert batch.span_max == 512
-        assert structural.join_trips(batch.span_max) == trips
+        assert (rows == 1 << 15) == (case == "longest-9-of-2^15-rows")
+        # one program whatever the group's longest trace: nothing of
+        # it is in the launch's statics
+        assert not hasattr(batch, "span_max")
+        last = np.asarray(batch.span_device["span_last"])
+        live = np.asarray(batch.span_device["span_trace"]) >= 0
+        longest = int((last - np.arange(rows))[live].max()) + 1
+        assert longest == {"chain-511": 511, "chain-600-cut-at-512": 512,
+                           "longest-9-of-2^15-rows": 9}[case]
 
         for q in DESC:
             want = int(rs.evaluate(q, corpus, block).sum())
@@ -531,9 +551,7 @@ def test_desc_answers_hold_at_the_trip_counts_boundary(case, trips):
                              batch=batch)
             assert int(got.count) == want, (case, q)
             plan = structural._LeafCollector().lower_trace(expr)
-            assert structural.plan_joins(plan, batch.span_max)[1] \
-                == trips * sum(op == "desc"
-                               for op in structural._plan_ops(plan))
+            assert structural.plan_joins(plan) == ("desc", 1)
         assert int(first.count) == int(rs.evaluate(DESC[0], corpus,
                                                    block).sum()) > 0
     finally:
@@ -614,26 +632,34 @@ def test_desc_on_the_device_equals_the_reference_in_either_order(order):
             staged = staged or got
             assert int(got.count) == int(rs.evaluate(q, corpus,
                                                      block).sum()), q
-        assert staged.batch.span_max == 32
+        # walk order is the layout: staging moved nothing there
+        cols = staged.batch.span_device
+        n = sum(len(sd.spans) for sd in entries)
+        assert np.array_equal(
+            np.asarray(cols["span_dur"])[:n],
+            pages.span_dur) == (order == "walk-order")
     finally:
         structural.STRUCTURAL.enabled = was
 
 
-def test_plan_joins_names_the_relation_and_counts_the_trips():
+def test_plan_joins_names_the_relation_and_counts_the_scans():
     def plan_of(q):
         return structural._LeafCollector().lower_trace(
             ir.parse(json.dumps(q)))
 
-    assert structural.plan_joins(None, 512) == ("none", 0)
-    assert structural.plan_joins(plan_of(DESC[0]), 512) == ("desc", 9)
-    assert structural.plan_joins(plan_of(DESC[0]), 16) == ("desc", 4)
-    assert structural.plan_joins(plan_of(DESC[0]), 1) == ("desc", 1)
+    assert structural.plan_joins(None) == ("none", 0)
+    assert structural.plan_joins(plan_of(DESC[0])) == ("desc", 1)
     child = {"child": {"parent": {"kind": 2}, "child": {"kind": 3}}}
-    assert structural.plan_joins(plan_of(child), 512) == ("child", 0)
+    assert structural.plan_joins(plan_of(child)) == ("child", 0)
     both = {"and": [DESC[0], DESC[2], child]}
-    assert structural.plan_joins(plan_of(both), 512) == ("desc", 18)
-    assert structural.plan_joins(("bucket", 4, 2, True), 16) == ("desc", 16)
-    assert structural.plan_joins(("bucket", 4, 2, False), 16) == ("none", 0)
+    assert structural.plan_joins(plan_of(both)) == ("desc", 2)
+    # every span slot of a bucket with relations runs the arm
+    assert structural.plan_joins(("bucket", 4, 2, True)) == ("desc", 4)
+    assert structural.plan_joins(("bucket", 4, 2, False)) == ("none", 0)
+    # no trip count left to read: the planner's weight of a `desc` is
+    # a pass over the span axis, as `child`'s
+    nb = structural.plan_node_bytes(plan_of(DESC[0]), 1 << 20, 4096)
+    assert sorted(nb.values())[-1] < 1 << 26
 
 
 def test_costs_count_each_column_a_plan_reads_once():

@@ -522,12 +522,18 @@ def test_profiler_listener_feeds_device_rate():
 
     p = _force("device")
     profile.configure(enabled=True)
+    pages = ColumnarPages.build(_corpus(150, seed=6), PageGeometry(32, 8))
+    staged = staged_dict(pages)
+    # the probe's program compiled where this process had not yet: a
+    # jit miss books `compile`, and only an `execute` feeds the rate
+    compile_query(pages.key_dict, pages.val_dict,
+                  _mk_req({"session.id": "session-02"}, limit=20),
+                  cache_on=pages, staged_dict=staged)
     before = p.snapshot()["cost_model"]["rates"]["device_probe"][
         "observations"]
-    pages = ColumnarPages.build(_corpus(150, seed=6), PageGeometry(32, 8))
     cq = compile_query(pages.key_dict, pages.val_dict,
                        _mk_req({"session.id": "session-01"}, limit=20),
-                       cache_on=pages, staged_dict=staged_dict(pages))
+                       cache_on=pages, staged_dict=staged)
     assert cq is not None and cq.val_hits is not None
     after = p.snapshot()["cost_model"]["rates"]["device_probe"][
         "observations"]

@@ -14,7 +14,9 @@ fused launch given its seven tables as one packed operand (PR 39) is
 the program it was given them one by one: the prelude's slices in front
 of the term loop move nothing the loop reads. And the one packed output
 (PR 41) leaves the scan in front of it op for op what it was when it
-returned four arrays: one more small fusion, no other pass.
+returned four arrays: one more small fusion, no other pass. And a
+structural launch that joins by ancestor (PR 46) is running maxes over
+the span axis at the cell's size: no loop, no lookup a span row.
 """
 
 import math
@@ -170,3 +172,55 @@ def test_the_packed_output_leaves_the_scan_in_front_of_it_as_it_was(
                 if d]
         assert math.prod(dims) <= row, (op, shape, n)
     assert now[("fusion", f"s32[{'2,' if Q else ''}{2 + 2 * K}]")] == 1
+
+
+@pytest.mark.parametrize("rel,lookups", [("desc", 0), ("child", 1)])
+def test_a_join_by_ancestor_is_no_loop_and_no_lookup_a_span_row(
+        rel, lookups, one_chip, no_compile_cache):
+    """The `desc` launch of `calltree16.structural`'s group (8 blocks,
+    2^23 span rows of 4 kv slots) compiled for the v5e: the join is
+    `reduce-window`s as the running sum of `_seg_count` is, the program
+    holds no `while` and no gather whose result is as long as the span
+    axis, and its scratch is a few passes' worth. `child` beside it
+    keeps its one lookup through the parent column."""
+    import re
+
+    from tempo_tpu.search import ir, structural
+    from tempo_tpu.search.multiblock import batch_scan_kernel
+
+    pages, blocks, spans, slots = 512, 8, 1 << 23, 4
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cols = (S((pages, E, C), jnp.int8), S((pages, E, C), jnp.int16),
+            *[S((pages, E), jnp.uint32)] * 3, S((pages, E), jnp.bool_),
+            S((pages,), jnp.int32))
+    span_cols = {
+        **{n: S((spans,), jnp.int32)
+           for n in ("span_trace", "span_parent", "span_last")},
+        "span_tile_block": S((spans // structural.SPAN_TILE,), jnp.int32),
+        "span_dur": S((spans,), jnp.uint32),
+        "span_kind": S((spans,), jnp.int8),
+        "span_kv_key": S((spans, slots), jnp.int32),
+        "span_kv_val": S((spans, slots), jnp.int32),
+        "entry_span_begin": S((pages, E), jnp.int32),
+        "entry_span_count": S((pages, E), jnp.int32)}
+    plan = structural._LeafCollector().lower_trace(ir.parse(
+        '{"exists": {"%s": {"%s": {"tag": {"k": "service.name", "v": "a"}},'
+        ' "%s": {"tag": {"k": "name", "v": "b"}}}}}'
+        % ((rel, "anc", "span") if rel == "desc"
+           else (rel, "parent", "child"))))
+    tables = (S((blocks, 0), jnp.int32), S((blocks, 0, 1, 2), jnp.int32),
+              None, *[S((), jnp.uint32)] * 4)
+    s_tables = (S((blocks, 2), jnp.int32), S((blocks, 2, 1, 2), jnp.int32),
+                *[None] * 5)
+    compiled = batch_scan_kernel.lower(
+        *cols, *tables, None, None, None, span_cols, s_tables, None,
+        n_terms=0, top_k=128, plan=plan).compile()
+    text = compiled.as_text()
+    assert not re.findall(r" while\(", text)
+    assert len(re.findall(r"= \w+\[%d\]\S* gather\(" % spans, text)) \
+        == lookups
+    assert ("reduce-window(" in text) and compiled.memory_analysis() \
+        .temp_size_in_bytes < 256 << 20
