@@ -171,8 +171,11 @@ def enqueue_split(server, calls: int = 200):
     eng = batcher.engine
     if eng.mesh is None:
         return None
-    with batcher._lock:
-        found = next(((c.batch, pre) for c in batcher._cache.values()
+    cache = batcher.cache
+    held = [c for c in map(cache.resident, cache.snapshot()["entries"])
+            if c is not None]
+    with cache.group_lock:       # the memos change under it
+        found = next(((c.batch, pre) for c in held
                       for pre in c.query_cache.values()
                       if not pre["all_skip"] and pre.get("val_hits") is None
                       and pre.get("structural") is None), None)

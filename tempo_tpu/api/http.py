@@ -618,7 +618,7 @@ class HTTPApi:
         snap = OWNERSHIP.snapshot()
         db = getattr(self.app, "reader_db", None)
         if db is not None:
-            snap["residency"] = db.batcher.ownership_residency()
+            snap["residency"] = db.batcher.cache.ownership_residency()
         return 200, snap
 
     def _debug_flightrecorder_route(self, query):

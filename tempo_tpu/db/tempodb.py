@@ -643,11 +643,11 @@ class TempoDB:
         if self._prewarm_stop is not None:
             self._prewarm_stop.set()
         if self.cfg.search_prewarm_on_poll:
-            self.batcher.invalidate(live)
+            self.batcher.cache.invalidate(live)
             self.prewarm(tenants=list(metas), reinvalidate=live)
         else:
             self.stop_prewarm()
-            self.batcher.invalidate(live)
+            self.batcher.cache.invalidate(live)
         self.save_host_state()
 
     def prewarm(self, tenants: list[str], background: bool = True,
@@ -685,7 +685,7 @@ class TempoDB:
             if prev_thread is not None and prev_thread.is_alive():
                 prev_thread.join()
             if reinvalidate is not None:
-                self.batcher.invalidate(reinvalidate)
+                self.batcher.cache.invalidate(reinvalidate)
             staged = 0
             for tenant in tenants:
                 if stop.is_set():
@@ -736,7 +736,7 @@ class TempoDB:
 
         moved = OWNERSHIP.set_members(members, self_id=self_id)
         out = {"generation": OWNERSHIP.generation, "moved_groups": moved}
-        out.update(self.batcher.rebalance_ownership())
+        out.update(self.batcher.cache.rebalance_ownership())
         if prestage and OWNERSHIP.enabled:
 
             def _prestage() -> None:
@@ -773,7 +773,7 @@ class TempoDB:
         from tempo_tpu.search.ownership import OWNERSHIP
 
         if direction == "down":
-            self.batcher.rebalance_ownership()
+            self.batcher.cache.rebalance_ownership()
             return
         me = OWNERSHIP.self_id
         reps = tuple(replicas or ())

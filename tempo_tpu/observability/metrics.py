@@ -578,14 +578,6 @@ structural_span_order_seconds = Counter(
     "host seconds staging spent laying blocks' spans out depth first, "
     "the sort and the permuted copies of the span columns: paid once "
     "a block when a group's host columns are stacked")
-structural_leaf_lookup_rows = Counter(
-    "tempo_search_structural_leaf_lookup_rows_total",
-    "rows the tag leaves of structural launches indexed their "
-    "per-block tables by (key, range lows, range highs a leaf; the "
-    "dictionary groups where a hit mask rides): one a TILE of the "
-    "staged span axis a lookup and member, so 1 / SPAN_TILE of the "
-    "launch's span rows a lookup (reckoned from the plan and the "
-    "staged shape, not read from the compiled program)")
 structural_span_rows = Counter(
     "tempo_search_structural_span_rows_total",
     "span rows put on the device with staged groups: kind=live (spans "

@@ -19,6 +19,7 @@ sequence axis, sharded over the mesh.
 
 from __future__ import annotations
 
+from collections import OrderedDict
 from dataclasses import dataclass, field
 
 import jax
@@ -59,9 +60,9 @@ import math
 
 
 # Ranges a term past which a member fuses apart from narrower ones
-# (batcher.submit): a fused launch pads every member to its widest, and
-# a fused launch of four costs 6.1 ms for 4,096 pages at 64 ranges, 17.8
-# at 512 (scripts/membership_bench.py on a v5e, PR 36).
+# (QueryCoalescer.submit): a fused launch pads every member to its
+# widest, and a fused launch of four costs 6.1 ms for 4,096 pages at 64
+# ranges, 17.8 at 512 (scripts/membership_bench.py on a v5e, PR 36).
 WIDE_RANGES = 64
 
 # Ranges a term from which the scan tests an ENTRY's value for the
@@ -211,9 +212,13 @@ class HostBatch:
     # bytes of `blocks`' columns that are views of `cat`: counted once
     aliased_nbytes: int = 0
     # the prepare memo of the group's last staged batch, kept across an
-    # HBM eviction (batcher._keep_memo_locked) and taken back by the
+    # HBM eviction (group_cache._keep_memo_locked) and taken back by the
     # next stage: host objects only, small beside the columns, uncharged
     query_memo: object | None = None
+    # the host route's own prepare memo (host-only compiles: range
+    # tables, no device state), read and kept through the group cache's
+    # `memo_get` / `memo_put` like a resident entry's
+    query_cache: OrderedDict = field(default_factory=OrderedDict)
 
     @property
     def cat_nbytes(self) -> int:
@@ -1604,20 +1609,14 @@ class MultiBlockEngine:
                     # a structural launch alone says how it joins and
                     # over how many span rows (pad rows included): no
                     # flat search carries these (PERF.md section 7 h11)
-                    from .structural import leaf_lookup_rows, plan_joins
+                    from .structural import plan_joins
 
                     rows = int(span_cols["span_parent"].shape[0])
                     tiles = int(span_cols["span_tile_block"].shape[0])
                     rel, scans = plan_joins(plan)
-                    # what the tag leaves index their tables by: a tile
-                    # of the span axis a lookup, once a member
-                    looked = members * leaf_lookup_rows(plan, s_tables,
-                                                        span_cols)
                     obs.structural_launches.inc(rel=rel)
-                    obs.structural_leaf_lookup_rows.inc(looked)
                     rec.set(rel=rel, join_scans=scans, span_rows=rows,
-                            span_tile=rows // tiles,
-                            leaf_lookup_rows=looked)
+                            span_tile=rows // tiles)
                 if q.n_terms:
                     # a launch without tag terms compares no range
                     compare = compares_by(q.val_ranges.shape[-2])

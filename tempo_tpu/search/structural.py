@@ -1478,31 +1478,6 @@ def _tile_leaf(sctx, tables, i):
     return m.reshape(S) & s_valid
 
 
-def leaf_lookup_rows(plan, tables, span_cols) -> int:
-    """Rows ONE query of a launch of `plan` indexes its per-block
-    tables by for its tag leaves over spans: three lookups a leaf (key,
-    range lows, range highs; every span slot of a bucket program with
-    tag terms is a leaf) and one more where a hit mask rides (the
-    tiles' dictionary groups), each of one index a TILE of the staged
-    span axis. Reckoned on the host from the static plan and the
-    tables' and the staged columns' shapes: over the launch's span
-    rows it reads 1 / SPAN_TILE a lookup. It says what
-    the plan asks of the layout, not what the compiled program does:
-    the jaxpr test (tests/test_structural_tiles.py) holds the kernel to
-    it."""
-    if not plan or tables[0] is None:
-        return 0
-    if plan[0] == "bucket":
-        leaves = plan[1]
-    else:
-        leaves = sum(op == "tag" for op in _plan_ops(plan))
-    if not leaves:
-        return 0
-    hit_mask = tables[2] is not None and tables[3] is not None
-    return (3 * leaves + hit_mask) * int(
-        span_cols["span_tile_block"].shape[0])
-
-
 def _span_mask(plan, sctx, tables, widths):
     """[S] bool mask for a span-level plan node. This is a DESCRIPTOR
     DISPATCHER over `plan` (branch structure decided at trace time):

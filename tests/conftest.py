@@ -124,6 +124,50 @@ def scan_batch(blocks, req, *, top_k=128, mesh=None, probe_min_vals=0,
     return BatchScan(engine, batch, mq, engine.scan(batch, mq)[:4])
 
 
+def drop_hbm(batcher):
+    """Every staged group leaves HBM, through the cache's own
+    accounting, with no eviction booked; the host tier stays. (Both
+    tiers: `batcher.cache.invalidate(set())`.)"""
+    cache = batcher.cache
+    for gkey in cache.snapshot()["entries"]:
+        with cache.group_lock:
+            cache._remove_locked(gkey)
+
+
+def settle(batcher, timeout=10.0):
+    """Look-aheads that no search came back for give their pins back
+    when they finish: wait for that, then return the pins still held."""
+    import time
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        snap = batcher.cache.snapshot()
+        held = sum(pins for _n, pins, _m in snap["entries"].values())
+        if not held and not snap["staging"]:
+            return 0
+        time.sleep(0.02)
+    return held
+
+
+def check_budget(cache):
+    """The budget's one invariant, on a cache at rest: the running
+    totals are the sums over the resident entries. Returns the entries'
+    snapshot."""
+    from tempo_tpu.search import group_cache
+
+    snap = cache.snapshot()
+    entries = snap["entries"]
+    held = [cache.resident(k) for k in entries]
+    assert snap["hbm_bytes"] == sum(n for n, _p, _m in entries.values()) >= 0
+    with cache.group_lock:
+        assert cache._probe_dict_total == sum(
+            group_cache._dict_bytes(e.batch) for e in held)
+        assert cache._span_total == sum(
+            group_cache._span_bytes(e.batch) for e in held)
+        assert cache._cache_logical == sum(e.logical for e in held)
+    return entries
+
+
 def staged_dict(pages, probe_min_vals=1):
     """The DeviceDict a one-block batch stages for the block's value
     dictionary (None under the threshold or a planner veto)."""

@@ -166,22 +166,19 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     try:
         launches = {r: obs.structural_launches.value(rel=r)
                     for r in ("none", "child", "desc")}
-        looked = obs.structural_leaf_lookup_rows.value()
         for request in served["flat"]["service-exhaustive"]:
             assert _ask(api, request)[0] == 200
         names = {s.name for s in collector.spans}
         assert "dispatch.execute" in names
         assert not names & {"structural.compile", "batcher.stage_spans"}
-        assert not any({"rel", "join_scans", "span_tile",
-                        "leaf_lookup_rows"} & set(s.attributes)
+        assert not any({"rel", "join_scans", "span_tile"} & set(s.attributes)
                        for s in collector.spans)
         assert launches == {r: obs.structural_launches.value(rel=r)
                             for r in launches}
-        assert obs.structural_leaf_lookup_rows.value() == looked
         # evicted, the group is staged again by the structural search
-        with batcher._lock:
-            for key in list(batcher._cache):
-                batcher._drop_hbm_locked(key)
+        for key in batcher.cache.snapshot()["entries"]:
+            with batcher.cache.group_lock:
+                batcher.cache._drop_hbm_locked(key)
         rows = {k: obs.structural_span_rows.value(kind=k)
                 for k in ("live", "pad")}
         reordered = obs.structural_span_reorder_rows.value()
@@ -220,21 +217,15 @@ def test_spans_and_counters_are_written_by_structural_searches_only(served):
     assert execute["rel"] == "desc" and execute["join_scans"] == 1
     assert "join_trips" not in execute
     assert execute["span_rows"] == staged["span_rows"]
-    # its two tag leaves look their tables up by the tile: key, lows
-    # and highs a leaf, one index a tile of the span axis
+    # its tag leaves look their tables up by the tile of the span axis
     assert execute["span_tile"] == structural.SPAN_TILE
-    assert execute["leaf_lookup_rows"] * structural.SPAN_TILE \
-        == 2 * 3 * execute["span_rows"]
-    assert obs.structural_leaf_lookup_rows.value() \
-        == looked + execute["leaf_lookup_rows"]
     assert obs.structural_launches.value(rel="desc") \
         == launches["desc"] + 1
     assert not hasattr(obs, "structural_join_trips")
     # the corpus is stored as an ingester stores it, a child mostly
     # before its parent: the layout moves most of its rows
-    with batcher._lock:
-        stored = [b for h in batcher._host_cache.values()
-                  for b in h.blocks if b.has_spans]
+    stored = [b for h in batcher.cache.snapshot()["host"].values()
+              for b in h.blocks if b.has_spans]
     assert sum(b.n_spans for b in stored) == m["spans"]
     moved = sum(structural.span_preorder(
         b.span_parent, b.entry_span_begin.reshape(-1)[b.span_trace])[3]

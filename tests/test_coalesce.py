@@ -1,4 +1,4 @@
-"""Cross-request query coalescing (search/batcher.QueryCoalescer).
+"""Cross-request query coalescing (search/coalescer.QueryCoalescer).
 
 Concurrent SearchRequests whose dispatches land on the same staged
 BlockBatch within the coalescing window stack along a query axis and run
@@ -20,10 +20,12 @@ import time
 import numpy as np
 import pytest
 
+from conftest import settle
 from tempo_tpu import tempopb
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.search import ColumnarPages, PageGeometry, SearchResults
-from tempo_tpu.search.batcher import BlockBatcher, QueryCoalescer, ScanJob
+from tempo_tpu.search.batcher import BlockBatcher, ScanJob
+from tempo_tpu.search.coalescer import QueryCoalescer
 from tempo_tpu.search.data import SearchData
 from tempo_tpu.search.engine import fetch_scan_out, resolve_top_k
 from tempo_tpu.search.multiblock import (
@@ -282,23 +284,20 @@ def test_device_params_cached_after_deferred_window_flush():
     req = _mk_req({"service.name": "svc-2"})  # fresh predicate, no dp yet
     # phantom peer on every staged batch: arms the window, so the solo
     # flush is timer-deferred instead of inline
-    with b._lock:
-        gkeys = list(b._cache)
+    gkeys = list(b.cache.snapshot()["entries"])
+    with b.cache.group_lock:
         for k in gkeys:
             b._interest[k] = b._interest.get(k, 0) + 1
     try:
         b.search(list(jobs), req)
     finally:
-        with b._lock:
+        with b.cache.group_lock:
             for k in gkeys:
-                n = b._interest.get(k, 0) - 1
-                if n <= 0:
-                    b._interest.pop(k, None)
-                else:
-                    b._interest[k] = n
+                b._lose_interest_locked(k)
     sig = _predicate_sig(req)
     cached_dps = [c.query_cache[sig].get("device_params")
-                  for c in b._cache.values() if sig in c.query_cache]
+                  for c in map(b.cache.resident, gkeys)
+                  if sig in c.query_cache]
     assert cached_dps and all(dp is not None for dp in cached_dps), (
         "deferred-flush dispatch did not cache its uploaded query tables")
 
@@ -401,15 +400,21 @@ def test_hbm_eviction_under_budget_pressure():
     want = b.search(list(jobs), req).response().SerializeToString()
     groups = b.plan(jobs)
     assert len(groups) > 1, "budget test needs multiple groups"
+    # the first search quit early and left a look-ahead behind. It lands
+    # first: a search joins what another thread is staging before it
+    # stages anything itself, so the second would scan other groups
+    # than the first did, and an early quit's answer follows its groups
+    assert settle(b) == 0
 
     # shrink the budget below one staged group: every group staged past
     # the first must evict a predecessor
     ev0 = obs.batch_cache_events.value(result="evict")
-    b.cache_bytes = 1
+    b.cache.cache_bytes = 1
     got = b.search(list(jobs), req).response().SerializeToString()
     assert got == want
     assert obs.batch_cache_events.value(result="evict") > ev0
-    assert len(b._cache) <= 1  # budget enforced after pins released
+    # budget enforced after pins released
+    assert len(b.cache.snapshot()["entries"]) <= 1
 
 
 def test_eviction_skips_pinned_batches():
@@ -417,21 +422,22 @@ def test_eviction_skips_pinned_batches():
     jobs = _jobs(blocks)
     b = BlockBatcher(coalesce_max_queries=1)
     b.search(list(jobs), _mk_req({"service.name": "svc-1"}, limit=20))
-    assert len(b._cache) == 1
-    entry = next(iter(b._cache.values()))
+    (gkey,) = b.cache.snapshot()["entries"]
+    entry = b.cache.resident(gkey)
     entry.pins = 1
-    b.cache_bytes = 1
-    with b._lock:
-        b._evict_hbm_locked()
-    assert len(b._cache) == 1, "pinned batch must survive eviction"
+    b.cache.cache_bytes = 1
+    with b.cache.group_lock:
+        b.cache._evict_hbm_locked()
+    assert b.cache.resident(gkey) is entry, "pinned batch must survive eviction"
     entry.pins = 0
     # pins released → next search enforces the budget again
     b.search(list(jobs), _mk_req({"service.name": "svc-2"}, limit=20))
-    assert b._cache_total <= max(b.cache_bytes, entry.nbytes)
+    assert b.cache.snapshot()["hbm_bytes"] <= max(b.cache.cache_bytes,
+                                                  entry.nbytes)
 
 
 def test_invalidation_mid_flight_is_safe():
-    """A blocklist change (batcher.invalidate) racing an in-flight
+    """A blocklist change (the cache's `invalidate`) racing an in-flight
     search must neither crash nor corrupt results; afterwards the dead
     batches are gone from both cache tiers."""
     blocks = _blocks(4, entries=150)
@@ -446,7 +452,7 @@ def test_invalidation_mid_flight_is_safe():
 
     def invalidator():
         while not stop.is_set():
-            b.invalidate(set())          # nothing is live: drop everything
+            b.cache.invalidate(set())    # nothing is live: drop everything
             time.sleep(0.001)
 
     inv = threading.Thread(target=invalidator)
@@ -460,8 +466,8 @@ def test_invalidation_mid_flight_is_safe():
         stop.set()
         inv.join()
     assert not errors
-    b.invalidate(set())
-    assert not b._cache and not b._host_cache
+    b.cache.invalidate(set())
+    assert not b.cache.snapshot()["entries"] and not b.cache.snapshot()["host"]
 
 
 def test_debug_stats_exposes_coalesce_ratio():

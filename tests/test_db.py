@@ -11,6 +11,7 @@ from tempo_tpu.search import extract_search_data
 from tempo_tpu.utils.ids import random_trace_id
 from tempo_tpu.utils.test_data import make_trace
 
+from conftest import drop_hbm
 from tests.test_search import _mk_req
 
 
@@ -277,8 +278,7 @@ def test_search_batched_pipeline(tmp_path):
         # early quit: force one group per block; a small limit stops
         # dispatching before all groups run
         db.batcher.max_batch_pages = 1
-        db.batcher._cache.clear()
-        db.batcher._cache_total = 0
+        drop_hbm(db.batcher)
         dispatches.clear()
         small = _mk_req({})
         small.limit = 3
@@ -633,7 +633,7 @@ def test_host_tier_survives_hbm_eviction(tmp_path):
     req = _mk_req({})
     req.limit = 10_000
     r1 = db.search("t1", req).response()
-    assert db.batcher._host_total > 0  # host tier populated
+    assert db.batcher.cache.snapshot()["host_bytes"] > 0  # host tier populated
 
     # count backend reads of search containers to prove no re-IO
     reads = [0]
@@ -644,9 +644,7 @@ def test_host_tier_survives_hbm_eviction(tmp_path):
     db.backend.read = counting_read
 
     # evict everything from HBM, keep the host tier
-    with db.batcher._lock:
-        db.batcher._cache.clear()
-        db.batcher._cache_total = 0
+    drop_hbm(db.batcher)
     h0 = obs.batch_cache_events.value(result="host_hit")
     r2 = db.search("t1", req).response()
     assert obs.batch_cache_events.value(result="host_hit") > h0
@@ -663,12 +661,12 @@ def test_host_tier_budget_evicts(tmp_path):
         _ingest(db, "t1", 4, seed_base=b * 50)
     db.poll()
     db.batcher.max_batch_pages = 1   # one group per block
-    db.batcher.host_cache_bytes = 1  # budget below any batch
+    db.batcher.cache.host_cache_bytes = 1  # budget below any batch
     req = _mk_req({})
     req.limit = 10_000
     db.search("t1", req)
     # budget of 1 byte keeps at most one entry (evict-to-last semantics)
-    assert len(db.batcher._host_cache) <= 1
+    assert len(db.batcher.cache.snapshot()["host"]) <= 1
 
 
 def test_staging_prefetch_results_identical(tmp_path):

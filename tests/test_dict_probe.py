@@ -34,7 +34,7 @@ from tempo_tpu.search.multiblock import (
     stack_queries,
 )
 
-from conftest import scan_batch, staged_dict
+from conftest import check_budget, scan_batch, staged_dict
 from tempo_tpu.search.batcher import BlockBatcher
 
 
@@ -194,8 +194,8 @@ def test_one_block_search_honors_probe_threshold():
     jobs = [BackendSearchBlock(be, meta).scan_job()]
 
     def staged_dicts(b):
-        (entry,) = b._cache.values()
-        return entry.batch.staged_dicts
+        (gkey,) = b.cache.snapshot()["entries"]
+        return b.cache.resident(gkey).batch.staged_dicts
 
     off = BlockBatcher(device_probe_min_vals=-1)
     r_off = off.search(jobs, req).response().SerializeToString()
@@ -475,14 +475,15 @@ def test_batcher_accounts_staged_dict_bytes():
     b = BlockBatcher(coalesce_max_queries=1, device_probe_min_vals=10)
     req = _mk_req({"session.id": "session-01"}, limit=100)
     b.search(_jobs(blocks), req)
-    assert b._cache, "nothing staged"
-    entry = next(iter(b._cache.values()))
+    entries = b.cache.snapshot()["entries"]
+    assert entries, "nothing staged"
+    entry = b.cache.resident(next(iter(entries)))
     page_bytes = sum(int(a.nbytes) for a in entry.batch.device.values())
     dict_bytes = sum(d.nbytes for d in entry.batch.staged_dicts.values())
     assert dict_bytes > 0
     assert entry.batch.nbytes == page_bytes + dict_bytes
     # the budget counter tracks the full entry sizes
-    assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    check_budget(b.cache)
 
 
 def test_evicted_batch_restages_dictionaries():
@@ -497,9 +498,10 @@ def test_evicted_batch_restages_dictionaries():
                      device_probe_min_vals=10)
     req = _mk_req({"session.id": "session-01"}, limit=100)
     r1 = b.search(_jobs(blocks), req).response().SerializeToString()
-    assert len(b._cache) == 2 and len(b._host_cache) == 2
-    old_dicts = {k: dict(v.batch.staged_dicts)
-                 for k, v in b._cache.items()}
+    snap = b.cache.snapshot()
+    assert len(snap["entries"]) == 2 and len(snap["host"]) == 2
+    old_dicts = {k: dict(b.cache.resident(k).batch.staged_dicts)
+                 for k in snap["entries"]}
     assert all(d for d in old_dicts.values())
     packed_before = [getattr(blk, "_device_dict_packed", None)
                      for blk in blocks]
@@ -507,19 +509,18 @@ def test_evicted_batch_restages_dictionaries():
 
     # evict the LRU group from HBM (blocklist churn) — the
     # host tier keeps the stacked arrays AND the packed dictionaries
-    with b._lock:
-        victim, old_entry = b._cache.popitem(last=False)
-        b._cache_total -= old_entry.nbytes
-    assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    victim = next(iter(b.cache.snapshot()["entries"]))
+    with b.cache.group_lock:
+        b.cache._remove_locked(victim)
+    check_budget(b.cache)
 
     pipeline._COMPILE_CACHE.clear()
     r2 = b.search(_jobs(blocks), req).response().SerializeToString()
     assert r2 == r1
     # the evicted group re-staged through the host tier with NEW device
     # dictionary arrays (one fresh H2D upload), the host packing reused
-    assert victim in b._cache
-    entry = b._cache[victim]
-    assert entry.batch.staged_dicts
+    entry = b.cache.resident(victim)
+    assert entry is not None and entry.batch.staged_dicts
     for fp, dd in entry.batch.staged_dicts.items():
         assert old_dicts[victim][fp] is not dd          # re-uploaded
         assert old_dicts[victim][fp].packed is dd.packed  # not re-packed
@@ -527,7 +528,7 @@ def test_evicted_batch_restages_dictionaries():
                     for blk in blocks]
     assert all(a is p for a, p in zip(packed_after, packed_before))
     # HBM accounting intact after evict + re-stage
-    assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    check_budget(b.cache)
 
 
 def test_batcher_concurrent_device_probe_coalesces_identically():

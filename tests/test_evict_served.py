@@ -19,11 +19,13 @@ import random
 import threading
 import time
 import urllib.parse
+from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
 
+from conftest import check_budget, settle
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 from tempo_tpu.search.batcher import BlockBatcher, ScanJob
@@ -131,20 +133,6 @@ def events(result):
     return obs.batch_cache_events.value(result=result)
 
 
-def settle(batcher, timeout=10.0):
-    """Look-aheads that no search came back for give their pins back
-    when they finish: wait for that, then return the pins still held."""
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        with batcher._lock:
-            held = sum(c.pins for c in batcher._cache.values())
-            staging = len(batcher._staging)
-        if not held and not staging:
-            return 0
-        time.sleep(0.02)
-    return held
-
-
 @pytest.mark.parametrize("callers", (3, 8))
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_served_answers_under_eviction_equal_the_reference(corpus, app,
@@ -187,19 +175,34 @@ def test_served_answers_under_eviction_equal_the_reference(corpus, app,
 
 @pytest.mark.parametrize("callers", (1, 2, 8))
 def test_the_cache_stands_over_budget_by_what_is_in_flight(corpus, app,
-                                                           callers):
+                                                           callers,
+                                                           monkeypatch):
     """Tenant-wide searches, `callers` at a time, over 12 groups against
     a budget of 2: the high water of the cache is at most the budget
     plus `pipeline_depth` + 1 groups for each caller (one caller: 2.7 of
     the tenant's 6 MB), never the tenant, which search-long pins held
     whole however few the callers (eight walk it together and read 7-8
     groups of the 12), and once they are done the cache is back under
-    its budget."""
+    its budget. One caller stands over the budget only while its
+    look-ahead's group is in beside the two it holds: its look-ahead's
+    put is made to land before the search goes on to the drain that
+    gives one of them back, as on an idle machine it does."""
+    from concurrent.futures import wait
+
     from chipbench.ops import search as op
     from tempo_tpu.api import HTTPApi
 
     api = HTTPApi(app, multitenancy=True)
     batcher = app.reader_db.batcher
+    submit = batcher._prefetcher.submit
+
+    def landed(fn, *args):
+        fut = submit(fn, *args)
+        wait([fut], 30)
+        return fut
+
+    if callers == 1:
+        monkeypatch.setattr(batcher._prefetcher, "submit", landed)
     requests = windowed(hunts(corpus, 5, variants=callers), 0, 24)
     for _ in range(2):
         missed = events("miss")
@@ -212,9 +215,10 @@ def test_the_cache_stands_over_budget_by_what_is_in_flight(corpus, app,
     # callers walk the tenant together: a group is staged for all of
     # them, not once for each (their own uncounted copies)
     assert events("miss") - missed <= 3 * (BLOCKS // 4)
-    with batcher._lock:
-        group = max(c.nbytes for c in batcher._cache.values())
-        total, peak = batcher._cache_total, batcher._cache_peak
+    cache = batcher.cache
+    snap = cache.snapshot()
+    group = max(n for n, _p, _m in snap["entries"].values())
+    total, peak = snap["hbm_bytes"], snap["hbm_peak_bytes"]
     tenant = BLOCKS // 4 * group
     allowance = callers * (batcher.pipeline_depth + 1) * group
     assert BUDGET < peak <= BUDGET + allowance
@@ -258,18 +262,19 @@ def test_a_group_staged_among_pinned_ones_keeps_its_place(corpus, app):
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
     (*_job_lists, groups), = app.reader_db._breq_jobs_cache.values()
-    taken = [batcher._staged(g, pin=True) for g in groups[:3]]
-    with batcher._lock:
-        keys = [tuple(j.key for j in g) for g in groups[:3]]
-        assert [batcher._cache.get(k) for k in keys] == taken
-        assert [c.pins for c in taken] == [1, 1, 1]
-        held = sum(c.nbytes for c in taken)
-        assert BUDGET < held <= batcher._cache_total
-        assert batcher._cache_peak >= held
-        batcher._unpin_locked(taken)
-        assert batcher._cache_total <= BUDGET
-        assert [c.pins for c in taken] == [0, 0, 0]
-        assert batcher._cache.get(keys[2]) is taken[2]   # the newest stays
+    cache = batcher.cache
+    taken = [cache.staged(g, pin=True) for g in groups[:3]]
+    keys = [tuple(j.key for j in g) for g in groups[:3]]
+    assert [cache.resident(k) for k in keys] == taken
+    assert [c.pins for c in taken] == [1, 1, 1]
+    held = sum(c.nbytes for c in taken)
+    assert BUDGET < held <= cache.snapshot()["hbm_bytes"]
+    assert cache.snapshot()["hbm_peak_bytes"] >= held
+    with cache.group_lock:
+        cache.unpin_locked(taken)
+    assert cache.snapshot()["hbm_bytes"] <= BUDGET
+    assert [c.pins for c in taken] == [0, 0, 0]
+    assert cache.resident(keys[2]) is taken[2]   # the newest stays
 
 
 def _jobs(n, rng):
@@ -331,8 +336,7 @@ def test_an_early_quit_gives_its_look_ahead_back(corpus, app):
         assert ok, why
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
-    with batcher._lock:
-        assert batcher._cache_total <= BUDGET
+    assert batcher.cache.snapshot()["hbm_bytes"] <= BUDGET
 
 
 def test_the_prepare_memo_outlives_the_hbm_copy(corpus, app):
@@ -358,13 +362,12 @@ def test_the_prepare_memo_outlives_the_hbm_copy(corpus, app):
     assert obs.prepare_memo.value(result="hit") - hit == BLOCKS // 4
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
-    with batcher._lock:
-        kept = [h.query_memo for h in batcher._host_cache.values()
-                if h.query_memo is not None]
-        assert kept and all("device_params" not in pre
-                            for memo in kept for pre in memo.values())
-        assert batcher._cache_total == sum(
-            c.nbytes for c in batcher._cache.values()) <= BUDGET
+    kept = [h.query_memo for h in batcher.cache.snapshot()["host"].values()
+            if h.query_memo is not None]
+    assert kept and all("device_params" not in pre
+                        for memo in kept for pre in memo.values())
+    check_budget(batcher.cache)
+    assert batcher.cache.snapshot()["hbm_bytes"] <= BUDGET
 
 
 def test_a_restage_says_what_it_moved(corpus, app):
@@ -487,9 +490,8 @@ def test_a_group_that_arrives_is_taken_before_one_to_be_staged(
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
     groups, gkeys = plan_of(app)
-    with batcher._lock:
-        assert set(batcher._cache) == set(gkeys[10:])
-    real, guest = batcher._staged, []
+    assert set(batcher.cache.snapshot()["entries"]) == set(gkeys[10:])
+    real, guest = batcher.cache.staged, []
 
     def staged(group, pin=False, parent=None):
         """The search's first put brings group 9 with it, pinned."""
@@ -498,13 +500,13 @@ def test_a_group_that_arrives_is_taken_before_one_to_be_staged(
             guest.append(real(groups[9], True))
         return entry
 
-    monkeypatch.setattr(batcher, "_staged", staged)
+    monkeypatch.setattr(batcher.cache, "staged", staged)
     missed = events("miss")
     a, walk = traced(api, r)
     ok, why = op.check(r, a, corpus["manifest"])
     assert ok, why
-    with batcher._lock:
-        batcher._unpin_locked(guest)
+    with batcher.cache.group_lock:
+        batcher.cache.unpin_locked(guest)
     order = [g for g, _p, _c in walk]
     assert sorted(order) == list(range(12))
     assert order == [10, 11, 0, 9] + list(range(1, 9))
@@ -527,11 +529,12 @@ def test_a_group_another_search_is_staging_is_joined(corpus, app,
     assert ask(api, flush)["status"] == 200       # group 0 is long gone
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
-    place, real = batcher.engine.place, batcher._staged
+    place, real = batcher.engine.place, batcher.cache.staged
     gate, waiting = threading.Event(), threading.Event()
 
     def staged(group, pin=False, parent=None):
-        if batcher._staging:        # the second search, about to wait
+        # the second search, about to wait
+        if batcher.cache.snapshot()["staging"]:
             waiting.set()
         return real(group, pin, parent)
 
@@ -539,13 +542,13 @@ def test_a_group_another_search_is_staging_is_joined(corpus, app,
         assert gate.wait(30)
         return place(host)
 
-    monkeypatch.setattr(batcher, "_staged", staged)
+    monkeypatch.setattr(batcher.cache, "staged", staged)
     monkeypatch.setattr(batcher.engine, "place", held)
     before, missed = picks(), events("miss")
     visits = events("hit") + missed
     with ThreadPoolExecutor(2) as pool:
         one = pool.submit(ask, api, first)
-        wait_until(lambda: batcher._staging)
+        wait_until(lambda: batcher.cache.snapshot()["staging"])
         two = pool.submit(ask, api, second)
         assert waiting.wait(30)
         gate.set()
@@ -577,11 +580,11 @@ def test_the_look_ahead_stages_only_what_nobody_holds_or_stages(
     batcher = app.reader_db.batcher
     assert settle(batcher) == 0
     groups, gkeys = plan_of(app)
-    place, real, submit = (batcher.engine.place, batcher._staged,
+    cache = batcher.cache
+    place, real, submit = (batcher.engine.place, cache.staged,
                            batcher._prefetcher.submit)
     gate, asked, guest = threading.Event(), [], []
-    with batcher._lock:
-        theirs = batcher._host_cache[gkeys[9]]
+    theirs = cache.snapshot()["host"][gkeys[9]]
 
     def held(host):
         if host is theirs:
@@ -595,25 +598,25 @@ def test_the_look_ahead_stages_only_what_nobody_holds_or_stages(
 
     def ahead(fn, group, *args):
         key = tuple(j.key for j in group)
-        with batcher._lock:
-            asked.append((gkeys.index(key), key in batcher._cache,
-                          key in batcher._staging,
-                          sum(c.pins for c in batcher._cache.values())))
+        pins = sum(p for _n, p, _m in cache.snapshot()["entries"].values())
+        with cache.group_lock:
+            asked.append((gkeys.index(key), cache.is_resident_locked(key),
+                          cache.is_staging_locked(key), pins))
         return submit(fn, group, *args)
 
     monkeypatch.setattr(batcher.engine, "place", held)
-    monkeypatch.setattr(batcher, "_staged", staged)
+    monkeypatch.setattr(cache, "staged", staged)
     monkeypatch.setattr(batcher._prefetcher, "submit", ahead)
     other = threading.Thread(
         target=lambda: guest.append(real(groups[9], True)), name="other")
     other.start()
-    wait_until(lambda: batcher._staging)
+    wait_until(lambda: cache.snapshot()["staging"])
     missed, before = events("miss"), picks()
     a, walk = traced(api, r)
     other.join(30)
     assert not other.is_alive()
-    with batcher._lock:
-        batcher._unpin_locked(guest)
+    with cache.group_lock:
+        cache.unpin_locked(guest)
     ok, why = op.check(r, a, corpus["manifest"])
     assert ok, why
     assert [g for g, _p, _c in walk] == [10, 11] + list(range(10))
@@ -749,3 +752,310 @@ def test_a_host_tier_entry_holds_each_column_once(layout, monkeypatch):
         getattr(b, n).nbytes for b in host.blocks for n in _STACKED)
     assert host.nbytes == host.cat_nbytes + own
     assert host.nbytes < sum(s.nbytes for s in source)
+
+
+# ---- PR 47: the budget's one rule. `search/group_cache.py` writes the
+# running totals in three functions; whatever moves bytes, the totals are
+# the sums over the resident entries ----
+
+GROUP_BYTES, DICT_BYTES, SPAN_BYTES = 1000, 100, 40
+
+
+class _Arr:
+    def __init__(self, nbytes):
+        self.nbytes = nbytes
+
+
+class _Masks:
+    """Stands in for `pipeline.MASK_BYTES`: this cache's masks alone."""
+
+    def __init__(self):
+        self.memo = 0
+
+    def add(self, holder, nbytes):
+        assert holder == "memo"
+        self.memo += nbytes
+
+
+class _Host:
+    def __init__(self):
+        self.nbytes = self.logical_nbytes = 3 * GROUP_BYTES
+        self.query_memo = None
+        self.query_cache = OrderedDict()
+
+
+class _Batch:
+    """What `engine.place` returns, as far as the cache reads it."""
+    nbytes = device_nbytes = GROUP_BYTES + DICT_BYTES + SPAN_BYTES
+    logical_nbytes = 2 * GROUP_BYTES
+    blocks = ()
+
+    def __init__(self):
+        self.staged_dicts = {"fp": _Arr(DICT_BYTES)}
+        self.span_device = {"span_trace": np.zeros(SPAN_BYTES, np.int8)}
+
+
+class _Engine:
+    n_shards = 2
+
+    def stage_host(self, pages):
+        return _Host()
+
+    def place(self, host):
+        return _Batch()
+
+
+def _group(i):
+    return [ScanJob(key=(f"blk-{i}", 0, 1), pages_fn=lambda: None, header={},
+                    n_pages=1, n_entries=1, geometry=(1, 1))]
+
+
+def _pre(mask=0):
+    return {"val_hits": _Arr(mask) if mask else None}
+
+
+@pytest.fixture
+def small(monkeypatch):
+    """A GroupCache over a stand-in engine, with a budget of three
+    groups, two of them staged; its masks counted apart from the
+    process's."""
+    from tempo_tpu.search import group_cache
+
+    masks = _Masks()
+    monkeypatch.setattr(group_cache, "MASK_BYTES", masks)
+    cache = group_cache.GroupCache(_Engine(), 3 * _Batch.nbytes + 500,
+                                   1 << 30, 2)
+    entries = [cache.staged(_group(i)) for i in range(2)]
+    return cache, entries, masks
+
+
+def _ev_stage(cache, entries):
+    cache.staged(_group(2))
+    return 3
+
+
+def _ev_restage_over_a_previous_entry(cache, entries):
+    from tempo_tpu.search.group_cache import _CachedBatch
+
+    gkey = next(iter(cache.snapshot()["entries"]))
+    cache.memo_put(gkey, entries[0], "p", _pre(mask=64))  # leaves with it
+    new = _CachedBatch(batch=_Batch(), nbytes=_Batch.nbytes + 7, logical=5)
+    with cache.group_lock:
+        cache._insert_locked(gkey, new)
+    assert cache.resident(gkey) is new
+    return 2
+
+
+def _ev_memo_params(cache, entries):
+    gkey = next(iter(cache.snapshot()["entries"]))
+    pre = _pre()
+    cache.memo_params(gkey, entries[0], pre, (_Arr(30), _Arr(12)))
+    cache.memo_params(gkey, entries[0], pre, (_Arr(999),))   # kept: once
+    # replicated on the mesh: every device holds the whole of each
+    assert pre["device_params_bytes"] == 84
+    assert entries[0].nbytes == _Batch.nbytes + 84
+    return 2
+
+
+def _ev_memo_put_with_a_mask(cache, entries):
+    gkey = next(iter(cache.snapshot()["entries"]))
+    cache.memo_put(gkey, entries[0], "p", _pre(mask=64))
+    assert cache.memo_get(entries[0], "p")["mask_bytes"] == 64
+    assert cache.memo_get(entries[0], "q") is None
+    assert entries[0].mask_bytes == 64
+    return 2
+
+
+def _ev_the_memos_lru_pop(cache, entries):
+    from tempo_tpu.search.group_cache import _QUERY_CACHE_MAX
+
+    gkey = next(iter(cache.snapshot()["entries"]))
+    first = _pre(mask=10)
+    cache.memo_put(gkey, entries[0], 0, first)
+    cache.memo_params(gkey, entries[0], first, (_Arr(3),))
+    for sig in range(1, _QUERY_CACHE_MAX + 1):
+        cache.memo_put(gkey, entries[0], sig, _pre(mask=1))
+    assert cache.memo_get(entries[0], 0) is None       # popped, refunded
+    assert entries[0].mask_bytes == _QUERY_CACHE_MAX
+    assert entries[0].nbytes == _Batch.nbytes + _QUERY_CACHE_MAX
+    return 2
+
+
+def _ev_the_host_routes_memo_charges_nothing(cache, entries):
+    from tempo_tpu.search.group_cache import _QUERY_CACHE_MAX
+
+    gkey, host = next(iter(cache.snapshot()["host"].items()))
+    for sig in range(_QUERY_CACHE_MAX + 2):
+        cache.memo_put(gkey, host, sig, _pre())
+    assert list(host.query_cache) == list(range(2, _QUERY_CACHE_MAX + 2))
+    assert cache.memo_get(host, 2)["mask_bytes"] == 0
+    assert next(reversed(host.query_cache)) == 2           # touched
+    assert host.nbytes == 3 * GROUP_BYTES
+    assert cache.snapshot()["host_bytes"] == 2 * host.nbytes
+    return 2
+
+
+def _ev_lru_eviction(cache, entries):
+    for i in range(2, 5):
+        cache.staged(_group(i))
+    assert [k[0][0] for k in cache.snapshot()["entries"]] == [
+        "blk-2", "blk-3", "blk-4"]
+    return 3
+
+
+def _ev_a_charge_that_pushes_over_budget_evicts(cache, entries):
+    cache.staged(_group(2))
+    gkey = tuple(j.key for j in _group(2))
+    cache.memo_put(gkey, cache.resident(gkey), "p", _pre(mask=600))
+    return 2
+
+
+def _ev_a_search_charges_an_entry_evicted_meanwhile(cache, entries):
+    gkey = next(iter(cache.snapshot()["entries"]))
+    with cache.group_lock:
+        cache._drop_hbm_locked(gkey)
+    before = cache.snapshot()["hbm_bytes"]
+    pre = _pre(mask=64)
+    cache.memo_put(gkey, entries[0], "p", pre)         # the guard: the
+    cache.memo_params(gkey, entries[0], pre, (_Arr(8),))   # entry alone
+    assert cache.snapshot()["hbm_bytes"] == before
+    assert entries[0].nbytes == _Batch.nbytes + 64 + 16
+    assert entries[0].mask_bytes == 64
+    return 1
+
+
+def _ev_invalidate(cache, entries):
+    cache.memo_put(next(iter(cache.snapshot()["entries"])), entries[0], "p",
+                   _pre(mask=64))
+    cache.invalidate({"blk-1"})
+    assert list(cache.snapshot()["host"]) == list(cache.snapshot()["entries"])
+    assert cache.snapshot()["host_bytes"] == 3 * GROUP_BYTES
+    return 1
+
+
+def _ev_a_rebalance_drop(cache, entries):
+    from tempo_tpu.search import ownership
+
+    ownership.configure(enabled=True, members="m0,m1", self_id="spectator",
+                        groups=32)
+    try:
+        assert cache.rebalance_ownership() == {"hbm_dropped": 2,
+                                               "hbm_deferred": 0}
+    finally:
+        ownership.OWNERSHIP.reset()
+    return 0
+
+
+def _ev_a_deferred_drop_at_unpin(cache, entries):
+    from tempo_tpu.search import ownership
+
+    held = cache.staged(_group(0), pin=True)
+    assert held is entries[0] and held.pins == 1
+    ownership.configure(enabled=True, members="m0,m1", self_id="spectator",
+                        groups=32)
+    try:
+        assert cache.rebalance_ownership() == {"hbm_dropped": 1,
+                                               "hbm_deferred": 1}
+        assert check_budget(cache) == {
+            tuple(j.key for j in _group(0)): (_Batch.nbytes, 1, 0)}
+        with cache.group_lock:
+            cache.unpin_locked((held,))
+    finally:
+        ownership.OWNERSHIP.reset()
+    return 0
+
+
+BYTE_EVENTS = [_ev_stage, _ev_restage_over_a_previous_entry, _ev_memo_params,
+               _ev_memo_put_with_a_mask, _ev_the_memos_lru_pop,
+               _ev_the_host_routes_memo_charges_nothing,
+               _ev_lru_eviction, _ev_a_charge_that_pushes_over_budget_evicts,
+               _ev_a_search_charges_an_entry_evicted_meanwhile,
+               _ev_invalidate, _ev_a_rebalance_drop,
+               _ev_a_deferred_drop_at_unpin]
+
+
+@pytest.mark.parametrize("event", BYTE_EVENTS,
+                         ids=lambda f: f.__name__[4:].replace("_", "-"))
+def test_whatever_moves_bytes_the_totals_are_the_residents_sums(small, event):
+    """After every event that moves bytes into or out of the HBM budget
+    the running totals (bytes, logical bytes, dictionaries, span
+    columns, the memo's masks) are the sums over the entries that are
+    resident."""
+    cache, entries, masks = small
+    assert len(check_budget(cache)) == 2
+    left = event(cache, entries)
+    now = check_budget(cache)
+    assert len(now) == left
+    assert masks.memo == sum(m for _n, _p, m in now.values())
+    assert all(m <= n for n, _p, m in now.values())
+    assert cache.snapshot()["hbm_bytes"] <= cache.cache_bytes
+
+
+TOTALS = {"_cache_total", "_cache_logical", "_probe_dict_total",
+          "_span_total", "_host_total", "_host_logical"}
+WRITERS = {"__init__", "_insert_locked", "_remove_locked", "charge_locked",
+           "_insert_host_locked", "_remove_host_locked", "charge_cpu_copies"}
+
+
+def _writes(tree):
+    """(function, what) of every assignment to a running total and
+    every `MASK_BYTES.add("memo", ...)` in a module."""
+    import ast
+
+    found = []
+    for fn in ast.walk(tree):
+        if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        for node in ast.walk(fn):
+            targets = []
+            if isinstance(node, ast.Assign):
+                targets = node.targets
+            elif isinstance(node, (ast.AugAssign, ast.AnnAssign)):
+                targets = [node.target]
+            for t in targets:
+                for leaf in ast.walk(t):
+                    if isinstance(leaf, ast.Attribute) and leaf.attr in TOTALS:
+                        found.append((fn.name, leaf.attr))
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "add"
+                    and getattr(node.func.value, "id", "") == "MASK_BYTES"
+                    and node.args
+                    and getattr(node.args[0], "value", None) == "memo"):
+                found.append((fn.name, "MASK_BYTES"))
+    return found
+
+
+def test_the_totals_are_written_in_the_cache_module_alone():
+    """No file under tempo_tpu/ but search/group_cache.py assigns to a
+    running total or moves the memo's mask bytes, that module only in
+    its insert / remove / charge functions, and the search loop names
+    no field of the cache."""
+    import ast
+    import pathlib
+
+    import tempo_tpu
+
+    root = pathlib.Path(tempo_tpu.__file__).parent
+    seen = 0
+    for path in sorted(root.rglob("*.py")):
+        tree = ast.parse(path.read_text())
+        writes = _writes(tree)
+        if path.name == "group_cache.py":
+            seen += 1
+            assert writes and {fn for fn, _ in writes} <= WRITERS, writes
+            assert {fn for fn, what in writes
+                    if what == "MASK_BYTES"} <= WRITERS - {"__init__"}
+            assert TOTALS <= {what for _fn, what in writes}
+        else:
+            assert not writes, (str(path), writes)
+        if path.name == "batcher.py":
+            seen += 1
+            loop = next(n for n in ast.walk(tree)
+                        if isinstance(n, ast.FunctionDef)
+                        and n.name == "_search_impl")
+            named = {n.attr for n in ast.walk(loop)
+                     if isinstance(n, ast.Attribute)}
+            assert not named & {"_cache", "_host_cache", "_staging",
+                                "_evict_hbm_locked", "_lock"} | TOTALS & named
+    assert seen == 2

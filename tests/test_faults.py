@@ -45,6 +45,8 @@ from tempo_tpu.search.columnar import ColumnarPages, PageGeometry
 
 import numpy as np
 
+from conftest import check_budget, drop_hbm
+
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
@@ -325,8 +327,7 @@ def test_h2d_hang_host_routes_group(tmp_path):
     db = _mkdb(tmp_path)
     req = _req()
     base = _canon(db.search("t", req).response())
-    db.batcher._cache.clear()          # force a re-stage
-    db.batcher._cache_total = 0
+    drop_hbm(db.batcher)               # force a re-stage
     robustness.BREAKER.reset()
     robustness.GUARD.timeout_s = 0.3
     with robustness.FAULTS.armed("h2d_delay", delay_s=5.0, count=1):
@@ -556,10 +557,7 @@ def test_poll_error_and_backend_read_error_surface(tmp_path):
     db2._headers.clear()
     db2._search_blocks.clear()
     db2._jobs_cache.clear()
-    db2.batcher._cache.clear()
-    db2.batcher._cache_total = 0
-    db2.batcher._host_cache.clear()
-    db2.batcher._host_total = 0
+    db2.batcher.cache.invalidate(set())
     with robustness.FAULTS.armed("backend_read_error", count=1), \
             pytest.raises(robustness.InjectedFault):
         db2.search("t", _req())
@@ -763,7 +761,7 @@ def test_chaos_wedged_owner_breaker_to_host_route(tmp_path,
                         groups=32)
     # block ids are uuid4: be the member that owns a staged group's
     # anchor, or the wedge has no owner to hit (a run in some dozens)
-    anchor = str(next(iter(db.batcher._cache))[0][0])
+    anchor = str(next(iter(db.batcher.cache.snapshot()["entries"]))[0][0])
     ownership.configure(self_id=ownership.OWNERSHIP.owner_of(anchor))
     robustness.BREAKER.reset()
     robustness.GUARD.timeout_s = 0.3
@@ -834,11 +832,10 @@ def test_chaos_rebalance_under_load_4way(tmp_path, _clean_ownership):
     assert not errors, errors[:1]
     # accounting survived the churn: totals never went negative and a
     # final unpinned sweep leaves a consistent cache
-    b = db.batcher
-    with b._lock:
-        b._run_deferred_evictions_locked()
-        assert b._cache_total >= 0
-        assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    cache = db.batcher.cache
+    with cache.group_lock:
+        cache._run_deferred_evictions_locked()
+    check_budget(cache)
 
 
 # --------------------------------------- replicated ownership + hedging
@@ -987,11 +984,10 @@ def test_chaos_promotion_flapping_residency_conserved(
     up = obs.hbm_replica_promotions.value(dir="up")
     down = obs.hbm_replica_promotions.value(dir="down")
     assert up >= 1 and down >= 1  # it really flapped
-    b = db.batcher
-    with b._lock:
-        b._run_deferred_evictions_locked()
-        assert b._cache_total >= 0
-        assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    cache = db.batcher.cache
+    with cache.group_lock:
+        cache._run_deferred_evictions_locked()
+    check_budget(cache)
     assert _canon(db.search("t", req).response()) == base
 
 

@@ -164,11 +164,7 @@ def test_a_cached_plan_does_not_outlive_its_cap(tmp_path, shards,
         db.batcher.engine.mesh = db.mesh
         db.batcher.engine.n_shards = shards
         # what the old layout staged is not this mesh's
-        with db.batcher._lock:
-            db.batcher._cache.clear()
-            db.batcher._cache_total = 0
-            db.batcher._host_cache.clear()
-            db.batcher._host_total = 0
+        db.batcher.cache.invalidate(set())
     assert _searched(db, "t1", req) == want
     gen2, groups2 = db.batcher._plan_cache["t1"]
     assert gen2[-1] == 2 * shards
@@ -213,8 +209,7 @@ def test_prewarm_plans_after_the_mesh_is_resolved(tmp_path):
     staged = db.prewarm(["t1"], background=False)
     assert db.batcher.engine.n_shards == n
     assert db.batcher.group_cap() == 2 * n
-    with db.batcher._lock:
-        keys = list(db.batcher._cache)
+    keys = list(db.batcher.cache.snapshot()["entries"])
     # (12 one-page blocks: 6 groups or more at one device's cap of 2)
     assert staged == len(keys) <= 3 and sum(map(len, keys)) == 12
     assert max(map(len, keys)) > 2

@@ -20,6 +20,7 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import check_budget
 from tempo_tpu import tempopb
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
@@ -188,12 +189,14 @@ def _wide_needle(blocks) -> str:
 def test_group_on_both_sides_of_the_floor_and_mixed_launches(monkeypatch):
     """A group with blocks on both sides of the floor, asked range and
     mask predicates at once: every answer is the host-only batcher's,
-    no launch mixes the kinds (ranges, ranges wider than the batcher's
-    `_WIDE_RANGES`, masks), and a range member's launch is given no
-    `val_hits`."""
-    from tempo_tpu.search import batcher as batcher_mod
+    no launch mixes the kinds (ranges, ranges wider than the
+    coalescer's `WIDE_RANGES`, masks), and a range member's launch is
+    given no `val_hits`. Counted from the launches of ITS batcher: a
+    process-wide counter also holds what an earlier test's abandoned
+    dispatch worker launches once its injected hang is slept out."""
+    from tempo_tpu.search import coalescer as coalescer_mod
 
-    monkeypatch.setattr(batcher_mod, "WIDE_RANGES", 1)
+    monkeypatch.setattr(coalescer_mod, "WIDE_RANGES", 1)
     blocks = _group()
     jobs = _jobs(blocks)
     wide = [{"customer.id": _wide_needle(blocks)}]
@@ -204,7 +207,8 @@ def test_group_on_both_sides_of_the_floor_and_mixed_launches(monkeypatch):
     pipeline._COMPILE_CACHE.clear()
     solo = BlockBatcher(coalesce_max_queries=1, device_probe_min_vals=FLOOR)
     assert _answers(solo, jobs, reqs) == want
-    batch = next(iter(solo._cache.values())).batch
+    (gkey, *_rest) = solo.cache.snapshot()["entries"]
+    batch = solo.cache.resident(gkey).batch
     assert len(batch.staged_dicts) == 3        # the small block: host
     kinds = {}
     for t in RANGE_REQS + MASK_REQS:
@@ -229,8 +233,6 @@ def test_group_on_both_sides_of_the_floor_and_mixed_launches(monkeypatch):
         return real(mode, batch, q, place, **kw)
 
     co.engine._launch = spy
-    range_before = obs.scan_membership.value(path="range")
-    mask_before = obs.scan_membership.value(path="mask")
     barrier = threading.Barrier(len(reqs))
     got = [None] * len(reqs)
 
@@ -252,10 +254,6 @@ def test_group_on_both_sides_of_the_floor_and_mixed_launches(monkeypatch):
     assert sum(n for _m, n, given, _, r in launches
                if not given and r > 1) == len(wide)
     assert sum(n for _m, n, given, *_ in launches if given) == len(MASK_REQS)
-    assert obs.scan_membership.value(path="range") - range_before == len(
-        RANGE_REQS + wide)
-    assert obs.scan_membership.value(path="mask") - mask_before == len(
-        MASK_REQS)
 
 
 @pytest.mark.parametrize("route", ["host_only", "breaker_open"])
@@ -328,12 +326,13 @@ def test_counters_gauges_and_span_attributes_say_what_happened():
     assert {p: obs.scan_membership.value(path=p) - members[p]
             for p in members} == {"range": 1, "mask": 3}
 
-    entry = next(iter(b._cache.values()))
+    (gkey,) = b.cache.snapshot()["entries"]
+    entry = b.cache.resident(gkey)
     stack = 3 * 1 * next(iter(entry.batch.staged_dicts.values())).v_pad
     assert entry.mask_bytes == 2 * stack        # two predicates' stacks
     assert obs.probe_mask_bytes.value(held_by="memo") - memo_before \
         == 2 * stack
-    assert b._cache_total == sum(e.nbytes for e in b._cache.values())
+    assert check_budget(b.cache)[gkey][2] == entry.mask_bytes
     assert entry.nbytes >= int(entry.batch.nbytes) + 2 * stack
     assert obs.probe_mask_peak_bytes.value() >= 2 * stack
 
@@ -357,8 +356,8 @@ def test_counters_gauges_and_span_attributes_say_what_happened():
                 and dict(s.attributes).get("mode") == "batched"]
     assert sorted(launched) == ["mask", "mask", "mask", "range"]
     # dropping the batch gives the memo's masks back
-    with b._lock:
-        b._drop_hbm_locked(next(iter(b._cache)))
+    with b.cache.group_lock:
+        b.cache._drop_hbm_locked(gkey)
     assert obs.probe_mask_bytes.value(held_by="memo") == memo_before
 
 

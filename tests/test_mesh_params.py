@@ -254,11 +254,11 @@ def test_placed_once_then_reused_and_charged_once_per_device(
                 ok, why = op.check(request, ask(api, request),
                                    corpus["manifest"])
                 assert ok, why
-            batcher = app.reader_db.batcher
-            with batcher._lock:
-                memo = [pre for c in batcher._cache.values()
-                        for pre in c.query_cache.values()
-                        if pre.get("device_params") is not None]
+            cache = app.reader_db.batcher.cache
+            memo = [pre for c in map(cache.resident,
+                                     cache.snapshot()["entries"])
+                    for pre in c.query_cache.values()
+                    if pre.get("device_params") is not None]
         finally:
             tracing.set_tracer(None)
             app.shutdown()

@@ -22,6 +22,7 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.search import ownership
 from tempo_tpu.search.ownership import OWNERSHIP, OwnershipMap
 
+from conftest import check_budget
 from test_faults import _canon, _mkdb, _req
 
 
@@ -209,8 +210,8 @@ def test_non_owner_stages_nothing(tmp_path):
     before_non = obs.hbm_owner_routed.value(route="non_owner_host")
     r = db.search("t", req).response()
     assert r.metrics.inspected_blocks == 4
-    assert not db.batcher._cache  # nothing staged to HBM
-    assert db.batcher._host_cache  # served from the host tier
+    assert not db.batcher.cache.snapshot()["entries"]  # nothing staged to HBM
+    assert db.batcher.cache.snapshot()["host"]  # served from the host tier
     assert obs.hbm_owner_routed.value(route="non_owner_host") > before_non
 
 
@@ -222,48 +223,56 @@ def test_prewarm_skips_non_owned_groups(tmp_path):
     ownership.configure(enabled=True, members="m0,m1",
                         self_id="spectator", groups=32)
     assert db.batcher.prewarm(groups, warm_compile=False) == 0
-    assert not db.batcher._cache
+    assert not db.batcher.cache.snapshot()["entries"]
     OWNERSHIP.self_id = "m0"
     owned = [g for g in groups
              if OWNERSHIP.owns_group(tuple(j.key for j in g))]
     staged = db.batcher.prewarm(groups, warm_compile=False)
     assert staged == len(owned)
-    assert len(db.batcher._cache) == len(owned)
+    assert len(db.batcher.cache.snapshot()["entries"]) == len(owned)
 
 
 # ------------------------------------------- rebalance + eviction shape
+
+
+def _deferred(cache) -> set:
+    """Anchor blocks of the resident groups a deferred eviction waits
+    on, as /debug/ownership shows them."""
+    return {r["anchor_block"] for r in cache.ownership_residency()
+            if r["deferred_evict"]}
 
 
 def test_rebalance_drops_unowned_defers_pinned(tmp_path):
     db = _mkdb(tmp_path, n_blocks=4, search_max_batch_pages=8)
     req = _req(limit=10_000)
     db.search("t", req)  # stage everything (ownership off)
-    b = db.batcher
-    assert b._cache
+    b = db.batcher.cache
+    keys = list(b.snapshot()["entries"])
+    assert keys
     ownership.configure(enabled=True, members="m0,m1",
                         self_id="spectator", groups=32)
     # pin one batch (an in-flight search), leave the rest unpinned
-    with b._lock:
-        keys = list(b._cache)
-        pinned_key = keys[0]
-        b._cache[pinned_key].pins += 1
+    pinned_key = keys[0]
+    held = b.resident(pinned_key)
+    with b.group_lock:
+        held.pins += 1
     out = b.rebalance_ownership()
     assert out["hbm_dropped"] == len(keys) - 1
     assert out["hbm_deferred"] == 1
-    assert set(b._cache) == {pinned_key}
-    assert b._cache_total == b._cache[pinned_key].nbytes
+    assert set(b.snapshot()["entries"]) == {pinned_key}
+    assert b.snapshot()["hbm_bytes"] == held.nbytes
+    assert _deferred(b) == {str(pinned_key[0][0])}
     # unpin: the deferred eviction runs exactly once
-    with b._lock:
-        b._cache[pinned_key].pins -= 1
+    with b.group_lock:
+        held.pins -= 1
         b._run_deferred_evictions_locked()
-    assert not b._cache and b._cache_total == 0
-    assert not b._evict_deferred
+    assert not b.snapshot()["entries"] and b.snapshot()["hbm_bytes"] == 0
     # idempotent: a second sweep cannot double-subtract (the
     # negative-bytes regression shape)
-    with b._lock:
+    with b.group_lock:
         b._run_deferred_evictions_locked()
         b._evict_hbm_locked()
-    assert b._cache_total == 0
+    assert b.snapshot()["hbm_bytes"] == 0
 
 
 def test_deferred_eviction_stale_marker_never_double_evicts(tmp_path):
@@ -275,31 +284,31 @@ def test_deferred_eviction_stale_marker_never_double_evicts(tmp_path):
     db = _mkdb(tmp_path, n_blocks=4, search_max_batch_pages=8)
     req = _req(limit=10_000)
     db.search("t", req)
-    b = db.batcher
+    b = db.batcher.cache
     ownership.configure(enabled=True, members="m0,m1",
                         self_id="spectator", groups=32)
-    with b._lock:
-        gkey = next(iter(b._cache))
-        entry = b._cache[gkey]
+    gkey = next(iter(b.snapshot()["entries"]))
+    entry = b.resident(gkey)
+    with b.group_lock:
         entry.pins += 1
     b.rebalance_ownership()
-    assert gkey in b._evict_deferred
+    assert _deferred(b) == {str(gkey[0][0])}
     # unpin, then an LRU eviction claims the batch BEFORE the sweep
-    with b._lock:
+    with b.group_lock:
         entry.pins -= 1
         b._drop_hbm_locked(gkey)
-        total_after_lru = b._cache_total
+    total_after_lru = b.snapshot()["hbm_bytes"]
+    with b.group_lock:
         b._run_deferred_evictions_locked()  # stale marker: must no-op
-    assert b._cache_total == total_after_lru >= 0
-    assert gkey not in b._evict_deferred
+    assert b.snapshot()["hbm_bytes"] == total_after_lru >= 0
     # a fresh batch re-staged under the same key is NOT a victim of the
     # old marker either
     OWNERSHIP.reset()
     db.search("t", req)  # re-stages (ownership off)
-    with b._lock:
-        assert b._cache_total >= 0
+    with b.group_lock:
         b._run_deferred_evictions_locked()
-    assert b._cache_total >= 0
+    assert b.resident(gkey) not in (None, entry) and not _deferred(b)
+    check_budget(b)
 
 
 def test_tempodb_rebalance_prestages_new_groups(tmp_path):
@@ -308,7 +317,7 @@ def test_tempodb_rebalance_prestages_new_groups(tmp_path):
     ownership.configure(enabled=True, members="m0,m1", self_id="m0",
                         groups=32)
     db.search("t", req)  # warm the jobs cache + stage owned groups
-    owned_before = len(db.batcher._cache)
+    owned_before = len(db.batcher.cache.snapshot()["entries"])
     # m1 leaves: m0 now owns everything; prestage runs in background
     out = db.rebalance_ownership(["m0"], self_id="m0", prestage=True)
     assert out["generation"] == OWNERSHIP.generation
@@ -317,10 +326,11 @@ def test_tempodb_rebalance_prestages_new_groups(tmp_path):
     jobs = [db._scan_job(m) for m in db.blocklist.metas("t")]
     n_groups = len(db.batcher.plan(jobs))
     while __import__("time").time() < deadline:
-        if len(db.batcher._cache) >= n_groups:
+        if len(db.batcher.cache.snapshot()["entries"]) >= n_groups:
             break
         __import__("time").sleep(0.05)
-    assert len(db.batcher._cache) >= max(owned_before, n_groups)
+    assert len(db.batcher.cache.snapshot()["entries"]) >= max(owned_before,
+                                                              n_groups)
     assert _canon(db.search("t", req).response())  # still serves
 
 
@@ -399,7 +409,6 @@ def test_frontend_owner_death_degrades_to_peer(tmp_path):
     proxies[0].die = True  # member 0's querier is gone
     got = _canon(fe.search("t", req))
     assert got == base
-    assert not db.batcher._cache or True  # serving path decided per self
 
 
 def test_frontend_pool_resize_mid_flight_keeps_plan_mapping(tmp_path):

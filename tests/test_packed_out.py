@@ -23,11 +23,12 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 from tempo_tpu.parallel import make_mesh
 from tempo_tpu.search import batcher as batcher_mod
+from tempo_tpu.search import coalescer as coalescer_mod
 from tempo_tpu.search import engine as engine_mod
 from tempo_tpu.search import multiblock as multiblock_mod
 from tempo_tpu.search.analytics import ANALYTICS
-from tempo_tpu.search.batcher import (BlockBatcher, _FusedOut, _FusedSlice,
-                                      host_scan)
+from tempo_tpu.search.batcher import BlockBatcher, host_scan
+from tempo_tpu.search.coalescer import _FusedOut, _FusedSlice
 from tempo_tpu.search.engine import (fetch_scan_out, pack_out, resolve_top_k,
                                      start_fetch, unpack_out)
 from tempo_tpu.search.multiblock import (MultiBlockEngine, compile_multi,
@@ -125,8 +126,8 @@ def test_start_fetch_starts_one_copy_and_survives_a_refusal():
 
 @pytest.fixture
 def fetches(monkeypatch):
-    """Every `start_fetch` the batcher makes and every blocking fetch
-    the engine makes, with what each was given."""
+    """Every `start_fetch` the batcher and the coalescer make and every
+    blocking fetch the engine makes, with what each was given."""
     seen = {"copies": [], "fetches": []}
     real_start, real_fetch = batcher_mod.start_fetch, engine_mod.fetch_scan_out
 
@@ -139,6 +140,7 @@ def fetches(monkeypatch):
         return real_fetch(out, n_agg)
 
     monkeypatch.setattr(batcher_mod, "start_fetch", start)
+    monkeypatch.setattr(coalescer_mod, "start_fetch", start)
     monkeypatch.setattr(multiblock_mod, "fetch_scan_out", fetch)
     return seen
 
@@ -252,8 +254,9 @@ def test_an_agg_launch_rides_the_same_array(tmp_path, fetches):
         assert launches >= 1
         assert _moved(before)["batched"] == launches
         assert len(fetches["fetches"]) == launches
-        K = ANALYTICS.stage_for_batch(
-            next(iter(db.batcher._cache.values())).batch).n_keys
+        cache = db.batcher.cache
+        (gkey, *_rest) = cache.snapshot()["entries"]
+        K = ANALYTICS.stage_for_batch(cache.resident(gkey).batch).n_keys
         for out in fetches["fetches"]:
             assert out.ndim == 1 and out.shape[0] > 2 + K
             assert (out.shape[0] - 2 - K) % 2 == 0

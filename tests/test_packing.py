@@ -477,8 +477,8 @@ def test_tempodb_serving_byte_identical_and_accounted(tmp_path):
         db.blocklist.update("t", add=metas)
         resp = db.search("t", req).response()
         resp.metrics.device_seconds = 0.0
-        phys = db.batcher._cache_total
-        logical = db.batcher._cache_logical
+        phys = db.batcher.cache.snapshot()["hbm_bytes"]
+        logical = db.batcher.debug_stats()["hbm_cache"]["logical_bytes"]
         return resp.SerializeToString(), phys, logical
 
     off, phys_off, logical_off = serve("off", False)

@@ -30,7 +30,8 @@ from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability.profile import PROFILER
 from tempo_tpu.search import SearchResults
 from tempo_tpu.search import query_stats
-from tempo_tpu.search.batcher import BlockBatcher, QueryCoalescer
+from tempo_tpu.search.batcher import BlockBatcher
+from tempo_tpu.search.coalescer import QueryCoalescer
 from tempo_tpu.search.multiblock import MultiBlockEngine, compile_multi
 from tempo_tpu.search.engine import resolve_top_k
 

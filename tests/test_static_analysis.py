@@ -139,6 +139,29 @@ def test_lock_order_clean_on_real_package(real_pkg):
     assert LockOrderChecker().check(real_pkg) == []
 
 
+def test_the_batchers_holds_of_the_caches_lock_are_seen(real_pkg):
+    """`BlockBatcher` keeps no lock of its own: its plan cache, prune
+    memo and interest counts are guarded by the staged-group cache's
+    `group_lock`, named through `self.cache` or a local `cache`. The
+    analyzer resolves both to that one lock (by the attribute's unique
+    name), so the search loop's holds stay in the graph it checks."""
+    import ast
+
+    from tempo_tpu.analysis.locks import _Symbols
+
+    sym = _Symbols(real_pkg)
+    mod = next(m for m in real_pkg.modules
+               if m.dotted == "tempo_tpu.search.batcher")
+    want = "tempo_tpu.search.group_cache:GroupCache.group_lock"
+    for expr in ("self.cache.group_lock", "cache.group_lock"):
+        node = ast.parse(expr, mode="eval").body
+        assert sym.resolve_lock(mod, "BlockBatcher", node, {}) == want
+    holds = [n for n in ast.walk(mod.tree) if isinstance(n, ast.With)
+             and any(sym.resolve_lock(mod, "BlockBatcher", i.context_expr,
+                                      {}) == want for i in n.items)]
+    assert len(holds) >= 10
+
+
 # ------------------------------------------------- noop-contract
 
 

@@ -460,14 +460,6 @@ def test_no_launch_gathers_by_the_span_row_or_loops(name, gathers, scans):
     leaves = sum(op == "tag" for op in structural._plan_ops(st.plan))
     assert leaves >= (name != "client-no-error-parent")
     assert _span_axis_gathers(jaxpr, S // SPAN_TILE) == 3 * leaves
-    # the host's reckoning of the same launch, and the counter's
-    assert structural.leaf_lookup_rows(
-        st.plan, st.device_tables(), batch.span_device) \
-        == 3 * leaves * (S // SPAN_TILE)
-    before = obs.structural_leaf_lookup_rows.value()
-    eng.scan(batch, mq)
-    assert obs.structural_leaf_lookup_rows.value() - before \
-        == 3 * leaves * (S // SPAN_TILE)
 
 
 def test_a_bucket_programs_desc_arm_is_a_running_max_a_slot():
@@ -501,9 +493,8 @@ def test_a_bucket_programs_desc_arm_is_a_running_max_a_slot():
     assert _span_axis_gathers(jaxpr, S) == n_slots
 
 
-def test_a_launch_says_its_tile_and_its_lookup_rows(probe_masks):
-    """`span_tile` and `leaf_lookup_rows` beside `span_rows` on a
-    structural launch: over the span rows, lookups / SPAN_TILE."""
+def test_a_launch_says_its_tile(probe_masks):
+    """`span_tile` beside `span_rows` on a structural launch."""
     from tempo_tpu.observability import profile
 
     blocks, _entries = _three_dictionaries()
@@ -514,8 +505,6 @@ def test_a_launch_says_its_tile_and_its_lookup_rows(probe_masks):
     attrs = rec["attrs"]
     rows = int(batch.span_device["span_trace"].shape[0])
     assert attrs["span_rows"] == rows and attrs["span_tile"] == SPAN_TILE
-    # two leaves of three lookups, and the tiles' groups for the mask
-    assert attrs["leaf_lookup_rows"] * SPAN_TILE == 7 * rows
 
 
 def test_pad_rows_between_blocks_count_as_pad():

@@ -205,7 +205,7 @@ WAITS = {"http.request", "frontend.queue_wait", "coalescer.wait",
 @pytest.mark.parametrize("flush", ["inline", "another_thread"])
 def test_served_search_says_where_its_threads_were_on_a_core(
         served_app, clock, monkeypatch, flush):
-    from tempo_tpu.search.batcher import QueryCoalescer
+    from tempo_tpu.search.coalescer import QueryCoalescer
 
     get, _app = served_app
     get("/api/search?tags=service.name%3Dfront&limit=5")  # compile
