@@ -645,6 +645,25 @@ search_analytics_staged_bytes = Gauge(
     "tempo_search_analytics_staged_bytes",
     "bytes staged to the device for the most recent analytics "
     "micro-batch (pow2-tier padded row columns)")
+# the query side: ?agg= reductions fused onto the scan launches
+agg_launches = Counter(
+    "tempo_search_agg_launches_total",
+    "scan launches that carried the ?agg= reduction, by the dispatch "
+    "modes of tempo_search_scan_dispatches_total (a fused launch is one "
+    "however many members reduce in it)")
+agg_key_rows = Counter(
+    "tempo_search_agg_key_rows_total",
+    "key rows the ?agg= reductions took in: members x the staged "
+    "group's entries, pad pages included (what the launches sorted)")
+agg_staged_bytes = Gauge(
+    "tempo_search_agg_staged_bytes",
+    "HBM held by the ?agg= key columns of the resident groups; part of "
+    "tempo_search_hbm_cache_bytes")
+agg_stage_seconds = Histogram(
+    "tempo_search_agg_stage_seconds",
+    "one staged group's ?agg= key column: its build on the host and "
+    "its put on the device, fenced",
+    buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0))
 # ---- owner-routed HBM (search/ownership.py) ----
 hbm_owner_generation = Gauge(
     "tempo_search_hbm_owner_generation",

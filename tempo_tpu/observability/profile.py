@@ -477,7 +477,7 @@ class DispatchProfiler:
                 for key in ("topk", "shards", "pages_per_shard", "params",
                             "membership", "compare", "blocks",
                             "blocks_bucket", "rel", "join_scans",
-                            "span_rows", "span_tile"):
+                            "span_rows", "span_tile", "agg_keys"):
                     if key in rec.attrs:
                         span.set_attribute(key, rec.attrs[key])
             span.end(end_ns, cpu1)

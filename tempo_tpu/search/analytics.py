@@ -644,9 +644,11 @@ class AnalyticsEngine:
     # query side
 
     def stage_for_batch(self, batch) -> AggStage:
-        """Memoized per-batch staging of the composite-key column
-        (BlockBatch or HostBatch — both carry .blocks; the page count
-        comes from the staged arrays so pads line up)."""
+        """Memoized per-batch staging of the composite-key column for
+        the host route's HostBatch (a resident group's column is part of
+        its cache entry: GroupCache.agg_staged); a BlockBatch works too
+        — both carry .blocks, and the page count comes from the staged
+        arrays so pads line up."""
         st = getattr(batch, "_agg_stage", None)
         if st is None:
             d = getattr(batch, "device", None) or getattr(

@@ -631,7 +631,11 @@ class BlockBatcher:
             for m in self.engine.results(cached.batch, mq, scores, idx):
                 results.add(m)
             if agg_counts:
-                results.add_agg(mq.agg_stage.decode(agg_counts[0]))
+                # written by aggregating searches only (a flat search's
+                # self-trace has no room for a new span name)
+                with tracing.start_span("analytics.decode",
+                                        parent=dspan.context, group=gi):
+                    results.add_agg(mq.agg_stage.decode(agg_counts[0]))
 
         def _skip_reason_counts(skip, reasons) -> dict:
             """reason -> count for the skipped blocks: the header prune
@@ -1108,11 +1112,20 @@ class BlockBatcher:
                     block_group=pre.get("block_group"),
                     structural=pre.get("structural"))
                 if want_agg:
-                    # memoized per batch: repeat ?agg= queries over a
-                    # resident batch pay one attribute read, and every
-                    # route (direct, coalesced, host resubmit) decodes
-                    # against the same service table
-                    mq.agg_stage = ANALYTICS.stage_for_batch(cached.batch)
+                    # the group's key column, part of its cache entry:
+                    # built, put and charged once (one flight), so
+                    # repeat ?agg= queries over a resident group pay a
+                    # lock and an attribute read, and a fused launch's
+                    # members share one staged column
+                    try:
+                        mq.agg_stage = cache.agg_staged(gkey, cached)
+                    except robustness.DeviceFault:
+                        # the column's put hit the wedged device (fault
+                        # booked): this group answers on the host route
+                        results.metrics.skipped_blocks += pre["skipped"]
+                        release(cached)
+                        host_route(gi, book_skips=False)
+                        continue
                 if qs is not None and pre.get("structural") is not None:
                     # explain plan registration: node cost weights merge
                     # across this query's groups; measured device time

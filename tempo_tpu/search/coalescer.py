@@ -225,11 +225,22 @@ class QueryCoalescer:
                 return fut
             key = skey
         if getattr(mq, "agg_stage", None) is not None:
-            # ?agg= members group apart from plain peers: the agg static
-            # changes the fused kernel's jit key, and a mixed group
-            # would make the no-agg hot path's compiled shape depend on
-            # whichever member happened to join the window
-            key = key + ("agg",)
+            # a ?agg= member launches solo, NOW: the reduction sorts the
+            # group's whole key column a member, a fused launch sorts
+            # one column a member all the same (vmap) and the batched
+            # sort is the slower (4,096 pages on a v5e: 9.0 ms solo;
+            # fused 54 / 63 / 107 ms at 2 / 4 / 8 members,
+            # scripts/red_bench.py, PR 48), and every fused (Q, T, R)
+            # is one more program of 12-16 s of cold compile that a
+            # window meets before any warm-up did. Solo, a search's
+            # programs are the ones its first launch compiled
+            with self._lock:
+                self._gen += 1
+                grp = _PendingCoalesce(batch, self._gen)
+            grp.items.append((mq, top_k, fut, tracing.now_ns(),
+                              query_stats.current(), parent))
+            self._run(grp)
+            return fut
         if mq.val_hits is not None:
             # a member that brings a hit mask groups apart from those
             # that bring ranges only: one mask in a fused launch gives

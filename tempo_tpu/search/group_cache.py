@@ -9,7 +9,10 @@ the evictions an ownership rebalance had to defer.
 Every byte enters or leaves the HBM budget through `_insert_locked`,
 `_remove_locked` (whole entries) or `charge_locked` (what a search adds
 to an entry that may have been evicted meanwhile): the running totals
-are written there and nowhere else (`tests/test_evict_served.py`).
+are written there and nowhere else (`tests/test_evict_served.py`). The
+`?agg=` key column of a group (`agg_staged`) is such an addition: built
+and put by the group's first aggregating search, under one flight, and
+given back by the group's eviction.
 
 One lock, `group_lock`, guards all of it, and the batcher's plan cache,
 prune memo and interest counts with it: the search loop decides under
@@ -21,6 +24,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 
@@ -28,6 +32,7 @@ from tempo_tpu import robustness
 from tempo_tpu.observability import metrics as obs
 from tempo_tpu.observability import tracing
 
+from .analytics import build_agg_stage
 from .ownership import OWNERSHIP
 from .pipeline import MASK_BYTES
 from .structural import span_device_bytes
@@ -60,6 +65,11 @@ class _CachedBatch:
     # device hit masks ([G, T, Vmax] stacks) the prepare memo pins, part
     # of `nbytes` and published as probe_mask_bytes{held_by="memo"}
     mask_bytes: int = 0
+    # the ?agg= key column (analytics.AggStage) once an aggregating
+    # search has staged it (`GroupCache.agg_staged`), and its HBM, part
+    # of `nbytes` and published as tempo_search_agg_staged_bytes
+    agg_stage: object = None
+    agg_bytes: int = 0
 
 
 _QUERY_CACHE_MAX = 32
@@ -106,6 +116,7 @@ class GroupCache:
         self._cache_peak = 0        # high water of _cache_total, as published
         self._probe_dict_total = 0  # staged-dict bytes across _cache
         self._span_total = 0        # span-column bytes across _cache
+        self._agg_total = 0         # ?agg= key-column bytes across _cache
         # logical (unpacked-layout) bytes across both tiers — the other
         # half of the packed-residency accounting split: budgets charge
         # PHYSICAL bytes (that is why packing fits more blocks), the
@@ -139,6 +150,7 @@ class GroupCache:
         self._cache_logical += entry.logical
         self._probe_dict_total += _dict_bytes(entry.batch)
         self._span_total += _span_bytes(entry.batch)
+        self._agg_total += entry.agg_bytes
         MASK_BYTES.add("memo", entry.mask_bytes)
 
     def _remove_locked(self, gkey: tuple) -> _CachedBatch | None:
@@ -150,27 +162,31 @@ class GroupCache:
             self._cache_logical -= old.logical
             self._probe_dict_total -= _dict_bytes(old.batch)
             self._span_total -= _span_bytes(old.batch)
+            self._agg_total -= old.agg_bytes
             MASK_BYTES.add("memo", -old.mask_bytes)
         return old
 
     def charge_locked(self, gkey: tuple, entry: _CachedBatch, *,
-                      params: int = 0, mask: int = 0) -> None:
+                      params: int = 0, mask: int = 0, agg: int = 0) -> None:
         """A search adds device memory to `entry` (a predicate's
-        uploaded tables, a hit mask the memo keeps) or takes it back
+        uploaded tables, a hit mask the memo keeps, the group's ?agg=
+        key column) or takes it back
         (the memo dropped the predicate). The entry always carries the
         change, so its eviction gives back what it holds; the shared
         totals only where it is still the group's resident entry — an
         eviction meanwhile removed `entry.nbytes` wholesale, and
         adjusting again would drift the budget by memory no eviction
         can reclaim. What a charge pushes over budget goes now."""
-        if not (params or mask):
+        if not (params or mask or agg):
             return
-        entry.nbytes += params + mask
+        entry.nbytes += params + mask + agg
         entry.mask_bytes += mask
+        entry.agg_bytes += agg
         if self._cache.get(gkey) is entry:
-            self._cache_total += params + mask
+            self._cache_total += params + mask + agg
+            self._agg_total += agg
             MASK_BYTES.add("memo", mask)
-            if params + mask > 0:
+            if params + mask + agg > 0:
                 self._evict_hbm_locked()
 
     def _insert_host_locked(self, gkey: tuple, host) -> None:
@@ -226,6 +242,7 @@ class GroupCache:
         obs.host_cache_bytes.set(self._host_total)
         obs.probe_dict_bytes.set(self._probe_dict_total)
         obs.structural_span_bytes.set(self._span_total)
+        obs.agg_staged_bytes.set(self._agg_total)
         obs.hbm_logical_bytes.set(self._cache_logical)
         obs.host_logical_bytes.set(self._host_logical)
 
@@ -472,6 +489,41 @@ class GroupCache:
             return entry
         finally:
             self._stage_done(key, ev)
+
+    def agg_staged(self, gkey: tuple, entry: _CachedBatch):
+        """The ?agg= key column of `entry`'s group (analytics.AggStage),
+        on the device: built and put once an entry by the group's first
+        aggregating search, one flight (a second first search waits for
+        the first's), and charged to the entry when it is there, so the
+        budget and tempo_search_hbm_cache_bytes hold it from then on and
+        the group's eviction gives it back. Charged here and not when
+        the group is staged: a group nobody aggregates over pays no HBM
+        and no build for the gate being on. The caller holds a pin on
+        `entry`; the put is watchdog-bounded like the group's own."""
+        ev_key = ("agg",) + gkey
+        stage, ev = self._claim_stage(ev_key, lambda: entry.agg_stage)
+        if stage is not None:
+            return stage
+        try:
+            t0 = time.perf_counter()
+            batch = entry.batch
+            with tracing.start_span("analytics.stage") as span:
+                stage = build_agg_stage(
+                    batch.blocks, int(batch.device["entry_valid"].shape[0]),
+                    batch.blocks[0].geometry.entries_per_page)
+                nbytes = int(stage.host.nbytes)
+                robustness.GUARD.run(
+                    "h2d", lambda: self.engine.place_agg(stage))
+                # the launches read the device's copy from here on
+                stage.host = None
+                span.set_attributes(bytes=nbytes, blocks=len(batch.blocks))
+            obs.agg_stage_seconds.observe(time.perf_counter() - t0)
+            with self.group_lock:
+                entry.agg_stage = stage
+                self.charge_locked(gkey, entry, agg=nbytes)
+            return stage
+        finally:
+            self._stage_done(ev_key, ev)
 
     def _load_host(self, key: tuple, group: list):
         """Host-tier staging (IO + decompress + stack, NO device put):

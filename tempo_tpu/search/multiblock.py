@@ -1414,6 +1414,12 @@ class MultiBlockEngine:
         return place_batch(host, sharding=self._page_sharding,
                            mesh=self.mesh)
 
+    def place_agg(self, stage):
+        """A group's ?agg= key column (analytics.AggStage) where this
+        engine's launches read their page arrays, fenced as `place` is:
+        its caller times the put."""
+        return jax.block_until_ready(stage.device(self._page_sharding))
+
     def _place_params(self, tables: tuple) -> tuple:
         """A launch's per-query tables (None entries stay None) as
         device arrays where its kernel reads them: the default device,
@@ -1494,9 +1500,9 @@ class MultiBlockEngine:
         the launch's one device array, a row a member (engine.pack_out:
         [Q, 2 + 2k (+ K)]). `top_k` is the GROUP k — max over the
         coalesced requests' resolved k, so every member's limit is
-        covered. ?agg= members fuse apart from plain ones
-        (QueryCoalescer's group key), so every row of a group carries
-        the counts or none does."""
+        covered. Where a member asks for the ?agg= counts every row of
+        the group carries them (stack_queries); the served path launches
+        ?agg= members solo (QueryCoalescer.submit says why)."""
         def place():
             # the stacked tables of THIS fused launch, one host buffer:
             # one transfer to where the launch reads them. A member's
@@ -1605,6 +1611,13 @@ class MultiBlockEngine:
                     obs.launch_table_rows.inc(members * (bucket - blocks),
                                               kind="pad")
                     rec.set(blocks_bucket=bucket)
+                if agg is not None:
+                    # a launch that reduces alone says over how many
+                    # keys; its key rows are the staged group's entries,
+                    # pad pages included, once a member
+                    obs.agg_launches.inc(mode=mode)
+                    obs.agg_key_rows.inc(members * int(entry_agg.size))
+                    rec.set(agg_keys=agg)
                 if span_cols is not None:
                     # a structural launch alone says how it joins and
                     # over how many span rows (pad rows included): no

@@ -10,6 +10,7 @@ skippedBlocks)."""
 from __future__ import annotations
 
 from tempo_tpu import tempopb
+from tempo_tpu.observability import tracing
 
 
 class SearchResults:
@@ -103,11 +104,13 @@ class SearchResults:
 
             from .analytics import merge_agg
 
-            try:
-                self.agg = merge_agg(self.agg,
-                                     json.loads(resp.metrics.agg_json))
-            except ValueError:
-                pass  # a malformed part never fails a merge
+            # aggregating searches only: no flat search writes the span
+            with tracing.start_span("results.merge_agg"):
+                try:
+                    self.agg = merge_agg(
+                        self.agg, json.loads(resp.metrics.agg_json))
+                except ValueError:
+                    pass  # a malformed part never fails a merge
 
     @property
     def n_results(self) -> int:

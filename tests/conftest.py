@@ -165,6 +165,7 @@ def check_budget(cache):
         assert cache._span_total == sum(
             group_cache._span_bytes(e.batch) for e in held)
         assert cache._cache_logical == sum(e.logical for e in held)
+        assert cache._agg_total == sum(e.agg_bytes for e in held)
     return entries
 
 

@@ -509,7 +509,7 @@ def test_agg_serving_path_and_host_route(tmp_path):
 
 def test_agg_concurrent_queries_match_serial(tmp_path):
     """Concurrent agg + non-agg queries through the coalescer: agg
-    members group apart, every answer byte-identical to serial."""
+    members launch solo, every answer byte-identical to serial."""
     entries = _corpus(43, n=100)
     db = _mkdb(tmp_path, entries, search_coalesce_window_s=0.05)
     reqs = [_mk_req({"env": "prod"}, limit=1000),

@@ -924,6 +924,26 @@ def _ev_a_search_charges_an_entry_evicted_meanwhile(cache, entries):
     return 1
 
 
+def _ev_a_key_column_is_charged_and_leaves_with_its_group(cache, entries):
+    """The ?agg= key column (`GroupCache.agg_staged` charges it so): in
+    the total while its group is resident, gone with the group, and an
+    entry evicted meanwhile carries it alone."""
+    first, second = cache.snapshot()["entries"]
+    before = cache.snapshot()["hbm_bytes"]
+    with cache.group_lock:
+        cache.charge_locked(first, entries[0], agg=96)
+        assert cache._agg_total == 96
+    assert cache.snapshot()["hbm_bytes"] == before + 96
+    with cache.group_lock:
+        cache._drop_hbm_locked(first)
+        assert cache._agg_total == 0
+        cache.charge_locked(first, entries[0], agg=96)   # evicted: no total
+        assert cache._agg_total == 0
+    assert entries[0].agg_bytes == 192
+    assert cache.snapshot()["hbm_bytes"] == before - _Batch.nbytes
+    return 1
+
+
 def _ev_invalidate(cache, entries):
     cache.memo_put(next(iter(cache.snapshot()["entries"])), entries[0], "p",
                    _pre(mask=64))
@@ -970,6 +990,7 @@ BYTE_EVENTS = [_ev_stage, _ev_restage_over_a_previous_entry, _ev_memo_params,
                _ev_the_host_routes_memo_charges_nothing,
                _ev_lru_eviction, _ev_a_charge_that_pushes_over_budget_evicts,
                _ev_a_search_charges_an_entry_evicted_meanwhile,
+               _ev_a_key_column_is_charged_and_leaves_with_its_group,
                _ev_invalidate, _ev_a_rebalance_drop,
                _ev_a_deferred_drop_at_unpin]
 
@@ -992,7 +1013,7 @@ def test_whatever_moves_bytes_the_totals_are_the_residents_sums(small, event):
 
 
 TOTALS = {"_cache_total", "_cache_logical", "_probe_dict_total",
-          "_span_total", "_host_total", "_host_logical"}
+          "_span_total", "_agg_total", "_host_total", "_host_logical"}
 WRITERS = {"__init__", "_insert_locked", "_remove_locked", "charge_locked",
            "_insert_host_locked", "_remove_host_locked", "charge_cpu_copies"}
 

@@ -16,7 +16,10 @@ of the term loop move nothing the loop reads. And the one packed output
 (PR 41) leaves the scan in front of it op for op what it was when it
 returned four arrays: one more small fusion, no other pass. And a
 structural launch that joins by ancestor (PR 46) is running maxes over
-the span axis at the cell's size: no loop, no lookup a span row.
+the span axis at the cell's size: no loop, no lookup a span row. And
+the launch that reduces (`?agg=red`, the cell `red16.dashboard`) fits the
+chip at the cell's group, solo and at the eight members the coalescer
+fuses, with the kv columns read as they are staged.
 """
 
 import math
@@ -224,3 +227,39 @@ def test_a_join_by_ancestor_is_no_loop_and_no_lookup_a_span_row(
         == lookups
     assert ("reduce-window(" in text) and compiled.memory_analysis() \
         .temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("Q", [None, 8], ids=["solo", "fused-8"])
+def test_the_launch_that_reduces_fits_the_chip_at_the_cells_group(
+        Q, one_chip, no_compile_cache):
+    """`red16.dashboard`'s launch (a full group, 4,096 pages, the key
+    column [P, E] int32, K = 7,680) compiled for the v5e: the reduction
+    sorts the key column (`sort` ops, the names `sort_share.red` reads
+    from the chip's trace), the kv columns are not copied for it, and
+    the program's scratch stays what the scan's was: 1.2 GB at the
+    eight members a burst fuses, beside the 2.8 GB the cell's columns
+    and keys hold (nothing of a sort a member is kept across members)."""
+    import re
+
+    from tempo_tpu.search.engine import DEFAULT_TOP_K, resolve_top_k
+    from tempo_tpu.search.multiblock import _packed_slots, batch_scan_kernel
+
+    T, R, K = 1, 16, 256 * 15 * 2
+    S, cols = _group(one_chip, jnp.int16)
+    if Q is None:
+        tables, packed = (S((B, T), jnp.int32), S((B, T, R, 2), jnp.int32),
+                          None, *[S((), jnp.uint32)] * 4), None
+    else:
+        packed = (Q, B, T, R)
+        tables = (S((_packed_slots(packed)[-1][1],), jnp.int32),
+                  *[None] * 6)
+    compiled = batch_scan_kernel.lower(
+        *cols, *tables, None, None, None, None, None, S((P, E), jnp.int32),
+        n_terms=T, packed=packed, agg=K,
+        top_k=resolve_top_k(DEFAULT_TOP_K, 20)).compile()
+    text = compiled.as_text()
+    assert "copy(%kv_key" not in text and "copy(%kv_val" not in text
+    assert re.findall(r"%sort[.\d]* = ", text)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= (Q or 1) * 4 * (K + 2 + 2 * 128)
+    assert mem.temp_size_in_bytes < (3 << 29 if Q else 1 << 29)
